@@ -22,35 +22,17 @@ if int(_os.environ.get("PADDLE_TRAINERS_NUM", "1")) > 1 \
         and _os.environ.get("PADDLE_MASTER"):
     import jax as _jax
 
-    from .core.jax_compat import distributed_client_exists as _dce
-
-    if not _dce():  # idempotent: skip if a coordinator client exists
-        try:
-            _jax.distributed.initialize(
-                coordinator_address=_os.environ["PADDLE_MASTER"],
-                num_processes=int(_os.environ["PADDLE_TRAINERS_NUM"]),
-                process_id=int(_os.environ.get("PADDLE_TRAINER_ID", "0")))
-        except Exception as _e:  # pragma: no cover - env-specific
-            # Double-init — another entry point won the race; the
-            # coordinator client is up, which is all we need.  Matched by
-            # the exact known message forms ("distributed.initialize
-            # should only be called once." on 0.4.x, "already
-            # initialized" on newer jax), NOT by exception type (jaxlib's
-            # XlaRuntimeError subclasses RuntimeError) and not by a loose
-            # keyword — "address already in use" must NOT match.
-            #
-            # Anything else (unreachable coordinator, timeout) RE-RAISES:
-            # in a PADDLE_TRAINERS_NUM>1 env a worker that silently
-            # degraded to single-process would see process_index()==0 and
-            # impersonate rank 0 — training unsynchronized and clobbering
-            # the real rank 0's checkpoint shards.  Fail fast and let the
-            # launcher's restart path retry with a fresh coordinator.
-            # (Layout drift of jax-private internals is already absorbed
-            # by jax_compat.distributed_client_exists above.)
-            _msg = str(_e).lower()
-            if "only be called once" not in _msg \
-                    and "already initialized" not in _msg:
-                raise
+    # A failure here (unreachable coordinator, timeout) must propagate:
+    # in a PADDLE_TRAINERS_NUM>1 env a worker that silently degraded to
+    # single-process would see process_index()==0 and impersonate rank 0
+    # — training unsynchronized and clobbering the real rank 0's
+    # checkpoint shards.  Fail fast and let the launcher's restart path
+    # retry with a fresh coordinator.
+    if not _jax.distributed.is_initialized():
+        _jax.distributed.initialize(
+            coordinator_address=_os.environ["PADDLE_MASTER"],
+            num_processes=int(_os.environ["PADDLE_TRAINERS_NUM"]),
+            process_id=int(_os.environ.get("PADDLE_TRAINER_ID", "0")))
 
 from .core import dtype as _dtype_mod
 from .core.dtype import (

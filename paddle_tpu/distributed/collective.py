@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.jax_compat import shard_map
 from ..core.tensor import Tensor
 from ..core.dispatch import apply_op
 from . import mesh as mesh_mod
@@ -125,7 +124,7 @@ def _check_stacked(arr, g: Group, api: str):
 
 def _smap(g: Group, body, n_in: int = 1):
     specs = [P(Group.AXIS)] * n_in
-    return shard_map(body, mesh=g.mesh, in_specs=tuple(specs) if n_in > 1 else specs[0],
+    return jax.shard_map(body, mesh=g.mesh, in_specs=tuple(specs) if n_in > 1 else specs[0],
                      out_specs=P(Group.AXIS))
 
 

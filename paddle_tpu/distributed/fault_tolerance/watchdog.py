@@ -1,10 +1,9 @@
 """Step watchdog: turn a wedged step into a diagnosable restart.
 
-A hung collective (peer died, tunnel dropped, deadlocked host callback)
+A hung collective (peer died, link dropped, deadlocked host callback)
 blocks the training thread forever — the process looks alive to the
 launcher, so nothing relaunches it and the whole job wedges (reference:
-fleet elastic treats "no heartbeat" the same way; BENCH_r05 showed the
-in-miniature version as back-to-back probe timeouts with no recovery).
+fleet elastic treats "no heartbeat" the same way).
 
 The watchdog is a daemon thread fed a heartbeat at every step boundary.
 If no boundary is crossed within ``timeout`` seconds it:

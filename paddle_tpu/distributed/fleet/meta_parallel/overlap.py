@@ -67,7 +67,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ....core.dispatch import apply_op
-from ....core.jax_compat import shard_map
 from ... import mesh as mesh_mod
 from ...sharding_spec import (
     BATCH_AXES, MODEL_AXIS, SEQ_AXIS, batch_spec, _divisible, _filter_spec,
@@ -157,13 +156,13 @@ def _shapes_ok(m, chunks, sharded_dim, *placements):
 
 
 def _smap(m, body, in_specs, out_spec):
-    # check_rep=False: the stacked/reshaped all-gather assembly (column
+    # check_vma=False: the stacked/reshaped all-gather assembly (column
     # path) is not statically inferable as replicated; gradients are
     # exercised by the tier-1 parity suite
-    return shard_map(
+    return jax.shard_map(
         body, mesh=m,
         in_specs=tuple(_filter_spec(s, m) for s in in_specs),
-        out_specs=_filter_spec(out_spec, m), check_rep=False)
+        out_specs=_filter_spec(out_spec, m), check_vma=False)
 
 
 def _linear_vjp(chunked, cdt):
@@ -408,12 +407,12 @@ def parallel_cross_entropy(logits, label, chunks: int, ignore_index: int):
             return jnp.where(mask, lse - acc[..., 1:2], 0.0), lse
 
         def chunked(lg_):
-            return shard_map(
+            return jax.shard_map(
                 body, mesh=m,
                 in_specs=(_filter_spec(lg_spec, m), _filter_spec(lb_spec, m)),
                 out_specs=(_filter_spec(out_spec, m),
                            _filter_spec(out_spec, m)),
-                check_rep=False)(lg_, lb_a)
+                check_vma=False)(lg_, lb_a)
 
         # label is closed over (int, never differentiated); the saved
         # lse makes the backward collective-free: softmax - onehot,

@@ -41,7 +41,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ....core.jax_compat import shard_map
 from ....core import autograd
 from ....core import rng as rng_mod
 from ....core.dispatch import apply_op
@@ -63,22 +62,9 @@ def structure_signature(layer: Layer):
               for name, t in sorted(layer.named_buffers()))
 
 
-def _require_partial_manual():
-    from ....core.jax_compat import SUPPORTS_PARTIAL_MANUAL
-
-    if not SUPPORTS_PARTIAL_MANUAL:
-        raise RuntimeError(
-            "the compiled pipeline schedule requires partial-manual "
-            "shard_map (jax.shard_map with axis_names), which this JAX "
-            "version lacks — upgrade JAX or run with pp=1")
-
-
 def _pipe_varying(x):
-    """Mark an array pipe-varying for the shard_map carry (jax_compat
-    resolves the pcast/pvary/identity version spread)."""
-    from ....core.jax_compat import pvary
-
-    return pvary(x, ("pipe",))
+    """Mark an array pipe-varying for the shard_map carry."""
+    return jax.lax.pcast(x, ("pipe",), to="varying")
 
 
 def _psum_pipe_f32(x):
@@ -151,7 +137,6 @@ def _scan_pipeline(stage_fn, xs, n_stages, n_micro, mesh, key_arr,
     computation is `copy`, and XLA CPU's bf16 AllReducePromotion pass
     CHECK-crashes cloning it ("Invalid binary instruction opcode copy"),
     killing every bf16 test on the virtual CPU mesh.)"""
-    _require_partial_manual()
 
     def inner(key_l, xs_full, *extras):
         stage = jax.lax.axis_index("pipe")
@@ -195,7 +180,7 @@ def _scan_pipeline(stage_fn, xs, n_stages, n_micro, mesh, key_arr,
         return _psum_pipe_f32(ys)                    # replicate output
 
     in_specs = (P(), P()) + tuple(extra_specs)
-    inner_f = shard_map(
+    inner_f = jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names={"pipe"})
     return inner_f(key_arr, xs, *extra_flat)
@@ -227,7 +212,6 @@ def _scan_pipeline_interleaved(chunk_fn, xs, n_stages, n_micro, n_virtual,
     schedule: the tick body is rematerialized, so the backward holds one
     per-tick chunk input.
     """
-    _require_partial_manual()
     vP = n_virtual * n_stages
     n_ticks = n_virtual * n_micro + n_stages - 1
 
@@ -270,7 +254,7 @@ def _scan_pipeline_interleaved(chunk_fn, xs, n_stages, n_micro, n_virtual,
         return _psum_pipe_f32(ys)
 
     in_specs = (P(), P()) + tuple(extra_specs)
-    inner_f = shard_map(
+    inner_f = jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=P(),
         axis_names={"pipe"})
     return inner_f(key_arr, xs, *extra_flat)

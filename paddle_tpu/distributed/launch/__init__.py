@@ -7,7 +7,12 @@ log files, proc watching) and fleet/elastic/manager.py:131 (watch loop,
 restart on worker failure).
 
 TPU-native notes: one launched process is one JAX *controller* that owns the
-host's local chips (multi-controller SPMD).  The launcher's env contract
+host's local chips (multi-controller SPMD) — a chip belongs to one process,
+so on a TPU host ``--nproc_per_node`` is 1 and the controller's mesh spans
+the chips; several workers per node are for CPU runs and must say so
+(``JAX_PLATFORMS=cpu``).  The launcher itself never imports jax: a parent
+that touched the backend would hold the chips its workers need.  The
+launcher's env contract
 (PADDLE_TRAINER_ID / PADDLE_TRAINERS_NUM / PADDLE_TRAINER_ENDPOINTS /
 PADDLE_MASTER / PADDLE_CURRENT_ENDPOINT) is what
 ``init_parallel_env`` (parallel.py) feeds into
@@ -130,8 +135,23 @@ class _Worker:
             self._log_f = None
 
 
+def _pinned_to_cpu(env) -> bool:
+    """True when ``JAX_PLATFORMS`` in ``env`` names the CPU and nothing
+    else (what a worker's jax will read; the launcher stays off jax)."""
+    platforms = [p.strip().lower()
+                 for p in env.get("JAX_PLATFORMS", "").split(",") if p.strip()]
+    return bool(platforms) and all(p == "cpu" for p in platforms)
+
+
 def _build_workers(args, master: str) -> List[_Worker]:
     n_local = args.nproc_per_node
+    if n_local > 1 and not _pinned_to_cpu(os.environ):
+        raise SystemExit(
+            f"--nproc_per_node {n_local}: every worker would claim all of "
+            "this host's TPU chips, and a chip belongs to one process.  On "
+            "a TPU host start ONE controller per host (--nproc_per_node 1; "
+            "its mesh spans the local chips).  Several workers per node "
+            "are for CPU runs only: export JAX_PLATFORMS=cpu to say so.")
     world = n_local * args.nnodes
     if args.nnodes > 1:
         if not args.ips:
@@ -160,7 +180,6 @@ def _build_workers(args, master: str) -> List[_Worker]:
             "PADDLE_TRAINER_ENDPOINTS": ",".join(endpoints),
             "PADDLE_CURRENT_ENDPOINT": endpoints[rank],
             "PADDLE_MASTER": master,
-            "FLAGS_selected_tpus": str(i),
         })
         cmd = [sys.executable, args.training_script] + \
             list(args.training_script_args)

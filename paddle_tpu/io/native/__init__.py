@@ -2,12 +2,15 @@
 workers (see shm_ring.cc for the design and reference mapping).
 
 The library is compiled on first use with the system toolchain and cached
-under the build directory; everything degrades gracefully to the
-multiprocessing.Queue transport when a toolchain is unavailable.
+under the build directory, keyed by a hash of the source it was built from
+(a binary left there by another checkout is never picked up); everything
+degrades gracefully to the multiprocessing.Queue transport when a
+toolchain is unavailable.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -32,16 +35,18 @@ def load_library() -> Optional[ctypes.CDLL]:
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        out = os.path.join(_build_dir(), "libshm_ring.so")
-        if not os.path.exists(out) or \
-                os.path.getmtime(out) < os.path.getmtime(_SRC):
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        out = os.path.join(_build_dir(), f"libshm_ring-{digest}.so")
+        if not os.path.exists(out):
+            tmp = f"{out}.{os.getpid()}.tmp"
             res = subprocess.run(
                 ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o",
-                 out + ".tmp", _SRC, "-lpthread", "-lrt"],
+                 tmp, _SRC, "-lpthread", "-lrt"],
                 capture_output=True, text=True)
             if res.returncode != 0:
                 return None
-            os.replace(out + ".tmp", out)
+            os.replace(tmp, out)
         lib = ctypes.CDLL(out)
         lib.shm_ring_create.restype = ctypes.c_void_p
         lib.shm_ring_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64]

@@ -16,7 +16,7 @@ from typing import Any, Callable, List, Optional
 
 import numpy as np
 import jax
-from ..core.jax_compat import jax_export
+from jax import export as jax_export
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor, to_tensor

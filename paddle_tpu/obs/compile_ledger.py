@@ -116,12 +116,6 @@ class CompileLedger:
         been built); call it after ``warmup()`` when driving manually."""
         self._steady = True
 
-    def reset_steady(self) -> None:
-        """Back out of steady state (e.g. an OOM retry at a new batch
-        size legitimately recompiles).  Already-counted anomalies stay
-        counted."""
-        self._steady = False
-
     @property
     def steady(self) -> bool:
         return self._steady

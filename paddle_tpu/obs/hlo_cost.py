@@ -37,14 +37,14 @@ Pallas custom kernels (they fall back to the XLA path off-TPU).
 from __future__ import annotations
 
 import hashlib
-import os
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
+
+from ..core.chip import chip_peaks
 
 __all__ = ["CostLedger", "count_hlo_ops", "opcode_sequence",
-           "schedule_fingerprint", "analyze_static_fn", "chip_spec",
-           "collective_exposure", "CHIP_SPECS", "HLO_OPS",
-           "COLLECTIVE_OPS", "ICI_BW"]
+           "schedule_fingerprint", "analyze_static_fn",
+           "collective_exposure", "HLO_OPS", "COLLECTIVE_OPS"]
 
 # one HLO instruction per line: `%name = <type> opcode(...)` — shared
 # with tools/perf_fingerprint.py (which imports these, so the tracked
@@ -69,38 +69,6 @@ COLLECTIVE_OPS = frozenset((
     "all-reduce", "all-gather", "reduce-scatter", "collective-permute",
     "all-to-all", "all-reduce-start", "all-gather-start",
     "collective-permute-start"))
-
-#: per-chip (peak bf16 flops/s, HBM bytes/s) for the analytic roofline.
-#: Keys are the names ``PADDLE_TPU_CHIP`` accepts; the default is v5e,
-#: the chip the north-star projection targets.
-CHIP_SPECS: Dict[str, Tuple[float, float]] = {
-    "v4": (275e12, 1228e9),
-    "v5e": (197e12, 819e9),
-    "v5p": (459e12, 2765e9),
-    "v6e": (918e12, 1640e9),
-}
-
-
-def chip_spec(chip: Optional[str] = None) -> Tuple[str, float, float]:
-    """``(name, peak_flops, hbm_bytes_per_s)`` for ``chip`` (default:
-    ``PADDLE_TPU_CHIP`` env, else v5e)."""
-    name = (chip or os.environ.get("PADDLE_TPU_CHIP") or "v5e").lower()
-    if name not in CHIP_SPECS:
-        raise ValueError(f"unknown chip {name!r}: expected one of "
-                         f"{sorted(CHIP_SPECS)}")
-    peak, bw = CHIP_SPECS[name]
-    return name, peak, bw
-
-
-#: usable per-chip ICI egress (B/s) for the analytic exposed-comm time
-#: in tools/step_ablation.py — conservative ~2/3 of aggregate link
-#: bandwidth, matching tools/northstar_projection.py's v5p figure.
-ICI_BW: Dict[str, float] = {
-    "v4": 2.4e11,
-    "v5e": 1.6e11,
-    "v5p": 4.0e11,
-    "v6e": 3.5e11,
-}
 
 # full instruction parse for collective_exposure: name, result type(s),
 # opcode, args — a superset of what _INSTR captures
@@ -216,14 +184,17 @@ def collective_exposure(hlo_text: str) -> dict:
     }
 
 
-def _roofline(flops: float, bytes_accessed: float,
-              chip: Optional[str] = None) -> dict:
-    name, peak, bw = chip_spec(chip)
+def _roofline(flops: float, bytes_accessed: float, chip: str) -> dict:
+    """Analytic roofline of a program on ``chip`` — a ``device_kind`` of
+    ``core.chip.CHIP_PEAKS``, always named by the caller: a program
+    compiled on the CPU says which chip it is being projected onto."""
+    peaks = chip_peaks(chip)
+    peak, bw = peaks.bf16_flops_per_s, peaks.hbm_bytes_per_s
     t_compute = flops / peak
     t_memory = bytes_accessed / bw if bytes_accessed else 0.0
     t_step = max(t_compute, t_memory) or 1e-30
     return {
-        "chip": name,
+        "chip": chip,
         "arithmetic_intensity": round(flops / max(bytes_accessed, 1.0), 3),
         "ridge_intensity": round(peak / bw, 3),
         "bound": "compute" if t_compute >= t_memory else "memory",
@@ -232,7 +203,7 @@ def _roofline(flops: float, bytes_accessed: float,
     }
 
 
-def analyze_static_fn(static_fn, *args, chip: Optional[str] = None) -> dict:
+def analyze_static_fn(static_fn, *args, chip: str) -> dict:
     """Cost-analyze one compiled program of a ``to_static`` function at
     the given example arguments.
 
@@ -287,8 +258,9 @@ class CostLedger:
     compiled structure.
     """
 
-    def __init__(self, chip: Optional[str] = None):
-        self.chip = chip_spec(chip)[0]
+    def __init__(self, chip: str):
+        chip_peaks(chip)                 # an unknown device_kind raises
+        self.chip = chip
         self.programs: Dict[str, dict] = {}
 
     def add(self, name: str, static_fn, *args,

@@ -19,6 +19,8 @@ Pallas kernel can slot in behind the same signature later.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -180,8 +182,23 @@ def block_prefill_attention(query, k_cache, v_cache, start, name=None):
                     [query, k_cache, v_cache, start])
 
 
+def _paged_per_shard(kernel, args, mesh):
+    """Run a paged kernel once per shard of a sharded engine's ``mesh``
+    (``ops.pallas.per_shard``): queries ``[B, S, H, D]`` and pool layers
+    ``[blocks, block_size, Hkv, D]`` both carry heads at dim 2 and split
+    there over the model axis, like the weights and the KV pool; the block
+    table and the scalars replicate."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..distributed.sharding_spec import MODEL_AXIS
+    from .pallas import per_shard
+
+    heads = P(None, None, MODEL_AXIS, None)
+    return per_shard(kernel, args, (heads, heads, heads, P(), P()), mesh)
+
+
 def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
-                           interpret=False, name=None):
+                           interpret=False, mesh=None, name=None):
     """Flash-decoding paged attention: the Pallas kernel path of the
     decode read (``ops.pallas.paged_attention_kernel``), consuming the
     block table *inside* the kernel — the fused replacement for
@@ -197,6 +214,8 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
         lengths:      ``[B]`` int32 current token index per slot.
         interpret:    run the kernel in Pallas interpret mode (the
                       CPU/tier-1 path; False compiles for real TPUs).
+        mesh:         the mesh a sharded engine's pool lives on (None:
+                      unsharded) — the kernel then runs per head shard.
 
     Returns:
         ``[B, 1, H, D]`` context, GQA expanded inside the kernel.
@@ -204,15 +223,17 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
     from .pallas.paged_attention_kernel import paged_decode_attention_kernel
 
     def _primal(q, kp, vp, tbl, ln):
-        return paged_decode_attention_kernel(q, kp, vp, tbl, ln,
-                                             interpret=interpret)
+        return _paged_per_shard(
+            functools.partial(paged_decode_attention_kernel,
+                              interpret=interpret),
+            (q, kp, vp, tbl, ln), mesh)
 
     return apply_op("paged_decode_attention", _primal,
                     [query, k_pool, v_pool, block_tables, lengths])
 
 
 def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
-                            interpret=False, name=None):
+                            interpret=False, mesh=None, name=None):
     """Fused cached-prefix + causal-tail prefill attention: the Pallas
     kernel path of the paged tail prefill, streaming the slot's block
     row straight off the pool — the fused replacement for
@@ -225,6 +246,8 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
         block_row: ``[max_blocks]`` int32 — the slot's block-table row.
         start:     scalar int32 — absolute position of the first query.
         interpret: Pallas interpret mode (CPU/tier-1 path).
+        mesh:      the mesh a sharded engine's pool lives on (None:
+                   unsharded) — the kernel then runs per head shard.
 
     Returns:
         ``[1, S, H, D]`` context.
@@ -232,9 +255,10 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
     from .pallas.paged_attention_kernel import paged_prefill_attention_kernel
 
     def _primal(q, kp, vp, row, st):
-        return paged_prefill_attention_kernel(
-            q, kp, vp, row, jnp.asarray(st).reshape(1),
-            interpret=interpret)
+        return _paged_per_shard(
+            functools.partial(paged_prefill_attention_kernel,
+                              interpret=interpret),
+            (q, kp, vp, row, jnp.asarray(st).reshape(1)), mesh)
 
     return apply_op("paged_prefill_attention", _primal,
                     [query, k_pool, v_pool, block_row, start])

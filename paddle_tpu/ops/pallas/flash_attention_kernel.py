@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...core.jax_compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 # Tuned on v5e (GPT-2 345M shapes, S=1024, D=64): 512x1024 runs the
 # fwd+bwd pair ~4x faster than 128x128 — the per-grid-step fixed cost
 # (DMA issue + revisiting scratch) dominates at small blocks, and VMEM
@@ -158,7 +154,7 @@ def _fwd(q, k, v, *, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -273,7 +269,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((BH, S, D), k.dtype),
             jax.ShapeDtypeStruct((BH, S, D), v.dtype),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -295,7 +291,7 @@ def _bwd(res, g, *, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, iq, ik: (bh, iq, 0)),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)

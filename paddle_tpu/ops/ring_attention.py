@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.jax_compat import shard_map
 from ..core.dispatch import apply_op
 from ..distributed import mesh as mesh_mod
 
@@ -37,9 +36,7 @@ SEP_AXIS = "sep"
 
 
 def _varying(x, axis):
-    from ..core.jax_compat import pvary
-
-    return pvary(x, (axis,))
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _ring_inner(q_l, k_l, v_l, p: int, s_local: int, scale: float,
@@ -98,20 +95,13 @@ def ring_flash_attention(query, key, value, is_causal: bool = True,
 
         return flash_attention(query, key, value, is_causal=is_causal,
                                dropout_p=0.0, training=False)
-    from ..core.jax_compat import SUPPORTS_PARTIAL_MANUAL
-
-    if not SUPPORTS_PARTIAL_MANUAL:
-        raise RuntimeError(
-            "ring attention over the sep axis requires partial-manual "
-            "shard_map (jax.shard_map with axis_names), which this JAX "
-            "version lacks — upgrade JAX or set sep=1 in the mesh")
     s_local = S // p
     D = query.shape[-1]
     scale = 1.0 / (D ** 0.5)
 
     def _primal(q, k, v):
         spec = P(None, SEP_AXIS, None, None)
-        f = shard_map(
+        f = jax.shard_map(
             lambda ql, kl, vl: _ring_inner(ql, kl, vl, p, s_local, scale,
                                            is_causal),
             mesh=m, in_specs=(spec, spec, spec), out_specs=spec,

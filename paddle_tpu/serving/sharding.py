@@ -216,6 +216,7 @@ class ServingShard:
         bt = getattr(cache, "block_tables", None)
         if bt is not None:
             self._pin(bt)
+            cache.mesh = self.mesh       # paged kernels run per head shard
 
     def place_sampler(self, sampler) -> None:
         """All sampling lanes replicate: one logical decision stream

@@ -29,7 +29,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 import jax
-from ..core.jax_compat import jax_export
+from jax import export as jax_export
 import jax.numpy as jnp
 
 from ..core import dispatch as dispatch_mod

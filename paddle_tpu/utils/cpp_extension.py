@@ -19,6 +19,7 @@ C ABI contract for exported functions (elementwise/shape-preserving)::
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -58,8 +59,17 @@ def _compile(name: str, sources: List[str], extra_cflags, build_directory,
              verbose: bool) -> str:
     build = build_directory or get_build_directory()
     os.makedirs(build, exist_ok=True)
-    out = os.path.join(build, f"lib{name}.so")
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", out]
+    # the binary is keyed by what it was built from: another source set
+    # under the same name never reuses (or overwrites a loaded) library
+    h = hashlib.sha256(repr(list(extra_cflags or [])).encode())
+    for s in sources:
+        with open(s, "rb") as f:
+            h.update(f.read())
+    out = os.path.join(build, f"lib{name}-{h.hexdigest()[:16]}.so")
+    if os.path.exists(out):
+        return out
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", "-o", tmp]
     cmd += list(extra_cflags or [])
     cmd += [os.path.abspath(s) for s in sources]
     if verbose:
@@ -68,6 +78,7 @@ def _compile(name: str, sources: List[str], extra_cflags, build_directory,
     if res.returncode != 0:
         raise RuntimeError(
             f"g++ failed for extension {name!r}:\n{res.stderr}")
+    os.replace(tmp, out)
     return out
 
 

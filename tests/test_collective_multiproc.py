@@ -76,6 +76,7 @@ class TestElasticResume:
         env = {k: v for k, v in os.environ.items()
                if not k.startswith(("PADDLE_", "XLA_FLAGS", "JAX_"))}
         env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"     # several workers per node: CPU only
         env.update(env_extra)
         cmd = [sys.executable, "-m", "paddle_tpu.distributed.launch",
                "--nproc_per_node", str(nproc), "--max_restarts", "2"]

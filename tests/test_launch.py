@@ -17,6 +17,9 @@ def _run(args, env_extra, timeout=300):
     env = {k: v for k, v in os.environ.items()
            if not k.startswith(("PADDLE_", "XLA_FLAGS", "JAX_"))}
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    # several workers per node are a CPU-only shape, and the launcher
+    # reads that from the workers' environment (it never imports jax)
+    env["JAX_PLATFORMS"] = "cpu"
     env.update(env_extra)
     return subprocess.run(args, env=env, timeout=timeout,
                           capture_output=True, text=True)
@@ -63,6 +66,17 @@ class TestLaunchCLI:
                   "--help"], {})
         assert r.returncode == 0
         assert "nproc_per_node" in r.stdout
+
+    def test_several_workers_per_node_refused_unless_cpu(self):
+        """A chip belongs to one process: without JAX_PLATFORMS=cpu in
+        the workers' environment, --nproc_per_node 2 would start two
+        processes that each claim every local chip."""
+        for platforms in ("", "tpu,cpu"):
+            r = _run([sys.executable, "-m", "paddle_tpu.distributed.launch",
+                      "--nproc_per_node", "2", SCRIPT],
+                     {"JAX_PLATFORMS": platforms}, timeout=60)
+            assert r.returncode != 0
+            assert "ONE controller per host" in r.stderr, r.stderr[-800:]
 
 
 class TestElasticLaunch:

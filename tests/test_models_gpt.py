@@ -3,7 +3,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core import jax_compat
 from paddle_tpu.distributed import fleet
 from paddle_tpu.models import (
     GPTConfig, GPTForCausalLM, GPTPretrainingCriterion, gpt_tiny,
@@ -99,10 +98,6 @@ class TestGPTHybrid:
         assert "model" in tuple(w._value().sharding.spec)
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="hybrid pp dryrun needs partial-manual shard_map "
-           "(jax.shard_map axis_names API)")
 class TestGraftEntry:
     def test_dryrun_multichip_8(self):
         # light mode: the riskiest factorization + the single-device

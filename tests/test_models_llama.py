@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core import jax_compat
 from paddle_tpu.distributed import fleet
 from paddle_tpu.models import (
     LlamaConfig, LlamaForCausalLM, LlamaPretrainingCriterion, llama_tiny,
@@ -98,9 +97,6 @@ class TestLlamaSingle:
             rtol=1e-5)
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestLlamaHybridSep:
     """Hybrid mesh including sep_degree=2 — the context-parallel axis
     actually exercised (round-1 verdict weak #7)."""

@@ -4,7 +4,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core import jax_compat
 import paddle_tpu.nn as nn
 import paddle_tpu.nn.functional as F
 from paddle_tpu.distributed import fleet
@@ -46,9 +45,6 @@ def _build(hybrid_pp):
     return pipe, fleet.distributed_model(pipe)
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestPipelineSchedule:
     def test_uniform_run_detected(self, hybrid_pp):
         pipe, model = _build(hybrid_pp)
@@ -118,10 +114,6 @@ class TestPipelineSchedule:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="varying-manual-axes AD under shard_map needs the "
-           "jax.shard_map axis_names API")
 class TestJaxSwitchVmaAD:
     """Pins the jax 0.9.0 bug that forced the non-uniform pipeline schedule
     to stay sequential: lax.switch under shard_map varying-manual-axes
@@ -134,7 +126,7 @@ class TestJaxSwitchVmaAD:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, Mesh
-        from paddle_tpu.core.jax_compat import shard_map
+        from jax import shard_map
 
         devs = np.array(jax.devices()[:2])
         mesh = Mesh(devs, ("pipe",))
@@ -203,9 +195,6 @@ class TestJaxSwitchVmaAD:
             "(pp_schedule.py docstring)")
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestPipelineMemoryBound:
     """The compiled schedule's activation memory must not grow with the
     microbatch count M at fixed total batch (the 1F1B memory property,
@@ -264,9 +253,6 @@ class TestPipelineMemoryBound:
         assert t8 <= t2 * 1.25, (t2, t8)
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestInterleavedSchedule:
     """num_virtual_pipeline_stages=v: the interleaved schedule must compute
     exactly what the sequential stack computes (values AND grads), with a

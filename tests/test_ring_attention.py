@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.core import jax_compat
 from paddle_tpu.distributed import mesh as mesh_mod
 from paddle_tpu.ops.pallas import flash_attention
 from paddle_tpu.ops.ring_attention import ring_flash_attention
@@ -29,9 +28,6 @@ def _qkv(B=2, S=16, H=2, D=8, seed=0):
     return q, k, v
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestRingVsOracle:
     @pytest.mark.parametrize("causal", [True, False])
     def test_values_and_grads_match(self, causal):
@@ -77,9 +73,6 @@ class TestRingVsOracle:
         mesh_mod.set_global_mesh(mesh_mod_backup)
 
 
-@pytest.mark.skipif(
-    not jax_compat.SUPPORTS_PARTIAL_MANUAL,
-    reason="partial-manual shard_map (pipeline/sep) needs the jax.shard_map axis_names API")
 class TestSepModelGradEquivalence:
     def test_gpt_sep2_grads_match_sep1(self):
         """Full model: loss AND parameter grads identical under sep=2 vs

@@ -226,7 +226,7 @@ def tp4_programs(mp4):
     ledger.attach()
     ledger.mark_steady()          # analyses must add ZERO compiles...
     try:
-        cost = CostLedger()
+        cost = CostLedger(chip="TPU v5 lite")   # CPU compile, named chip
         rb = cost.add("base", base_fn, x, y)
         ro = cost.add("ovl", ovl_fn, x, y)
         ro2 = cost.add("ovl_again", ovl_fn, x, y)

@@ -32,8 +32,12 @@ from paddle_tpu.distributed.fault_tolerance import (
     DivergenceSentry, FaultPlan, ResilientLoop, global_grad_norm)
 from paddle_tpu.obs import (NULL_TIMELINE, CompileLedger, CostLedger,
                             StepTimeline, validate_timeline)
-from paddle_tpu.obs.hlo_cost import (chip_spec, count_hlo_ops,
-                                     schedule_fingerprint)
+from paddle_tpu.core.chip import chip_peaks
+from paddle_tpu.obs.hlo_cost import count_hlo_ops, schedule_fingerprint
+
+# these programs compile on the CPU; the analytic roofline projects them
+# onto a chip the test names (never one inherited from a default)
+CHIP = "TPU v5 lite"
 
 
 def _sentry(**kw):
@@ -405,7 +409,7 @@ class TestCostLedger:
         magnitude — a broken cost analysis (0, or double-counted
         backward) lands far outside it."""
         train_step, x, y, tokens, n_params = tiny_gpt_step
-        ledger = CostLedger()
+        ledger = CostLedger(chip=CHIP)
         rec = ledger.add("train_step", train_step, x, y,
                          tokens_per_step=tokens, n_params=n_params)
         assert rec["flops"] > 0
@@ -417,7 +421,7 @@ class TestCostLedger:
     def test_analytic_roofline_and_fingerprint_stable(self,
                                                       tiny_gpt_step):
         train_step, x, y, tokens, n_params = tiny_gpt_step
-        ledger = CostLedger(chip="v5e")
+        ledger = CostLedger(chip=CHIP)
         r1 = ledger.add("train_step", train_step, x, y)
         r2 = ledger.add("train_step", train_step, x, y)
         # identical program, identical analysis → identical fingerprint
@@ -426,7 +430,8 @@ class TestCostLedger:
         assert r1["arithmetic_intensity"] > 0
         assert r1["bound"] in ("compute", "memory")
         # roofline consistency: step time = max of the two components
-        name, peak, bw = chip_spec("v5e")
+        peaks = chip_peaks(CHIP)
+        peak, bw = peaks.bf16_flops_per_s, peaks.hbm_bytes_per_s
         t_c = r1["flops"] / peak
         t_m = r1["bytes_accessed"] / bw
         assert r1["roofline_step_ms"] == pytest.approx(
@@ -440,7 +445,7 @@ class TestCostLedger:
         schedule fingerprint — otherwise it can't catch a schedule
         regression either."""
         train_step, x, y, _, _ = tiny_gpt_step
-        ledger = CostLedger()
+        ledger = CostLedger(chip=CHIP)
         r1 = ledger.add("a", train_step, x, y)
         x2 = paddle.to_tensor(np.asarray(x.numpy())[:1])
         y2 = paddle.to_tensor(np.asarray(y.numpy())[:1])
@@ -463,7 +468,9 @@ class TestCostLedger:
 
     def test_unknown_chip_rejected(self):
         with pytest.raises(ValueError):
-            chip_spec("v99")
+            chip_peaks("TPU v99")
+        with pytest.raises(ValueError):
+            CostLedger(chip="cpu")
 
 
 class TestZeroCompileKeys:
@@ -484,7 +491,7 @@ class TestZeroCompileKeys:
             with tl.phase("step_dispatch"):
                 train_step(x)
             tl.end_step()
-            cost = CostLedger()
+            cost = CostLedger(chip=CHIP)
             cost.add("step", train_step, x)
             cost.add("step", train_step, x)
         finally:
@@ -498,7 +505,7 @@ class TestStatsAndMetrics:
     def test_train_stats_and_exposition(self, tmp_path):
         tl = StepTimeline()
         ledger = CompileLedger()
-        cost = CostLedger()
+        cost = CostLedger(chip=CHIP)
         loop, sentry, train_step = _run_nan_drill(
             tmp_path, tl, compile_ledger=ledger, cost_ledger=cost)
         # analyze the drill's warmed program into the loop's cost
