@@ -10,7 +10,6 @@ from __future__ import annotations
 import functools
 import hashlib
 import os
-import time
 import traceback
 from typing import Any, Callable, List, Optional
 
@@ -21,6 +20,7 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor, to_tensor
 from ..core import dtype as dtype_mod
+from ..obs import spans as _spans
 from .trace import CompiledProgram, _flatten_io, spec_of
 
 # tracer-leak errors: a Tensor whose value exists only inside the trace
@@ -79,16 +79,25 @@ def _arg_specs_str(leaves: List[Tensor]) -> str:
                     for t in leaves)
 
 
-def _notify_compile(static_fn, key, leaves, seconds: float,
-                    executed: bool) -> None:
-    prog = static_fn._programs.get(key)
-    rec = {
+def _miss_attrs(static_fn, key, leaves) -> dict:
+    """What names a program-cache miss: the attributes of its
+    ``jit.trace`` / ``jit.compile`` spans and the head of its ledger
+    record.  Only computed on a miss."""
+    return {
         "fn": getattr(static_fn._fn, "__qualname__",
                       getattr(static_fn._fn, "__name__", "<fn>")),
         "key": hashlib.sha1(repr(key).encode()).hexdigest()[:12],
         "arg_specs": _arg_specs_str(leaves),
-        "seconds": round(seconds, 6),
         "site": _compile_call_site(),
+    }
+
+
+def _notify_compile(static_fn, key, attrs: dict, seconds: float,
+                    executed: bool) -> None:
+    prog = static_fn._programs.get(key)
+    rec = {
+        **attrs,
+        "seconds": round(seconds, 6),
         "cache_size": len(static_fn._programs),
         "state_inputs": len(prog.state_keys) if prog is not None else 0,
         # False = trace-only (get_concrete_program: eval_shape discovery,
@@ -206,22 +215,27 @@ class StaticFunction:
         if prog is None:
             prog = CompiledProgram(self._fn, args_tree, kwargs_tree,
                                    donate=self._donate)
-            # time trace + build + the FIRST call (jax.jit compiles
-            # lazily, so the first execution pays the XLA compile —
-            # that wall time is the ledger's whole point); one miss
-            # path whether or not a listener is attached.  Notify in
-            # finally: a first call that raises still CACHED the
-            # program, and the retry will be a silent hit — skipping
-            # the record would undercount that key's compile forever
-            t0 = time.perf_counter()
-            _build_mapped(prog, leaves)
+            # a miss is two spans (``obs.spans``; nothing is recorded on
+            # a hit): ``jit.trace`` = Python tracing to a fixed point
+            # (prog.build), ``jit.compile`` = the FIRST call (jax.jit
+            # compiles lazily, so the first execution pays the lowering
+            # and the XLA compile or persistent-cache load).  Their
+            # stamps are the ledger's wall time too.  Notify in finally:
+            # a first call that raises still CACHED the program, and the
+            # retry will be a silent hit — skipping the record would
+            # undercount that key's compile forever
+            attrs = _miss_attrs(self, key, leaves)
+            with _spans.span("jit.trace", **attrs) as traced:
+                _build_mapped(prog, leaves)
             self._programs[key] = prog
+            compiled = _spans.span("jit.compile", **attrs)
             try:
-                out = prog(leaves)
+                with compiled:
+                    out = prog(leaves)
             finally:
                 if _compile_listeners:
-                    _notify_compile(self, key, leaves,
-                                    time.perf_counter() - t0,
+                    _notify_compile(self, key, attrs,
+                                    compiled.t1 - traced.t0,
                                     executed=True)
             return out
         return prog(leaves)
@@ -244,12 +258,13 @@ class StaticFunction:
         if prog is None:
             prog = CompiledProgram(self._fn, args_tree, kwargs_tree,
                                    donate=self._donate)
-            t0 = time.perf_counter()
-            _build_mapped(prog, leaves)
+            attrs = _miss_attrs(self, key, leaves)
+            with _spans.span("jit.trace", **attrs) as traced:
+                _build_mapped(prog, leaves)
             self._programs[key] = prog
             if _compile_listeners:
-                _notify_compile(self, key, leaves,
-                                time.perf_counter() - t0, executed=False)
+                _notify_compile(self, key, attrs, traced.t1 - traced.t0,
+                                executed=False)
         return prog
 
     def rollback(self):

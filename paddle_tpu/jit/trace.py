@@ -23,6 +23,7 @@ stack (SURVEY.md §2.3) — which, TPU-native, collapse into ``jax.jit``
 """
 from __future__ import annotations
 
+import re
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -259,6 +260,13 @@ def spec_of(tree, leaves) -> tuple:
     return _spec(tree)
 
 
+def program_name(fn) -> str:
+    """The name a compiled program runs under: the traced function's own
+    ``__name__`` as an identifier (``<lambda>`` reads ``_lambda_``)."""
+    name = getattr(fn, "__name__", None) or type(fn).__name__
+    return re.sub(r"\W", "_", str(name)) or "program"
+
+
 class CompiledProgram:
     """One (input-spec → XLA executable) entry (reference: ConcreteProgram +
     cached InterpreterCore, executor_cache.cc)."""
@@ -359,6 +367,11 @@ class CompiledProgram:
                     write_arrays.append(w)
             return tuple(out_arrays), tuple(write_arrays)
 
+        # the program carries the traced function's name, so that a
+        # profiler trace's ``XLA Modules`` line reads ``jit_decode_step``,
+        # ``jit_prefill_step``, ``jit_<the user's train function>`` and
+        # every operation's op_name starts ``jit(<that name>)/``
+        program.__name__ = program.__qualname__ = program_name(self.fn)
         # donating variant for the state-mutating fast path; non-donating
         # for the differentiable path (vjp residuals may alias state bufs)
         self.jitted = jax.jit(program)

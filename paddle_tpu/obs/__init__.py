@@ -21,6 +21,12 @@ A thin, dependency-free export layer over
 Everything here is host-side and read-only: exporting never touches an
 engine, a traced value, or a compiled program.
 
+Underneath them all sits :mod:`~.spans`, the one span primitive: an
+always-on bounded ring of ``(name, start, end, parent, attrs, sid)`` rows on
+``time.perf_counter()``, each span also a ``jax.profiler.TraceAnnotation``
+(the phases of ``Engine.step``, ``jit``'s compile spans, ``RecordEvent``,
+``StepTimeline.phase``) — docs/OBSERVABILITY.md "The span primitive".
+
 :class:`~.flight.FlightRecorder` also lives here — the always-on
 bounded step-summary ring both the serving engine and the training
 runtime feed (frozen into a post-mortem dump on unhealthy/eject/

@@ -24,6 +24,8 @@ import time
 from collections import deque
 from typing import List
 
+from . import spans as _spans
+
 __all__ = ["FlightRecorder"]
 
 
@@ -56,8 +58,17 @@ class FlightRecorder:
         is the scheduler/training loop, so this must stay
         allocation-light)."""
         self.steps_seen += 1
-        fields["t"] = round(time.perf_counter(), 6)
+        fields["t"] = round(_spans.clock(), 6)
         self._ring.append(fields)
+
+    def record_span(self, sp) -> None:
+        """Append a CLOSED span's attributes as the step summary, stamped
+        with the span's own end: the serving engine's per-step record is
+        its ``engine.step`` span, so the ring holds the same dict the
+        span ring's row does and no clock is read for it."""
+        self.steps_seen += 1
+        sp.attrs["t"] = round(sp.t1, 6)
+        self._ring.append(sp.attrs)
 
     def dump(self, reason: str) -> dict:
         """Freeze the ring into a post-mortem record (newest events

@@ -49,6 +49,8 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional
 
+from . import spans as _spans
+
 __all__ = ["StepTimeline", "NullTimeline", "NULL_TIMELINE",
            "resolve_timeline", "validate_timeline",
            "STEP_TERMINAL_STATES"]
@@ -254,10 +256,11 @@ class StepTimeline:
                            "state": None}
         return sid
 
-    def _end_span(self, sid: Optional[int], state: str) -> None:
+    def _end_span(self, sid: Optional[int], state: str,
+                  t: Optional[float] = None) -> None:
         sp = self.spans.get(sid)
         if sp is not None and sp["t_end"] is None:
-            sp["t_end"] = self._now()
+            sp["t_end"] = self._now() if t is None else t
             sp["state"] = state
 
     # -- step lifecycle -----------------------------------------------------
@@ -363,18 +366,25 @@ class StepTimeline:
             sid = self._begin_span(
                 f"{self.process}:bg{next(self._bg_ids)}", name,
                 thread=name)
-        t0 = time.perf_counter()
+        # the phase is a span of the program's one primitive
+        # (``obs.spans``: its ring, and the profiler's trace when one is
+        # being taken); the timeline's own row takes the span's stamps
+        sp = _spans.span("train." + name, **(
+            {} if self._step is None else {"step": self._step})).begin()
+        row = self.spans.get(sid)
+        if row is not None:
+            row["t_start"] = sp.t0 - self.t0
         try:
             yield
         finally:
+            sp.end()
             # an abandoned attempt already removed this span — its
             # duration must not leak into the counters either, or
             # phase_ms would disagree with the exported spans
             if sid in self.spans:
-                self._end_span(sid, "finished")
+                self._end_span(sid, "finished", t=sp.t1 - self.t0)
                 self.phase_seconds[name] = \
-                    self.phase_seconds.get(name, 0.0) \
-                    + (time.perf_counter() - t0)
+                    self.phase_seconds.get(name, 0.0) + (sp.t1 - sp.t0)
 
     # -- sentry transitions -------------------------------------------------
 

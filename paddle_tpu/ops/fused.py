@@ -28,6 +28,9 @@ from ._helpers import op
 
 __all__ = ["fused_linear_cross_entropy"]
 
+#: the named scope of the streamed fused CE in a compiled program's op names
+CE_SCOPE = "loss.streamed_ce"
+
 
 def _block_view(w, block: int):
     """Pad [V, H] to a multiple of `block` and reshape to [nb, block, H]."""
@@ -154,7 +157,11 @@ def fused_linear_cross_entropy(hidden, weight, label, loss_mask=None,
         # clamp so a stray ignore label can't index out of range
         safe = jnp.clip(lblf, 0, w.shape[0] - 1)
         cdt = h.dtype if h.dtype in (jnp.bfloat16, jnp.float16) else jnp.float32
-        loss = _flce(h2, w, safe, valid, int(block_size), cdt)   # [N] f32
+        # one scope over the streamed loop, forward and backward: autograd
+        # wraps it (``jvp(loss.streamed_ce)``, ``transpose(jvp(...))``), so
+        # every op of either pass carries the words in its op_name
+        with jax.named_scope(CE_SCOPE):
+            loss = _flce(h2, w, safe, valid, int(block_size), cdt)  # [N] f32
         if maybe_mask:
             mflat = maybe_mask[0].reshape(N).astype(jnp.float32)
             return jnp.sum(loss * mflat) / jnp.maximum(jnp.sum(mflat), 1.0)

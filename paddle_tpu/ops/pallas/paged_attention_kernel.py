@@ -157,6 +157,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q_g, k_pool, v_pool)
     return o_g.transpose(0, 2, 1, 3).reshape(B, 1, H, D)
@@ -278,6 +279,7 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_row.astype(jnp.int32),
       jnp.asarray(start, dtype=jnp.int32).reshape(1), q_g, k_pool, v_pool)
     return o_g.reshape(H, S, D).transpose(1, 0, 2)[None]

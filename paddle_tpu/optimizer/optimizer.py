@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Dict, List, Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
@@ -196,15 +197,20 @@ class Optimizer:
 
     @no_grad()
     def step(self):
-        params_grads = self._collect_params_grads()
-        if self._grad_clip is not None:
-            params_grads = self._grad_clip(params_grads)
-        self._global_step += 1
-        for p, g in params_grads:
-            garr = g._value() if isinstance(g, Tensor) else g
-            if garr.dtype in (jnp.bfloat16, jnp.float16):
-                garr = garr.astype(jnp.float32)
-            self._update_with_overrides(p, garr)
+        # one named scope over the whole update (``optimizer.adamw`` for
+        # AdamW): in a compiled train step every op of the update carries
+        # it in its op_name, which is how a profiler trace tells the
+        # optimizer's device time from the backward's
+        with jax.named_scope(f"optimizer.{type(self).__name__.lower()}"):
+            params_grads = self._collect_params_grads()
+            if self._grad_clip is not None:
+                params_grads = self._grad_clip(params_grads)
+            self._global_step += 1
+            for p, g in params_grads:
+                garr = g._value() if isinstance(g, Tensor) else g
+                if garr.dtype in (jnp.bfloat16, jnp.float16):
+                    garr = garr.astype(jnp.float32)
+                self._update_with_overrides(p, garr)
 
     minimize_step = step
 

@@ -277,22 +277,23 @@ def export_protobuf(dir_name: str, worker_name: Optional[str] = None
 class RecordEvent:
     """User-annotated span, visible in the trace and the statistics tables.
     Reference: paddle.profiler.RecordEvent / platform::RecordEvent
-    (event_tracing.h) — here a jax.profiler.TraceAnnotation."""
+    (event_tracing.h) — here a span of :mod:`paddle_tpu.obs.spans`: a
+    ``jax.profiler.TraceAnnotation`` for the interval, and a row in the
+    program's span ring beside the engine's and the compiler's own."""
 
     def __init__(self, name: str, event_type: Optional[str] = None):
         self.name = name
-        self._ann = None
+        self._span = None
 
     def begin(self):
-        import jax
+        from ..obs import spans
 
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+        self._span = spans.span(self.name).begin()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        if self._span is not None:
+            self._span.end()
+            self._span = None
 
     def __enter__(self):
         self.begin()

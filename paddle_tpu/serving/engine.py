@@ -81,6 +81,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor, to_tensor
+from ..obs import spans as _spans
 from .kv_cache import KVCache, CacheContext
 from .metrics import ServingMetrics
 from .sampling import DeviceSampler, SamplingParams
@@ -625,6 +626,11 @@ class Engine:
         self._consecutive_failures = 0
         self._step_counter = 0
         self._last_step_t: Optional[float] = None
+        # what the ``engine.step`` span reports, kept as running
+        # integers by ``_occupy`` / ``_vacate`` / ``_advance`` / ``_admit``
+        # (never summed over requests for the span's sake)
+        self._kv_tokens = 0          # cached tokens of the running slots
+        self._admitted_step = 0      # prompts admitted by the current step
         self._watchdog = None
         self._arm_counter = 0
 
@@ -885,11 +891,12 @@ class Engine:
         if self._watchdog is not None:
             self._watchdog.pause()
 
-    def _step_call(self, point: str, fn, *args):
+    def _step_call(self, point: str, fn, *args, span=None):
         """One compiled step with watchdog arming, fault injection, and a
         bounded retry.  Retry is state-safe: ``jit`` writes cache state
         back only after a call returns, so a failed attempt left the KV
-        cache and lengths untouched."""
+        cache and lengths untouched.  ``span`` (the caller's dispatch
+        span) is told how many attempts the call took."""
         last_err = None
         for attempt in range(self.max_step_retries + 1):
             if attempt:
@@ -903,6 +910,9 @@ class Engine:
                 finally:
                     self._disarm_watchdog()
                 self._consecutive_failures = 0
+                if span is not None:
+                    span.attrs["attempts"] = \
+                        span.attrs.get("attempts", 0) + attempt + 1
                 return out
             except Exception as e:       # noqa: BLE001 — isolated upstream
                 last_err = e
@@ -1362,7 +1372,7 @@ class Engine:
                                            salt=self._tenant_salt(victim))
             except Exception:            # noqa: BLE001 — isolation boundary
                 self.metrics.on_prefix_register_error()
-        self.running.pop(slot, None)
+        self._vacate(slot)
         if slot not in self.free_slots:
             self.free_slots.append(slot)
         if self.kv_layout == "paged":
@@ -1394,6 +1404,35 @@ class Engine:
             # the journaled stream restarts too: tokens before this
             # record are superseded by the resume's replay from token 0
             self.journal.record_restart(victim.journal_id, "preempt")
+
+    # -- the running set (and the integers the step span reports) ----------
+
+    def _occupy(self, req: Request, seq_len: int) -> None:
+        """``req`` starts running in its slot with ``seq_len`` cached
+        tokens: the one place the running set grows."""
+        req._seq_len = seq_len
+        self.running[req.slot] = req
+        self._kv_tokens += seq_len
+        self._admitted_step += 1
+
+    def _vacate(self, slot: int) -> None:
+        """The slot stops running (retired or preempted): the one place
+        the running set shrinks."""
+        req = self.running.pop(slot, None)
+        if req is not None:
+            self._kv_tokens -= req._seq_len
+
+    def _advance(self, req: Request, n: int = 1) -> None:
+        """``n`` more tokens of ``req`` were written to the cache."""
+        req._seq_len += n
+        self._kv_tokens += n
+
+    def _trace_id(self, req: Request) -> str:
+        """The id a request's spans share: the request tracer's when one
+        is armed (fleet-rooted under a router), else the engine's own."""
+        tr = self.tracer
+        return (tr._req_trace.get(req) if tr.enabled else None) \
+            or f"{self.name}:r{req.request_id}"
 
     def _on_cancel(self, req: Request) -> None:
         """Queued requests leave immediately; running ones are retired at
@@ -1503,8 +1542,10 @@ class Engine:
         retire ``req`` as failed and return None (shared by both KV
         layouts so the retire semantics cannot diverge)."""
         try:
-            return self._step_call("serving.prefill", self._prefill_fn,
-                                   *args)
+            with _spans.span("engine.prefill",
+                             bucket=int(args[0].shape[1])) as sp:
+                return self._step_call("serving.prefill",
+                                       self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
             n = self.max_step_retries
             self._retire(req, "failed",
@@ -1523,25 +1564,10 @@ class Engine:
         ``first_token`` is the on-device-sampled first token (a scalar
         int32 device handle); ``prefix_hit`` is the reused prefix length
         in tokens."""
-        P, shared = self._prefix_lookup(req)
-        bucket = self.bucket_for(L - P)
-        # a PARTIAL hit can push prefix + padded tail past the slot's
-        # block table (e.g. hit 8 of a 32-token prompt with buckets
-        # {8,16,32}: 1 + 32/8 = 5 blocks on a 4-block table) — drop hit
-        # blocks from the end until the padded tail fits; the remaining
-        # hit is still a contiguous prefix
-        while shared and (len(shared) + bucket // self.block_size
-                          > self.cache.max_blocks_per_slot):
-            shared = shared[:-1]
-            P -= self.block_size
-            bucket = self.bucket_for(L - P)
-        if self.prefix_cache is not None and req._defers == 0:
-            # one logical lookup per request (deferral retries re-look-up
-            # for freshness but don't re-count), credited with only the
-            # hit span that is ACTUALLY reused post-cap — discarded and
-            # raising lookups land here as P == 0, i.e. a plain miss
-            self.prefix_cache.record_lookup(L, P)
-        if not self.cache.begin_sequence(req.slot, shared, P, bucket):
+        with _spans.span("engine.prefix_lookup") as sp:
+            P, bucket, ok = self._assign_blocks(req, L)
+            sp.attrs["hit_tokens"] = P
+        if not ok:
             return "deferred", None, bucket, P
         ids = np.zeros((1, bucket), dtype=np.int64)
         ids[0, :L - P] = req.prompt_ids[P:]
@@ -1561,24 +1587,62 @@ class Engine:
                 self.metrics.on_prefix_register_error()
         return "ok", last, bucket, P
 
-    # tpulint: hot-path
+    def _assign_blocks(self, req: Request, L: int):
+        """The host half of a paged admission: prefix lookup, the
+        partial-hit cap, and the slot's block assignment.  Returns
+        ``(hit_tokens, bucket, assigned)``; ``assigned`` False = the pool
+        cannot supply the tail blocks right now."""
+        P, shared = self._prefix_lookup(req)
+        bucket = self.bucket_for(L - P)
+        # a PARTIAL hit can push prefix + padded tail past the slot's
+        # block table (e.g. hit 8 of a 32-token prompt with buckets
+        # {8,16,32}: 1 + 32/8 = 5 blocks on a 4-block table) — drop hit
+        # blocks from the end until the padded tail fits; the remaining
+        # hit is still a contiguous prefix
+        while shared and (len(shared) + bucket // self.block_size
+                          > self.cache.max_blocks_per_slot):
+            shared = shared[:-1]
+            P -= self.block_size
+            bucket = self.bucket_for(L - P)
+        if self.prefix_cache is not None and req._defers == 0:
+            # one logical lookup per request (deferral retries re-look-up
+            # for freshness but don't re-count), credited with only the
+            # hit span that is ACTUALLY reused post-cap — discarded and
+            # raising lookups land here as P == 0, i.e. a plain miss
+            self.prefix_cache.record_lookup(L, P)
+        return P, bucket, self.cache.begin_sequence(req.slot, shared, P,
+                                                    bucket)
+
     def _admit(self, req: Request) -> Optional[bool]:
         """Prefill ``req`` into its pre-assigned slot.  Never raises for
         request-level problems — a prefill/sampling/callback failure fails
         this request only (``_retire`` reclaims the slot).  Returns False
         when paged admission must be deferred (no KV blocks free); the
         scheduler re-queues the request with its slot returned."""
+        with _spans.span("engine.admit", trace=self._trace_id(req),
+                         slot=req.slot,
+                         prompt_tokens=int(req.prompt_ids.size)) as sp:
+            sp.attrs["queue_wait_ms"] = round(
+                1e3 * (sp.t0 - req.t_enqueue), 3)
+            deferred = self._admit_into_slot(req, sp)
+            # "admitted" was set where the slot was occupied; a request
+            # retired before that reports how (failed | cancelled)
+            sp.attrs.setdefault(
+                "outcome", "deferred" if deferred is False else req.state)
+            return deferred
+
+    # tpulint: hot-path
+    def _admit_into_slot(self, req: Request, sp) -> Optional[bool]:
         if req._cancel:                  # cancelled between pop and prefill
             self._retire(req, "cancelled")
             return None
-        if self._deadline_expired(req, time.perf_counter()):
+        if self._deadline_expired(req, sp.t0):
             # expired while queued (possibly during an earlier admission
             # this very step): retire as a deadline failure WITHOUT
             # paying a compiled prefill for work that is already dead
             self._fail_deadline(req)
             return None
         L = int(req.prompt_ids.size)
-        t0 = time.perf_counter()
         prefix_hit = 0
         # stage the slot's device sampling lanes (params + key re-seed)
         # BEFORE the prefill dispatch: the compiled step samples the
@@ -1627,11 +1691,11 @@ class Engine:
         if self.spec is not None and not self._spec_admit(req, L):
             return None
         now = time.perf_counter()
-        self.metrics.prefill_time_s += now - t0
+        self.metrics.prefill_time_s += now - sp.t0
         req.state, req.prefill_bucket = "running", bucket
         req.model_version = self.model_version
-        req._seq_len = L
-        self.running[req.slot] = req
+        self._occupy(req, L)
+        sp.set(bucket=bucket, hit_tokens=prefix_hit, outcome="admitted")
         self.metrics.on_admit(bucket, L, len(self.queue))
         self.tracer.on_admitted(req, self.name, bucket, req.slot,
                                 prefix_hit)
@@ -1671,7 +1735,8 @@ class Engine:
         token.  The only host copy is the token scalar itself — a
         per-admission (never per-decode-step) pull, outside the
         hot-path dispatch functions."""
-        tok = int(tok_t.numpy())
+        with _spans.span("engine.first_token"):
+            tok = int(tok_t.numpy())
         if self.journal is not None and req.journal_id is not None:
             # journal BEFORE the user-visible emit: delivery is
             # at-least-once across a crash by contract
@@ -1714,7 +1779,7 @@ class Engine:
         req.t_finish = time.perf_counter()
         slot = req.slot
         if slot is not None:
-            self.running.pop(slot, None)
+            self._vacate(slot)
             if slot not in self.free_slots:
                 self.free_slots.append(slot)
             if self.kv_layout == "paged":
@@ -1811,22 +1876,23 @@ class Engine:
         d2h coercion belongs here (tpulint TPL106 enforces it, with ZERO
         suppressions since on-device sampling landed).  Returns
         ``(token_tensor, t0)`` or None (nothing ran / batch failed)."""
-        if self.kv_layout == "paged":
-            self._prepare_decode_paged()
-            if not self.running:
-                return None
-        active = np.zeros((self.num_slots,), dtype=np.int32)
-        for slot in self.running:
-            active[slot] = 1
-        t0 = time.perf_counter()
+        with _spans.span("engine.prepare_decode"):
+            if self.kv_layout == "paged":
+                self._prepare_decode_paged()
+                if not self.running:
+                    return None
+            active = np.zeros((self.num_slots,), dtype=np.int32)
+            for slot in self.running:
+                active[slot] = 1
         san = self.sanitizer
         try:
             # the compiled step itself must not round-trip to host: the
             # sanitizer arms jax.transfer_guard_device_to_host around it
             # (log, or disallow in strict mode — backend-enforced on TPU)
-            with (nullcontext() if san is None else san.compiled_guard()):
+            with (nullcontext() if san is None else san.compiled_guard()), \
+                    _spans.span("engine.decode") as sp:
                 out = self._step_call("serving.decode", self._decode_fn,
-                                      to_tensor(active))
+                                      to_tensor(active), span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
             # retry budget exhausted: every request in THIS batch is
             # implicated; fail them (reclaiming their slots) and keep the
@@ -1844,7 +1910,7 @@ class Engine:
             return None
         if san is not None:
             san.note_step()             # the compiled step actually ran
-        return out, t0
+        return out, sp.t0
 
     def _deliver_tokens(self, out, t0: float) -> None:
         """Post-dispatch host half of a decode step: pull the sampled
@@ -1852,8 +1918,15 @@ class Engine:
         and stop checks are host work by nature, and the pull sits
         outside both the sanitizer window and the hot-path dispatch),
         then run callbacks and retirement checks."""
-        toks = out.numpy()                       # [slots] int32
-        now = time.perf_counter()
+        with _spans.span("engine.pull") as pull:
+            toks = out.numpy()                   # [slots] int32
+        now = pull.t1            # the step's latency runs on the spans' stamps
+        with _spans.span("engine.deliver") as sp:
+            ran = len(self.running)
+            self._deliver_pulled(toks, now, now - t0)
+            sp.attrs["retired"] = ran - len(self.running)
+
+    def _deliver_pulled(self, toks, now: float, step_s: float) -> None:
         if self.journal is not None:
             # ONE batched record per engine step covering every active
             # slot (never one record per token) — the same batching
@@ -1864,14 +1937,14 @@ class Engine:
             if tokmap:
                 self.journal.record_tokens(self.name, self._step_counter,
                                            tokmap)
-        self.metrics.on_decode_step(len(self.running), now - t0)
+        self.metrics.on_decode_step(len(self.running), step_s)
         tr = self.tracer
         if tr.enabled:
             # ONE batched event per engine step, never one per token
             tr.on_decode_step(self.name, self._step_counter,
-                              list(self.running), now - t0)
+                              list(self.running), step_s, t=now)
         for slot, req in list(self.running.items()):
-            req._seq_len += 1                    # token written this step
+            self._advance(req)                   # token written this step
             if not self._emit_token(req, int(toks[slot]), now):
                 continue
             if req.done:                 # cancelled from inside its cb
@@ -1924,10 +1997,41 @@ class Engine:
         the measured per-round host transfers stay 0.0).  Returns
         ``(round_tensor, t0)`` or None (nothing ran / round failed)."""
         spec = self.spec
-        if self.kv_layout == "paged":
-            self._prepare_spec_paged()
-        if not self.running:
+        with _spans.span("engine.prepare_decode"):
+            if self.kv_layout == "paged":
+                self._prepare_spec_paged()
+            if not self.running:
+                return None
+            active, cap = self._spec_masks()
+        san = self.sanitizer
+        try:
+            with (nullcontext() if san is None else san.compiled_guard()), \
+                    _spans.span("engine.decode") as sp:
+                act_t = to_tensor(active)
+                for j in range(spec.k):
+                    self._step_call("serving.spec_draft",
+                                    self._draft_decode_fn, act_t,
+                                    to_tensor(np.int32(j)), span=sp)
+                out = self._step_call("serving.spec_verify",
+                                      self._verify_fn, act_t,
+                                      to_tensor(cap), span=sp)
+        except Exception as e:           # noqa: BLE001 — isolated upstream
+            if san is not None and "device-to-host transfer" in str(e):
+                san.guard_violations += 1
+            msg = (f"speculative round failed after "
+                   f"{self.max_step_retries} "
+                   f"retr{'y' if self.max_step_retries == 1 else 'ies'}: "
+                   f"{type(e).__name__}: {e}")
+            for req in list(self.running.values()):
+                self._retire(req, "failed", error=msg, kind="replica")
             return None
+        if san is not None:
+            san.note_step()             # one round == one counted step
+        return out, sp.t0
+
+    def _spec_masks(self):
+        """``(active, cap)`` of a speculative round, host ints only."""
+        spec = self.spec
         active = np.zeros((self.num_slots,), dtype=np.int32)
         cap = np.ones((self.num_slots,), dtype=np.int32)
         for slot, req in self.running.items():
@@ -1944,31 +2048,7 @@ class Engine:
                                    req.max_new_tokens
                                    - len(req.output_ids),
                                    self.max_seq - req._seq_len))
-        t0 = time.perf_counter()
-        san = self.sanitizer
-        try:
-            with (nullcontext() if san is None else san.compiled_guard()):
-                act_t = to_tensor(active)
-                for j in range(spec.k):
-                    self._step_call("serving.spec_draft",
-                                    self._draft_decode_fn, act_t,
-                                    to_tensor(np.int32(j)))
-                out = self._step_call("serving.spec_verify",
-                                      self._verify_fn, act_t,
-                                      to_tensor(cap))
-        except Exception as e:           # noqa: BLE001 — isolated upstream
-            if san is not None and "device-to-host transfer" in str(e):
-                san.guard_violations += 1
-            msg = (f"speculative round failed after "
-                   f"{self.max_step_retries} "
-                   f"retr{'y' if self.max_step_retries == 1 else 'ies'}: "
-                   f"{type(e).__name__}: {e}")
-            for req in list(self.running.values()):
-                self._retire(req, "failed", error=msg, kind="replica")
-            return None
-        if san is not None:
-            san.note_step()             # one round == one counted step
-        return out, t0
+        return active, cap
 
     def _deliver_spec(self, out, t0: float) -> None:
         """Post-dispatch host half of a speculative round: pull the ONE
@@ -1980,11 +2060,16 @@ class Engine:
         journal/metrics/tracer records (one batched record per ROUND —
         the decode_step discipline), stream callbacks, and retirement
         checks."""
-        arr = out.numpy()                # [slots, k+2] int32
-        now = time.perf_counter()
+        with _spans.span("engine.pull") as pull:
+            arr = out.numpy()            # [slots, k+2] int32
+        with _spans.span("engine.deliver") as sp:
+            ran = len(self.running)
+            self._deliver_round(arr, pull.t1, pull.t1 - t0)
+            sp.attrs["retired"] = ran - len(self.running)
+
+    def _deliver_round(self, arr, now: float, step_s: float) -> None:
         spec = self.spec
         running = list(self.running.items())
-        step_s = now - t0
         delivered: Dict[int, List[int]] = {}
         accepted_total = 0
         for slot, req in running:
@@ -2019,10 +2104,10 @@ class Engine:
             tr.on_verify_step(self.name, self._step_counter,
                               [s for s, _ in running], step_s,
                               proposed=spec.k * len(running),
-                              accepted=accepted_total)
+                              accepted=accepted_total, t=now)
         for slot, req in running:
             m = int(arr[slot, 0])
-            req._seq_len += m            # the in-graph advance, mirrored
+            self._advance(req, m)        # the in-graph advance, mirrored
             if self.kv_layout == "paged":
                 # rollback bookkeeping: drop table blocks past the
                 # accepted length (no copy — refcounts + table writes)
@@ -2080,7 +2165,26 @@ class Engine:
                     f"{self._unhealthy_reason}") from e
         if self._prefill_fn is None:
             self._build_steps()
-        self._reap(time.perf_counter())
+        with _spans.span("engine.step", step=self._step_counter,
+                         kv_tokens=self._kv_tokens) as sp:
+            self._admitted_step = 0
+            self._schedule(sp.t0)
+            self._step_counter += 1
+            sp.set(admitted=self._admitted_step,
+                   running=len(self.running), queued=len(self.queue))
+            if self.kv_layout == "paged":
+                sp.attrs["free_blocks"] = self.cache.allocator.free_blocks
+        # the step's one record: the closed span's stamps and attributes
+        # are the health clock and the always-on flight ring's summary
+        # (the post-mortem tail), with no clock read of their own
+        self._last_step_t = sp.t1
+        self.flight.record_span(sp)
+        return bool(self.running or self.queue)
+
+    def _schedule(self, now: float) -> None:
+        """The body of one tick: reap, admit, decode."""
+        with _spans.span("engine.reap"):
+            self._reap(now)
         while self.queue:
             now_a = time.perf_counter()
             i = self._best_queued_index(now_a)
@@ -2142,21 +2246,6 @@ class Engine:
         self.metrics.on_slots(len(self.running))
         if self.running:
             self._decode()
-        self._step_counter += 1
-        self._last_step_t = time.perf_counter()
-        # always-on flight recorder: one compact host-side summary per
-        # step into the bounded ring (the post-mortem tail)
-        if self.kv_layout == "paged":
-            self.flight.record(step=self._step_counter,
-                               running=len(self.running),
-                               queued=len(self.queue),
-                               free_blocks=self.cache.allocator
-                               .free_blocks)
-        else:
-            self.flight.record(step=self._step_counter,
-                               running=len(self.running),
-                               queued=len(self.queue))
-        return bool(self.running or self.queue)
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Drive ``step()`` until idle (or ``max_steps``)."""

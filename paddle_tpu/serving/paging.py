@@ -51,6 +51,18 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheContext",
 #: Block id every idle/retired slot's table points at.  Never allocated.
 SCRATCH_BLOCK = 0
 
+#: named scopes of the pool's traffic in a compiled program's op names: the
+#: scatter that writes new K/V through the block table, and the slice that
+#: reads one layer out of the pool
+KV_WRITE_SCOPE = "kv.write"
+KV_READ_SCOPE = "kv.layer_read"
+
+
+def _layer_of(pool, layer_idx: int):
+    """One layer's ``[blocks, block_size, Hkv, D]`` out of the pool."""
+    with jax.named_scope(KV_READ_SCOPE):
+        return pool[:, layer_idx]
+
 
 class AllocatorError(RuntimeError):
     """A block-accounting invariant was about to be violated (double
@@ -418,7 +430,8 @@ class PagedKVCache:
             arr = buf._value()
             upd = new._value().astype(arr.dtype)[0]     # [S, Hkv, D]
             upd = upd.reshape(n_blocks, bs, *upd.shape[1:])
-            buf._set_data(arr.at[block_ids, layer_idx].set(upd))
+            with jax.named_scope(KV_WRITE_SCOPE):
+                buf._set_data(arr.at[block_ids, layer_idx].set(upd))
 
     def set_length(self, slot, length) -> None:
         s = _as_i32(slot).reshape(())
@@ -442,9 +455,10 @@ class PagedKVCache:
         for buf, new in ((self.k, k), (self.v, v)):
             arr = buf._value()
             upd = new._value().astype(arr.dtype)[:, 0]   # [slots, Hkv, D]
-            arr = arr.at[block_ids, layer_idx, off].set(upd)
+            with jax.named_scope(KV_WRITE_SCOPE):
+                arr = arr.at[block_ids, layer_idx, off].set(upd)
             buf._set_data(arr)
-            layers.append(arr[:, layer_idx])
+            layers.append(_layer_of(arr, layer_idx))
         return layers[0], layers[1], tbl, lens
 
     def decode_write(self, layer_idx: int, k, v
@@ -501,9 +515,10 @@ class PagedKVCache:
         for buf, new in ((self.k, k), (self.v, v)):
             arr = buf._value()
             upd = new._value().astype(arr.dtype)    # [slots, W, Hkv, D]
-            arr = arr.at[block_ids, layer_idx, off].set(upd)
+            with jax.named_scope(KV_WRITE_SCOPE):
+                arr = arr.at[block_ids, layer_idx, off].set(upd)
             buf._set_data(arr)
-            layers.append(arr[:, layer_idx])
+            layers.append(_layer_of(arr, layer_idx))
         return layers[0], layers[1], tbl, lens
 
     def verify_attention(self, layer_idx: int, q, k, v):
@@ -598,13 +613,15 @@ class PagedCacheContext(CacheContext):
                 tbl, s, axis=0, keepdims=False)              # [MB]
             return paged_prefill_attention(
                 q,
-                Tensor._wrap(self.cache.k._value()[:, self.layer_idx]),
-                Tensor._wrap(self.cache.v._value()[:, self.layer_idx]),
+                Tensor._wrap(_layer_of(self.cache.k._value(),
+                                       self.layer_idx)),
+                Tensor._wrap(_layer_of(self.cache.v._value(),
+                                       self.layer_idx)),
                 Tensor._wrap(row), start,
                 interpret=self.cache._interpret, mesh=self.cache.mesh)
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)   # [1, MB]
         k_all = Tensor._wrap(gather_block_kv(
-            self.cache.k._value()[:, self.layer_idx], row))
+            _layer_of(self.cache.k._value(), self.layer_idx), row))
         v_all = Tensor._wrap(gather_block_kv(
-            self.cache.v._value()[:, self.layer_idx], row))
+            _layer_of(self.cache.v._value(), self.layer_idx), row))
         return block_prefill_attention(q, k_all, v_all, start)

@@ -271,6 +271,7 @@ class DeviceSampler:
 
     # -- traced sampling (inside the compiled steps) -----------------------
 
+    @jax.named_scope("sampler.sample")
     def sample_slot(self, slot, logits_row):
         """Prefill-side: sample ONE slot's first token from its ``[V]``
         last-position logits.  ``slot`` may be traced; key and token
@@ -306,6 +307,7 @@ class DeviceSampler:
                     self.grammar.advance(gid, gst, tok[0])))
         return tok[0]
 
+    @jax.named_scope("sampler.sample")
     def sample_all(self, logits):
         """Decode-side: sample every slot from ``[slots, V]`` logits;
         advances every key lane and rewrites the token lane (idle slots

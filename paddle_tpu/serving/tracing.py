@@ -178,11 +178,14 @@ class RequestTracer:
 
     def _event(self, kind: str, trace: Optional[str] = None,
                span: Optional[int] = None, replica: Optional[str] = None,
-               **attrs) -> None:
+               _t: Optional[float] = None, **attrs) -> None:
         if len(self.events) >= self.max_events:
             self.dropped += 1
             return
-        ev = {"ts": self._now(), "kind": kind}
+        # ``_t``: a ``perf_counter`` stamp the caller already holds (the
+        # engine's step spans): the event reads no clock of its own
+        ev = {"ts": self._now() if _t is None else _t - self.t0,
+              "kind": kind}
         if trace is not None:
             ev["trace"] = trace
         if span is not None:
@@ -267,23 +270,25 @@ class RequestTracer:
                     tenant=getattr(req, "tenant", "base"))
 
     def on_decode_step(self, replica: str, step: int, slots,
-                       dt_s: float) -> None:
+                       dt_s: float, t: Optional[float] = None) -> None:
         """ONE event per engine step (not per token): the slots that
-        decoded this step and the step latency."""
-        self._event("decode_step", replica=replica, step=step,
+        decoded this step and the step latency.  ``t`` is the end stamp
+        of the engine's ``engine.pull`` span (``obs.spans``), which
+        ``dt_s`` was taken from too."""
+        self._event("decode_step", replica=replica, step=step, _t=t,
                     slots=list(slots), n_active=len(slots),
                     dt_ms=round(dt_s * 1e3, 3))
 
     def on_verify_step(self, replica: str, step: int, slots,
                        dt_s: float, *, proposed: int,
-                       accepted: int) -> None:
+                       accepted: int, t: Optional[float] = None) -> None:
         """The speculative variant of :meth:`on_decode_step`: ONE event
         per engine ROUND (k draft steps + one verify step, never one
         per token or per draft step), carrying the round's (proposed,
         accepted) draft-token pair — the acceptance story per round,
         rendered by the Perfetto exporter as an ``accepted_tokens``
         counter track next to ``active_slots``."""
-        self._event("verify_step", replica=replica, step=step,
+        self._event("verify_step", replica=replica, step=step, _t=t,
                     slots=list(slots), n_active=len(slots),
                     dt_ms=round(dt_s * 1e3, 3),
                     proposed=int(proposed), accepted=int(accepted))

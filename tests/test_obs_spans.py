@@ -1,0 +1,455 @@
+"""The program's one span primitive (``paddle_tpu.obs.spans``), the surfaces
+that go through it, the spans of ``Engine.step`` and of a program-cache miss,
+and the device-side names a compiled program carries (module name, kernel
+names, scopes)."""
+import re
+import threading
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import profiler
+from paddle_tpu.obs import spans
+from paddle_tpu.obs.flight import FlightRecorder
+from paddle_tpu.obs.train import StepTimeline
+from paddle_tpu.serving import Engine
+from paddle_tpu.serving.tracing import RequestTracer
+
+NAME, START, END, PARENT, ATTRS, SID = range(6)
+
+
+def rows_since(t):
+    return spans.snapshot(since=t)
+
+
+# -- the primitive -----------------------------------------------------------
+
+def test_nesting_gives_parent_pointers_and_children_close_first():
+    t = spans.clock()
+    with spans.span("outer", k=1) as outer:
+        with spans.span("inner") as inner:
+            assert spans._stack()[-1] is inner
+        spans.mark("moment", n=2)
+    got = rows_since(t)
+    assert [r[NAME] for r in got] == ["inner", "moment", "outer"]
+    by = {r[NAME]: r for r in got}
+    assert by["outer"][PARENT] is None
+    assert by["inner"][PARENT] == by["moment"][PARENT] == outer.sid
+    assert by["outer"][SID] == outer.sid and by["outer"][ATTRS] == {"k": 1}
+    assert by["outer"][START] <= by["inner"][START] <= by["inner"][END] \
+        <= by["outer"][END]
+    assert by["moment"][START] == by["moment"][END]
+    assert (outer.t0, outer.t1) == (by["outer"][START], by["outer"][END])
+    assert spans._stack() == []
+
+
+def test_attributes_set_until_the_span_closes_reach_its_row():
+    t = spans.clock()
+    with spans.span("late", a=1) as sp:
+        sp.set(b=2)
+        sp.attrs["c"] = 3
+    assert rows_since(t)[-1][ATTRS] == {"a": 1, "b": 2, "c": 3}
+
+
+def test_the_ring_is_one_bounded_deque():
+    assert spans._ring.maxlen == spans.RING_ROWS >= 100_000
+    for _ in range(spans.RING_ROWS + 10):
+        spans.mark("fill")
+    assert len(spans.snapshot()) == spans.RING_ROWS
+
+
+def test_begin_end_without_with_and_a_double_end():
+    t = spans.clock()
+    sp = spans.span("manual").begin()
+    sp.end()
+    sp.end()
+    assert [r[NAME] for r in rows_since(t)] == ["manual"]
+    assert spans._stack() == []
+
+
+def test_the_open_span_stack_is_per_thread():
+    seen = {}
+
+    def other():
+        seen["open"] = list(spans._stack())
+        with spans.span("other.thread"):
+            pass
+
+    t = spans.clock()
+    with spans.span("main.thread"):
+        th = threading.Thread(target=other)
+        th.start()
+        th.join()
+    assert seen["open"] == []
+    by = {r[NAME]: r for r in rows_since(t)}
+    assert by["other.thread"][PARENT] is None
+
+
+def test_since_keeps_rows_that_ended_at_or_after_it():
+    with spans.span("before"):
+        pass
+    t = spans.clock()
+    with spans.span("after"):
+        pass
+    assert [r[NAME] for r in rows_since(t)] == ["after"]
+
+
+# -- the older surfaces are users of it --------------------------------------
+
+def test_record_event_lands_in_the_ring():
+    t = spans.clock()
+    with profiler.RecordEvent("user.block"):
+        ev = profiler.RecordEvent("user.manual")
+        ev.begin()
+        ev.end()
+        ev.end()                             # idempotent, as before
+    by = {r[NAME]: r for r in rows_since(t)}
+    assert by["user.manual"][PARENT] == by["user.block"][SID]
+
+
+def test_step_timeline_phase_opens_through_the_primitive():
+    tl = StepTimeline()
+    t = spans.clock()
+    tl.begin_step(7)
+    with tl.phase("data_fetch"):
+        time.sleep(0.002)
+    tl.end_step()
+    with tl.phase("checkpoint_commit"):      # a background phase
+        pass
+    got = [r for r in rows_since(t) if r[NAME].startswith("train.")]
+    assert [(r[NAME], r[ATTRS]) for r in got] == [
+        ("train.data_fetch", {"step": 7}), ("train.checkpoint_commit", {})]
+    # the timeline's own row carries the span's stamps, not a second read
+    phase = [s for s in tl.spans.values() if s["name"] == "data_fetch"][0]
+    assert phase["t_start"] == pytest.approx(got[0][START] - tl.t0, abs=1e-9)
+    assert phase["t_end"] == pytest.approx(got[0][END] - tl.t0, abs=1e-9)
+    assert tl.phase_seconds["data_fetch"] == pytest.approx(
+        got[0][END] - got[0][START])
+
+
+def test_flight_ring_takes_a_closed_span_as_its_step_record():
+    rec = FlightRecorder(capacity=4, name="fr-span")
+    with spans.span("engine.step", step=3) as sp:
+        sp.set(running=2)
+    rec.record_span(sp)
+    ev = rec.peek("x")["events"][-1]
+    assert ev == {"step": 3, "running": 2, "t": round(sp.t1, 6)}
+    assert rec._ring[-1] is sp.attrs         # one dict, not a copy
+    rec.record(step=4)                       # the kwargs form stays
+    assert rec.peek("x")["events"][-1]["step"] == 4
+
+
+# -- Engine.step --------------------------------------------------------------
+
+STEP_CHILDREN = ["engine.reap", "engine.admit", "engine.prepare_decode",
+                 "engine.decode", "engine.pull", "engine.deliver"]
+ADMIT_CHILDREN = ["engine.prefix_lookup", "engine.prefill",
+                  "engine.first_token"]
+
+
+@pytest.fixture(scope="module")
+def engine_run(serving_model):
+    """A paged ``gpt_tiny`` engine driven through three requests that share
+    a prefix; returns ``(engine, rows of the run, requests)``."""
+    tr = RequestTracer()
+    eng = Engine(serving_model, num_slots=4, max_seq=64, min_bucket=8,
+                 kv_layout="paged", block_size=8, tracer=tr)
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    prefix = rng.integers(1, 100, (16,))
+    t = spans.clock()
+    reqs = [eng.add_request(
+        np.concatenate([prefix, rng.integers(1, 100, (5 + i,))]),
+        max_new_tokens=4 + i) for i in range(3)]
+    eng.run()
+    assert all(r.finished for r in reqs)
+    return eng, rows_since(t), reqs
+
+
+def kids_of(rows):
+    out = {}
+    for r in rows:
+        out.setdefault(r[PARENT], []).append(r)
+    return out
+
+
+def test_every_engine_step_has_its_phases_in_order(engine_run):
+    eng, rows, _reqs = engine_run
+    kids = kids_of(rows)
+    steps = [r for r in rows if r[NAME] == "engine.step"]
+    assert len(steps) >= 4 and all(r[PARENT] is None for r in steps)
+    for st in steps:
+        names = [c[NAME] for c in kids[st[SID]]]
+        # the order of the table in PERF.md: reap, admits, then the decode
+        order = [STEP_CHILDREN.index(n) for n in names]
+        assert order == sorted(order), names
+        assert names[0] == "engine.reap"
+        assert names[-4:] == STEP_CHILDREN[2:], names
+        assert names.count("engine.admit") == st[ATTRS]["admitted"]
+        assert {"step", "kv_tokens", "admitted", "running", "queued",
+                "free_blocks"} <= set(st[ATTRS])
+    assert [s[ATTRS]["step"] for s in steps] == list(range(
+        steps[0][ATTRS]["step"], steps[0][ATTRS]["step"] + len(steps)))
+    # kv_tokens is a running integer: cached tokens of the running slots
+    assert steps[0][ATTRS]["kv_tokens"] == 0
+    assert steps[1][ATTRS]["kv_tokens"] == sum(
+        21 + i for i in range(3)) + 3        # prompts + one decoded token each
+    assert steps[-1][ATTRS]["running"] == 0 and eng._kv_tokens == 0
+
+
+def test_admit_spans_carry_the_request(engine_run):
+    _eng, rows, reqs = engine_run
+    kids = kids_of(rows)
+    admits = [r for r in rows if r[NAME] == "engine.admit"]
+    assert len(admits) == len(reqs)
+    assert len({a[ATTRS]["trace"] for a in admits}) == len(reqs)
+    for a, req in zip(admits, reqs):
+        at = a[ATTRS]
+        assert at["outcome"] == "admitted" and at["queue_wait_ms"] >= 0
+        assert at["prompt_tokens"] == int(req.prompt_ids.size)
+        assert at["trace"].endswith(f":r{req.request_id}")
+        assert [c[NAME] for c in kids[a[SID]]] == ADMIT_CHILDREN
+        assert kids[a[SID]][1][ATTRS] == {"bucket": at["bucket"],
+                                          "attempts": 1}
+    # the later two hit the first one's two whole prefix blocks
+    assert [a[ATTRS]["hit_tokens"] for a in admits] == [0, 16, 16]
+
+
+def test_step_feeds_flight_and_tracer_from_its_spans(engine_run):
+    eng, rows, _reqs = engine_run
+    last = [r for r in rows if r[NAME] == "engine.step"][-1]
+    ev = eng.flight.peek("x")["events"][-1]
+    assert ev["t"] == round(last[END], 6)
+    assert {k: ev[k] for k in ("step", "running", "queued", "admitted")} == \
+        {k: last[ATTRS][k] for k in ("step", "running", "queued", "admitted")}
+    assert eng._last_step_t == last[END]
+    # the tracer's batched decode_step event is stamped with the pull's end
+    pulls = [r[END] for r in rows if r[NAME] == "engine.pull"]
+    evs = [e for e in eng.tracer.events if e["kind"] == "decode_step"]
+    assert [e["ts"] for e in evs[-len(pulls):]] == pytest.approx(
+        [p - eng.tracer.t0 for p in pulls], abs=1e-9)
+
+
+def test_a_step_costs_a_constant_number_of_rows_and_clock_reads(
+        engine_run, monkeypatch):
+    """No timing: a decode-only step is six spans, each two clock reads and
+    one row, whatever the batch holds."""
+    eng, _rows, _reqs = engine_run
+    for n in (1, 3):
+        for i in range(n):
+            eng.add_request(np.arange(1, 9 + i), max_new_tokens=6)
+        eng.step()                           # admits
+        reads = []
+        real = spans.clock
+        monkeypatch.setattr(spans, "clock",
+                            lambda: reads.append(1) or real())
+        t = real()
+        eng.step()                           # decode only
+        monkeypatch.setattr(spans, "clock", real)
+        got = rows_since(t)
+        assert [r[NAME] for r in got] == [
+            "engine.reap", "engine.prepare_decode", "engine.decode",
+            "engine.pull", "engine.deliver", "engine.step"]
+        assert len(reads) == 2 * len(got)
+        eng.run()
+
+
+# -- a program-cache miss ------------------------------------------------------
+
+def test_a_miss_is_a_trace_and_a_compile_span_and_a_hit_is_nothing():
+    @paddle.jit.to_static
+    def doubled(x):
+        return x * 2
+
+    x = paddle.to_tensor(np.ones((3,), "float32"))
+    t = spans.clock()
+    doubled(x)
+    doubled(x)
+    got = [r for r in rows_since(t) if r[NAME].startswith("jit.")]
+    assert [r[NAME] for r in got] == ["jit.trace", "jit.compile"]
+    for r in got:
+        assert r[ATTRS]["fn"].endswith("doubled")
+        assert r[ATTRS]["arg_specs"] == "float32[3]"
+        assert "test_obs_spans.py" in r[ATTRS]["site"]
+        assert re.fullmatch(r"[0-9a-f]{12}", r[ATTRS]["key"])
+    doubled(paddle.to_tensor(np.ones((4,), "float32")))     # another miss
+    assert len([r for r in rows_since(t) if r[NAME] == "jit.trace"]) == 2
+
+
+# -- device-side names ---------------------------------------------------------
+
+@pytest.mark.parametrize("fn,want", [
+    (lambda x: x, "_lambda_"),
+    (np.ones((1,)).sum, "sum"),
+    (type("Odd", (), {"__call__": lambda self, x: x})(), "Odd"),
+])
+def test_program_name_is_the_functions_own_as_an_identifier(fn, want):
+    from paddle_tpu.jit.trace import program_name
+
+    assert program_name(fn) == want
+
+
+def test_program_names_leave_the_executable_cache_keys_alone():
+    def first_name(x):
+        return x + 1
+
+    def second_name(x):
+        return x + 1
+
+    a, b = paddle.jit.to_static(first_name), paddle.jit.to_static(second_name)
+    x = paddle.to_tensor(np.ones((2, 3), "float32"))
+    a(x), b(x)
+    assert list(a.program_cache) == list(b.program_cache)
+    assert "jit_first_name" in a.last_program().compiled_stats()["hlo"]
+    assert "jit_second_name" in b.last_program().compiled_stats()["hlo"]
+
+
+def test_engine_programs_carry_their_names_and_scopes(engine_run):
+    eng, _rows, _reqs = engine_run
+    decode = eng._decode_fn.last_program().compiled_stats()["hlo"]
+    assert decode.startswith("HloModule jit_decode_step")
+    for scope in ("kv.write", "kv.layer_read", "sampler.sample"):
+        assert f"jit(decode_step)/{scope}" in decode or \
+            re.search(rf"jit\(decode_step\)/\S*{re.escape(scope)}", decode), \
+            scope
+    prefill = eng._prefill_fn.last_program().compiled_stats()["hlo"]
+    assert prefill.startswith("HloModule jit_prefill_step")
+    assert "kv.write" in prefill and "sampler.sample" in prefill
+
+
+def test_train_step_carries_its_name_and_scopes():
+    from paddle_tpu.models import GPTForCausalLM, gpt_tiny
+
+    paddle.seed(0)
+    model = GPTForCausalLM(gpt_tiny())
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+
+    @paddle.jit.to_static
+    def tiny_train_step(x, y):
+        loss = model.compute_loss(x, y)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    ids = paddle.to_tensor(np.random.default_rng(0).integers(
+        0, 100, (2, 16)).astype("int64"))
+    tiny_train_step(ids, ids)
+    hlo = tiny_train_step.last_program().compiled_stats()["hlo"]
+    assert hlo.startswith("HloModule jit_tiny_train_step")
+    assert "loss.streamed_ce" in hlo                       # forward ...
+    assert "transpose(jvp(loss.streamed_ce))" in hlo       # ... and backward
+    assert "optimizer.adamw" in hlo
+    assert "attention." in hlo
+
+
+# -- the kernels' names, compiled for the chip that is described here ---------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                   # noqa: BLE001 — no compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def kernel_lines(fn, *shapes):
+    """The ``tpu_custom_call`` instructions of ``fn`` compiled for the
+    described chip, printed as a profiler trace names its events: operands
+    with their shapes."""
+    import jax
+    from jax._src.lib import xla_client as xc
+
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    opts = xc._xla.HloPrintOptions()
+    opts.print_operand_shape = True
+    opts.print_metadata = False
+    opts.print_backend_config = False
+    text = compiled.runtime_executable().hlo_modules()[0].to_string(opts)
+    return [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+            if "tpu_custom_call" in ln]
+
+
+def load_patterns(kernel):
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "kernel_costs",
+        kernel + ".py")
+    spec = importlib.util.spec_from_file_location("kc_" + kernel, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_flash_kernels_are_named_and_the_accepted_patterns_still_match(
+        one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import ATTN_SCOPE_PALLAS
+    from paddle_tpu.ops.pallas import flash_attention_kernel as fk
+
+    def train(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope(ATTN_SCOPE_PALLAS):
+                o = fk.flash_attention_fused(q, k, v, causal=True)
+            return (o.astype(jnp.float32) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    def infer(q, k, v):
+        with jax.named_scope(ATTN_SCOPE_PALLAS):
+            return fk.flash_attention_fused(q, k, v, causal=True)
+
+    x = jax.ShapeDtypeStruct((2, 1024, 16, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kc = load_patterns("flash_attention")
+    lines = kernel_lines(train, x, x, x)
+    names = [ln.split(" = ")[0] for ln in lines]
+    assert [re.sub(r"\.\d+$", "", n) for n in names] == [
+        "%" + fk.FWD_NAME, "%" + fk.BWD_DKV_NAME, "%" + fk.BWD_DQ_NAME]
+    fwd = [ln for ln in lines if any(re.search(p, ln) for p in kc.FORWARD)]
+    bwd = [ln for ln in lines if any(re.search(p, ln) for p in kc.BACKWARD)]
+    assert len(fwd) == 1 and len(bwd) == 2 and not set(fwd) & set(bwd)
+    (only,) = kernel_lines(infer, x, x, x)
+    assert any(re.search(p, only) for p in kc.FORWARD)
+    assert not any(re.search(p, only) for p in kc.BACKWARD)
+
+
+def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(
+        one_chip):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import paged_attention_kernel as pk
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots, heads, hd, bs, blocks, mb = 32, 16, 64, 16, 2049, 64
+    pool = sds((blocks, bs, heads, hd), jnp.bfloat16)
+    kc = load_patterns("paged_decode")
+    (decode,) = kernel_lines(
+        lambda q, k, v, t, n: pk.paged_decode_attention_kernel(q, k, v, t, n),
+        sds((slots, 1, heads, hd), jnp.bfloat16), pool, pool,
+        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32))
+    assert decode.startswith("%paged_decode_attention.")
+    assert any(re.search(p, decode) for p in kc.PATTERNS)
+    (prefill,) = kernel_lines(
+        lambda q, k, v, row, st: pk.paged_prefill_attention_kernel(
+            q, k, v, row, st),
+        sds((1, 256, heads, hd), jnp.bfloat16), pool, pool,
+        sds((mb,), jnp.int32), sds((), jnp.int32))
+    assert prefill.startswith("%paged_prefill_attention.")
+    assert not any(re.search(p, prefill) for p in kc.PATTERNS)
