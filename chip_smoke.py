@@ -41,6 +41,7 @@ from __future__ import annotations
 import gc
 import json
 import math
+import re
 import sys
 import time
 import traceback
@@ -175,9 +176,10 @@ def train_phase(cfg, *, seq: int, batch_per_device: int, steps: int,
 
 # -- serve -------------------------------------------------------------------
 
-def _decode_hlo(eng) -> str:
-    """Optimized HLO of the engine's one decode program, lowered under the
-    contexts ``Engine._call_counted`` runs it in."""
+def _decode_stats(eng) -> dict:
+    """``compiled_stats()`` (optimized HLO, memory analysis) of the engine's
+    one decode program, lowered under the contexts ``Engine._call_counted``
+    runs it in."""
     from contextlib import nullcontext
 
     from paddle_tpu.core.autograd import no_grad
@@ -185,7 +187,29 @@ def _decode_hlo(eng) -> str:
     (prog,) = eng._decode_fn.program_cache.values()
     mesh_ctx = eng.shard.context() if eng.shard is not None else nullcontext()
     with mesh_ctx, no_grad():
-        return prog.compiled_stats()["hlo"]
+        return prog.compiled_stats()
+
+
+_HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "bf16": 2, "f16": 2, "s16": 2,
+                 "u16": 2, "f32": 4, "s32": 4, "u32": 4, "f64": 8, "s64": 8,
+                 "u64": 8}
+_HLO_MOVE = re.compile(
+    r"^\s*(?:ROOT\s+)?(%?[\w.\-]+) = (\w+)\[([\d,]*)\]\S*\s+"
+    r"(copy|transpose|slice)\(", re.M)
+
+
+def pool_sized_moves(hlo: str, nbytes: int) -> list:
+    """The ``copy``, ``transpose`` and ``slice`` instructions of an optimized
+    HLO module, fused computations included, whose result holds ``nbytes``
+    or more (by its shape; a tiled layout's padding is not counted): what a
+    program does to a KV pool that it cannot write and read where it is."""
+    out = []
+    for name, dtype, dims, op in _HLO_MOVE.findall(hlo):
+        size = _HLO_ITEMSIZE.get(dtype, 0) * math.prod(
+            int(d) for d in dims.split(",") if d)
+        if size >= nbytes:
+            out.append(f"{op} {name} {dtype}[{dims}]")
+    return out
 
 
 def _prompts(rs, vocab: int, lengths, shared_len: int) -> list:
@@ -213,8 +237,11 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
     retried (the first failure's error string is printed), compile misses
     == buckets + 1, prefix hit rate > 0, block invariants ok, the paged
     kernels compiled for the device (interpret mode only off-TPU) and are
-    in the decode program's HLO.  Greedy outputs must equal those of a
-    second engine over the same model with ``kernel="reference"``.
+    in the decode program's HLO, which on the chip moves no layer buffer of
+    the KV pool (no ``copy``, ``transpose`` or ``slice`` of that size,
+    both pools aliased; the one-chip engine's program only).  Greedy
+    outputs must equal those of a second engine over the same model with
+    ``kernel="reference"``.
     ``model_parallel`` serves through ``serving_mesh(model_parallel)``,
     checks that the KV pool is sharded over that many devices, and
     compares greedy outputs with ``expect_tokens`` (the one-chip run's):
@@ -288,10 +315,28 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
             kernel == "pallas", kernel)
     c.check("paged kernels compiled for the device iff it is a TPU",
             interpret is (not _on_tpu()), f"_interpret={interpret}")
-    n_pallas = _decode_hlo(eng).count(PALLAS_CALL)
+    decode = _decode_stats(eng)
+    n_pallas = decode["hlo"].count(PALLAS_CALL)
     want = eng.config.num_hidden_layers if _on_tpu() else 0
     c.check(f"{want} Pallas custom calls in the decode program's HLO",
             n_pallas == want, n_pallas)
+    # the pool is written and read where it is stored: one buffer per layer
+    # and side, donated, in the kernels' own form (interpret mode's
+    # emulation copies its operands, so only the chip's program is held to
+    # it; a sharded engine's program is per shard and is not checked here)
+    pools, layer_buf = eng.cache.nbytes(), eng.cache.layer_nbytes()
+    say(f"  decode program: alias_bytes {decode.get('alias_bytes')} "
+        f"temp_bytes {decode.get('temp_bytes')} pools {pools} "
+        f"layer buffer {layer_buf}")
+    if _on_tpu() and not model_parallel:
+        moves = pool_sized_moves(decode["hlo"], layer_buf)
+        c.check("no copy, transpose or slice of a layer buffer's size in "
+                "the decode program", not moves, moves[:4] or layer_buf)
+        c.check("the decode program aliases both pools and holds under one "
+                "layer buffer of temporaries",
+                decode["alias_bytes"] >= pools
+                and decode["temp_bytes"] < layer_buf,
+                (decode["alias_bytes"], decode["temp_bytes"]))
     tokens = [list(map(int, r.output_ids)) for r in reqs]
     greedy = [tokens[i] for i in greedy_idx]
     out = {"phase": name, "attention_path": path,
@@ -301,7 +346,7 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
            "warmup_s": round(warm_s, 2), "run_s": round(run_s, 3),
            "compile_misses": st["compile_cache"]["misses"]}
     if model_parallel:
-        span = len(eng.cache.k._value().sharding.device_set)
+        span = len(eng.cache.k[0]._value().sharding.device_set)
         c.check(f"KV pool sharded over {model_parallel} devices",
                 span == model_parallel, span)
         out["model_parallel"] = model_parallel

@@ -185,7 +185,7 @@ def block_prefill_attention(query, k_cache, v_cache, start, name=None):
 def _paged_per_shard(kernel, args, mesh):
     """Run a paged kernel once per shard of a sharded engine's ``mesh``
     (``ops.pallas.per_shard``): queries ``[B, S, H, D]`` and pool layers
-    ``[blocks, block_size, Hkv, D]`` both carry heads at dim 2 and split
+    ``[blocks, block_size, Hkv, Dp]`` both carry heads at dim 2 and split
     there over the model axis, like the weights and the KV pool; the block
     table and the scalars replicate."""
     from jax.sharding import PartitionSpec as P
@@ -207,8 +207,9 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
 
     Args:
         query:        ``[B, 1, H, D]`` current-token queries.
-        k_pool:       ``[num_blocks, block_size, Hkv, D]`` one layer of
-                      the paged key pool (current token already written).
+        k_pool:       ``[num_blocks, block_size, Hkv, Dp]`` one layer of
+                      the paged key pool (current token already written;
+                      ``Dp >= D``, lanes past ``D`` zero).
         v_pool:       same for values.
         block_tables: ``[B, max_blocks]`` int32 per-slot block ids.
         lengths:      ``[B]`` int32 current token index per slot.
@@ -241,7 +242,8 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
 
     Args:
         query:     ``[1, S, H, D]`` tail queries (S = tail bucket).
-        k_pool:    ``[num_blocks, block_size, Hkv, D]`` layer key pool.
+        k_pool:    ``[num_blocks, block_size, Hkv, Dp]`` layer key pool
+                   (``Dp >= D``, lanes past ``D`` zero).
         v_pool:    same for values.
         block_row: ``[max_blocks]`` int32 — the slot's block-table row.
         start:     scalar int32 — absolute position of the first query.

@@ -60,6 +60,13 @@ NEG_INF = -1e30
 PREFILL_Q_TILE = 128
 
 
+def _to_lanes(q, lanes: int):
+    """Queries ``[..., D]`` zero-padded to the pool's minor dim: the pad
+    lanes add nothing to a score, and the pool's are zero in the output."""
+    pad = lanes - q.shape[-1]
+    return q if pad == 0 else jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)])
+
+
 # -- decode: one query token per slot, K/V streamed by block table ----------
 
 def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -114,8 +121,10 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
 
     Args:
         q:            ``[B, 1, H, D]`` current-token queries.
-        k_pool:       ``[num_blocks, block_size, Hkv, D]`` one layer of
-                      the paged key pool (current token already written).
+        k_pool:       ``[num_blocks, block_size, Hkv, Dp]`` one layer of
+                      the paged key pool (current token already written);
+                      ``Dp >= D``, lanes past ``D`` zero (the serving
+                      pool stores whole 128-lane rows).
         v_pool:       same for values.
         block_tables: ``[B, max_blocks]`` int32 block ids per slot.
         lengths:      ``[B]`` int32 current token index per slot
@@ -125,11 +134,12 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         ``[B, 1, H, D]`` context.  No contiguous K/V copy is ever
         materialized: each grid step reads one pool block by table value.
     """
-    B, _, H, D = q.shape
-    block_size, Hkv = k_pool.shape[1], k_pool.shape[2]
+    B, _, H, head_dim = q.shape
+    block_size, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
     MB = block_tables.shape[1]
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (head_dim ** 0.5)
+    q = _to_lanes(q, D)
     kernel = functools.partial(_decode_kernel, scale=scale,
                                block_size=block_size)
     # query head h = g * rep + r  ->  q_g[b, r, g]: kv head g lines up
@@ -160,7 +170,7 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       q_g, k_pool, v_pool)
-    return o_g.transpose(0, 2, 1, 3).reshape(B, 1, H, D)
+    return o_g.transpose(0, 2, 1, 3).reshape(B, 1, H, D)[..., :head_dim]
 
 
 # -- fused prefill: cached prefix + causal tail in one kernel scope ---------
@@ -238,7 +248,8 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
 
     Args:
         q:         ``[1, S, H, D]`` tail queries.
-        k_pool:    ``[num_blocks, block_size, Hkv, D]`` layer key pool.
+        k_pool:    ``[num_blocks, block_size, Hkv, Dp]`` layer key pool
+                   (``Dp >= D``, lanes past ``D`` zero).
         v_pool:    same for values.
         block_row: ``[max_blocks]`` int32 — the slot's block-table row.
         start:     ``[1]`` int32 — absolute position of the first query
@@ -247,12 +258,13 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
     Returns:
         ``[1, S, H, D]`` context.
     """
-    _, S, H, D = q.shape
-    block_size, Hkv = k_pool.shape[1], k_pool.shape[2]
+    _, S, H, head_dim = q.shape
+    block_size, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
     MB = block_row.shape[0]
     ts = _q_tile(S)
-    scale = 1.0 / (D ** 0.5)
+    scale = 1.0 / (head_dim ** 0.5)
+    q = _to_lanes(q, D)
     kernel = functools.partial(_prefill_kernel, scale=scale,
                                block_size=block_size)
     # head-major queries, query head h = g * rep + r  ->  q_g[g, r]
@@ -282,4 +294,4 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
         name="paged_prefill_attention",
     )(block_row.astype(jnp.int32),
       jnp.asarray(start, dtype=jnp.int32).reshape(1), q_g, k_pool, v_pool)
-    return o_g.reshape(H, S, D).transpose(1, 0, 2)[None]
+    return o_g.reshape(H, S, D).transpose(1, 0, 2)[None, ..., :head_dim]

@@ -2,8 +2,11 @@
 
 Instead of reserving a contiguous ``max_seq`` stripe per slot (the
 :class:`~.kv_cache.KVCache` layout — HBM sized for the worst-case
-sequence), the paged layout stores K/V in a fixed pool of
-``[num_blocks, layers, block_size, kv_heads, head_dim]`` blocks and
+sequence), the paged layout stores K/V in a fixed pool of blocks — one
+``[num_blocks, block_size, kv_heads, lane_dim]`` buffer per layer and per
+side, the paged kernels' own operand (``lane_dim`` is ``head_dim`` rounded
+up to the 128 lanes of a vector register, see :data:`LANES`), so a
+compiled program writes each in place and reads it as it is — and
 addresses them through per-slot int32 block tables of fixed shape
 ``[slots, max_blocks_per_slot]``.  Two things fall out:
 
@@ -51,17 +54,20 @@ __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheContext",
 #: Block id every idle/retired slot's table points at.  Never allocated.
 SCRATCH_BLOCK = 0
 
-#: named scopes of the pool's traffic in a compiled program's op names: the
-#: scatter that writes new K/V through the block table, and the slice that
-#: reads one layer out of the pool
+#: Lanes of a TPU vector register.  The pool's minor dim is ``head_dim``
+#: rounded up to a multiple of it, the pad lanes zero and never read back:
+#: a Pallas kernel's operand is row-major with its minor dim tiled to 128
+#: lanes, while XLA:TPU *stores* an array whose minor dim is narrower with
+#: another dim minor-most (bf16 ``[2049, 16, 16, 64]`` as ``{0,3,2,1}``), and
+#: then converts the whole buffer to the kernel's form and back in every
+#: program that touches it.  Storing the lanes the operand has anyway makes
+#: the stored form the operand: no copy, and nothing held twice.
+LANES = 128
+
+#: named scope of the pool's traffic in a compiled program's op names: the
+#: scatter that writes new K/V through the block table into a layer's buffer
+#: (the read is the buffer itself)
 KV_WRITE_SCOPE = "kv.write"
-KV_READ_SCOPE = "kv.layer_read"
-
-
-def _layer_of(pool, layer_idx: int):
-    """One layer's ``[blocks, block_size, Hkv, D]`` out of the pool."""
-    with jax.named_scope(KV_READ_SCOPE):
-        return pool[:, layer_idx]
 
 
 class AllocatorError(RuntimeError):
@@ -211,9 +217,11 @@ class PagedKVCache:
     """Block-pool KV storage exposing the :class:`KVCache` duck surface.
 
     Device state (threaded through compiled programs exactly like the
-    contiguous cache): the K/V pools, the ``[slots, max_blocks_per_slot]``
-    int32 block tables, and the ``[slots]`` lengths.  Host state: the
-    :class:`BlockAllocator` and each slot's owned-block list.
+    contiguous cache): the K/V pools — ``k[layer]`` / ``v[layer]``, one
+    ``[num_blocks, block_size, kv_heads, lane_dim]`` buffer each — the
+    ``[slots, max_blocks_per_slot]`` int32 block tables, and the
+    ``[slots]`` lengths.  Host state: the :class:`BlockAllocator` and each
+    slot's owned-block list.
     """
 
     def __init__(self, num_slots: int, num_layers: int, max_seq: int,
@@ -257,16 +265,20 @@ class PagedKVCache:
         self.mesh = None
         self.dtype = dtype_mod.convert_dtype(dtype)
         self.allocator = BlockAllocator(self.num_blocks, reserved=1)
-        shape = (self.num_blocks, self.num_layers, self.block_size,
-                 self.num_kv_heads, self.head_dim)
-        self.k = Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
-        self.v = Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
+        #: the buffers' minor dim: ``head_dim`` in whole :data:`LANES`
+        self.lane_dim = -(-self.head_dim // LANES) * LANES
+        shape = (self.num_blocks, self.block_size,
+                 self.num_kv_heads, self.lane_dim)
+        self.k = [Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
+                  for _ in range(self.num_layers)]
+        self.v = [Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
+                  for _ in range(self.num_layers)]
         self.block_tables = Tensor._wrap(jnp.full(
             (self.num_slots, self.max_blocks_per_slot), SCRATCH_BLOCK,
             dtype=jnp.int32))
         self.lengths = Tensor._wrap(
             jnp.zeros((self.num_slots,), dtype=jnp.int32))
-        for t in (self.k, self.v, self.block_tables, self.lengths):
+        for t in (*self.k, *self.v, self.block_tables, self.lengths):
             t.persistable = True
         #: blocks each slot owns one ref on, by table index order
         self._slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
@@ -368,7 +380,7 @@ class PagedKVCache:
             fresh = self.allocator.alloc(1)
             if fresh is None:
                 return False
-            for buf in (self.k, self.v):
+            for buf in (*self.k, *self.v):
                 arr = buf._value()
                 buf._set_data(arr.at[fresh[0]].set(arr[block_id]))
             owned[bidx] = fresh[0]
@@ -408,6 +420,16 @@ class PagedKVCache:
 
     # -- traced state ops (CacheContext surface) --------------------------
 
+    def _to_lanes(self, upd, dtype):
+        """New K/V ``[..., Hkv, D]`` in the pool's dtype and lane width."""
+        pad = [(0, 0)] * (upd.ndim - 1) + [(0, self.lane_dim - self.head_dim)]
+        return jnp.pad(upd.astype(dtype), pad)
+
+    def gather(self, pool_layer, block_tables):
+        """Reference read: the tables' blocks of one layer's buffer as
+        contiguous ``[B, max_blocks * block_size, Hkv, D]`` sequences."""
+        return gather_block_kv(pool_layer, block_tables)[..., :self.head_dim]
+
     def prefill_write(self, layer_idx: int, slot, k, v, start=0) -> None:
         """Write a tail bucket's K/V through the block table.
 
@@ -426,12 +448,12 @@ class PagedKVCache:
         tbl = self.block_tables._value()
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0, keepdims=False)
         block_ids = jax.lax.dynamic_slice_in_dim(row, st // bs, n_blocks)
-        for buf, new in ((self.k, k), (self.v, v)):
+        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
             arr = buf._value()
-            upd = new._value().astype(arr.dtype)[0]     # [S, Hkv, D]
+            upd = self._to_lanes(new._value()[0], arr.dtype)  # [S, Hkv, Dp]
             upd = upd.reshape(n_blocks, bs, *upd.shape[1:])
             with jax.named_scope(KV_WRITE_SCOPE):
-                buf._set_data(arr.at[block_ids, layer_idx].set(upd))
+                buf._set_data(arr.at[block_ids].set(upd))
 
     def set_length(self, slot, length) -> None:
         s = _as_i32(slot).reshape(())
@@ -452,13 +474,13 @@ class PagedKVCache:
             tbl, bidx[:, None], axis=1)[:, 0]       # [slots]
         off = lens % bs
         layers = []
-        for buf, new in ((self.k, k), (self.v, v)):
+        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
             arr = buf._value()
-            upd = new._value().astype(arr.dtype)[:, 0]   # [slots, Hkv, D]
+            upd = self._to_lanes(new._value()[:, 0], arr.dtype)
             with jax.named_scope(KV_WRITE_SCOPE):
-                arr = arr.at[block_ids, layer_idx, off].set(upd)
+                arr = arr.at[block_ids, off].set(upd)
             buf._set_data(arr)
-            layers.append(_layer_of(arr, layer_idx))
+            layers.append(arr)
         return layers[0], layers[1], tbl, lens
 
     def decode_write(self, layer_idx: int, k, v
@@ -470,8 +492,8 @@ class PagedKVCache:
         block_size``."""
         k_layer, v_layer, tbl, lens = self._decode_token_write(
             layer_idx, k, v)
-        return (Tensor._wrap(gather_block_kv(k_layer, tbl)),
-                Tensor._wrap(gather_block_kv(v_layer, tbl)),
+        return (Tensor._wrap(self.gather(k_layer, tbl)),
+                Tensor._wrap(self.gather(v_layer, tbl)),
                 Tensor._wrap(lens))
 
     def decode_attention(self, layer_idx: int, q, k, v):
@@ -512,13 +534,13 @@ class PagedKVCache:
                               SCRATCH_BLOCK)
         off = pos % bs
         layers = []
-        for buf, new in ((self.k, k), (self.v, v)):
+        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
             arr = buf._value()
-            upd = new._value().astype(arr.dtype)    # [slots, W, Hkv, D]
+            upd = self._to_lanes(new._value(), arr.dtype)  # [slots,W,Hkv,Dp]
             with jax.named_scope(KV_WRITE_SCOPE):
-                arr = arr.at[block_ids, layer_idx, off].set(upd)
+                arr = arr.at[block_ids, off].set(upd)
             buf._set_data(arr)
-            layers.append(_layer_of(arr, layer_idx))
+            layers.append(arr)
         return layers[0], layers[1], tbl, lens
 
     def verify_attention(self, layer_idx: int, q, k, v):
@@ -531,8 +553,8 @@ class PagedKVCache:
         kernel selection still never changes a compiled shape."""
         k_layer, v_layer, tbl, lens = self.verify_write(layer_idx, k, v)
         return verify_attention(
-            q, Tensor._wrap(gather_block_kv(k_layer, tbl)),
-            Tensor._wrap(gather_block_kv(v_layer, tbl)),
+            q, Tensor._wrap(self.gather(k_layer, tbl)),
+            Tensor._wrap(self.gather(v_layer, tbl)),
             Tensor._wrap(lens))
 
     def advance(self, active) -> None:
@@ -551,10 +573,14 @@ class PagedKVCache:
     def length_of(self, slot: int) -> int:
         return int(self.lengths.numpy()[slot])
 
-    def nbytes(self) -> int:
+    def layer_nbytes(self) -> int:
+        """Bytes of one layer's K (or V) buffer, pad lanes included."""
         itemsize = jnp.zeros((), dtype=self.dtype).dtype.itemsize
-        return 2 * self.num_blocks * self.num_layers * self.block_size * \
-            self.num_kv_heads * self.head_dim * itemsize
+        return self.num_blocks * self.block_size * self.num_kv_heads * \
+            self.lane_dim * itemsize
+
+    def nbytes(self) -> int:
+        return 2 * self.num_layers * self.layer_nbytes()
 
     def blocks_in_use(self) -> int:
         s = self.allocator.stats()
@@ -608,20 +634,15 @@ class PagedCacheContext(CacheContext):
         s = _as_i32(self.slot).reshape(())
         tbl = self.cache.block_tables._value()
         start = self.start if self.start is not None else 0
+        k_layer = self.cache.k[self.layer_idx]
+        v_layer = self.cache.v[self.layer_idx]
         if self.cache.kernel == "pallas":
             row = jax.lax.dynamic_index_in_dim(
                 tbl, s, axis=0, keepdims=False)              # [MB]
             return paged_prefill_attention(
-                q,
-                Tensor._wrap(_layer_of(self.cache.k._value(),
-                                       self.layer_idx)),
-                Tensor._wrap(_layer_of(self.cache.v._value(),
-                                       self.layer_idx)),
-                Tensor._wrap(row), start,
+                q, k_layer, v_layer, Tensor._wrap(row), start,
                 interpret=self.cache._interpret, mesh=self.cache.mesh)
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)   # [1, MB]
-        k_all = Tensor._wrap(gather_block_kv(
-            _layer_of(self.cache.k._value(), self.layer_idx), row))
-        v_all = Tensor._wrap(gather_block_kv(
-            _layer_of(self.cache.v._value(), self.layer_idx), row))
+        k_all = Tensor._wrap(self.cache.gather(k_layer._value(), row))
+        v_all = Tensor._wrap(self.cache.gather(v_layer._value(), row))
         return block_prefill_attention(q, k_all, v_all, start)

@@ -15,10 +15,11 @@ pieces and why they compose (docs/SERVING.md "Sharded serving"):
   compiled call, so the SAME model code the single-chip engine traces
   becomes a GSPMD tensor-parallel program.
 
-- **The KV pool shards by ``kv_heads``.**  Both cache layouts are 5-D
-  with kv_heads at dim 3 (contiguous ``[slots, layers, max_seq,
-  kv_heads, head_dim]``, paged ``[blocks, layers, block_size, kv_heads,
-  head_dim]``), and attention is head-batched: every contraction is
+- **The KV pool shards by ``kv_heads``.**  The contiguous cache is 5-D
+  with kv_heads at dim 3 (``[slots, layers, max_seq, kv_heads,
+  head_dim]``), the paged cache one 4-D buffer per layer and side with
+  kv_heads at dim 2 (``[blocks, block_size, kv_heads, lane_dim]``), and
+  attention is head-batched: every contraction is
   independent per head, so a shard holding ``kv_heads/mp`` whole heads
   (GQA groups stay local — ``kv_heads % mp == 0`` is validated up
   front) runs paged/contiguous ``decode_attention`` with ZERO
@@ -67,14 +68,16 @@ from ..distributed.sharding_spec import (
 )
 
 __all__ = ["ServingShard", "serving_mesh", "mesh_shape_key",
-           "viable_ladder", "degrade_step", "KV_POOL_SPEC"]
+           "viable_ladder", "degrade_step", "KV_POOL_SPEC",
+           "KV_LAYER_SPEC"]
 
-#: KV pools are 5-D with kv_heads at dim 3 in BOTH layouts:
-#: contiguous ``[slots, layers, max_seq, kv_heads, head_dim]`` and
-#: paged ``[blocks, layers, block_size, kv_heads, head_dim]`` — heads
-#: split over the model axis, every other dim (and the block tables /
-#: lengths / sampler lanes) replicated.
+#: The contiguous KV pool is 5-D, ``[slots, layers, max_seq, kv_heads,
+#: head_dim]``, kv_heads at dim 3; the paged pool is one 4-D buffer per
+#: layer and side, ``[blocks, block_size, kv_heads, lane_dim]``, kv_heads
+#: at dim 2.  Heads split over the model axis, every other dim (and the
+#: block tables / lengths / sampler lanes) replicated.
 KV_POOL_SPEC = P(None, None, None, MODEL_AXIS, None)
+KV_LAYER_SPEC = P(None, None, MODEL_AXIS, None)
 
 
 def serving_mesh(model_parallel: int,
@@ -210,13 +213,16 @@ class ServingShard:
         """KV pool k/v shard on the kv_heads dim; lengths (and the paged
         block tables) replicate — they are host-driven metadata every
         shard must agree on."""
-        self._pin(cache.k, KV_POOL_SPEC)
-        self._pin(cache.v, KV_POOL_SPEC)
         self._pin(cache.lengths)
         bt = getattr(cache, "block_tables", None)
-        if bt is not None:
-            self._pin(bt)
-            cache.mesh = self.mesh       # paged kernels run per head shard
+        if bt is None:
+            self._pin(cache.k, KV_POOL_SPEC)
+            self._pin(cache.v, KV_POOL_SPEC)
+            return
+        for buf in (*cache.k, *cache.v):
+            self._pin(buf, KV_LAYER_SPEC)
+        self._pin(bt)
+        cache.mesh = self.mesh           # paged kernels run per head shard
 
     def place_sampler(self, sampler) -> None:
         """All sampling lanes replicate: one logical decision stream

@@ -53,6 +53,42 @@ class TestPhasesOnCpu:
         assert c.failed == ["demo: breaks — why"]
 
 
+#: lines as XLA:TPU prints them (PR 24's decode program: the pool's layout
+#: copies and per-layer slices; this PR's: the in-place scatter of a layer)
+_HLO = {
+    "copy": "  %copy.151 = bf16[2049,24,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} "
+            "copy(%sd_2_.1), sharding={replicated}",
+    "transpose": "  ROOT %transpose.7 = bf16[16,2049,16,128]{3,2,1,0} "
+                 "transpose(%param_0.3), dimensions={2,0,1,3}",
+    "slice": "  %slice.12 = bf16[2049,1,16,16,64]{4,3,2,1,0:T(8,128)(2,1)} "
+             "slice(%copy.151), slice={[0:2049], [3:4], [0:16], [0:16], [0:64]}",
+    "scatter": "  %fusion.5 = bf16[2049,16,16,128]{3,2,1,0:T(8,128)(2,1)} "
+               "fusion(%sd_2_.1, %fusion.169, %get-tuple-element.53), "
+               "kind=kCustom, calls=%fused_computation.5",
+    "small": "  %copy.9 = bf16[32,16,128]{2,1,0:T(8,128)(2,1)} copy(%x.1)",
+}
+
+
+class TestPoolSizedMoves:
+    """What the serve stage fails the decode program for on the chip."""
+
+    LAYER_BUF = 2049 * 16 * 16 * 64 * 2         # one layer's buffer, bf16
+
+    @pytest.mark.parametrize("op", ["copy", "transpose", "slice"])
+    def test_a_move_of_a_layer_buffer_is_reported(self, op):
+        (found,) = chip_smoke.pool_sized_moves(_HLO[op], self.LAYER_BUF)
+        assert found.startswith(op + " ")
+
+    @pytest.mark.parametrize("op", ["scatter", "small"])
+    def test_in_place_writes_and_small_moves_are_not(self, op):
+        assert chip_smoke.pool_sized_moves(_HLO[op], self.LAYER_BUF) == []
+
+    def test_a_whole_module_is_read_line_by_line(self):
+        text = "HloModule jit_decode_step\n" + "\n".join(_HLO.values())
+        assert [m.split()[0] for m in chip_smoke.pool_sized_moves(
+            text, self.LAYER_BUF)] == ["copy", "transpose", "slice"]
+
+
 class TestNoCpuMode:
     def test_script_refuses_the_cpu_by_name(self):
         env = dict(os.environ, JAX_PLATFORMS="cpu")

@@ -39,6 +39,12 @@ def _rand_pool(rs, nb, bs, hkv, d):
             jnp.asarray(rs.randn(nb, bs, hkv, d), jnp.float32))
 
 
+def _to_lanes(pool, lanes):
+    """The serving pool's stored form: the minor dim in whole lanes, the
+    pad lanes zero."""
+    return jnp.pad(pool, [(0, 0)] * 3 + [(0, lanes - pool.shape[-1])])
+
+
 def _ref_decode(q, kp, vp, tbl, lens):
     """gather_block_kv + cached_attention: the kernel="reference" path."""
     B, MB = tbl.shape
@@ -72,6 +78,25 @@ class TestDecodeKernelParity:
         ref = _ref_decode(q, kp, vp, tbl, lens)
         np.testing.assert_allclose(np.asarray(out), ref,
                                    rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("hkv,h", [(4, 4), (2, 4)])
+    def test_lane_padded_pool_matches_reference(self, hkv, h):
+        """The pool as ``PagedKVCache`` stores it (minor dim 16 -> 128
+        lanes, zeros): queries pad inside the wrapper, the softmax scale
+        stays the head's own 1/sqrt(16), the output is head_dim wide."""
+        rs = np.random.RandomState(0)
+        NB, BS, D, B, MB = 13, 8, 16, 4, 4
+        kp, vp = _rand_pool(rs, NB, BS, hkv, D)
+        tbl = jnp.asarray(rs.randint(1, NB, (B, MB)), jnp.int32)
+        lens = jnp.asarray([0, 7, 18, 31], jnp.int32)
+        q = jnp.asarray(rs.randn(B, 1, h, D), jnp.float32)
+        out = paged_decode_attention_kernel(
+            q, _to_lanes(kp, 128), _to_lanes(vp, 128), tbl, lens,
+            interpret=True)
+        assert out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out), _ref_decode(q, kp, vp, tbl, lens),
+            rtol=1e-5, atol=1e-5)
 
     def test_positions_past_length_are_invisible(self):
         """Scribbling over pool positions beyond a slot's window must not
@@ -129,6 +154,22 @@ class TestPrefillKernelParity:
         ref = _ref_prefill(q, kp, vp, row, start)
         np.testing.assert_allclose(np.asarray(out), ref,
                                    rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("hkv,h", [(4, 4), (2, 4)])
+    @pytest.mark.parametrize("start", [0, 16])
+    def test_lane_padded_pool_matches_reference(self, hkv, h, start):
+        rs = np.random.RandomState(3)
+        NB, BS, D, MB, S = 11, 8, 16, 4, 16
+        kp, vp = _rand_pool(rs, NB, BS, hkv, D)
+        row = jnp.asarray(rs.randint(1, NB, (MB,)), jnp.int32)
+        q = jnp.asarray(rs.randn(1, S, h, D), jnp.float32)
+        out = paged_prefill_attention_kernel(
+            q, _to_lanes(kp, 128), _to_lanes(vp, 128), row, start,
+            interpret=True)
+        assert out.shape == q.shape
+        np.testing.assert_allclose(
+            np.asarray(out), _ref_prefill(q, kp, vp, row, start),
+            rtol=1e-5, atol=1e-5)
 
     def test_future_positions_are_invisible(self):
         """The absolute-position causal mask: keys past a query's own

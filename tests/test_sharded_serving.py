@@ -27,7 +27,9 @@ from paddle_tpu.serving import (
     Engine, Fleet, RequestJournal, SpecConfig, serving_mesh,
     mesh_shape_key,
 )
-from paddle_tpu.serving.sharding import KV_POOL_SPEC, ServingShard
+from paddle_tpu.serving.sharding import (
+    KV_LAYER_SPEC, KV_POOL_SPEC, ServingShard,
+)
 
 _FAMILIES = {
     "gpt": (GPTForCausalLM, gpt_tiny),
@@ -113,12 +115,18 @@ class TestShardedParity:
         out = eng.generate(PROMPTS, max_new_tokens=MAX_NEW)
         assert out == baseline(tag, layout)
         assert eng.metrics.compile_misses == warm
-        # the sharded state really is sharded: kv_heads (dim 3) split
-        # over the model axis, every other dim whole (JAX drops the
-        # trailing Nones of the stored spec)
-        spec = tuple(eng.cache.k._value().sharding.spec)
-        assert tuple(KV_POOL_SPEC)[:len(spec)] == spec
-        assert spec[3] == "model"
+        # the sharded state really is sharded: kv_heads (dim 3 of the
+        # contiguous pool, dim 2 of each of the paged pool's per-layer
+        # buffers) split over the model axis, every other dim whole (JAX
+        # drops the trailing Nones of the stored spec)
+        if layout == "paged":
+            bufs, want, heads = (*eng.cache.k, *eng.cache.v), KV_LAYER_SPEC, 2
+        else:
+            bufs, want, heads = (eng.cache.k, eng.cache.v), KV_POOL_SPEC, 3
+        for buf in bufs:
+            spec = tuple(buf._value().sharding.spec)
+            assert tuple(want)[:len(spec)] == spec
+            assert spec[heads] == "model"
         snap = eng.stats()
         assert snap["sharding"] == {"mesh_shape": "model=2",
                                     "model_parallel": 2}
