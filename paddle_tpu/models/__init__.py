@@ -15,3 +15,6 @@ from .llama import (
     LlamaPretrainingCriterion, LLAMA_CONFIGS, llama_tiny, llama2_7b,
     llama2_13b, llama2_70b,
 )
+from .deepseek_v3 import (
+    DeepseekV3Config, DeepseekV3ForCausalLM, deepseek_v3_tiny,
+)

@@ -1,0 +1,430 @@
+"""DeepSeek-V3-shaped decoder: latent attention (MLA) and sigmoid-routed
+experts with a shared one.  The layer code of a family of configurations
+(the configuration names the model); serving only.
+
+Pre-RMSNorm residual blocks with ``cache_ctx`` threaded through and an untied
+head, like ``llama.py``; what differs:
+
+- **Latent attention.**  ``c_q = norm(x W_qa)``, ``q = c_q W_qb`` ->
+  ``[q_nope | q_rope]`` per head; ``[c | k_r] = x W_kva``, ``c_kv = norm(c)``,
+  ``k_rope = rope(k_r)`` — one rotary head shared by all.  What a token
+  leaves in the cache is the ONE vector ``[c_kv | k_rope]`` (after the norm
+  and the rotation), and attention runs in the **absorbed form** in every
+  condition: ``q_lat = [q_nope W^K | q_rope]`` against that vector,
+  ``o = (softmax . c_kv) W^V``.  ``W^K [H, nope, rank]`` and ``W^V [H, rank,
+  v]`` are the two halves of the published ``kv_b_proj``, stored per head so
+  that no step slices or transposes it.  Decode and tail prefill go through
+  the cache context's latent calls (the Pallas kernels of
+  ``ops/pallas/mla_attention_kernel.py`` over the paged pool); a forward with
+  no cache is the same mathematics as one masked softmax in jnp.
+- **Rotary**, ``rope_interleave``: the pairs ``(2i, 2i+1)`` are de-interleaved
+  to halves, then rotate-half, at absolute positions; angles are computed
+  from the positions in float32 (no table is baked into a compiled step) and
+  the result is cast back to the activations' dtype.
+- **Experts** (layers from ``first_k_dense_replace`` on).  The router is
+  float32 from the hidden state: ``s = sigmoid(x W_r)``, the top ``k`` of
+  ``s + b`` (``e_score_correction_bias``), weights ``s[chosen] / sum * scale``.
+  The layer is **told which experts it holds** (``held_experts = (start,
+  stop)`` of ``n_routed_experts``): it routes over all of them, normalises
+  over all ``k`` chosen, and computes its own experts' terms only; what the
+  absent experts would add is left out (nothing stands in for other chips).
+  **Dropless**: every assignment to a held expert is computed, by one
+  grouped matmul over the tokens sorted by expert
+  (``ops/pallas/moe_kernel.py``) against stacked weights ``[held, h, 2 f]``
+  and ``[held, f, h]`` that no step stacks or copies.  The shared expert is a
+  plain SwiGLU beside them.
+- Parameters are created in ``config.dtype``: no float32 copy of the model
+  exists at any time.  Matmul operands are in that dtype; the residual
+  stream, the norms, the router, the softmax statistics and the logits are
+  float32.
+- The model states the cache it needs (:meth:`cache_spec`: one side, one
+  "head", ``kv_lora_rank + qk_rope_head_dim`` wide).  No multi-token
+  prediction module.
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..core import rng as rng_mod
+from ..core import dtype as dtype_mod
+from ..core.tensor import Tensor
+from ..nn import initializer as I
+from ..nn.layer_base import Layer
+from ..nn.layer.container import LayerList
+
+#: named scopes of this family's work in a compiled program's op names
+ROUTE_SCOPE = "moe.route"
+EXPERTS_SCOPE = "moe.experts"
+ABSORB_SCOPE = "mla.absorb"
+
+F32 = jnp.float32
+
+
+@dataclass
+class DeepseekV3Config:
+    vocab_size: int = 129280
+    hidden_size: int = 2048
+    num_hidden_layers: int = 40
+    num_attention_heads: int = 32
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 7168            # the leading dense layers' MLP
+    moe_intermediate_size: int = 768
+    first_k_dense_replace: int = 1
+    n_routed_experts: int = 256              # the router's outputs
+    num_experts_per_tok: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    #: ``(start, stop)`` of the routed experts this chip holds; None = all
+    held_experts: Optional[Tuple[int, int]] = None
+    max_position_embeddings: int = 131072
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 32e6
+    initializer_range: float = 0.02
+    dtype: str = "float32"
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return tuple(self.held_experts) if self.held_experts is not None \
+            else (0, self.n_routed_experts)
+
+
+def deepseek_v3_tiny(**kw) -> DeepseekV3Config:
+    """The CPU tests' preset: every mechanism, toy widths."""
+    for k, v in dict(
+            vocab_size=512, hidden_size=64, num_hidden_layers=3,
+            num_attention_heads=4, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            intermediate_size=128, moe_intermediate_size=32,
+            n_routed_experts=16, num_experts_per_tok=4, held_experts=(0, 4),
+            max_position_embeddings=256).items():
+        kw.setdefault(k, v)
+    return DeepseekV3Config(**kw)
+
+
+class _Normal(I.Initializer):
+    """normal(0, std) drawn in the dtype it is asked for."""
+
+    def __init__(self, std: float):
+        self.std = std
+
+    def __call__(self, shape, dtype):
+        dt = dtype_mod.convert_dtype(dtype)
+        return jax.random.normal(rng_mod.next_key(), tuple(shape), dt) \
+            * jnp.asarray(self.std, dt)
+
+
+def _interpret() -> bool:
+    from ..ops.pallas import use_pallas
+
+    return not use_pallas()
+
+
+def _rms(x, g, eps):
+    """RMSNorm in float32; the result in the gain's dtype (the dtype the
+    next matmul's weights are in)."""
+    x32 = x.astype(F32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
+                            + eps)
+    return (y * g.astype(F32)).astype(g.dtype)
+
+
+def _rope(x, pos, theta: float):
+    """Interleaved rotary on ``x [B, S, ..., D]`` at positions ``pos [B, S]``:
+    pairs ``(2i, 2i+1)`` to halves, then rotate-half; float32 inside, the
+    input's dtype out."""
+    D = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=F32) / D))
+    ang = pos.astype(F32)[..., None] * inv                   # [B, S, D/2]
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (D // 2,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(F32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _swiglu(x, w_gu, w_d):
+    """``(silu(x W_g) * x W_u) W_d``; the result float32 (it is added to the
+    residual stream)."""
+    gu = jnp.dot(x, w_gu)
+    f = gu.shape[-1] // 2
+    return jnp.dot(jax.nn.silu(gu[..., :f]) * gu[..., f:], w_d,
+                   preferred_element_type=F32)
+
+
+def causal_latent_attention(q_lat, lat, *, scale: float, dv: int):
+    """Absorbed attention with no cache: ``q_lat [B, S, H, W]`` against the
+    sequence's own latents ``lat [B, S, W]``; one masked softmax in float32."""
+    S = q_lat.shape[1]
+    s = jnp.einsum("bqhw,bkw->bhqk", q_lat, lat,
+                   preferred_element_type=F32) * scale
+    s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None, None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1).astype(q_lat.dtype)
+    return jnp.einsum("bhqk,bkv->bqhv", p, lat[..., :dv],
+                      preferred_element_type=F32).astype(q_lat.dtype)
+
+
+class DeepseekV3Attention(Layer):
+    def __init__(self, c: DeepseekV3Config):
+        super().__init__()
+        self.c = c
+        H, h = c.num_attention_heads, c.hidden_size
+        init = _Normal(c.initializer_range)
+
+        def mat(*shape):
+            return self.create_parameter(list(shape), dtype=c.dtype,
+                                         default_initializer=init)
+
+        def gain(n):
+            return self.create_parameter([n], dtype=c.dtype,
+                                         default_initializer=I.Constant(1.0))
+
+        self.q_a_proj = mat(h, c.q_lora_rank)
+        self.q_a_layernorm = gain(c.q_lora_rank)
+        self.q_b_proj = mat(c.q_lora_rank, H * c.qk_head_dim)
+        self.kv_a_proj_with_mqa = mat(h, c.latent_dim)
+        self.kv_a_layernorm = gain(c.kv_lora_rank)
+        # the published kv_b_proj [rank, H * (nope + v)], per head and in
+        # the two halves the absorbed form multiplies by
+        self.w_uk = mat(H, c.qk_nope_head_dim, c.kv_lora_rank)
+        self.w_uv = mat(H, c.kv_lora_rank, c.v_head_dim)
+        self.o_proj = mat(H * c.v_head_dim, h)
+
+    def forward(self, x, cache_ctx=None):
+        c = self.c
+        B, S, _ = x.shape
+        H, rank = c.num_attention_heads, c.kv_lora_rank
+        if cache_ctx is None:
+            pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
+                                   (B, S))
+        elif cache_ctx.mode == "prefill":
+            pos = cache_ctx.prefill_positions(S)
+            pos = jnp.arange(S, dtype=jnp.int32)[None] if pos is None \
+                else pos._value()
+        elif cache_ctx.mode == "decode":
+            pos = cache_ctx.positions()._value()
+        else:
+            raise ValueError(f"latent attention has no {cache_ctx.mode!r} "
+                             f"form")
+        c_q = _rms(jnp.dot(x, self.q_a_proj._value()),
+                   self.q_a_layernorm._value(), c.rms_norm_eps)
+        q = jnp.dot(c_q, self.q_b_proj._value()).reshape(
+            B, S, H, c.qk_head_dim)
+        q_nope = q[..., :c.qk_nope_head_dim]
+        q_rope = _rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
+        ckr = jnp.dot(x, self.kv_a_proj_with_mqa._value())
+        c_kv = _rms(ckr[..., :rank], self.kv_a_layernorm._value(),
+                    c.rms_norm_eps)
+        k_rope = _rope(ckr[..., rank:], pos, c.rope_theta)
+        lat = jnp.concatenate([c_kv, k_rope], axis=-1)        # [B, S, W]
+        with jax.named_scope(ABSORB_SCOPE):
+            q_lat = jnp.concatenate(
+                [jnp.einsum("bshn,hnr->bshr", q_nope, self.w_uk._value()),
+                 q_rope], axis=-1)                            # [B, S, H, W]
+        kw = dict(scale=c.qk_head_dim ** -0.5, dv=rank)
+        if cache_ctx is None:
+            o_lat = causal_latent_attention(q_lat, lat, **kw)
+        elif cache_ctx.mode == "prefill":
+            cache_ctx.write_prefill_latent(Tensor._wrap(lat))
+            o_lat = cache_ctx.latent_prefill_attention(
+                Tensor._wrap(q_lat), **kw)._value()
+        else:
+            o_lat = cache_ctx.latent_decode_attention(
+                Tensor._wrap(q_lat), Tensor._wrap(lat), **kw)._value()
+        with jax.named_scope(ABSORB_SCOPE):
+            o = jnp.einsum("bshr,hrv->bshv", o_lat, self.w_uv._value())
+        return jnp.dot(o.reshape(B, S, H * c.v_head_dim),
+                       self.o_proj._value(), preferred_element_type=F32)
+
+
+class DeepseekV3MLP(Layer):
+    """SwiGLU, gate and up side by side in one matrix."""
+
+    def __init__(self, c: DeepseekV3Config, width: int):
+        super().__init__()
+        init = _Normal(c.initializer_range)
+        self.gate_up_proj = self.create_parameter(
+            [c.hidden_size, 2 * width], dtype=c.dtype,
+            default_initializer=init)
+        self.down_proj = self.create_parameter(
+            [width, c.hidden_size], dtype=c.dtype, default_initializer=init)
+
+    def forward(self, x):
+        return _swiglu(x, self.gate_up_proj._value(), self.down_proj._value())
+
+
+def route(x, w_r, bias, *, top_k: int, scale: float):
+    """``(chosen [T, k], weights [T, k])``: sigmoid scores in float32, the
+    top ``k`` of score + bias, weights normalised over the chosen and
+    scaled."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(F32), w_r.astype(F32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(s + bias.astype(F32)[None, :], top_k)
+    w = jnp.take_along_axis(s, chosen, axis=1)
+    return chosen, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
+
+
+def held_experts_forward(x, chosen, weights, live, w_gu, w_d, *,
+                         held: Tuple[int, int], interpret: bool):
+    """The held experts' part of the layer's output for ``x [T, h]``
+    (float32), and the load: ``(y [T, h], assignments_held,
+    experts_touched)``.  Every
+    assignment of a live token to a held expert is computed; the others
+    add nothing."""
+    from ..ops.pallas.moe_kernel import moe_grouped_matmul
+
+    T, k = chosen.shape
+    G = held[1] - held[0]
+    local = chosen - held[0]
+    mine = (local >= 0) & (local < G) & live[:, None]
+    key = jnp.where(mine, local, G).reshape(-1)       # G = "not here", last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)[:G]
+    rows = jnp.take(x, order // k, axis=0)            # [T*k, h] by expert
+    gu = moe_grouped_matmul(rows, w_gu, sizes, interpret=interpret)
+    f = gu.shape[-1] // 2
+    y = moe_grouped_matmul(jax.nn.silu(gu[:, :f]) * gu[:, f:], w_d, sizes,
+                           interpret=interpret)
+    # back to token order: row ``back[t * k + j]`` is token t's j-th choice
+    back = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    y = jnp.take(y, back, axis=0).reshape(T, k, -1)
+    y = jnp.einsum("tkh,tk->th", y.astype(F32),
+                   jnp.where(mine, weights, 0.0))
+    return y, jnp.sum(sizes), jnp.sum(sizes > 0)
+
+
+class DeepseekV3MoE(Layer):
+    def __init__(self, c: DeepseekV3Config):
+        super().__init__()
+        self.c = c
+        init = _Normal(c.initializer_range)
+        G = c.held[1] - c.held[0]
+        h, f = c.hidden_size, c.moe_intermediate_size
+        self.gate = self.create_parameter(
+            [h, c.n_routed_experts], dtype=c.dtype, default_initializer=init)
+        self.register_buffer("e_score_correction_bias", Tensor._wrap(
+            jnp.zeros((c.n_routed_experts,), F32)))
+        self.experts_gate_up = self.create_parameter(
+            [G, h, 2 * f], dtype=c.dtype, default_initializer=init)
+        self.experts_down = self.create_parameter(
+            [G, f, h], dtype=c.dtype, default_initializer=init)
+        self.shared_experts = DeepseekV3MLP(c, f * c.n_shared_experts)
+
+    def forward(self, x, cache_ctx=None):
+        c = self.c
+        B, S, h = x.shape
+        flat = x.reshape(B * S, h)
+        live = jnp.ones((B * S,), bool) if cache_ctx is None \
+            else cache_ctx.live_tokens(S).reshape(-1)
+        with jax.named_scope(ROUTE_SCOPE):
+            chosen, weights = route(
+                flat, self.gate._value(),
+                self.e_score_correction_bias._value(),
+                top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor)
+        with jax.named_scope(EXPERTS_SCOPE):
+            y, n_held, n_touched = held_experts_forward(
+                flat, chosen, weights, live, self.experts_gate_up._value(),
+                self.experts_down._value(), held=c.held,
+                interpret=_interpret())
+        if cache_ctx is not None:
+            cache_ctx.note_experts(n_held, n_touched)
+        return y.reshape(B, S, h) + self.shared_experts(x)
+
+
+class DeepseekV3DecoderLayer(Layer):
+    def __init__(self, c: DeepseekV3Config, index: int):
+        super().__init__()
+        self.eps = c.rms_norm_eps
+
+        def gain():
+            return self.create_parameter([c.hidden_size], dtype=c.dtype,
+                                         default_initializer=I.Constant(1.0))
+
+        self.input_layernorm = gain()
+        self.self_attn = DeepseekV3Attention(c)
+        self.post_attention_layernorm = gain()
+        self.is_moe = index >= c.first_k_dense_replace
+        self.mlp = DeepseekV3MoE(c) if self.is_moe \
+            else DeepseekV3MLP(c, c.intermediate_size)
+
+    def forward(self, x, cache_ctx=None):
+        x = x + self.self_attn(
+            _rms(x, self.input_layernorm._value(), self.eps), cache_ctx)
+        m = _rms(x, self.post_attention_layernorm._value(), self.eps)
+        return x + (self.mlp(m, cache_ctx) if self.is_moe else self.mlp(m))
+
+
+class DeepseekV3Model(Layer):
+    def __init__(self, c: DeepseekV3Config):
+        super().__init__()
+        self.c = c
+        self.embed_tokens = self.create_parameter(
+            [c.vocab_size, c.hidden_size], dtype=c.dtype,
+            default_initializer=_Normal(c.initializer_range))
+        self.layers = LayerList([DeepseekV3DecoderLayer(c, i)
+                                 for i in range(c.num_hidden_layers)])
+        self.norm = self.create_parameter(
+            [c.hidden_size], dtype=c.dtype,
+            default_initializer=I.Constant(1.0))
+
+    def forward(self, input_ids, cache_ctx=None):
+        """``input_ids [B, S]`` (raw) -> final hidden states ``[B, S, h]``
+        (raw, float32, not yet normed)."""
+        # the residual stream is float32: every sublayer reads it through a
+        # norm (cast to the weights' dtype) and adds a float32 result, so
+        # the stream's own rounding does not pile up layer by layer and
+        # reach the router, whose top-k flips on a near tie
+        h = jnp.take(self.embed_tokens._value(), input_ids, axis=0
+                     ).astype(F32)
+        for i, layer in enumerate(self.layers):
+            if cache_ctx is not None:
+                cache_ctx.layer_idx = i
+            h = layer(h, cache_ctx)
+        return h
+
+
+class DeepseekV3ForCausalLM(Layer):
+    """The decoder, the final norm and an untied head; logits float32."""
+
+    def __init__(self, config: DeepseekV3Config):
+        super().__init__()
+        self.config = config
+        self.model = DeepseekV3Model(config)
+        self.lm_head = self.create_parameter(
+            [config.hidden_size, config.vocab_size], dtype=config.dtype,
+            default_initializer=_Normal(config.initializer_range))
+
+    def cache_spec(self):
+        """One side, one "head": the latent vector ``[c_kv | k_rope]``."""
+        from ..serving.kv_cache import CacheSpec
+
+        return CacheSpec.latent(self.config.num_hidden_layers,
+                                self.config.latent_dim)
+
+    def forward(self, input_ids, cache_ctx=None):
+        ids = input_ids._value() if isinstance(input_ids, Tensor) \
+            else jnp.asarray(input_ids)
+        h = self.model(ids.astype(jnp.int32), cache_ctx)
+        if cache_ctx is not None:
+            # prefill: the head sees the one row the engine samples from
+            h = cache_ctx.select_last(Tensor._wrap(h))._value()
+        h = _rms(h, self.model.norm._value(), self.config.rms_norm_eps)
+        return Tensor._wrap(jnp.dot(h, self.lm_head._value(),
+                                    preferred_element_type=F32))

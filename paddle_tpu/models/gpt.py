@@ -260,6 +260,14 @@ class GPTForCausalLM(Layer):
         else:
             self.lm_head = None
 
+    def cache_spec(self):
+        """What serving caches a token a layer: K and V of every head."""
+        from ..serving.kv_cache import CacheSpec
+
+        c = self.config
+        return CacheSpec.kv(c.num_hidden_layers, c.num_attention_heads,
+                            c.head_dim)
+
     def forward(self, input_ids, position_ids=None, cache_ctx=None):
         h = self.gpt(input_ids, position_ids, cache_ctx=cache_ctx)
         if self.lm_head is not None:

@@ -310,6 +310,13 @@ class LlamaForCausalLM(Layer):
                                   bias_attr=False)
             set_param_spec(self.lm_head.weight, P(None, MODEL_AXIS))
 
+    def cache_spec(self):
+        """What serving caches a token a layer: K and V per KV head."""
+        from ..serving.kv_cache import CacheSpec
+
+        c = self.config
+        return CacheSpec.kv(c.num_hidden_layers, c.n_kv_heads, c.head_dim)
+
     def forward(self, input_ids, cache_ctx=None):
         h = self.llama(input_ids, cache_ctx=cache_ctx)
         if self.lm_head is not None:

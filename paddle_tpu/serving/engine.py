@@ -82,7 +82,7 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor, to_tensor
 from ..obs import spans as _spans
-from .kv_cache import KVCache, CacheContext
+from .kv_cache import KVCache, CacheContext, cache_spec_of
 from .metrics import ServingMetrics
 from .sampling import DeviceSampler, SamplingParams
 from .sanitize import SyncSanitizer
@@ -457,7 +457,11 @@ class Engine:
             raise ValueError("priority_aging_s must be > 0 (or None to "
                              "disable aging)")
         self.buckets = self._make_buckets()
-        kv_heads = getattr(cfg, "n_kv_heads", None) or cfg.num_attention_heads
+        # the pool is built from what the model says it caches, not from
+        # head counts read off its config
+        spec = cache_spec_of(model)
+        self.cache_spec = spec
+        kv_heads = spec.sides[0][0]
         if cache_dtype is None:
             params = model.parameters()
             cache_dtype = params[0].dtype if params else "float32"
@@ -467,6 +471,21 @@ class Engine:
         if kernel not in ("auto", "pallas", "reference"):
             raise ValueError(f"kernel must be 'auto', 'pallas' or "
                              f"'reference', got {kernel!r}")
+        if spec.kind == "latent":
+            # one vector a token has no per-KV-head axis: nothing to lay
+            # out contiguously per head, to shard by head, or to verify
+            refused = [what for what, asked in (
+                (f"kv_layout={kv_layout!r} (it runs with kv_layout='paged' "
+                 "only)", kv_layout != "paged"),
+                ("a serving mesh of more than one device (the latent pool "
+                 "has no kv_heads axis to shard)",
+                 mesh is not None and mesh.size > 1),
+                ("speculation= (the verify window has no latent form)",
+                 speculation is not None)) if asked]
+            if refused:
+                raise ValueError(
+                    f"{type(model).__name__} caches one latent vector a "
+                    f"token and cannot serve with " + "; ".join(refused))
         self.kv_layout = kv_layout
         # the Pallas paged kernels are the default paged path (interpret
         # mode off-TPU keeps CPU tier-1 on the same code); contiguous
@@ -490,9 +509,8 @@ class Engine:
                     f"block_size {self.block_size} must divide "
                     f"max_seq {self.max_seq}")
             self.cache = PagedKVCache(
-                num_slots=self.num_slots, num_layers=cfg.num_hidden_layers,
-                max_seq=self.max_seq, num_kv_heads=kv_heads,
-                head_dim=cfg.head_dim, dtype=cache_dtype,
+                num_slots=self.num_slots, num_layers=spec.num_layers,
+                max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
                 block_size=self.block_size, num_blocks=num_kv_blocks,
                 kernel=self.kernel)
             if enable_prefix_cache:
@@ -500,9 +518,9 @@ class Engine:
                                                 self.block_size)
         else:
             self.cache = KVCache(
-                num_slots=self.num_slots, num_layers=cfg.num_hidden_layers,
+                num_slots=self.num_slots, num_layers=spec.num_layers,
                 max_seq=self.max_seq, num_kv_heads=kv_heads,
-                head_dim=cfg.head_dim, dtype=cache_dtype)
+                head_dim=spec.sides[0][1], dtype=cache_dtype)
         self.name = name or f"engine-{next(_engine_counter)}"
         self.metrics = ServingMetrics(self.name, num_slots=self.num_slots)
         self.metrics.health_cb = self.health
@@ -631,6 +649,11 @@ class Engine:
         # (never summed over requests for the span's sake)
         self._kv_tokens = 0          # cached tokens of the running slots
         self._admitted_step = 0      # prompts admitted by the current step
+        self._step_span = None       # the open ``engine.step`` span
+        #: decode-step load of the model's expert layers (empty for a
+        #: model without experts: ``stats()`` then has no ``"moe"``)
+        self._moe = {"tokens": 0, "assignments_held": 0,
+                     "experts_touched": 0, "layer_steps": 0}
         self._watchdog = None
         self._arm_counter = 0
 
@@ -686,10 +709,9 @@ class Engine:
                         pool.clear_rows()
                 cache.set_length(slot, length)
                 arr = logits._value()                   # [1, S, V]
-                idx = (length._value() - start._value()).astype(
-                    jnp.int32) - 1
-                last = jax.lax.dynamic_index_in_dim(
-                    arr[0], idx, axis=0, keepdims=False)
+                last = ctx.last_logits(
+                    arr, (length._value() - start._value()).astype(
+                        jnp.int32) - 1)
                 # first token sampled on-device from the slot's staged
                 # lanes; key + token lanes update in-program
                 tok = sampler.sample_slot(slot._value(),
@@ -708,9 +730,8 @@ class Engine:
                         pool.clear_rows()
                 cache.set_length(slot, length)
                 arr = logits._value()                   # [1, S, V]
-                last = jax.lax.dynamic_index_in_dim(
-                    arr[0], length._value().astype(jnp.int32) - 1,
-                    axis=0, keepdims=False)
+                last = ctx.last_logits(
+                    arr, length._value().astype(jnp.int32) - 1)
                 tok = sampler.sample_slot(slot._value(),
                                           last.astype(jnp.float32))
                 return Tensor._wrap(tok)
@@ -722,7 +743,8 @@ class Engine:
             # paged cache may route attention through the Pallas
             # flash-decoding kernel instead of a materializing gather
             tokens = Tensor._wrap(sampler.tokens._value()[:, None])
-            ctx = CacheContext(cache, "decode", active=active)
+            ctx = (PagedCacheContext if self.kv_layout == "paged"
+                   else CacheContext)(cache, "decode", active=active)
             if pool is not None:
                 # all slots decode at once: the full [slots] id lane
                 pool.set_rows(pool.adapter_ids._value())
@@ -734,7 +756,9 @@ class Engine:
             cache.advance(active)
             toks = sampler.sample_all(
                 logits._value()[:, -1, :].astype(jnp.float32))
-            return Tensor._wrap(toks)
+            # a model with expert layers: their load rides behind the
+            # tokens, in the one array the host pulls
+            return Tensor._wrap(ctx.with_expert_counts(toks))
 
         self._prefill_fn = jit_mod.to_static(prefill_step)
         self._warmers = [("prefill", self._warm_prefill)]
@@ -785,6 +809,10 @@ class Engine:
     def _warm_decode(self, buckets) -> None:
         idle = np.zeros((self.num_slots,), dtype=np.int32)
         self._call_counted(self._decode_fn, to_tensor(idle))
+        if self.kv_layout == "paged":
+            # the host-side table and block-copy programs of a growing
+            # sequence, which no warm-up prefill reaches
+            self.cache.warm_host_programs()
 
     def _warm_draft_prefill(self, buckets) -> None:
         for b in buckets:
@@ -933,6 +961,7 @@ class Engine:
         from ..models import (
             GPT_CONFIGS, GPTConfig, GPTForCausalLM,
             LLAMA_CONFIGS, LlamaConfig, LlamaForCausalLM,
+            DeepseekV3Config, DeepseekV3ForCausalLM,
         )
 
         if isinstance(config, Layer):
@@ -941,6 +970,8 @@ class Engine:
             return GPTForCausalLM(config)
         if isinstance(config, LlamaConfig):
             return LlamaForCausalLM(config)
+        if isinstance(config, DeepseekV3Config):
+            return DeepseekV3ForCausalLM(config)
         if isinstance(config, str):
             family, _, which = config.partition(":")
             reg = {"gpt": (GPT_CONFIGS, GPTForCausalLM),
@@ -1921,10 +1952,26 @@ class Engine:
         with _spans.span("engine.pull") as pull:
             toks = out.numpy()                   # [slots] int32
         now = pull.t1            # the step's latency runs on the spans' stamps
+        if len(toks) > self.num_slots:           # a model with experts
+            self._note_experts(toks[self.num_slots:])
         with _spans.span("engine.deliver") as sp:
             ran = len(self.running)
             self._deliver_pulled(toks, now, now - t0)
             sp.attrs["retired"] = ran - len(self.running)
+
+    def _note_experts(self, counts) -> None:
+        """The decode step's expert load, as its program counted it: summed
+        into ``stats()["moe"]``, and the step's own on its span."""
+        held, touched, layers = (int(c) for c in counts)
+        moe = self._moe
+        moe["tokens"] += len(self.running)
+        moe["assignments_held"] += held
+        moe["experts_touched"] += touched
+        moe["layer_steps"] += layers
+        if self._step_span is not None:
+            self._step_span.set(moe_tokens=len(self.running),
+                                moe_assignments_held=held,
+                                moe_experts_touched=touched)
 
     def _deliver_pulled(self, toks, now: float, step_s: float) -> None:
         if self.journal is not None:
@@ -2168,7 +2215,9 @@ class Engine:
         with _spans.span("engine.step", step=self._step_counter,
                          kv_tokens=self._kv_tokens) as sp:
             self._admitted_step = 0
+            self._step_span = sp
             self._schedule(sp.t0)
+            self._step_span = None
             self._step_counter += 1
             sp.set(admitted=self._admitted_step,
                    running=len(self.running), queued=len(self.queue))
@@ -2733,6 +2782,8 @@ class Engine:
                 "grammars": (list(self.grammar_table.names)
                              if self.grammar_table is not None else []),
             }
+        if self._moe["layer_steps"]:
+            snap["moe"] = dict(self._moe)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,
                                 "model_parallel": self.shard.mp}

@@ -33,13 +33,52 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from ..core import dtype as dtype_mod
 
-__all__ = ["KVCache", "CacheContext"]
+__all__ = ["KVCache", "CacheContext", "CacheSpec", "cache_spec_of"]
 
 
 def _as_i32(x):
     if isinstance(x, Tensor):
         return x._value().astype(jnp.int32)
     return jnp.asarray(x, dtype=jnp.int32)
+
+
+@dataclass(frozen=True)
+class CacheSpec:
+    """What a model caches a token a layer: the statement the engine builds
+    its pool from (``model.cache_spec()``).
+
+    ``sides`` is one ``(heads, width)`` per buffer a layer keeps.  A
+    K/V-caching model states two sides of ``(kv_heads, head_dim)``
+    (``kind="kv"``); a latent-attention model states ONE side of one "head"
+    whose width is the latent vector's (``kind="latent"``): key and value at
+    once, shared by every query head.  The ``kind`` names the attention
+    calls the model makes on its cache context."""
+
+    num_layers: int
+    sides: Tuple[Tuple[int, int], ...]
+    kind: str = "kv"
+
+    @classmethod
+    def kv(cls, num_layers: int, kv_heads: int, head_dim: int) -> "CacheSpec":
+        return cls(int(num_layers),
+                   ((int(kv_heads), int(head_dim)),) * 2, "kv")
+
+    @classmethod
+    def latent(cls, num_layers: int, width: int) -> "CacheSpec":
+        return cls(int(num_layers), ((1, int(width)),), "latent")
+
+
+def cache_spec_of(model) -> CacheSpec:
+    """The model's own statement; a model that makes none (a duck-typed
+    decoder with a ``.config``) is taken to cache K and V per KV head."""
+    stated = getattr(model, "cache_spec", None)
+    if stated is not None:
+        return stated()
+    cfg = model.config
+    return CacheSpec.kv(
+        cfg.num_hidden_layers,
+        getattr(cfg, "n_kv_heads", None) or cfg.num_attention_heads,
+        cfg.head_dim)
 
 
 class KVCache:
@@ -202,11 +241,72 @@ class CacheContext:
     active: Optional[Tensor] = None     # decode/verify: [slots] i32 mask
     layer_idx: int = 0
     width: int = 1                      # verify: tokens per slot (k+1)
+    #: set by :meth:`select_last`: the model handed its head one row
+    narrowed: bool = False
+    #: int32 scalars an expert layer reports (:meth:`note_experts`), one
+    #: pair a layer, in trace order
+    expert_counts: Optional[list] = None
 
     def __post_init__(self):
         if self.mode not in ("prefill", "decode", "verify"):
             raise ValueError(f"CacheContext mode {self.mode!r} "
                              "(want 'prefill', 'decode' or 'verify')")
+
+    # -- what a model may ask besides attention ----------------------------
+
+    def live_tokens(self, seq_len: int):
+        """``[B, S]`` bool: the tokens of this call that a request owns.
+        Decode: the active slots' one token; prefill: the bucket's rows
+        below the prompt's real length.  An expert layer routes no other
+        token (an idle slot or a pad row would read experts' weights for
+        nothing and count as load)."""
+        if self.mode == "prefill":
+            real = _as_i32(self.length).reshape(()) - self._prefill_start()
+            return (jnp.arange(seq_len, dtype=jnp.int32) < real)[None, :]
+        live = _as_i32(self.active) > 0
+        return jnp.broadcast_to(live[:, None], (live.shape[0], seq_len))
+
+    def _prefill_start(self):
+        return jnp.int32(0)
+
+    def select_last(self, h):
+        """Prefill: the hidden states ``[1, S, n]`` narrowed to the one row
+        the engine samples from (the prompt's last real token), so that a
+        wide head is applied to one row and not to the bucket.  Other modes
+        return ``h`` as it is."""
+        if self.mode != "prefill":
+            return h
+        idx = _as_i32(self.length).reshape(()) - self._prefill_start() - 1
+        self.narrowed = True
+        return Tensor._wrap(jax.lax.dynamic_slice_in_dim(
+            h._value(), idx, 1, axis=1))
+
+    def last_logits(self, logits, idx):
+        """The logits row a prefill samples from: row ``idx`` of
+        ``logits [1, S, V]``, or the one row there is when the model
+        narrowed its head's input with :meth:`select_last`."""
+        if self.narrowed:
+            return logits[0, 0]
+        return jax.lax.dynamic_index_in_dim(logits[0], idx, axis=0,
+                                            keepdims=False)
+
+    def with_expert_counts(self, tokens):
+        """``tokens [slots]`` followed by ``[assignments_held,
+        experts_touched, expert layers]`` of this call when the model has
+        expert layers; ``tokens`` as they are when it has none."""
+        if not self.expert_counts:
+            return tokens
+        held, touched = (sum(c) for c in zip(*self.expert_counts))
+        return jnp.concatenate([tokens, jnp.stack([
+            held, touched, jnp.int32(len(self.expert_counts))
+        ]).astype(tokens.dtype)])
+
+    def note_experts(self, assignments_held, experts_touched) -> None:
+        """An expert layer's load in this call (traced int32 scalars): the
+        decode program hands their sums over beside the tokens."""
+        if self.expert_counts is None:
+            self.expert_counts = []
+        self.expert_counts.append((assignments_held, experts_touched))
 
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v)

@@ -225,9 +225,15 @@ class PagedKVCache:
     """
 
     def __init__(self, num_slots: int, num_layers: int, max_seq: int,
-                 num_kv_heads: int, head_dim: int, dtype="float32", *,
+                 num_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype="float32", *,
                  block_size: int = 16, num_blocks: Optional[int] = None,
-                 kernel: str = "reference"):
+                 kernel: str = "reference",
+                 sides: Optional[Sequence[Tuple[int, int]]] = None):
+        if sides is None:
+            if num_kv_heads is None or head_dim is None:
+                raise ValueError("give num_kv_heads and head_dim, or sides")
+            sides = ((num_kv_heads, head_dim),) * 2
         if num_slots < 1 or num_layers < 1 or max_seq < 1:
             raise ValueError("num_slots/num_layers/max_seq must be >= 1")
         if kernel not in ("reference", "pallas"):
@@ -241,8 +247,11 @@ class PagedKVCache:
         self.num_slots = int(num_slots)
         self.num_layers = int(num_layers)
         self.max_seq = int(max_seq)
-        self.num_kv_heads = int(num_kv_heads)
-        self.head_dim = int(head_dim)
+        #: ``(heads, width)`` of each buffer a layer keeps, as the model
+        #: stated them (``CacheSpec.sides``): K and V per KV head, or the
+        #: one latent vector
+        self.side_shapes = tuple((int(h), int(w)) for h, w in sides)
+        self.num_kv_heads, self.head_dim = self.side_shapes[0]
         self.block_size = int(block_size)
         self.max_blocks_per_slot = self.max_seq // self.block_size
         if num_blocks is None:
@@ -265,24 +274,31 @@ class PagedKVCache:
         self.mesh = None
         self.dtype = dtype_mod.convert_dtype(dtype)
         self.allocator = BlockAllocator(self.num_blocks, reserved=1)
-        #: the buffers' minor dim: ``head_dim`` in whole :data:`LANES`
+        #: the buffers' minor dim: the side's width in whole :data:`LANES`
         self.lane_dim = -(-self.head_dim // LANES) * LANES
-        shape = (self.num_blocks, self.block_size,
-                 self.num_kv_heads, self.lane_dim)
-        self.k = [Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
-                  for _ in range(self.num_layers)]
-        self.v = [Tensor._wrap(jnp.zeros(shape, dtype=self.dtype))
-                  for _ in range(self.num_layers)]
+        #: ``sides[s][layer]``: one ``[num_blocks, block_size, heads,
+        #: lane_dim]`` buffer per side and layer
+        self.sides = [
+            [Tensor._wrap(jnp.zeros(
+                (self.num_blocks, self.block_size, h, -(-w // LANES) * LANES),
+                dtype=self.dtype)) for _ in range(self.num_layers)]
+            for h, w in self.side_shapes]
+        if len(self.sides) == 2:            # the K/V pool's own names
+            self.k, self.v = self.sides
         self.block_tables = Tensor._wrap(jnp.full(
             (self.num_slots, self.max_blocks_per_slot), SCRATCH_BLOCK,
             dtype=jnp.int32))
         self.lengths = Tensor._wrap(
             jnp.zeros((self.num_slots,), dtype=jnp.int32))
-        for t in (*self.k, *self.v, self.block_tables, self.lengths):
+        for t in (*self.buffers(), self.block_tables, self.lengths):
             t.persistable = True
         #: blocks each slot owns one ref on, by table index order
         self._slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
         self.copy_on_extends = 0
+
+    def buffers(self) -> List[Tensor]:
+        """Every layer buffer of every side."""
+        return [buf for side in self.sides for buf in side]
 
     # -- host-side slot lifecycle -----------------------------------------
 
@@ -290,6 +306,18 @@ class PagedKVCache:
         self.block_tables._set_data(
             self.block_tables._value().at[slot, idx].set(
                 jnp.int32(block_id)))
+
+    def warm_host_programs(self) -> None:
+        """Runs, on the scratch block and an empty slot's table entry (both
+        left as they were), the small eager programs the host-side block
+        maintenance uses between steps — the table entry's scatter of
+        ``ensure_capacity`` and the block copy of copy-on-extend — so that
+        the first sequence to grow past its reserved blocks inside a
+        measured window compiles nothing."""
+        self._set_table(0, 0, int(self.block_tables.numpy()[0, 0]))
+        for buf in {tuple(b.shape): b for b in self.buffers()}.values():
+            arr = buf._value()
+            buf._set_data(arr.at[SCRATCH_BLOCK].set(arr[SCRATCH_BLOCK]))
 
     def begin_sequence(self, slot: int, shared_blocks: Sequence[int],
                        prefix_len: int, tail_bucket: int) -> bool:
@@ -380,7 +408,7 @@ class PagedKVCache:
             fresh = self.allocator.alloc(1)
             if fresh is None:
                 return False
-            for buf in (*self.k, *self.v):
+            for buf in self.buffers():
                 arr = buf._value()
                 buf._set_data(arr.at[fresh[0]].set(arr[block_id]))
             owned[bidx] = fresh[0]
@@ -421,9 +449,13 @@ class PagedKVCache:
     # -- traced state ops (CacheContext surface) --------------------------
 
     def _to_lanes(self, upd, dtype):
-        """New K/V ``[..., Hkv, D]`` in the pool's dtype and lane width."""
-        pad = [(0, 0)] * (upd.ndim - 1) + [(0, self.lane_dim - self.head_dim)]
+        """New entries ``[..., heads, width]`` in the pool's dtype and lane
+        width."""
+        pad = [(0, 0)] * (upd.ndim - 1) + [(0, self.lane_dim - upd.shape[-1])]
         return jnp.pad(upd.astype(dtype), pad)
+
+    def _layer(self, layer_idx: int) -> List[Tensor]:
+        return [side[layer_idx] for side in self.sides]
 
     def gather(self, pool_layer, block_tables):
         """Reference read: the tables' blocks of one layer's buffer as
@@ -441,14 +473,18 @@ class PagedKVCache:
         scatter of whole blocks per pool: a per-block update loop made
         the 1024-token bucket of a 24-layer model a 3,000-op program that
         took minutes to trace and compile."""
+        self._prefill_write(layer_idx, slot, (k, v), start)
+
+    def _prefill_write(self, layer_idx: int, slot, news, start) -> None:
+        """``prefill_write`` of one new tensor per side."""
         s = _as_i32(slot).reshape(())
         st = _as_i32(start).reshape(())
         bs = self.block_size
-        n_blocks = int(k.shape[1]) // bs
+        n_blocks = int(news[0].shape[1]) // bs
         tbl = self.block_tables._value()
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0, keepdims=False)
         block_ids = jax.lax.dynamic_slice_in_dim(row, st // bs, n_blocks)
-        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
+        for buf, new in zip(self._layer(layer_idx), news):
             arr = buf._value()
             upd = self._to_lanes(new._value()[0], arr.dtype)  # [S, Hkv, Dp]
             upd = upd.reshape(n_blocks, bs, *upd.shape[1:])
@@ -460,12 +496,12 @@ class PagedKVCache:
         ln = _as_i32(length).reshape(())
         self.lengths._set_data(self.lengths._value().at[s].set(ln))
 
-    def _decode_token_write(self, layer_idx: int, k, v):
+    def _decode_token_write(self, layer_idx: int, *news):
         """Write one token per slot at ``lengths[slot]`` through the
-        table.  Idle slots' tables point at the scratch block, so the
-        fixed-shape all-slots write never lands on live storage.
-        Returns ``(k_layer, v_layer, tables, lengths)`` raw arrays
-        (post-write layer pools)."""
+        table, one new tensor per side.  Idle slots' tables point at the
+        scratch block, so the fixed-shape all-slots write never lands on
+        live storage.  Returns ``(*layer buffers, tables, lengths)`` raw
+        arrays (post-write layer pools)."""
         lens = self.lengths._value()
         bs = self.block_size
         tbl = self.block_tables._value()            # [slots, max_blocks]
@@ -474,14 +510,65 @@ class PagedKVCache:
             tbl, bidx[:, None], axis=1)[:, 0]       # [slots]
         off = lens % bs
         layers = []
-        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
+        for buf, new in zip(self._layer(layer_idx), news):
             arr = buf._value()
             upd = self._to_lanes(new._value()[:, 0], arr.dtype)
             with jax.named_scope(KV_WRITE_SCOPE):
                 arr = arr.at[block_ids, off].set(upd)
             buf._set_data(arr)
             layers.append(arr)
-        return layers[0], layers[1], tbl, lens
+        return (*layers, tbl, lens)
+
+    # -- the latent pool's calls (one side, one "head") ---------------------
+
+    def latent_prefill_write(self, layer_idx: int, slot, lat, start) -> None:
+        """``prefill_write`` of the one latent vector a token:
+        ``lat [1, S, width]``."""
+        self._prefill_write(layer_idx, slot,
+                            (Tensor._wrap(lat._value()[:, :, None, :]),),
+                            start)
+
+    def _latent_call(self, layer_idx: int, name: str, q_lat, *args, **kw):
+        """``mla_<name>`` (the Pallas kernel, or its jnp oracle under
+        ``kernel="reference"``) on this layer's pool as the kernels take it
+        (``[num_blocks, block_size, lanes]``: the one "head" dropped) with
+        ``q_lat [..., H, width]`` zero-padded to the pool's lanes."""
+        from ..ops.pallas import mla_attention_kernel as mla
+
+        pool = self.sides[0][layer_idx]._value()
+        pool = pool.reshape(pool.shape[0], pool.shape[1], pool.shape[3])
+        q = self._to_lanes(q_lat, q_lat.dtype)
+        if self.kernel == "pallas":
+            return getattr(mla, f"mla_paged_{name}")(
+                q, pool, *args, interpret=self._interpret, **kw)
+        return getattr(mla, f"mla_{name}_reference")(q, pool, *args, **kw)
+
+    def latent_decode_attention(self, layer_idx: int, q_lat, lat, active, *,
+                                scale: float, dv: int):
+        """One decode step of absorbed latent attention for this layer:
+        write each slot's vector ``lat [slots, 1, width]``, then
+        ``q_lat [slots, 1, H, width]`` attends over each active slot's
+        window.  Returns ``[slots, 1, H, dv]``."""
+        _pool, tbl, lens = self._decode_token_write(
+            layer_idx, Tensor._wrap(lat._value()[:, :, None, :]))
+        out = self._latent_call(layer_idx, "decode", q_lat._value()[:, 0],
+                                tbl, lens, _as_i32(active), scale=scale,
+                                dv=dv)
+        return Tensor._wrap(out[:, None])
+
+    def latent_prefill_attention(self, layer_idx: int, slot, q_lat, start,
+                                 length, *, scale: float, dv: int):
+        """Tail queries ``q_lat [1, S, H, width]`` over the slot's whole
+        block row (cached prefix + the tail just written).  Returns
+        ``[1, S, H, dv]``."""
+        row = jax.lax.dynamic_index_in_dim(
+            self.block_tables._value(), _as_i32(slot).reshape(()), axis=0,
+            keepdims=False)
+        out = self._latent_call(layer_idx, "prefill", q_lat._value()[0], row,
+                                _as_i32(start).reshape(()),
+                                _as_i32(length).reshape(()), scale=scale,
+                                dv=dv)
+        return Tensor._wrap(out[None])
 
     def decode_write(self, layer_idx: int, k, v
                      ) -> Tuple[Tensor, Tensor, Tensor]:
@@ -534,7 +621,7 @@ class PagedKVCache:
                               SCRATCH_BLOCK)
         off = pos % bs
         layers = []
-        for buf, new in ((self.k[layer_idx], k), (self.v[layer_idx], v)):
+        for buf, new in zip(self._layer(layer_idx), (k, v)):
             arr = buf._value()
             upd = self._to_lanes(new._value(), arr.dtype)  # [slots,W,Hkv,Dp]
             with jax.named_scope(KV_WRITE_SCOPE):
@@ -574,13 +661,12 @@ class PagedKVCache:
         return int(self.lengths.numpy()[slot])
 
     def layer_nbytes(self) -> int:
-        """Bytes of one layer's K (or V) buffer, pad lanes included."""
-        itemsize = jnp.zeros((), dtype=self.dtype).dtype.itemsize
-        return self.num_blocks * self.block_size * self.num_kv_heads * \
-            self.lane_dim * itemsize
+        """Bytes of one layer's buffer of the first side (K and V are
+        alike), pad lanes included."""
+        return int(self.sides[0][0]._value().nbytes)
 
     def nbytes(self) -> int:
-        return 2 * self.num_layers * self.layer_nbytes()
+        return sum(int(buf._value().nbytes) for buf in self.buffers())
 
     def blocks_in_use(self) -> int:
         s = self.allocator.stats()
@@ -611,18 +697,36 @@ class PagedCacheContext(CacheContext):
 
     start: Optional[Tensor] = None              # prefill: scalar int32
 
+    def _prefill_start(self):
+        return _as_i32(self.start if self.start is not None else 0
+                       ).reshape(())
+
+    # -- the latent pool: one write and two attention calls -----------------
+
+    def write_prefill_latent(self, lat) -> None:
+        self.cache.latent_prefill_write(self.layer_idx, self.slot, lat,
+                                        self._prefill_start())
+
+    def latent_prefill_attention(self, q_lat, *, scale: float, dv: int):
+        return self.cache.latent_prefill_attention(
+            self.layer_idx, self.slot, q_lat, self._prefill_start(),
+            self.length, scale=scale, dv=dv)
+
+    def latent_decode_attention(self, q_lat, lat, *, scale: float, dv: int):
+        if self.mode != "decode":
+            raise ValueError("the latent pool has no verify form")
+        return self.cache.latent_decode_attention(
+            self.layer_idx, q_lat, lat, self.active, scale=scale, dv=dv)
+
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v,
-                                 self.start if self.start is not None
-                                 else 0)
+                                 self._prefill_start())
 
     def prefill_positions(self, seq_len: int) -> Optional[Tensor]:
         """Absolute positions of the tail bucket's tokens ``[1, S]`` —
         offset by the cached-prefix length."""
-        st = _as_i32(self.start if self.start is not None else 0
-                     ).reshape(())
-        return Tensor._wrap(
-            (st + jnp.arange(seq_len, dtype=jnp.int32))[None, :])
+        return Tensor._wrap((self._prefill_start() + jnp.arange(
+            seq_len, dtype=jnp.int32))[None, :])
 
     def prefill_attention(self, q, k, v):
         """Tail queries attending over the slot's whole block table

@@ -527,3 +527,67 @@ def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
     assert chip_smoke.pool_sized_moves(hlo, layer_buf) == []
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < layer_buf
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_pool_programs_move_no_layer_buffer_on_the_chip(
+        one_chip, program):
+    """The same proof for the pool of a latent-attention model:
+    JoyAI-LLM-Flash's widths (latent 512 + 64 stored in 640 lanes, 32 heads,
+    experts of 768 with 32 of 256 held; one dense and one expert layer,
+    vocabulary cut to 8,192), 32 slots, block 16, a 2,049-block pool of ONE
+    buffer a layer ``[2049, 16, 1, 640]``: the decode program and the
+    bucket-256 prefill program, with ``mla_paged_decode`` /
+    ``mla_paged_prefill`` and ``moe_grouped_matmul`` as the chip runs them,
+    hold no ``copy`` / ``transpose`` / ``slice`` of a layer buffer's size
+    and alias the whole pool."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import deepseek_v3 as dm
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = dm.DeepseekV3ForCausalLM(dm.DeepseekV3Config(
+        vocab_size=8192, num_hidden_layers=2, held_experts=(0, 32),
+        max_position_embeddings=1024, dtype="bfloat16"))
+    eng = Engine(model, num_slots=32, max_seq=1024, min_bucket=256,
+                 kv_layout="paged", block_size=16, kernel="pallas")
+    assert [tuple(b.shape) for b in eng.cache.buffers()] == \
+        [(2049, 16, 1, 640)] * 2
+    eng.cache._interpret = False          # the kernels as the chip runs them
+    interpret, dm._interpret = dm._interpret, lambda: False
+    try:
+        eng._build_steps()
+        if program == "decode":
+            fn, args = eng._decode_fn, [np.zeros((32,), np.int32)]
+        else:
+            fn, args = eng._prefill_fn, [np.zeros((1, 256), np.int64),
+                                         np.int32(0), np.int32(1), np.int32(0)]
+            assert eng.cache.begin_sequence(0, [], 0, 256)
+        leaves = []
+        args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+        prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+        def on_chip(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        with no_grad():
+            prog.build(leaves)
+            sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+            compiled = prog.jitted_donate.lower(
+                [on_chip(t._value()) for t in leaves],
+                [on_chip(a) for a in sd], [on_chip(a) for a in sk]).compile()
+    finally:
+        dm._interpret = interpret
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    pool, layer_buf = eng.cache.nbytes(), eng.cache.layer_nbytes()
+    assert layer_buf == 2049 * 16 * 640 * 2 and pool == 2 * layer_buf
+    # one attention call a layer, two grouped products in the expert layer
+    assert hlo.count(chip_smoke.PALLAS_CALL) == 2 + 2
+    for kernel in ("mla_paged_" + program, "moe_grouped_matmul"):
+        assert re.search(r"%" + kernel + r"(\.\d+)? = ", hlo), kernel
+    assert chip_smoke.pool_sized_moves(hlo, layer_buf) == []
+    assert mem.alias_size_in_bytes >= pool

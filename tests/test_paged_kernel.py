@@ -271,3 +271,95 @@ class TestEngineKernelPath:
         # contiguous ignores the kernel flag (jnp oracle only)
         eng = Engine(m, num_slots=2, max_seq=32)
         assert eng.kernel == "reference"
+
+
+# -- the latent (MLA) kernels and the grouped expert kernel -------------------
+
+def _latent_case(rs, *, slots=4, heads=4, width=40, bs=8, mb=40, nb=200):
+    """A pool in its stored form (``width`` in whole 128-lane rows, pad lanes
+    zero), a table whose rows 1 and 3 share their first block, and queries
+    padded like the pool.  ``mb * bs`` = 320 tokens is two chunks of 256."""
+    from paddle_tpu.serving.paging import SCRATCH_BLOCK
+
+    lanes = 128
+    pool = np.zeros((nb, bs, lanes), np.float32)
+    pool[:, :, :width] = rs.randn(nb, bs, width)
+    tbl = np.full((slots, mb), SCRATCH_BLOCK, np.int32)
+    ids = rs.permutation(nb - 1)[:slots * mb].reshape(slots, mb) + 1
+    for b in (0, 1, 3):
+        tbl[b] = ids[b]
+    tbl[3, 0] = tbl[1, 0]                   # a shared prefix block
+    q = np.zeros((slots, heads, lanes), np.float32)
+    q[..., :width] = rs.randn(slots, heads, width)
+    return jnp.asarray(pool), jnp.asarray(tbl), jnp.asarray(q)
+
+
+class TestLatentKernels:
+    @pytest.mark.parametrize("lengths", [(5, 130, 0, 300), (255, 256, 0, 7)])
+    def test_decode_matches_its_oracle_on_ragged_lengths(self, lengths):
+        """Slot 2 is idle on the scratch block (no work item, output zero);
+        slot 3 crosses the chunk boundary; slots 1 and 3 share a block."""
+        from paddle_tpu.ops.pallas import mla_attention_kernel as mk
+
+        pool, tbl, q = _latent_case(np.random.RandomState(0))
+        lens = jnp.asarray(lengths, jnp.int32)
+        active = jnp.asarray([1, 1, 0, 1], jnp.int32)
+        kw = dict(scale=0.2, dv=32)
+        got = mk.mla_paged_decode(q, pool, tbl, lens, active, interpret=True,
+                                  **kw)
+        want = mk.mla_decode_reference(q, pool, tbl, lens, active, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=0)
+        assert not np.asarray(got[2]).any()
+        slot, chunk, n = mk.decode_work_list(lens, active, 256, 2)
+        per = [ln // 256 + 1 if a else 0 for ln, a in zip(lengths, (1, 1, 0, 1))]
+        assert int(n) == sum(per)
+        assert list(np.asarray(slot[:int(n)])) == \
+            [b for b, k in enumerate(per) for _ in range(k)]
+
+    @pytest.mark.parametrize("start,length", [(0, 50), (96, 160), (256, 266)])
+    def test_prefill_matches_its_oracle_below_the_prompts_length(
+            self, start, length):
+        """A 64-token tail bucket behind ``start`` cached tokens; the rows
+        past the prompt's real length are pad: a tile wholly of them is not
+        computed and comes back zero."""
+        from paddle_tpu.ops.pallas import mla_attention_kernel as mk
+
+        rs = np.random.RandomState(1)
+        pool, tbl, _ = _latent_case(rs)
+        q = np.zeros((64, 4, 128), np.float32)
+        q[..., :40] = rs.randn(64, 4, 40)
+        q = jnp.asarray(q)
+        kw = dict(scale=0.2, dv=32)
+        got = mk.mla_paged_prefill(q, pool, tbl[1], start, length,
+                                   interpret=True, **kw)
+        want = mk.mla_prefill_reference(q, pool, tbl[1], start, length, **kw)
+        real = length - start
+        np.testing.assert_allclose(np.asarray(got[:real]),
+                                   np.asarray(want[:real]), atol=2e-5, rtol=0)
+        if real <= 32:
+            assert not np.asarray(got[32:]).any()
+
+
+@pytest.mark.parametrize("rows,sizes", [
+    (300, [0, 130, 5, 0, 100]),       # empty groups, rows in no group
+    (256, [0, 0, 256, 0]),            # one expert gets every row
+    (40, [3, 0, 9, 1]),               # fewer rows than a tile
+    (256, [0, 0, 0, 0]),              # nothing routed here: a grid of none
+])
+def test_grouped_matmul_matches_the_loop_over_experts(rows, sizes):
+    from paddle_tpu.ops.pallas import moe_kernel as gk
+
+    rs = np.random.RandomState(2)
+    lhs = jnp.asarray(rs.randn(rows, 64), jnp.float32)
+    rhs = jnp.asarray(rs.randn(len(sizes), 64, 96), jnp.float32)
+    gs = jnp.asarray(sizes, jnp.int32)
+    got = jax.jit(lambda a, b, c: gk.moe_grouped_matmul(
+        a, b, c, interpret=True))(lhs, rhs, gs)
+    want = gk.grouped_matmul_reference(lhs, rhs, gs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4,
+                               rtol=0)
+    assert not np.asarray(got[sum(sizes):]).any()
+    _off, group, _tile, n = gk._visits(gs, -(-rows // gk.TILE_M), gk.TILE_M)
+    visited = set(np.asarray(group[:int(n)]).tolist())
+    assert visited == {g for g, s in enumerate(sizes) if s}   # no empty one
