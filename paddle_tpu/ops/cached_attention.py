@@ -187,18 +187,19 @@ def _paged_per_shard(kernel, args, mesh):
     (``ops.pallas.per_shard``): queries ``[B, S, H, D]`` and pool layers
     ``[blocks, block_size, Hkv, Dp]`` both carry heads at dim 2 and split
     there over the model axis, like the weights and the KV pool; the block
-    table and the scalars replicate."""
+    table and the per-slot or scalar arguments after it replicate."""
     from jax.sharding import PartitionSpec as P
 
     from ..distributed.sharding_spec import MODEL_AXIS
     from .pallas import per_shard
 
     heads = P(None, None, MODEL_AXIS, None)
-    return per_shard(kernel, args, (heads, heads, heads, P(), P()), mesh)
+    return per_shard(kernel, args,
+                     (heads,) * 3 + (P(),) * (len(args) - 3), mesh)
 
 
 def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
-                           interpret=False, mesh=None, name=None):
+                           active, interpret=False, mesh=None, name=None):
     """Flash-decoding paged attention: the Pallas kernel path of the
     decode read (``ops.pallas.paged_attention_kernel``), consuming the
     block table *inside* the kernel — the fused replacement for
@@ -213,24 +214,27 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
         v_pool:       same for values.
         block_tables: ``[B, max_blocks]`` int32 per-slot block ids.
         lengths:      ``[B]`` int32 current token index per slot.
+        active:       ``[B]`` int32, nonzero for the running slots: the
+                      kernel visits no other slot.
         interpret:    run the kernel in Pallas interpret mode (the
                       CPU/tier-1 path; False compiles for real TPUs).
         mesh:         the mesh a sharded engine's pool lives on (None:
                       unsharded) — the kernel then runs per head shard.
 
     Returns:
-        ``[B, 1, H, D]`` context, GQA expanded inside the kernel.
+        ``[B, 1, H, D]`` context, GQA expanded inside the kernel; zero
+        for the slots that are not active.
     """
     from .pallas.paged_attention_kernel import paged_decode_attention_kernel
 
-    def _primal(q, kp, vp, tbl, ln):
+    def _primal(q, kp, vp, tbl, ln, act):
         return _paged_per_shard(
             functools.partial(paged_decode_attention_kernel,
                               interpret=interpret),
-            (q, kp, vp, tbl, ln), mesh)
+            (q, kp, vp, tbl, ln, act), mesh)
 
     return apply_op("paged_decode_attention", _primal,
-                    [query, k_pool, v_pool, block_tables, lengths])
+                    [query, k_pool, v_pool, block_tables, lengths, active])
 
 
 def paged_prefill_attention(query, k_pool, v_pool, block_row, start,

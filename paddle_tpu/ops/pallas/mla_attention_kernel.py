@@ -62,6 +62,11 @@ def _chunk_blocks(block_size: int, max_blocks: int) -> int:
     return max(1, min(CHUNK_TOKENS // block_size, max_blocks))
 
 
+def chunk_tokens(block_size: int, max_blocks: int) -> int:
+    """Tokens one work item of the decode kernel attends over."""
+    return _chunk_blocks(block_size, max_blocks) * block_size
+
+
 def _fetch_chunk(tbl_row, first_block, pool_ref, kv_ref, sem, *, cb, bs, mb):
     """Copies blocks ``first_block .. first_block+cb-1`` of a table row into
     ``kv_ref [cb*bs, Dp]``.  Indices past the row are clamped: what they

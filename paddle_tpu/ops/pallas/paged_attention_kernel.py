@@ -10,16 +10,34 @@ table is consumed *inside* the kernel instead of first materializing a
 contiguous ``[slots, max_blocks * block_size, Hkv, D]`` copy of every
 slot's K/V in HBM:
 
-- **decode** (``paged_decode_attention``): grid ``(slots, max_blocks)``;
-  the block table and lengths ride in scalar-prefetch SMEM, and each
-  grid step DMAs exactly ONE ``[block_size, Hkv, D]`` K/V block —
-  selected by the table *value*, the automatic-kernel-generation move of
-  arXiv:2006.12645 (the index map is data-driven, the kernel is not
-  specialized per table) — accumulating an online softmax per query
-  head.  One query row per head is no work for the MXU, so scores and
-  the weighted sum are elementwise products reduced on the VPU with
-  ``(Hkv, D)`` kept as the minor dims throughout (Mosaic refuses the
-  head-batched ``einsum("hd,jhd->hj")`` this replaced).
+- **decode** (``paged_decode_attention``): the grid is a *work list*, not
+  ``slots x blocks``: one place per (active slot, live chunk), built outside
+  the kernel from the lengths and the step's ``active`` mask
+  (``mla_attention_kernel.decode_work_list``, the latent kernel's own) and
+  handed over in scalar prefetch behind the block table and the lengths.
+  A chunk is ``decode_chunk_tokens`` tokens' blocks (256 tokens at GPT-2
+  345M's pool; a function of ``block_size``, ``max_blocks``, ``Hkv``, the
+  lane width and the dtype under v5e's 16 MiB of scoped VMEM, no knob).  The
+  pools stay in HBM (``memory_space=ANY``): a work item copies the chunk's
+  *live* blocks into VMEM, one DMA a block and side, selected by the table
+  *value* (the automatic-kernel-generation move of arXiv:2006.12645: the
+  kernel is not specialized per table), then does one online-softmax update
+  per query head over the whole chunk; the accumulators reset at a slot's
+  first chunk and its output row is written at its last.  The grid itself is
+  **static**, ``slots x max_chunks`` places with the live count ``n`` in
+  scalar prefetch: places ``i >= n`` do nothing and keep the last item's
+  slot, so their index maps move no row in or out (under 0.1 us each).  A
+  dynamic bound would be the custom call's *first* operand on this jax and
+  ``paged_decode_roofline`` tells the kernel in a trace by its first two,
+  the ``s32[slots, max_blocks]`` table and the ``s32[slots]`` lengths.  Rows
+  of slots that are not active are never written; the wrapper zeroes them.
+  One query row per head is no work for the MXU, so scores and the weighted
+  sum are elementwise products reduced on the VPU with ``(Hkv, D)`` kept as
+  the minor dims throughout (Mosaic refuses the head-batched
+  ``einsum("hd,jhd->hj")`` this replaced).  Keys past a slot's length are
+  masked in the scores and *values* past it are dropped before the product
+  (a weight of zero does not hide a NaN), so nothing a dead block or the
+  tail of the last live one holds reaches an output.
 - **prefill** (``paged_prefill_attention``): grid ``(S / q_tile,
   max_blocks)``; each tile of the tail bucket's queries attends over the
   slot's whole block row (shared prefix blocks + the freshly written
@@ -37,9 +55,10 @@ out so kv head ``g`` serves query heads ``g * rep .. g * rep + rep - 1``
 
 Both kernels run under ``interpret=True`` off-TPU so the CPU tier-1
 suite executes the exact kernel code path; shapes depend only on
-``(slots, block_size, max_blocks, heads, head_dim)`` — block ids and
-lengths are *values*, so the serving engine's zero-recompile discipline
-holds unchanged.  All accumulation is f32 (matching the oracle's f32
+``(slots, block_size, max_blocks, heads, head_dim)`` — block ids,
+lengths, the active mask and the work list are *values*, so the serving
+engine's zero-recompile discipline holds unchanged.  All accumulation is
+f32 (matching the oracle's f32
 softmax); parity vs the jnp path is ~1e-6 in interpret mode, asserted in
 tests/test_paged_kernel.py.  On the chip f32 operands go through the
 MXU at its default (bf16-pass) precision, as the oracle's XLA einsums do.
@@ -53,7 +72,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mla_attention_kernel import decode_work_list
+
 NEG_INF = -1e30
+
+#: the decode kernel's chunk: at most this many tokens a work item ...
+DECODE_CHUNK_TOKENS = 256
+#: ... and buffers of at most this much of v5e's 16 MiB of scoped VMEM (the
+#: rest is the compiler's own and the small per-head arrays)
+DECODE_VMEM_BYTES = 12 << 20
+#: float32 arrays of a chunk's shape ``[tokens, kv_heads, lanes]`` the kernel
+#: body holds at once: keys, values, and the two products
+DECODE_F32_CHUNKS = 4
 
 #: query rows per prefill grid step (f32 q/out/acc tiles of 16 heads x
 #: 128 rows x 64->128 lanes are 1 MiB each; ~7 MiB of VMEM in all)
@@ -67,56 +97,98 @@ def _to_lanes(q, lanes: int):
     return q if pad == 0 else jnp.pad(q, [(0, 0)] * (q.ndim - 1) + [(0, pad)])
 
 
-# -- decode: one query token per slot, K/V streamed by block table ----------
+# -- decode: one query token per slot, K/V copied by block table ------------
 
-def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, scale, block_size):
-    b, i = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
+def decode_chunk_tokens(block_size: int, max_blocks: int, kv_heads: int,
+                        lanes: int, itemsize: int) -> int:
+    """Tokens one work item of the decode kernel attends over: whole blocks,
+    as many as the chunk's buffers (K and V in the pool's dtype, and
+    :data:`DECODE_F32_CHUNKS` float32 arrays of the chunk's shape, each
+    token's ``(kv_heads, lanes)`` in whole sublane tiles) fit in
+    :data:`DECODE_VMEM_BYTES`; at most :data:`DECODE_CHUNK_TOKENS` and the
+    slot's whole row, at least one block.  A function of the shapes alone:
+    the engine's host counts its ``decode_chunks`` with it."""
+    tile = 32 // itemsize                       # rows of a sublane tile
+    pool_rows = -(-kv_heads // tile) * tile
+    f32_rows = -(-kv_heads // 8) * 8
+    per_token = lanes * (2 * pool_rows * itemsize
+                         + DECODE_F32_CHUNKS * f32_rows * 4)
+    tokens = min(DECODE_CHUNK_TOKENS, DECODE_VMEM_BYTES // per_token)
+    return max(1, min(tokens // block_size, max_blocks)) * block_size
+
+
+def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
+                   k_hbm, v_hbm, o_ref, k_ref, v_ref, sem, acc_ref, m_ref,
+                   l_ref, *, scale, cb, bs, mb):
+    i = pl.program_id(0)
     rep = q_ref.shape[1]
+    ct = cb * bs
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    @pl.when(i < n_ref[0])                       # places past the list: idle
+    def _item():
+        b, c = slot_ref[i], chunk_ref[i]
+        length = len_ref[b]                      # window 0..length inclusive
 
-    length = len_ref[b]                          # current token index
-    # a block is live iff it intersects the valid window 0..length
-    # (blocks past the sequence are skipped — their DMA still resolves,
-    # to whatever the table row holds, but nothing is accumulated)
-    live = i * block_size <= length
+        @pl.when(c == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(live)
-    def _compute():
-        k = k_ref[0].astype(jnp.float32)         # [BS, Hkv, D]
-        v = v_ref[0].astype(jnp.float32)
-        pos = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, k.shape[:2] + (1,), 0)    # [BS, Hkv, 1]
+        # one copy a live block of the chunk and side, by table value; the
+        # blocks past the slot's length are not read (what the buffers hold
+        # there is masked below).  All are started, then all awaited; the
+        # loops are the device's, so a chunk of many blocks traces as one.
+        def block_copies(j):
+            blk = tbl_ref[b, jnp.minimum(c * cb + j, mb - 1)]
+            row = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            return [pltpu.make_async_copy(pool.at[blk], buf.at[row],
+                                          sem.at[side, j])
+                    for side, (pool, buf) in enumerate(((k_hbm, k_ref),
+                                                        (v_hbm, v_ref)))]
+
+        def start(j, carry):
+            for cp in block_copies(j):
+                cp.start()
+            return carry
+
+        def wait(j, carry):
+            for cp in block_copies(j):
+                cp.wait()
+            return carry
+
+        live = jnp.minimum(length // bs - c * cb + 1, cb)
+        jax.lax.fori_loop(0, live, start, 0)
+        jax.lax.fori_loop(0, live, wait, 0)
+
+        k = k_ref[...].astype(jnp.float32)       # [ct, Hkv, D]
+        pos = c * ct + jax.lax.broadcasted_iota(
+            jnp.int32, k.shape[:2] + (1,), 0)    # [ct, Hkv, 1]
         valid = pos <= length
+        # a weight of zero does not hide a NaN: masked values are dropped
+        v = jnp.where(valid, v_ref[...].astype(jnp.float32), 0.0)
         for r in range(rep):                     # static: H // Hkv
             q = q_ref[0, r].astype(jnp.float32)  # [Hkv, D]
             s = jnp.sum(q[None] * k, axis=-1, keepdims=True) * scale
-            s = jnp.where(valid, s, NEG_INF)     # [BS, Hkv, 1]
+            s = jnp.where(valid, s, NEG_INF)     # [ct, Hkv, 1]
             m_prev = m_ref[r, :, 0:1]            # [Hkv, 1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
-            p = jnp.exp(s - m_new[None])         # [BS, Hkv, 1]
+            p = jnp.exp(s - m_new[None])         # [ct, Hkv, 1]
             corr = jnp.exp(m_prev - m_new)       # [Hkv, 1]
             l_new = l_ref[r, :, 0:1] * corr + jnp.sum(p, axis=0)
             acc_ref[r] = acc_ref[r] * corr + jnp.sum(p * v, axis=0)
             m_ref[r] = jnp.broadcast_to(m_new, m_ref.shape[1:])
             l_ref[r] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
-    @pl.when(i == nb - 1)
-    def _finalize():
-        for r in range(rep):
-            l = l_ref[r, :, 0:1]
-            l = jnp.where(l == 0.0, 1.0, l)      # unreachable: pos 0 valid
-            o_ref[0, r] = (acc_ref[r] / l).astype(o_ref.dtype)
+        @pl.when(c == length // ct)              # the slot's last live chunk
+        def _finalize():
+            for r in range(rep):                 # l > 0: position c*ct valid
+                o_ref[0, r] = (acc_ref[r] / l_ref[r, :, 0:1]
+                               ).astype(o_ref.dtype)
 
 
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
-                                  lengths, *, interpret=False):
+                                  lengths, active, *, interpret=False):
     """One decode step of attention straight off the block pool.
 
     Args:
@@ -129,32 +201,56 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         block_tables: ``[B, max_blocks]`` int32 block ids per slot.
         lengths:      ``[B]`` int32 current token index per slot
                       (attention window ``0..lengths[b]`` inclusive).
+        active:       ``[B]`` int32, nonzero for the running slots.
 
     Returns:
-        ``[B, 1, H, D]`` context.  No contiguous K/V copy is ever
-        materialized: each grid step reads one pool block by table value.
+        ``[B, 1, H, D]`` context; zero for slots that are not active.  No
+        contiguous K/V copy is ever materialized: a work item copies its
+        chunk's live blocks by table value.
     """
+    bs, Hkv, D = k_pool.shape[1:]
+    ct = decode_chunk_tokens(bs, block_tables.shape[1], Hkv, D,
+                             k_pool.dtype.itemsize)
+    return _decode_call(q, k_pool, v_pool, block_tables, lengths, active,
+                        chunk_tokens=ct, interpret=interpret)
+
+
+# jitted so that a model's layers, and the passes a program is traced in,
+# trace and lower the kernel once for their shapes and not once each
+@functools.partial(jax.jit, static_argnames=("chunk_tokens", "interpret"))
+def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, *,
+                 chunk_tokens, interpret):
     B, _, H, head_dim = q.shape
-    block_size, Hkv, D = k_pool.shape[1:]
+    bs, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
-    MB = block_tables.shape[1]
+    mb = block_tables.shape[1]
+    ct, cb = chunk_tokens, chunk_tokens // bs
+    max_chunks = -(-mb // cb)
     scale = 1.0 / (head_dim ** 0.5)
     q = _to_lanes(q, D)
-    kernel = functools.partial(_decode_kernel, scale=scale,
-                               block_size=block_size)
+    lengths = lengths.astype(jnp.int32)
+    slot, chunk, n = decode_work_list(lengths, active, ct, max_chunks)
+    # a place past the list keeps the last item's slot: its index maps
+    # move no query row in and, above all, no output row out
+    slot = jnp.where(jnp.arange(slot.shape[0]) < n, slot,
+                     slot[jnp.maximum(n - 1, 0)])
+    kernel = functools.partial(_decode_kernel, scale=scale, cb=cb, bs=bs,
+                               mb=mb)
     # query head h = g * rep + r  ->  q_g[b, r, g]: kv head g lines up
     # with every one of its rep query heads without an in-kernel repeat
     q_g = q.reshape(B, Hkv, rep, D).transpose(0, 2, 1, 3)
-    kv_spec = pl.BlockSpec((1, block_size, Hkv, D),
-                           lambda b, i, tbl, lens: (tbl[b, i], 0, 0, 0))
     qo_spec = pl.BlockSpec((1, rep, Hkv, D),
-                           lambda b, i, tbl, lens: (b, 0, 0, 0))
+                           lambda i, tbl, lens, sl, ch, n: (sl[i], 0, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, MB),
-        in_specs=[qo_spec, kv_spec, kv_spec],
+        num_scalar_prefetch=5,
+        grid=(B * max_chunks,),                  # static: see the docstring
+        in_specs=[qo_spec, pool_spec, pool_spec],
         out_specs=qo_spec,
         scratch_shapes=[
+            pltpu.VMEM((ct, Hkv, D), k_pool.dtype),
+            pltpu.VMEM((ct, Hkv, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, cb)),
             pltpu.VMEM((rep, Hkv, D), jnp.float32),
             pltpu.VMEM((rep, Hkv, 128), jnp.float32),
             pltpu.VMEM((rep, Hkv, 128), jnp.float32),
@@ -165,12 +261,13 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rep, Hkv, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), lengths, slot, chunk, n.reshape(1),
       q_g, k_pool, v_pool)
-    return o_g.transpose(0, 2, 1, 3).reshape(B, 1, H, D)[..., :head_dim]
+    out = o_g.transpose(0, 2, 1, 3).reshape(B, 1, H, D)[..., :head_dim]
+    return jnp.where((active > 0)[:, None, None, None], out, 0)
 
 
 # -- fused prefill: cached prefix + causal tail in one kernel scope ---------

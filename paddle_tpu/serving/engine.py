@@ -650,6 +650,9 @@ class Engine:
         self._kv_tokens = 0          # cached tokens of the running slots
         self._admitted_step = 0      # prompts admitted by the current step
         self._step_span = None       # the open ``engine.step`` span
+        #: tokens a work item of the paged decode kernel covers (set with
+        #: the programs; None: no work list, no ``decode_chunks``)
+        self._decode_chunk_tokens: Optional[int] = None
         #: decode-step load of the model's expert layers (empty for a
         #: model without experts: ``stats()`` then has no ``"moe"``)
         self._moe = {"tokens": 0, "assignments_held": 0,
@@ -683,6 +686,8 @@ class Engine:
 
         model, cache, sampler = self.model, self.cache, self.sampler
         pool = self.adapter_pool
+        if self.kv_layout == "paged" and self.spec is None:
+            self._decode_chunk_tokens = cache.decode_chunk_tokens()
 
         def _prefill_rows(slot):
             # this prefill's slot selects its adapter lane: a [1] row id
@@ -1915,6 +1920,12 @@ class Engine:
             active = np.zeros((self.num_slots,), dtype=np.int32)
             for slot in self.running:
                 active[slot] = 1
+            ct = self._decode_chunk_tokens
+            if ct is not None and self._step_span is not None:
+                # the kernel's work list, counted where the lengths are
+                # known without asking the device
+                self._step_span.attrs["decode_chunks"] = sum(
+                    req._seq_len // ct + 1 for req in self.running.values())
         san = self.sanitizer
         try:
             # the compiled step itself must not round-trip to host: the

@@ -328,7 +328,7 @@ class CacheContext:
             return self.cache.verify_attention(self.layer_idx, q, k, v)
         cache_fn = getattr(self.cache, "decode_attention", None)
         if cache_fn is not None:
-            return cache_fn(self.layer_idx, q, k, v)
+            return cache_fn(self.layer_idx, q, k, v, self.active)
         from ..ops.cached_attention import cached_attention
 
         k_full, v_full, lens = self.write_decode(k, v)

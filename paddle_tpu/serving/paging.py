@@ -583,21 +583,43 @@ class PagedKVCache:
                 Tensor._wrap(self.gather(v_layer, tbl)),
                 Tensor._wrap(lens))
 
-    def decode_attention(self, layer_idx: int, q, k, v):
+    def decode_attention(self, layer_idx: int, q, k, v, active):
         """One decode step of attention for this layer: write the token,
         then attend.  ``kernel="pallas"`` consumes the block table inside
-        the flash-decoding kernel (no materialized contiguous K/V);
-        ``"reference"`` gathers and runs the jnp oracle — identical
-        semantics, asserted in tests/test_paged_kernel.py."""
+        the flash-decoding kernel (no materialized contiguous K/V), which
+        visits the ``active`` slots only and returns zero rows for the
+        others; ``"reference"`` gathers and runs the jnp oracle over every
+        slot — identical on the active rows, asserted in
+        tests/test_paged_kernel.py."""
         if self.kernel == "pallas":
             k_layer, v_layer, tbl, lens = self._decode_token_write(
                 layer_idx, k, v)
             return paged_decode_attention(
                 q, Tensor._wrap(k_layer), Tensor._wrap(v_layer),
                 Tensor._wrap(tbl), Tensor._wrap(lens),
+                Tensor._wrap(_as_i32(active)),
                 interpret=self._interpret, mesh=self.mesh)
         k_full, v_full, lens = self.decode_write(layer_idx, k, v)
         return cached_attention(q, k_full, v_full, lens)
+
+    def decode_chunk_tokens(self) -> Optional[int]:
+        """Tokens one work item of this pool's Pallas decode kernel attends
+        over, from the shapes a shard of the pool has (the kernels' own
+        functions); None under ``kernel="reference"``, which has no work
+        list.  A running slot with ``n`` cached tokens costs a decode step
+        ``n // tokens + 1`` items a layer: the engine's ``decode_chunks``."""
+        if self.kernel != "pallas":
+            return None
+        arr = self.sides[0][0]._value()
+        _, bs, heads, lanes = arr.sharding.shard_shape(arr.shape)
+        if len(self.sides) == 1:
+            from ..ops.pallas.mla_attention_kernel import chunk_tokens
+
+            return chunk_tokens(bs, self.max_blocks_per_slot)
+        from ..ops.pallas.paged_attention_kernel import decode_chunk_tokens
+
+        return decode_chunk_tokens(bs, self.max_blocks_per_slot, heads, lanes,
+                                   arr.dtype.itemsize)
 
     def verify_write(self, layer_idx: int, k, v):
         """Speculative verify write through the block table: W tokens

@@ -213,6 +213,50 @@ def test_every_engine_step_has_its_phases_in_order(engine_run):
     assert steps[-1][ATTRS]["running"] == 0 and eng._kv_tokens == 0
 
 
+def test_decode_chunks_on_the_step_span_is_the_kernels_work_list(
+        serving_model, monkeypatch):
+    """``decode_chunks`` is counted on the host from the lengths the engine
+    keeps; the device's ``n`` is the work list's own, from the lengths and
+    the mask the decode program is handed.  A chunk cut to 16 tokens (two
+    blocks of 8; four chunks a row) makes slots cross chunk edges in a
+    short run."""
+    from paddle_tpu.ops.pallas import paged_attention_kernel as pk
+    from paddle_tpu.ops.pallas.mla_attention_kernel import decode_work_list
+
+    monkeypatch.setattr(pk, "DECODE_CHUNK_TOKENS", 16)
+    eng = Engine(serving_model, num_slots=4, max_seq=64, min_bucket=8,
+                 kv_layout="paged", block_size=8)
+    eng.warmup()
+    ct = eng._decode_chunk_tokens
+    assert ct == eng.cache.decode_chunk_tokens() == 16
+    device_n, real = [], eng._step_call
+
+    def spy(point, fn, *args, **kw):
+        if point == "serving.decode":
+            device_n.append(int(decode_work_list(
+                eng.cache.lengths._value(), args[0]._value(), ct,
+                64 // ct)[2]))
+        return real(point, fn, *args, **kw)
+
+    monkeypatch.setattr(eng, "_step_call", spy)
+    rng = np.random.default_rng(1)
+    t = spans.clock()
+    reqs = [eng.add_request(rng.integers(1, 100, (n,)), max_new_tokens=6)
+            for n in (5, 14, 30, 43)]
+    eng.run()
+    assert all(r.finished for r in reqs)
+    steps = [r[ATTRS] for r in rows_since(t) if r[NAME] == "engine.step"]
+    host_n = [a["decode_chunks"] for a in steps if "decode_chunks" in a]
+    # 5 // 16 + 14 // 16 + 30 // 16 + 43 // 16 + 4 at the first decode; at
+    # the third the second and the third request cross a chunk's edge
+    assert host_n == device_n == [7, 7, 9, 9, 9]
+    # a reference engine has no work list, and says nothing
+    ref = Engine(serving_model, num_slots=2, max_seq=64, min_bucket=8,
+                 kv_layout="paged", block_size=8, kernel="reference")
+    ref._build_steps()
+    assert ref._decode_chunk_tokens is None
+
+
 def test_admit_spans_carry_the_request(engine_run):
     _eng, rows, reqs = engine_run
     kids = kids_of(rows)
@@ -379,12 +423,17 @@ def one_chip():
 
 def kernel_lines(fn, *shapes):
     """The ``tpu_custom_call`` instructions of ``fn`` compiled for the
-    described chip, printed as a profiler trace names its events: operands
-    with their shapes."""
+    described chip."""
     import jax
+
+    return custom_call_lines(jax.jit(fn).lower(*shapes).compile())
+
+
+def custom_call_lines(compiled):
+    """The ``tpu_custom_call`` instructions of a compiled program, printed
+    as a profiler trace names its events: operands with their shapes."""
     from jax._src.lib import xla_client as xc
 
-    compiled = jax.jit(fn).lower(*shapes).compile()
     opts = xc._xla.HloPrintOptions()
     opts.print_operand_shape = True
     opts.print_metadata = False
@@ -455,11 +504,16 @@ def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(
     pool = sds((blocks, bs, heads, 128), jnp.bfloat16)   # hd in whole lanes
     kc = load_patterns("paged_decode")
     (decode,) = kernel_lines(
-        lambda q, k, v, t, n: pk.paged_decode_attention_kernel(q, k, v, t, n),
+        lambda q, k, v, t, n, a: pk.paged_decode_attention_kernel(
+            q, k, v, t, n, a),
         sds((slots, 1, heads, hd), jnp.bfloat16), pool, pool,
-        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32))
+        sds((slots, mb), jnp.int32), sds((slots,), jnp.int32),
+        sds((slots,), jnp.int32))
     assert decode.startswith("%paged_decode_attention.")
     assert any(re.search(p, decode) for p in kc.PATTERNS)
+    # the table and the lengths lead: a dynamic grid bound would come first
+    assert re.match(r"%\S+ = \S+ custom-call\(s32\[32,64\]\S* %\S+ "
+                    r"s32\[32\]\S* %\S+ ", decode)
     (prefill,) = kernel_lines(
         lambda q, k, v, row, st: pk.paged_prefill_attention_kernel(
             q, k, v, row, st),
@@ -467,24 +521,24 @@ def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(
         sds((mb,), jnp.int32), sds((), jnp.int32))
     assert prefill.startswith("%paged_prefill_attention.")
     assert not any(re.search(p, prefill) for p in kc.PATTERNS)
+    # in the engine's own decode program: one match a layer, nothing else
+    _eng, compiled = engine_program(one_chip, "decode", layers=2)
+    told = [ln for ln in custom_call_lines(compiled)
+            if any(re.search(p, ln) for p in kc.PATTERNS)]
+    assert len(told) == 2
+    assert all(ln.startswith("%paged_decode_attention.") for ln in told)
 
 
 # -- the engine's programs, compiled for the chip that is described here ------
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
-        one_chip, program):
-    """GPT-2 345M's widths (16 heads x 64, vocab 50304, bf16; one layer, 32
-    slots, block 16, a 513-block pool), the paged engine's decode and bucket-32
-    prefill programs built as ``to_static`` builds them and compiled for the
-    described v5e: XLA:TPU stores a buffer whose minor dim is 64 with the
-    block dim minor-most and converts it to the Pallas kernel's row-major,
-    lane-padded operand and back in every program; the pool's per-layer
-    buffers in whole lanes are the operand, written in place."""
+def engine_program(one_chip, program, layers=1):
+    """``(engine, compiled)``: GPT-2 345M's widths (16 heads x 64, vocab
+    50304, bf16; ``layers`` layers, 32 slots, block 16, a 513-block pool),
+    the paged engine's decode or bucket-32 prefill program built as
+    ``to_static`` builds it and compiled for the described v5e, the kernels
+    as the chip runs them."""
     import jax
-    import jax.numpy as jnp
 
-    import chip_smoke
     from paddle_tpu.core.autograd import no_grad
     from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
     from paddle_tpu.models import GPTConfig, GPTForCausalLM
@@ -492,7 +546,7 @@ def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
 
     paddle.seed(0)
     model = GPTForCausalLM(GPTConfig(
-        vocab_size=50304, hidden_size=1024, num_hidden_layers=1,
+        vocab_size=50304, hidden_size=1024, num_hidden_layers=layers,
         num_attention_heads=16, max_position_embeddings=1024,
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
     model.to(dtype="bfloat16")
@@ -520,6 +574,21 @@ def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
         compiled = prog.jitted_donate.lower(
             [on_chip(t._value()) for t in leaves], [on_chip(a) for a in sd],
             [on_chip(a) for a in sk]).compile()
+    return eng, compiled
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
+        one_chip, program):
+    """The paged engine's decode and bucket-32 prefill programs at GPT-2
+    345M's widths (:func:`engine_program`, one layer): XLA:TPU stores a
+    buffer whose minor dim is 64 with the block dim minor-most and converts
+    it to the Pallas kernel's row-major, lane-padded operand and back in
+    every program; the pool's per-layer buffers in whole lanes are the
+    operand, written in place."""
+    import chip_smoke
+
+    eng, compiled = engine_program(one_chip, program)
     hlo, mem = compiled.as_text(), compiled.memory_analysis()
     pools, layer_buf = eng.cache.nbytes(), eng.cache.layer_nbytes()
     assert layer_buf == 513 * 16 * 16 * 128 * 2

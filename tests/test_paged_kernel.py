@@ -64,6 +64,15 @@ def _ref_prefill(q, kp, vp, row, start):
     return np.asarray(out.numpy())
 
 
+def _ones(n):
+    return jnp.ones((n,), jnp.int32)
+
+
+#: window ends at both edges of a 16-token block and of a 256-token chunk,
+#: and at the row's last position
+RAGGED_LENGTHS = (0, 15, 16, 255, 256, 1023)
+
+
 class TestDecodeKernelParity:
     @pytest.mark.parametrize("hkv,h", [(4, 4), (2, 4)])  # MHA and GQA
     def test_matches_reference(self, hkv, h):
@@ -73,7 +82,7 @@ class TestDecodeKernelParity:
         tbl = jnp.asarray(rs.randint(1, NB, (B, MB)), jnp.int32)
         lens = jnp.asarray([0, 7, 18, 31], jnp.int32)
         q = jnp.asarray(rs.randn(B, 1, h, D), jnp.float32)
-        out = paged_decode_attention_kernel(q, kp, vp, tbl, lens,
+        out = paged_decode_attention_kernel(q, kp, vp, tbl, lens, _ones(B),
                                             interpret=True)
         ref = _ref_decode(q, kp, vp, tbl, lens)
         np.testing.assert_allclose(np.asarray(out), ref,
@@ -91,12 +100,52 @@ class TestDecodeKernelParity:
         lens = jnp.asarray([0, 7, 18, 31], jnp.int32)
         q = jnp.asarray(rs.randn(B, 1, h, D), jnp.float32)
         out = paged_decode_attention_kernel(
-            q, _to_lanes(kp, 128), _to_lanes(vp, 128), tbl, lens,
+            q, _to_lanes(kp, 128), _to_lanes(vp, 128), tbl, lens, _ones(B),
             interpret=True)
         assert out.shape == q.shape
         np.testing.assert_allclose(
             np.asarray(out), _ref_decode(q, kp, vp, tbl, lens),
             rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("active", [(1, 1, 1, 1, 1, 1),
+                                        (1, 0, 1, 0, 1, 1),
+                                        (0, 0, 0, 1, 0, 0)],
+                             ids=["all", "some", "one"])
+    @pytest.mark.parametrize("hkv,h,d", [(4, 4, 64), (2, 8, 128)],
+                             ids=["mha64in128", "gqa128"])
+    def test_ragged_lengths_idle_slots_and_garbage_blocks(self, hkv, h, d,
+                                                          active):
+        """Six slots of 64 blocks of 16 (four chunks of 256 a row), idle
+        slots between running ones.  The kernel sees a pool in which every
+        block an idle slot's row names, every block of a running slot's row
+        past its window and the tail of its last live block are NaN; the
+        oracle sees the clean pool.  Active rows agree, the others are
+        exactly zero, and no NaN comes out."""
+        rs = np.random.RandomState(5)
+        BS, MB, B, lanes = 16, 64, len(active), 128
+        NB = B * MB + 1
+        kp, vp = _rand_pool(rs, NB, BS, hkv, d)
+        tbl = rs.permutation(NB - 1)[:B * MB].reshape(B, MB) + 1
+        q = jnp.asarray(rs.randn(B, 1, h, d), jnp.float32)
+        lens = jnp.asarray(RAGGED_LENGTHS, jnp.int32)
+        bad = np.zeros((NB, BS), bool)
+        for b, (ln, a) in enumerate(zip(RAGGED_LENGTHS, active)):
+            first = ln // BS + 1 if a else 0     # first block wholly unseen
+            bad[tbl[b, first:]] = True
+            if a:
+                bad[tbl[b, ln // BS], ln % BS + 1:] = True
+        poison = jnp.asarray(np.where(bad, np.nan, 0.0)[:, :, None, None],
+                             jnp.float32)
+        tbl = jnp.asarray(tbl, jnp.int32)
+        act = jnp.asarray(active, jnp.int32)
+        out = np.asarray(paged_decode_attention_kernel(
+            q, _to_lanes(kp, lanes) + poison, _to_lanes(vp, lanes) + poison,
+            tbl, lens, act, interpret=True))
+        assert out.shape == q.shape
+        ref = _ref_decode(q, kp, vp, tbl, lens)
+        on = np.asarray(active, bool)
+        np.testing.assert_allclose(out[on], ref[on], rtol=1e-5, atol=1e-5)
+        assert not out[~on].any()                # exactly zero, never NaN
 
     def test_positions_past_length_are_invisible(self):
         """Scribbling over pool positions beyond a slot's window must not
@@ -108,7 +157,7 @@ class TestDecodeKernelParity:
         tbl = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)  # distinct
         lens = jnp.asarray([4, 11], jnp.int32)
         q = jnp.asarray(rs.randn(B, 1, 4, D), jnp.float32)
-        out = paged_decode_attention_kernel(q, kp, vp, tbl, lens,
+        out = paged_decode_attention_kernel(q, kp, vp, tbl, lens, _ones(B),
                                             interpret=True)
         # slot 0's window is 0..4 inside its first block: poison the
         # rest of that block and every later block it references
@@ -119,7 +168,7 @@ class TestDecodeKernelParity:
             kp2 = kp2.at[int(tbl[0, j])].set(999.0)
             vp2 = vp2.at[int(tbl[0, j])].set(-999.0)
         out2 = paged_decode_attention_kernel(q, kp2, vp2, tbl, lens,
-                                             interpret=True)
+                                             _ones(B), interpret=True)
         np.testing.assert_array_equal(np.asarray(out[0]),
                                       np.asarray(out2[0]))
 
@@ -130,11 +179,44 @@ class TestDecodeKernelParity:
         tbl = jnp.asarray([[1, 2]], jnp.int32)
         q = jnp.asarray(rs.randn(1, 1, 2, D), jnp.float32)
         out = paged_decode_attention_kernel(
-            q, kp, vp, tbl, jnp.asarray([0], jnp.int32), interpret=True)
+            q, kp, vp, tbl, jnp.asarray([0], jnp.int32), _ones(1),
+            interpret=True)
         # softmax over exactly one valid position == that position's V
         np.testing.assert_allclose(np.asarray(out[0, 0]),
                                    np.asarray(vp[1, 0]),
                                    rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("active", [(1, 1, 1, 1, 1, 1),
+                                        (1, 0, 1, 0, 1, 1),
+                                        (0, 0, 0, 0, 0, 0)],
+                             ids=["all", "some", "none"])
+    def test_work_list_of_the_kv_shapes(self, active):
+        """``n`` is the sum over active slots of ``lengths // chunk + 1``,
+        the pairs come slot-major with a slot's chunks in order, and the
+        chunk is what the shapes allow: 256 tokens at GPT-2 345M's pool
+        (16 heads in 128 bf16 lanes), fewer where a token is wider, never
+        more than a slot's row."""
+        from paddle_tpu.ops.pallas.mla_attention_kernel import \
+            decode_work_list
+        from paddle_tpu.ops.pallas.paged_attention_kernel import \
+            decode_chunk_tokens
+
+        ct = decode_chunk_tokens(16, 64, 16, 128, 2)
+        assert ct == 256
+        assert decode_chunk_tokens(16, 64, 8, 128, 2) == 256
+        assert decode_chunk_tokens(16, 512, 32, 128, 2) == 144
+        assert decode_chunk_tokens(8, 4, 4, 128, 4) == 32
+        assert decode_chunk_tokens(512, 4, 2, 128, 2) == 512  # one block
+        lens = jnp.asarray(RAGGED_LENGTHS, jnp.int32)
+        slot, chunk, n = decode_work_list(
+            lens, jnp.asarray(active, jnp.int32), ct, 1024 // ct)
+        per = [ln // ct + 1 if a else 0
+               for ln, a in zip(RAGGED_LENGTHS, active)]
+        assert int(n) == sum(per)
+        assert list(np.asarray(slot[:int(n)])) == \
+            [b for b, k in enumerate(per) for _ in range(k)]
+        assert list(np.asarray(chunk[:int(n)])) == \
+            [c for k in per for c in range(k)]
 
 
 class TestPrefillKernelParity:
