@@ -187,7 +187,7 @@ def _trace_replay(model):
         # priority-vs-baseline TTFT comparison)
         tracer = RequestTracer() if priorities_on else NULL_TRACER
         eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                     kv_layout="paged", block_size=8, tracer=tracer)
+                     block_size=8, tracer=tracer)
         eng.warmup()
         t0 = _time.perf_counter()
         handles = []
@@ -308,7 +308,7 @@ def _paged_kernel_microbench(model):
     tps, outs = {}, {}
     for kern in ("pallas", "reference"):
         eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                     kv_layout="paged", block_size=8, kernel=kern)
+                     block_size=8, kernel=kern)
         eng.warmup()
         eng.generate(prompts, max_new_tokens=4)     # prime steady state
         reqs = [eng.add_request(p, max_new_tokens=24) for p in prompts]
@@ -369,7 +369,7 @@ def _spec_decode_drill(model):
         kw = {} if mode == "nospec" else dict(
             speculation=SpecConfig(draft_model=draft, k=4))
         eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                     kv_layout="paged", block_size=8, **kw)
+                     block_size=8, **kw)
         eng.warmup()
         eng.generate(prompts, max_new_tokens=4)     # prime steady state
         m0 = eng.metrics.compile_misses
@@ -426,8 +426,7 @@ def _multi_tenant_drill(model):
     FAIL_METRIC = "serving_gpt_tiny_decode_tokens_per_sec"
     spec = JsonArrayGrammar(eos_token_id=1, max_elems=3, max_digits=2)
     eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                 kv_layout="paged", block_size=8,
-                 adapters=dict(max_adapters=2, rank=4),
+                 block_size=8, adapters=dict(max_adapters=2, rank=4),
                  grammars={"json": spec})
     eng.warmup()
     pool = eng.adapter_pool
@@ -567,7 +566,7 @@ def _durability_drill(model):
     with tempfile.TemporaryDirectory() as td:
         jdir = os.path.join(td, "journal")
         eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                     journal=RequestJournal(jdir))
+                     block_size=8, journal=RequestJournal(jdir))
         eng.warmup()
         rs = np.random.RandomState(123)
         prompts = [rs.randint(0, 128, (L,)).tolist()
@@ -582,7 +581,7 @@ def _durability_drill(model):
             fail_structured("durability drill: nothing was in flight "
                             "at the crash point", metric=FAIL_METRIC)
         eng2 = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                      journal=j2)
+                      block_size=8, journal=j2)
         eng2.warmup()
         misses0 = eng2.metrics.compile_misses
         t0 = _time.perf_counter()
@@ -633,7 +632,7 @@ def _hot_swap_drill(model):
     paddle.seed(31)
     new_sd = GPTForCausalLM(gpt_tiny()).state_dict()
     fleet = Fleet(model, num_replicas=2, num_slots=2, max_seq=64,
-                  min_bucket=8, kv_layout="paged", block_size=8)
+                  min_bucket=8, block_size=8)
     fleet.warmup()
     if not fleet.weights_isolated:
         fail_structured("hot-swap drill: fleet fell back to shared "
@@ -717,8 +716,7 @@ def _sharded_serving_drill_child():
     rs = np.random.RandomState(0)
     lengths = [5, 13, 21, 34, 9, 17, 48, 3, 27, 11, 40, 6]
     prompts = [rs.randint(0, 128, (L,)).tolist() for L in lengths]
-    kw = dict(num_slots=4, max_seq=64, min_bucket=8,
-              kv_layout="paged", block_size=8)
+    kw = dict(num_slots=4, max_seq=64, min_bucket=8, block_size=8)
 
     base = Engine(build(), **kw)
     base.warmup()
@@ -816,7 +814,8 @@ def _degraded_serving_serve_child():
     m = GPTForCausalLM(gpt_tiny())
     m.eval()
     eng = Engine(m, mesh=serving_mesh(2), num_slots=2, max_seq=32,
-                 min_bucket=8, journal=RequestJournal(sys.argv[-1]))
+                 min_bucket=8, block_size=8,
+                 journal=RequestJournal(sys.argv[-1]))
     eng.warmup()
     rs = np.random.RandomState(5)
     prompts = [rs.randint(0, 128, (L,)).tolist() for L in (6, 11, 14)]
@@ -868,7 +867,7 @@ def _degraded_serving_recover_child():
     new_mp = degrade_step(4, 4, len(survivors))
     t0 = time.perf_counter()
     eng = Engine(m, mesh=serving_mesh(new_mp, devices=survivors),
-                 num_slots=2, max_seq=32, min_bucket=8)
+                 num_slots=2, max_seq=32, min_bucket=8, block_size=8)
     eng.warmup()
     rebuild_s = time.perf_counter() - t0
 
@@ -998,11 +997,10 @@ def serving_main():
     no external baseline for this metric yet; the absolute fields
     (``value``, ``ttft_ms``) are the tracked quantities.
 
-    A shared-prefix workload variant (ISSUE 5) then runs the SAME
-    prompts through the warm contiguous engine and through a paged
-    engine with prefix reuse, emitting ``serving_prefix_hit_rate``,
-    ``serving_kv_blocks_in_use``, and paged vs contiguous ``ttft_ms``
-    side by side; greedy outputs from the two layouts must agree.
+    A shared-prefix workload variant (ISSUE 5) then runs through the
+    same warm engine with prefix reuse, emitting
+    ``serving_prefix_hit_rate``, ``serving_kv_blocks_in_use`` and
+    ``ttft_ms_paged``; greedy outputs must equal the no-cache recompute.
 
     A fleet failover smoke (ISSUE 6) then serves a batch through a
     2-replica :class:`Fleet` while a replica-scoped fault plan kills
@@ -1030,7 +1028,8 @@ def serving_main():
 
     paddle.seed(0)
     model = GPTForCausalLM(gpt_tiny())
-    eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8)
+    eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
+                 block_size=8)
     # sync-point sanitizer on the measured engine: counts every
     # framework-level d2h transfer per decode step — the host-sync
     # baseline ROADMAP item 2 (on-device sampling / Pallas decode
@@ -1047,34 +1046,38 @@ def serving_main():
             f"steady-state recompile detected: {st['compile_cache']}",
             metric="serving_gpt_tiny_decode_tokens_per_sec")
 
-    # -- shared-prefix workload: paged vs contiguous, side by side -------
+    # -- shared-prefix workload, on the same warm engine ------------------
     shared = rs.randint(0, 128, (16,)).tolist()     # 2 blocks of 8
     tails = [rs.randint(0, 128, (t,)).tolist()
              for t in (5, 9, 3, 12, 7, 2, 10, 6)]
     sp_prompts = [shared + t for t in tails]
-    c_reqs = [eng.add_request(p, max_new_tokens=8) for p in sp_prompts]
-    eng.run()
-    p_eng = Engine(model, num_slots=4, max_seq=64, min_bucket=8,
-                   kv_layout="paged", block_size=8)
-    p_eng.warmup()
     # prime one pass so the measured pass is steady state with a
-    # populated prefix cache — the same position the contiguous engine
-    # is measured in (its shared-prefix batch follows the base workload)
-    p_eng.generate(sp_prompts, max_new_tokens=8)
-    p_reqs = [p_eng.add_request(p, max_new_tokens=8) for p in sp_prompts]
+    # populated prefix cache
+    eng.generate(sp_prompts, max_new_tokens=8)
+    p_reqs = [eng.add_request(p, max_new_tokens=8) for p in sp_prompts]
     blocks_in_use_peak = 0
-    while p_eng.step():
+    while eng.step():
         blocks_in_use_peak = max(
-            blocks_in_use_peak, p_eng._paging_snapshot()["blocks_in_use"])
-    pst = p_eng.stats()
-    if pst["compile_cache"]["misses"] != len(p_eng.buckets) + 1:
+            blocks_in_use_peak, eng._paging_snapshot()["blocks_in_use"])
+    pst = eng.stats()
+    if pst["compile_cache"]["misses"] != len(eng.buckets) + 1:
         fail_structured(
             f"paged steady-state recompile detected: "
             f"{pst['compile_cache']}",
             metric="serving_gpt_tiny_decode_tokens_per_sec")
-    if [r.output_ids for r in p_reqs] != [r.output_ids for r in c_reqs]:
+
+    def _no_cache_greedy(prompt, out_ids):
+        # ONE full-recompute forward of prompt + outputs: causal
+        # attention makes its logits those of a token-by-token loop
+        seq = np.asarray(prompt + out_ids[:-1], np.int64)[None]
+        with paddle.no_grad():
+            logits = model(paddle.to_tensor(seq)).numpy()[0]
+        return logits[len(prompt) - 1:].argmax(-1).tolist()
+
+    if any(r.output_ids != _no_cache_greedy(p, r.output_ids)
+           for p, r in zip(sp_prompts, p_reqs)):
         fail_structured(
-            "paged greedy outputs diverge from the contiguous layout",
+            "paged greedy outputs diverge from the no-cache recompute",
             metric="serving_gpt_tiny_decode_tokens_per_sec")
     if any(not r.finished for r in p_reqs) or \
             pst["health"]["kv_block_invariants"] != "ok":
@@ -1085,9 +1088,8 @@ def serving_main():
     # -- fleet failover smoke: kill 1 of 2 replicas mid-decode -----------
     plan = ServingFaultPlan().add("serving.r1.decode", at_call=2, times=2)
     fleet = Fleet(model, num_replicas=2, num_slots=2, max_seq=64,
-                  min_bucket=8, kv_layout="paged", block_size=8,
-                  eject_after_failures=2, max_redispatch=2,
-                  fault_plan=plan)
+                  min_bucket=8, block_size=8, eject_after_failures=2,
+                  max_redispatch=2, fault_plan=plan)
     fleet.warmup()
     f_prompts = [rs.randint(0, 128, (L,)).tolist()
                  for L in (5, 11, 7, 16, 4, 9)]
@@ -1189,13 +1191,12 @@ def serving_main():
         # win needs a distilled draft + hardware)
         **spec_bench,
         # paged KV + prefix reuse (ISSUE 5): the shared-prefix workload
-        # through both layouts — hit rate must be > 0, and the paged
-        # TTFT reflects prefilling only the uncached tail bucket
+        # — hit rate must be > 0, and the TTFT reflects prefilling only
+        # the uncached tail bucket
         "serving_prefix_hit_rate": pst["paging"]["prefix"]["hit_rate"],
         "serving_kv_blocks_in_use": blocks_in_use_peak,
         "serving_kv_blocks_total": pst["paging"]["blocks"]["total"],
         "ttft_ms_paged": _p50_ttft_ms(p_reqs),
-        "ttft_ms_contiguous": _p50_ttft_ms(c_reqs),
         "paged_copy_on_extends": pst["paging"]["copy_on_extends"],
         "paged_engine_state": pst["health"]["state"],
         # fleet failover smoke (ISSUE 6): aggregate throughput measured

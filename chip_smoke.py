@@ -11,8 +11,8 @@ seeded random weights:
   fleet.init -> distributed_model -> AdamW -> AMP-O2 -> ``@to_static`` step
   with bf16 autocast and the streamed fused CE), batch 8 x 1024 per chip,
   a few steps on one repeated batch;
-- **serve**: ``inference.create_engine("gpt:gpt2-345m", kv_layout="paged",
-  ...)`` with the default ``kernel="auto"`` -> ``warmup()`` -> requests of
+- **serve**: ``inference.create_engine("gpt:gpt2-345m", ...)`` with the
+  default ``kernel="pallas"`` -> ``warmup()`` -> requests of
   mixed prompt lengths, greedy and sampled, two sharing a prefix ->
   ``run()``; then the same greedy requests through a second engine over
   the same model with ``kernel="reference"`` (the jnp gather oracle);
@@ -242,8 +242,8 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
                 max_new_tokens: int, model_parallel: int = None,
                 expect_tokens: list = None, name: str = None,
                 pallas_calls: int = None, exact: bool = True) -> dict:
-    """Serve through ``inference.create_engine(model, kv_layout="paged",
-    ...)`` with the default ``kernel="auto"``: ``warmup()``, one request
+    """Serve through ``inference.create_engine(model, ...)`` with the
+    default ``kernel="pallas"``: ``warmup()``, one request
     per length in ``prompt_lens`` plus two sharing a ``shared_len`` prefix
     (greedy, except the last two of ``prompt_lens`` which sample), then
     ``run()``.
@@ -272,8 +272,8 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
 
     name = name or ("serve" if not model_parallel else "serve-sharded")
     c = Checks(name)
-    kw = dict(kv_layout="paged", block_size=block_size,
-              min_bucket=min_bucket, max_seq=max_seq, num_slots=num_slots)
+    kw = dict(block_size=block_size, min_bucket=min_bucket, max_seq=max_seq,
+              num_slots=num_slots)
     if model_parallel:
         from paddle_tpu.serving.sharding import serving_mesh
 
@@ -331,7 +331,7 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
     kernel = st["paging"]["kernel"]
     interpret = eng.cache._interpret
     path = f"paged {kernel}" + (" (interpret)" if interpret else "")
-    c.check("kernel=auto chose the Pallas paged kernels",
+    c.check("the default engine runs the Pallas paged kernels",
             kernel == "pallas", kernel)
     c.check("paged kernels compiled for the device iff it is a TPU",
             interpret is (not _on_tpu()), f"_interpret={interpret}")
@@ -543,7 +543,7 @@ def main() -> int:
     base = run_phase(results, train_phase, cfg, **train,
                      title="train GPT-2 345M, 8 x 1024 per chip")
     one = run_phase(results, serve_phase, "gpt:gpt2-345m", **serve,
-                    title="serve GPT-2 345M, paged, kernel=auto")
+                    title="serve GPT-2 345M, the default engine")
     run_phase(results, serve_phase, latent_model(), name="serve-latent",
               max_seq=2048, num_slots=32, block_size=16, min_bucket=256,
               prompt_lens=(200, 300, 700, 2000, 400, 260), shared_len=512,

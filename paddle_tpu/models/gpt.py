@@ -131,15 +131,16 @@ class GPTAttention(Layer):
                 training=self.training)
         elif cache_ctx.mode == "prefill":
             # prompt forward writes K/V into the cache; attention routes
-            # through the context — ordinary causal for the contiguous
-            # layout, gather-by-block-table with a cached-prefix mask for
-            # the paged layout (the tail bucket attends onto shared blocks)
+            # through the context — gather-by-block-table with a
+            # cached-prefix mask over the engine's pool (the tail bucket
+            # attends onto shared blocks), ordinary causal for the
+            # speculative draft's dense cache
             cache_ctx.write_prefill(k, v)
             ctx = cache_ctx.prefill_attention(q, k, v)
         else:               # decode (S == 1) or verify (S == k+1) window
-            # write + attend routed through the context: the paged cache
-            # may stream blocks through the Pallas flash-decoding kernel
-            # instead of gathering a contiguous copy (ROADMAP item 2);
+            # write + attend routed through the context: the pool
+            # streams blocks through the Pallas flash-decoding kernel
+            # instead of gathering a copy of each slot's sequence;
             # verify mode routes the same call to the cache's W-token
             # speculative window attention — models stay single-path
             ctx = cache_ctx.decode_attention(q, k, v)
@@ -229,8 +230,8 @@ class GPTModel(Layer):
                 # window's k+1 tokens likewise ([slots, k+1])
                 position_ids = cache_ctx.positions()
             else:
-                # paged tail prefill: tokens sit past the cached prefix
-                # (None for the contiguous layout — default 0..S-1)
+                # tail prefill: tokens sit past the cached prefix
+                # (None from the draft's dense cache — default 0..S-1)
                 position_ids = cache_ctx.prefill_positions(
                     input_ids.shape[-1])
         h = self.embeddings(input_ids, position_ids)

@@ -1,10 +1,13 @@
-"""Decode-step attention against a slot-paged KV cache.
+"""Decode-step attention against the serving caches.
 
 Serving counterpart of ``ops.pallas.flash_attention``: during continuous-
 batching decode every sequence contributes exactly ONE query token, and the
-keys/values live in a preallocated fixed-shape cache (``serving.KVCache``),
-so the kernel is a masked single-row attention over ``[B, T, Hkv, D]``
-where T is the cache capacity.  Static shapes are the point: the same
+keys/values live in a preallocated fixed-shape cache, so the dense read
+(:func:`cached_attention` — what the speculative draft's
+``serving.KVCache`` uses, and the oracle of the paged reads after
+:func:`gather_block_kv`) is a masked single-row attention over
+``[B, T, Hkv, D]`` where T is the cache capacity; the ``paged_*`` reads
+consume the engine pool's block table inside a Pallas kernel.  Static shapes are the point: the same
 compiled executable serves every step of every request (XLA recompiles on
 any new shape — FlashFuser-style fused decode attention assumes exactly
 this fixed-layout cache).
@@ -13,9 +16,8 @@ GQA is handled inside the kernel: ``Hkv`` may divide ``H`` and kv heads are
 repeated consecutively (kv head ``h // (H // Hkv)`` serves query head
 ``h``), matching the models' no-cache expand path bit-for-bit.
 
-The XLA formulation below is the oracle/CPU path; on TPU it is already a
-single fused masked-softmax-matmul under jit, and the layout is chosen so a
-Pallas kernel can slot in behind the same signature later.
+The XLA formulations below are the oracle path; on TPU each is already a
+single fused masked-softmax-matmul under jit.
 """
 from __future__ import annotations
 

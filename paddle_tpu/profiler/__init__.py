@@ -79,10 +79,9 @@ def serving_health() -> dict:
 
 
 def serving_paging() -> dict:
-    """Paged-KV observability across every live paged engine, keyed by
-    engine name: block-pool occupancy (free/used/cached), eviction and
-    copy-on-extend counters, and prefix-cache hit rates.  Engines running
-    the contiguous layout are omitted."""
+    """Paged-KV observability across every live engine, keyed by engine
+    name: block-pool occupancy (free/used/cached), eviction and
+    copy-on-extend counters, and prefix-cache hit rates."""
     out = {}
     for m in _live_serving_metrics():
         p = m._paging_section()

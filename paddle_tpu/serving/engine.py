@@ -23,8 +23,8 @@ device dispatch with ZERO blocking host transfers.  Sampling runs inside
 the compiled step (``serving.sampling.DeviceSampler``: per-slot
 temperature/top-k/top-p lanes and ``jax.random`` key state lifted like KV
 cache state), the sampled token ids feed the next step's inputs
-device-side through the sampler's token lane, and in paged mode the
-attention itself consumes the block table inside a Pallas flash-decoding
+device-side through the sampler's token lane, and the attention itself
+consumes the paged pool's block table inside a Pallas flash-decoding
 kernel (``kernel="pallas"``, the default; ``"reference"`` keeps the jnp
 gather oracle).  The host touches only the tiny ``[slots] int32`` token
 array — for stream delivery and stop checks, pulled AFTER the sanitizer's
@@ -50,8 +50,8 @@ pressure is a first-class regime, not a failure mode.  Requests carry a
 **priority class** (``PRIORITY_LOW|NORMAL|HIGH`` or any int); the queue
 is served highest-effective-priority first with **deferral aging**
 (``priority_aging_s`` — a waiting request's effective priority rises over
-time, so low-priority work is never starved).  When no slot — or, in
-paged mode, no KV block — can serve a higher-priority admission, the
+time, so low-priority work is never starved).  When no slot — or no
+KV block — can serve a higher-priority admission, the
 scheduler **preempts** the lowest-priority running victim: its prompt
 blocks are registered in the prefix cache *before* its slot releases
 (resume becomes a cheap prefix hit), and it requeues replay-from-prompt
@@ -82,8 +82,10 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor, to_tensor
 from ..obs import spans as _spans
-from .kv_cache import KVCache, CacheContext, cache_spec_of
+from .kv_cache import cache_spec_of
 from .metrics import ServingMetrics
+from .paging import PagedCacheContext, PagedKVCache
+from .prefix_cache import PrefixCache
 from .sampling import DeviceSampler, SamplingParams
 from .sanitize import SyncSanitizer
 from .tracing import NULL_TRACER, FlightRecorder, RequestTracer
@@ -289,8 +291,8 @@ class Engine:
         num_slots: fixed decode batch width.
         max_seq: per-slot cache capacity (prompt + generated); defaults to
             the model's ``max_position_embeddings``.
-        min_bucket: smallest prefill bucket; buckets are powers of two up
-            to ``max_seq``.
+        min_bucket: smallest prefill bucket (default: one default
+            ``block_size``); buckets are powers of two up to ``max_seq``.
         cache_dtype: KV cache dtype (default: the model's param dtype).
         max_queue: bound on queued (not-yet-admitted) requests; ``None``
             (default) is unbounded.
@@ -312,25 +314,27 @@ class Engine:
             ``health()``) instead of wedging silently.
         fault_plan: a ``ServingFaultPlan`` for chaos testing; defaults to
             the env-armed plan (``PADDLE_TPU_FT_SERVING_FAULTS``).
-        kv_layout: ``"contiguous"`` (default — one ``max_seq`` stripe per
-            slot) or ``"paged"`` (block-pool KV storage addressed through
-            per-slot block tables, with refcounted cross-request prefix
-            reuse — see docs/SERVING.md "Paged KV cache").
-        kernel: paged attention path — ``"auto"`` (default: the Pallas
-            flash-decoding/fused-prefill kernels that consume the block
-            table in-kernel; interpret mode off-TPU so CPU runs the same
-            code path), ``"pallas"`` to force them, or ``"reference"``
-            for the jnp gather + masked-softmax oracle.  Ignored by the
-            contiguous layout.  Selection never changes a compiled
-            shape — see docs/SERVING.md "Decode hot path".
-        block_size: tokens per KV block in paged mode; must divide
-            ``min_bucket`` (and therefore every prefill bucket).
-        num_kv_blocks: paged pool size; default
-            ``num_slots * max_seq / block_size + 1`` (contiguous-parity
-            capacity plus the reserved scratch block).
-        enable_prefix_cache: paged mode only — hash whole prompt blocks
-            host-side and serve repeated prefixes from refcounted shared
-            blocks, shrinking the prefill to the uncached tail bucket.
+        kv_layout: no choice: the cache is always the paged pool
+            (block-pool KV storage addressed through per-slot block
+            tables, with refcounted cross-request prefix reuse — see
+            docs/SERVING.md "Paged KV cache").  The keyword survives
+            for callers that still pass ``"paged"``; any other value
+            raises.
+        kernel: attention path over the pool — ``"pallas"`` (default:
+            the flash-decoding/fused-prefill kernels that consume the
+            block table in-kernel; interpret mode off-TPU so CPU runs
+            the same code path) or ``"reference"``, the jnp gather +
+            masked-softmax oracle the kernels are tested against.
+            Selection never changes a compiled shape — see
+            docs/SERVING.md "Decode hot path".
+        block_size: tokens per KV block; must divide ``min_bucket``
+            (and therefore every prefill bucket) and ``max_seq``.
+        num_kv_blocks: pool size; default
+            ``num_slots * max_seq / block_size + 1`` (every slot at
+            ``max_seq`` plus the reserved scratch block).
+        enable_prefix_cache: hash whole prompt blocks host-side and
+            serve repeated prefixes from refcounted shared blocks,
+            shrinking the prefill to the uncached tail bucket.
         prefix_lookup_timeout_s: classifier for a degraded prefix cache:
             a lookup that took longer than this (the lookup is
             synchronous, so the time is already spent) is treated as a
@@ -396,7 +400,7 @@ class Engine:
     """
 
     def __init__(self, model, *, num_slots: int = 4,
-                 max_seq: Optional[int] = None, min_bucket: int = 8,
+                 max_seq: Optional[int] = None, min_bucket: int = 16,
                  cache_dtype=None, name: Optional[str] = None,
                  max_queue: Optional[int] = None,
                  queue_policy: str = "reject",
@@ -406,8 +410,8 @@ class Engine:
                  retry_backoff_s: float = 0.05,
                  step_timeout_s: Optional[float] = None,
                  fault_plan=None,
-                 kv_layout: str = "contiguous",
-                 kernel: str = "auto",
+                 kv_layout: str = "paged",
+                 kernel: str = "pallas",
                  block_size: int = 16,
                  num_kv_blocks: Optional[int] = None,
                  enable_prefix_cache: bool = True,
@@ -465,18 +469,14 @@ class Engine:
         if cache_dtype is None:
             params = model.parameters()
             cache_dtype = params[0].dtype if params else "float32"
-        if kv_layout not in ("contiguous", "paged"):
-            raise ValueError(f"kv_layout must be 'contiguous' or 'paged', "
-                             f"got {kv_layout!r}")
-        if kernel not in ("auto", "pallas", "reference"):
-            raise ValueError(f"kernel must be 'auto', 'pallas' or "
-                             f"'reference', got {kernel!r}")
+        if kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout={kv_layout!r}: the contiguous layout was "
+                f"removed and the cache is always paged (drop the argument)")
         if spec.kind == "latent":
-            # one vector a token has no per-KV-head axis: nothing to lay
-            # out contiguously per head, to shard by head, or to verify
+            # one vector a token has no per-KV-head axis: nothing to
+            # shard by head or to verify
             refused = [what for what, asked in (
-                (f"kv_layout={kv_layout!r} (it runs with kv_layout='paged' "
-                 "only)", kv_layout != "paged"),
                 ("a serving mesh of more than one device (the latent pool "
                  "has no kv_heads axis to shard)",
                  mesh is not None and mesh.size > 1),
@@ -486,46 +486,30 @@ class Engine:
                 raise ValueError(
                     f"{type(model).__name__} caches one latent vector a "
                     f"token and cannot serve with " + "; ".join(refused))
-        self.kv_layout = kv_layout
-        # the Pallas paged kernels are the default paged path (interpret
-        # mode off-TPU keeps CPU tier-1 on the same code); contiguous
-        # has only the jnp oracle
-        self.kernel = ("reference" if kv_layout == "contiguous"
-                       else ("pallas" if kernel == "auto" else kernel))
+        self.kernel = kernel
         self.block_size = int(block_size)
-        self.prefix_cache = None
         self.prefix_lookup_timeout_s = float(prefix_lookup_timeout_s)
-        if kv_layout == "paged":
-            from .paging import PagedKVCache
-            from .prefix_cache import PrefixCache
-
-            if self.min_bucket % self.block_size != 0:
-                raise ValueError(
-                    f"block_size {self.block_size} must divide "
-                    f"min_bucket {self.min_bucket} (so every prefill "
-                    f"bucket is whole blocks)")
-            if self.max_seq % self.block_size != 0:
-                raise ValueError(
-                    f"block_size {self.block_size} must divide "
-                    f"max_seq {self.max_seq}")
-            self.cache = PagedKVCache(
-                num_slots=self.num_slots, num_layers=spec.num_layers,
-                max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
-                block_size=self.block_size, num_blocks=num_kv_blocks,
-                kernel=self.kernel)
-            if enable_prefix_cache:
-                self.prefix_cache = PrefixCache(self.cache.allocator,
-                                                self.block_size)
-        else:
-            self.cache = KVCache(
-                num_slots=self.num_slots, num_layers=spec.num_layers,
-                max_seq=self.max_seq, num_kv_heads=kv_heads,
-                head_dim=spec.sides[0][1], dtype=cache_dtype)
+        if self.min_bucket % self.block_size != 0:
+            raise ValueError(
+                f"block_size {self.block_size} must divide "
+                f"min_bucket {self.min_bucket} (so every prefill "
+                f"bucket is whole blocks)")
+        if self.max_seq % self.block_size != 0:
+            raise ValueError(
+                f"block_size {self.block_size} must divide "
+                f"max_seq {self.max_seq}")
+        self.cache = PagedKVCache(
+            num_slots=self.num_slots, num_layers=spec.num_layers,
+            max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
+            block_size=self.block_size, num_blocks=num_kv_blocks,
+            kernel=self.kernel)
+        self.prefix_cache = (
+            PrefixCache(self.cache.allocator, self.block_size)
+            if enable_prefix_cache else None)
         self.name = name or f"engine-{next(_engine_counter)}"
         self.metrics = ServingMetrics(self.name, num_slots=self.num_slots)
         self.metrics.health_cb = self.health
-        if self.kv_layout == "paged":
-            self.metrics.paging_cb = self._paging_snapshot
+        self.metrics.paging_cb = self._paging_snapshot
         self.queue: deque = deque()
         self.running: Dict[int, Request] = {}
         self.free_slots: List[int] = list(range(self.num_slots))
@@ -686,7 +670,7 @@ class Engine:
 
         model, cache, sampler = self.model, self.cache, self.sampler
         pool = self.adapter_pool
-        if self.kv_layout == "paged" and self.spec is None:
+        if self.spec is None:
             self._decode_chunk_tokens = cache.decode_chunk_tokens()
 
         def _prefill_rows(slot):
@@ -696,60 +680,37 @@ class Engine:
                 pool.adapter_ids._value(),
                 slot._value().astype(jnp.int32), axis=0, keepdims=True)
 
-        if self.kv_layout == "paged":
-            from .paging import PagedCacheContext
-
-            def prefill_step(input_ids, slot, length, start):
-                # tail-bucket prefill: tokens are the UNCACHED tail of the
-                # prompt, sitting at absolute positions start..; the last
-                # real token is at tail index (length - start - 1)
-                ctx = PagedCacheContext(cache, "prefill", slot=slot,
-                                        length=length, start=start)
+        def prefill_step(input_ids, slot, length, start):
+            # tail-bucket prefill: tokens are the UNCACHED tail of the
+            # prompt, sitting at absolute positions start..; the last
+            # real token is at tail index (length - start - 1)
+            ctx = PagedCacheContext(cache, "prefill", slot=slot,
+                                    length=length, start=start)
+            if pool is not None:
+                pool.set_rows(_prefill_rows(slot))
+            try:
+                logits = model(input_ids, cache_ctx=ctx)
+            finally:
                 if pool is not None:
-                    pool.set_rows(_prefill_rows(slot))
-                try:
-                    logits = model(input_ids, cache_ctx=ctx)
-                finally:
-                    if pool is not None:
-                        pool.clear_rows()
-                cache.set_length(slot, length)
-                arr = logits._value()                   # [1, S, V]
-                last = ctx.last_logits(
-                    arr, (length._value() - start._value()).astype(
-                        jnp.int32) - 1)
-                # first token sampled on-device from the slot's staged
-                # lanes; key + token lanes update in-program
-                tok = sampler.sample_slot(slot._value(),
-                                          last.astype(jnp.float32))
-                return Tensor._wrap(tok)
-        else:
-            def prefill_step(input_ids, slot, length):
-                ctx = CacheContext(cache, "prefill", slot=slot,
-                                   length=length)
-                if pool is not None:
-                    pool.set_rows(_prefill_rows(slot))
-                try:
-                    logits = model(input_ids, cache_ctx=ctx)
-                finally:
-                    if pool is not None:
-                        pool.clear_rows()
-                cache.set_length(slot, length)
-                arr = logits._value()                   # [1, S, V]
-                last = ctx.last_logits(
-                    arr, length._value().astype(jnp.int32) - 1)
-                tok = sampler.sample_slot(slot._value(),
-                                          last.astype(jnp.float32))
-                return Tensor._wrap(tok)
+                    pool.clear_rows()
+            cache.set_length(slot, length)
+            arr = logits._value()                   # [1, S, V]
+            last = ctx.last_logits(
+                arr, (length._value() - start._value()).astype(
+                    jnp.int32) - 1)
+            # first token sampled on-device from the slot's staged
+            # lanes; key + token lanes update in-program
+            tok = sampler.sample_slot(slot._value(),
+                                      last.astype(jnp.float32))
+            return Tensor._wrap(tok)
 
         def decode_step(active):
             # input ids come from the sampler's device-side token lane
             # (the previous step's sampled tokens — no host round-trip);
-            # the CacheContext decode surface is layout-agnostic, and the
-            # paged cache may route attention through the Pallas
-            # flash-decoding kernel instead of a materializing gather
+            # the pool routes attention through the Pallas flash-decoding
+            # kernel or the gathering oracle (``kernel=``)
             tokens = Tensor._wrap(sampler.tokens._value()[:, None])
-            ctx = (PagedCacheContext if self.kv_layout == "paged"
-                   else CacheContext)(cache, "decode", active=active)
+            ctx = PagedCacheContext(cache, "decode", active=active)
             if pool is not None:
                 # all slots decode at once: the full [slots] id lane
                 pool.set_rows(pool.adapter_ids._value())
@@ -791,33 +752,27 @@ class Engine:
     def _warm_prefill(self, buckets) -> None:
         for b in buckets:
             ids = np.zeros((1, int(b)), dtype=np.int64)
-            if self.kv_layout == "paged":
-                # dummy admission into slot 0: real block assignment so
-                # the traced table reads see representative state, then
-                # released — warmup registers nothing in the prefix cache
-                if not self.cache.begin_sequence(0, [], 0, int(b)):
-                    raise RuntimeError(
-                        f"warmup: pool of {self.cache.num_blocks} blocks "
-                        f"cannot hold one bucket-{b} prefill")
-                try:
-                    self._call_counted(
-                        self._prefill_fn, to_tensor(ids),
-                        to_tensor(np.int32(0)), to_tensor(np.int32(1)),
-                        to_tensor(np.int32(0)))
-                finally:
-                    self.cache.release_slot(0)
-            else:
+            # dummy admission into slot 0: real block assignment so
+            # the traced table reads see representative state, then
+            # released — warmup registers nothing in the prefix cache
+            if not self.cache.begin_sequence(0, [], 0, int(b)):
+                raise RuntimeError(
+                    f"warmup: pool of {self.cache.num_blocks} blocks "
+                    f"cannot hold one bucket-{b} prefill")
+            try:
                 self._call_counted(
                     self._prefill_fn, to_tensor(ids),
-                    to_tensor(np.int32(0)), to_tensor(np.int32(1)))
+                    to_tensor(np.int32(0)), to_tensor(np.int32(1)),
+                    to_tensor(np.int32(0)))
+            finally:
+                self.cache.release_slot(0)
 
     def _warm_decode(self, buckets) -> None:
         idle = np.zeros((self.num_slots,), dtype=np.int32)
         self._call_counted(self._decode_fn, to_tensor(idle))
-        if self.kv_layout == "paged":
-            # the host-side table and block-copy programs of a growing
-            # sequence, which no warm-up prefill reaches
-            self.cache.warm_host_programs()
+        # the host-side table and block-copy programs of a growing
+        # sequence, which no warm-up prefill reaches
+        self.cache.warm_host_programs()
 
     def _warm_draft_prefill(self, buckets) -> None:
         for b in buckets:
@@ -1013,17 +968,16 @@ class Engine:
             return f"max_new_tokens must be >= 1, got {req.max_new_tokens}"
         if req.deadline_s is not None and req.deadline_s <= 0:
             return f"deadline_s must be > 0, got {req.deadline_s}"
-        if self.kv_layout == "paged":
-            # worst case (no prefix hit) the prompt prefills a full bucket
-            # of fresh blocks; a prompt that can never fit the pool is
-            # rejected up front instead of deferring forever
-            need = self.bucket_for(req.prompt_ids.size) // self.block_size
-            usable = self.cache.num_blocks - self.cache.allocator.reserved
-            if need > usable:
-                return (f"prompt needs {need} KV blocks "
-                        f"(bucket {self.bucket_for(req.prompt_ids.size)}, "
-                        f"block_size {self.block_size}) but the pool "
-                        f"holds {usable}")
+        # worst case (no prefix hit) the prompt prefills a full bucket
+        # of fresh blocks; a prompt that can never fit the pool is
+        # rejected up front instead of deferring forever
+        need = self.bucket_for(req.prompt_ids.size) // self.block_size
+        usable = self.cache.num_blocks - self.cache.allocator.reserved
+        if need > usable:
+            return (f"prompt needs {need} KV blocks "
+                    f"(bucket {self.bucket_for(req.prompt_ids.size)}, "
+                    f"block_size {self.block_size}) but the pool "
+                    f"holds {usable}")
         s = req.sampling
         if s.adapter is not None:
             if self.adapter_pool is None:
@@ -1389,7 +1343,7 @@ class Engine:
 
     def _preempt(self, victim: Request) -> None:
         """Evict a running request so a higher-priority admission can
-        take its slot (or, in paged mode, its blocks).  NOT a terminal
+        take its slot (or its blocks).  NOT a terminal
         transition — the victim requeues replay-from-prompt under the
         redispatch stream contract: ``preempted``/``preemptions`` set
         and ``output_ids`` reset BEFORE the replay's token 0, stream
@@ -1401,7 +1355,7 @@ class Engine:
         only the uncached tail bucket — reusing existing prefill
         executables, never adding a compile key."""
         slot = victim.slot
-        if self.kv_layout == "paged" and self.prefix_cache is not None:
+        if self.prefix_cache is not None:
             try:
                 self.prefix_cache.register(victim.prompt_ids,
                                            self.cache.owned_blocks(slot),
@@ -1411,13 +1365,12 @@ class Engine:
         self._vacate(slot)
         if slot not in self.free_slots:
             self.free_slots.append(slot)
-        if self.kv_layout == "paged":
-            try:
-                self.cache.release_slot(slot)
-            except Exception as e:       # noqa: BLE001 — accounting bug
-                self._mark_block_corruption(
-                    f"release_slot({slot}) failed on preemption: "
-                    f"{type(e).__name__}: {e}")
+        try:
+            self.cache.release_slot(slot)
+        except Exception as e:           # noqa: BLE001 — accounting bug
+            self._mark_block_corruption(
+                f"release_slot({slot}) failed on preemption: "
+                f"{type(e).__name__}: {e}")
         if self.spec is not None:
             # draft KV is never resumed — the replay-from-prompt resume
             # re-prefills it (draft state is deliberately not durable)
@@ -1653,7 +1606,7 @@ class Engine:
         """Prefill ``req`` into its pre-assigned slot.  Never raises for
         request-level problems — a prefill/sampling/callback failure fails
         this request only (``_retire`` reclaims the slot).  Returns False
-        when paged admission must be deferred (no KV blocks free); the
+        when admission must be deferred (no KV blocks free); the
         scheduler re-queues the request with its slot returned."""
         with _spans.span("engine.admit", trace=self._trace_id(req),
                          slot=req.slot,
@@ -1679,7 +1632,6 @@ class Engine:
             self._fail_deadline(req)
             return None
         L = int(req.prompt_ids.size)
-        prefix_hit = 0
         # stage the slot's device sampling lanes (params + key re-seed)
         # BEFORE the prefill dispatch: the compiled step samples the
         # first token on-device from exactly this state
@@ -1709,21 +1661,11 @@ class Engine:
                 }
                 self._retire(req, "failed", error=str(e.args[0]))
                 return None
-        if self.kv_layout == "paged":
-            status, tok_t, bucket, prefix_hit = self._paged_prefill(req, L)
-            if status == "deferred":
-                return False
-            if status == "failed":
-                return None
-        else:
-            bucket = self.bucket_for(L)
-            ids = np.zeros((1, bucket), dtype=np.int64)
-            ids[0, :L] = req.prompt_ids
-            tok_t = self._prefill_call(
-                req, to_tensor(ids), to_tensor(np.int32(req.slot)),
-                to_tensor(np.int32(L)))
-            if tok_t is None:
-                return None
+        status, tok_t, bucket, prefix_hit = self._paged_prefill(req, L)
+        if status == "deferred":
+            return False
+        if status == "failed":
+            return None
         if self.spec is not None and not self._spec_admit(req, L):
             return None
         now = time.perf_counter()
@@ -1818,15 +1760,14 @@ class Engine:
             self._vacate(slot)
             if slot not in self.free_slots:
                 self.free_slots.append(slot)
-            if self.kv_layout == "paged":
-                # drop the slot's block refs (idempotent); blocks also
-                # registered in the prefix cache stay alive on its ref
-                try:
-                    self.cache.release_slot(slot)
-                except Exception as e:   # noqa: BLE001 — accounting bug
-                    self._mark_block_corruption(
-                        f"release_slot({slot}) failed: "
-                        f"{type(e).__name__}: {e}")
+            # drop the slot's block refs (idempotent); blocks also
+            # registered in the prefix cache stay alive on its ref
+            try:
+                self.cache.release_slot(slot)
+            except Exception as e:       # noqa: BLE001 — accounting bug
+                self._mark_block_corruption(
+                    f"release_slot({slot}) failed: "
+                    f"{type(e).__name__}: {e}")
             if self.spec is not None:
                 self.spec.release_slot(slot)
         if state == "finished":
@@ -1913,10 +1854,9 @@ class Engine:
         suppressions since on-device sampling landed).  Returns
         ``(token_tensor, t0)`` or None (nothing ran / batch failed)."""
         with _spans.span("engine.prepare_decode"):
-            if self.kv_layout == "paged":
-                self._prepare_decode_paged()
-                if not self.running:
-                    return None
+            self._prepare_decode_paged()
+            if not self.running:
+                return None
             active = np.zeros((self.num_slots,), dtype=np.int32)
             for slot in self.running:
                 active[slot] = 1
@@ -2056,8 +1996,7 @@ class Engine:
         ``(round_tensor, t0)`` or None (nothing ran / round failed)."""
         spec = self.spec
         with _spans.span("engine.prepare_decode"):
-            if self.kv_layout == "paged":
-                self._prepare_spec_paged()
+            self._prepare_spec_paged()
             if not self.running:
                 return None
             active, cap = self._spec_masks()
@@ -2166,15 +2105,14 @@ class Engine:
         for slot, req in running:
             m = int(arr[slot, 0])
             self._advance(req, m)        # the in-graph advance, mirrored
-            if self.kv_layout == "paged":
-                # rollback bookkeeping: drop table blocks past the
-                # accepted length (no copy — refcounts + table writes)
-                try:
-                    self.cache.truncate_blocks(slot, req._seq_len)
-                except Exception as e:   # noqa: BLE001 — accounting bug
-                    self._mark_block_corruption(
-                        f"truncate_blocks({slot}) failed: "
-                        f"{type(e).__name__}: {e}")
+            # rollback bookkeeping: drop table blocks past the
+            # accepted length (no copy — refcounts + table writes)
+            try:
+                self.cache.truncate_blocks(slot, req._seq_len)
+            except Exception as e:       # noqa: BLE001 — accounting bug
+                self._mark_block_corruption(
+                    f"truncate_blocks({slot}) failed: "
+                    f"{type(e).__name__}: {e}")
             finished = False
             for tok in delivered[slot]:
                 if not self._emit_token(req, tok, now):
@@ -2231,9 +2169,8 @@ class Engine:
             self._step_span = None
             self._step_counter += 1
             sp.set(admitted=self._admitted_step,
-                   running=len(self.running), queued=len(self.queue))
-            if self.kv_layout == "paged":
-                sp.attrs["free_blocks"] = self.cache.allocator.free_blocks
+                   running=len(self.running), queued=len(self.queue),
+                   free_blocks=self.cache.allocator.free_blocks)
         # the step's one record: the closed span's stamps and attributes
         # are the health clock and the always-on flight ring's summary
         # (the post-mortem tail), with no clock read of their own
@@ -2277,7 +2214,7 @@ class Engine:
                                  error="admission aborted by engine error")
                 raise
             if deferred:
-                # paged mode: the pool has no blocks for this prompt
+                # the pool has no blocks for this prompt
                 # right now — hand the slot back.  A higher-priority
                 # admission may evict a lower-priority victim (freeing
                 # its blocks) and retry immediately; otherwise requeue
@@ -2403,8 +2340,7 @@ class Engine:
                      adapter: Optional[str] = None) -> int:
         """Longest prompt prefix (in tokens) this engine's prefix cache
         already holds — side-effect-free (no LRU refresh, no counters,
-        no refs).  0 for the contiguous layout or a disabled/failing
-        cache; the fleet router's affinity signal.  ``adapter`` probes
+        no refs).  0 for a disabled/failing cache; the fleet router's affinity signal.  ``adapter`` probes
         under that tenant's salt (cached KV is tenant-keyed; a base
         probe can never see adapter blocks and vice versa)."""
         if self.prefix_cache is None:
@@ -2732,33 +2668,26 @@ class Engine:
     def health(self) -> dict:
         """Liveness snapshot: engine state, last-step age, consecutive
         compiled-step failures, and capacity gauges — the probe a load
-        balancer or the profiler surface polls.  In paged mode it also
-        audits the block allocator's invariants (free + used + cached ==
+        balancer or the profiler surface polls.  It also audits the block allocator's invariants (free + used + cached ==
         total − reserved, no negative refcounts, no slot holding a freed
         block) and flips the engine ``unhealthy`` on any violation
         instead of letting the pool corrupt silently."""
-        paged_extra = {}
-        if self.kv_layout == "paged":
+        violations = self.cache.check_invariants()
+        if violations:
+            # health() may be polled from a monitor thread while the
+            # scheduler is mid-way through a multi-op accounting
+            # change (block popped, refcount not yet set): confirm on
+            # a re-read before declaring the pool corrupt — a
+            # transient snapshot clears, real corruption persists
             violations = self.cache.check_invariants()
-            if violations:
-                # health() may be polled from a monitor thread while the
-                # scheduler is mid-way through a multi-op accounting
-                # change (block popped, refcount not yet set): confirm on
-                # a re-read before declaring the pool corrupt — a
-                # transient snapshot clears, real corruption persists
-                violations = self.cache.check_invariants()
-            if violations:
-                self._mark_block_corruption("; ".join(violations))
-            al = self.cache.allocator.stats()
-            paged_extra = {
-                "kv_blocks": {k: al[k] for k in
-                              ("total", "reserved", "free", "used",
-                               "cached")},
-                "kv_block_invariants": violations or "ok",
-            }
+        if violations:
+            self._mark_block_corruption("; ".join(violations))
+        al = self.cache.allocator.stats()
         now = time.perf_counter()
         return {
-            **paged_extra,
+            "kv_blocks": {k: al[k] for k in
+                          ("total", "reserved", "free", "used", "cached")},
+            "kv_block_invariants": violations or "ok",
             "state": self.state,
             "reason": self._unhealthy_reason,
             "steps": self._step_counter,

@@ -1,9 +1,17 @@
-"""Slot-paged KV cache for continuous-batching decode.
+"""What a model caches, the handle its layers reach a cache through, and
+the speculative draft's dense cache.
 
-Design (TPU-first): ONE preallocated array per K and V of shape
+There are two cache objects.  The ENGINE's cache is always the paged pool
+(:class:`~.paging.PagedKVCache`, reached through
+:class:`~.paging.PagedCacheContext`).  :class:`KVCache` here is the dense
+per-slot cache ``SpecState`` keeps for the speculative DRAFT model, and
+:class:`CacheContext` is the draft's handle and the base class the paged
+context extends.
+
+Design of the dense cache: ONE preallocated array per K and V of shape
 ``[slots, layers, max_seq, kv_heads, head_dim]`` plus a ``[slots]`` int32
-length vector.  Every shape the serving engine ever compiles is a function
-of (slots, bucket, max_seq) only — never of request content — so XLA
+length vector.  Every shape a program over it compiles is a function of
+(slots, bucket, max_seq) only — never of request content — so XLA
 compiles each program once and steady-state serving runs zero recompiles.
 
 State threading: the cache payloads are ordinary eager ``Tensor``s.  Inside
@@ -82,7 +90,9 @@ def cache_spec_of(model) -> CacheSpec:
 
 
 class KVCache:
-    """Preallocated per-slot KV storage shared by all layers of one model.
+    """The speculative draft's cache (``SpecState.cache``): preallocated
+    dense per-slot KV storage shared by all layers of the draft model.
+    The engine's own cache is the paged pool, never this.
 
     Args:
         num_slots:    fixed decode batch width (continuous-batching slots).
@@ -210,9 +220,6 @@ class KVCache:
         self.lengths._set_data(
             jnp.zeros((self.num_slots,), dtype=jnp.int32))
 
-    def length_of(self, slot: int) -> int:
-        return int(self.lengths.numpy()[slot])
-
     def nbytes(self) -> int:
         itemsize = jnp.zeros((), dtype=self.dtype).dtype.itemsize
         return 2 * self.num_slots * self.num_layers * self.max_seq * \
@@ -232,6 +239,11 @@ class CacheContext:
     ``layer_idx`` is advanced by the model's layer loop (a per-trace
     python constant).  Models only duck-type this object, keeping
     ``models/`` free of serving imports.
+
+    This base class is the handle over the draft's dense :class:`KVCache`
+    (and over the pool for the target's verify window, which needs no
+    paged-specific routing); :class:`~.paging.PagedCacheContext` extends
+    it with the pool's prefill and decode routing.
     """
 
     cache: KVCache
@@ -311,27 +323,20 @@ class CacheContext:
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v)
 
-    def write_decode(self, k, v) -> Tuple[Tensor, Tensor, Tensor]:
-        return self.cache.decode_write(self.layer_idx, k, v)
-
     def decode_attention(self, q, k, v):
         """One decode step of attention through the cache: write this
-        layer's token K/V, then attend over the slot's valid window.
-        The contiguous layout writes + runs the masked one-row oracle;
-        a cache that defines its own ``decode_attention`` (the paged
-        pool's kernel-vs-reference routing) takes over the whole step —
-        models stay single-path either way.  In ``verify`` mode the same
-        call site routes the W-token speculative window through the
-        cache's ``verify_attention`` instead, so models need no
+        layer's token K/V, then attend over the slot's valid window (the
+        dense cache runs the masked one-row oracle; the paged context
+        overrides the decode step with the pool's kernel-vs-reference
+        routing).  In ``verify`` mode the same call site routes the
+        W-token speculative window through the cache's
+        ``verify_attention`` instead, so models need no
         speculation-specific branch at all."""
         if self.mode == "verify":
             return self.cache.verify_attention(self.layer_idx, q, k, v)
-        cache_fn = getattr(self.cache, "decode_attention", None)
-        if cache_fn is not None:
-            return cache_fn(self.layer_idx, q, k, v, self.active)
         from ..ops.cached_attention import cached_attention
 
-        k_full, v_full, lens = self.write_decode(k, v)
+        k_full, v_full, lens = self.cache.decode_write(self.layer_idx, k, v)
         return cached_attention(q, k_full, v_full, lens)
 
     def positions(self) -> Tensor:
@@ -355,7 +360,7 @@ class CacheContext:
         return None
 
     def prefill_attention(self, q, k, v):
-        """Prompt-forward attention.  The contiguous layout is ordinary
+        """Prompt-forward attention.  Over the dense cache it is ordinary
         causal attention (GQA kv heads expanded first, exactly like the
         models' no-cache path); the paged context overrides this with a
         gather-by-block-table attention that also covers its cached

@@ -303,7 +303,7 @@ class ServingMetrics:
             if self.decode_time_s > 0 else 0.0
 
     def _paging_section(self):
-        """Engine-fed paged-KV gauges (None for the contiguous layout)."""
+        """Engine-fed paged-KV gauges (None with no engine attached)."""
         if self.paging_cb is None:
             return None
         out = self.paging_cb()

@@ -1,8 +1,9 @@
-"""Paged KV cache: a block-granular pool behind the CacheContext surface.
+"""The engine's KV cache: a block-granular pool behind the CacheContext
+surface.
 
-Instead of reserving a contiguous ``max_seq`` stripe per slot (the
-:class:`~.kv_cache.KVCache` layout — HBM sized for the worst-case
-sequence), the paged layout stores K/V in a fixed pool of blocks — one
+Instead of reserving a ``max_seq`` stripe per slot (HBM sized for the
+worst-case sequence, as the draft's dense :class:`~.kv_cache.KVCache`
+does), the pool stores K/V in a fixed set of blocks — one
 ``[num_blocks, block_size, kv_heads, lane_dim]`` buffer per layer and per
 side, the paged kernels' own operand (``lane_dim`` is ``head_dim`` rounded
 up to the 128 lanes of a vector register, see :data:`LANES`), so a
@@ -19,11 +20,11 @@ addresses them through per-slot int32 block tables of fixed shape
 The zero-recompile invariant survives because every compiled shape is a
 function of ``(slots, bucket, block_size, max_blocks_per_slot)`` only:
 block ids live *inside* the block-table tensor (device state threaded
-through traces exactly like the contiguous cache's payloads), and all
+through traces exactly like optimizer state), and all
 allocation/eviction/copy-on-extend happens host-side between steps,
 changing argument *values* only.
 
-Write discipline (same contract as the contiguous cache, block-indirect):
+Write discipline (the dense cache's contract, block-indirect):
 prefill writes whole tail-bucket blocks starting at the block boundary
 ``start_pos // block_size``; decode writes each slot's token at
 ``lengths[slot]`` through the table; attention reads positions
@@ -214,10 +215,11 @@ class BlockAllocator:
 
 
 class PagedKVCache:
-    """Block-pool KV storage exposing the :class:`KVCache` duck surface.
+    """The engine's cache: block-pool KV storage with the traced-state
+    surface of the dense :class:`KVCache`.
 
-    Device state (threaded through compiled programs exactly like the
-    contiguous cache): the K/V pools — ``k[layer]`` / ``v[layer]``, one
+    Device state (threaded through compiled programs as lifted state): the
+    K/V pools — ``k[layer]`` / ``v[layer]``, one
     ``[num_blocks, block_size, kv_heads, lane_dim]`` buffer each — the
     ``[slots, max_blocks_per_slot]`` int32 block tables, and the
     ``[slots]`` lengths.  Host state: the :class:`BlockAllocator` and each
@@ -255,7 +257,7 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.max_blocks_per_slot = self.max_seq // self.block_size
         if num_blocks is None:
-            # contiguous-parity capacity + the reserved scratch block; the
+            # every slot at max_seq + the reserved scratch block; the
             # prefix cache then *saves* blocks relative to this baseline
             num_blocks = self.num_slots * self.max_blocks_per_slot + 1
         self.num_blocks = int(num_blocks)
@@ -574,7 +576,7 @@ class PagedKVCache:
                      ) -> Tuple[Tensor, Tensor, Tensor]:
         """Reference decode read: token write, then gather each slot's
         sequence back contiguous — the same ``([slots, T, Hkv, D],
-        lengths)`` triple the contiguous cache hands
+        lengths)`` triple the dense cache hands
         ``ops.cached_attention``, with ``T = max_blocks_per_slot *
         block_size``."""
         k_layer, v_layer, tbl, lens = self._decode_token_write(
@@ -679,9 +681,6 @@ class PagedKVCache:
         preempted request's resume is a cheap prefix hit)."""
         return self._slot_blocks[slot]
 
-    def length_of(self, slot: int) -> int:
-        return int(self.lengths.numpy()[slot])
-
     def layer_nbytes(self) -> int:
         """Bytes of one layer's buffer of the first side (K and V are
         alike), pad lanes included."""
@@ -743,6 +742,14 @@ class PagedCacheContext(CacheContext):
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v,
                                  self._prefill_start())
+
+    def decode_attention(self, q, k, v):
+        """The pool takes the whole decode step (token write + its
+        kernel-vs-reference read); the verify window stays the base's."""
+        if self.mode == "verify":
+            return super().decode_attention(q, k, v)
+        return self.cache.decode_attention(self.layer_idx, q, k, v,
+                                           self.active)
 
     def prefill_positions(self, seq_len: int) -> Optional[Tensor]:
         """Absolute positions of the tail bucket's tokens ``[1, S]`` —

@@ -234,7 +234,7 @@ class Fleet:
             recovery replays bitwise onto any mesh of the same shape —
             see docs/SERVING.md "Sharded serving".
         **engine_kwargs: forwarded to every replica's ``Engine(...)``
-            (``num_slots``, ``max_seq``, ``kv_layout``, ...).  ``name``,
+            (``num_slots``, ``max_seq``, ``block_size``, ...).  ``name``,
             ``fault_plan``, ``tracer``, ``journal``, ``model_version``
             and ``mesh`` are fleet-managed and rejected here.
     """

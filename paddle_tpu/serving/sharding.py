@@ -15,14 +15,12 @@ pieces and why they compose (docs/SERVING.md "Sharded serving"):
   compiled call, so the SAME model code the single-chip engine traces
   becomes a GSPMD tensor-parallel program.
 
-- **The KV pool shards by ``kv_heads``.**  The contiguous cache is 5-D
-  with kv_heads at dim 3 (``[slots, layers, max_seq, kv_heads,
-  head_dim]``), the paged cache one 4-D buffer per layer and side with
-  kv_heads at dim 2 (``[blocks, block_size, kv_heads, lane_dim]``), and
-  attention is head-batched: every contraction is
-  independent per head, so a shard holding ``kv_heads/mp`` whole heads
-  (GQA groups stay local — ``kv_heads % mp == 0`` is validated up
-  front) runs paged/contiguous ``decode_attention`` with ZERO
+- **The KV pool shards by ``kv_heads``.**  The pool is one 4-D buffer
+  per layer and side with kv_heads at dim 2 (``[blocks, block_size,
+  kv_heads, lane_dim]``), and attention is head-batched: every
+  contraction is independent per head, so a shard holding
+  ``kv_heads/mp`` whole heads (GQA groups stay local — ``kv_heads % mp
+  == 0`` is validated up front) runs ``decode_attention`` with ZERO
   cross-shard traffic.  Only the per-layer TP collectives (row-parallel
   out-proj/fc2) cross chips.
 
@@ -71,13 +69,14 @@ __all__ = ["ServingShard", "serving_mesh", "mesh_shape_key",
            "viable_ladder", "degrade_step", "KV_POOL_SPEC",
            "KV_LAYER_SPEC"]
 
-#: The contiguous KV pool is 5-D, ``[slots, layers, max_seq, kv_heads,
-#: head_dim]``, kv_heads at dim 3; the paged pool is one 4-D buffer per
-#: layer and side, ``[blocks, block_size, kv_heads, lane_dim]``, kv_heads
-#: at dim 2.  Heads split over the model axis, every other dim (and the
-#: block tables / lengths / sampler lanes) replicated.
-KV_POOL_SPEC = P(None, None, None, MODEL_AXIS, None)
+#: The pool is one 4-D buffer per layer and side, ``[blocks, block_size,
+#: kv_heads, lane_dim]``, kv_heads at dim 2.  Heads split over the model
+#: axis, every other dim (and the block tables / lengths / sampler lanes)
+#: replicated.
 KV_LAYER_SPEC = P(None, None, MODEL_AXIS, None)
+#: The speculative draft's dense cache only (``[slots, layers, max_seq,
+#: kv_heads, head_dim]``, kv_heads at dim 3).
+KV_POOL_SPEC = P(None, None, None, MODEL_AXIS, None)
 
 
 def serving_mesh(model_parallel: int,
@@ -210,19 +209,23 @@ class ServingShard:
             place_parameters(model, self.mesh)
 
     def place_cache(self, cache) -> None:
-        """KV pool k/v shard on the kv_heads dim; lengths (and the paged
-        block tables) replicate — they are host-driven metadata every
+        """The pool's layer buffers shard on the kv_heads dim; lengths and
+        block tables replicate — they are host-driven metadata every
         shard must agree on."""
         self._pin(cache.lengths)
-        bt = getattr(cache, "block_tables", None)
-        if bt is None:
-            self._pin(cache.k, KV_POOL_SPEC)
-            self._pin(cache.v, KV_POOL_SPEC)
-            return
         for buf in (*cache.k, *cache.v):
             self._pin(buf, KV_LAYER_SPEC)
-        self._pin(bt)
+        self._pin(cache.block_tables)
         cache.mesh = self.mesh           # paged kernels run per head shard
+
+    def place_draft_cache(self, cache) -> None:
+        """The speculative draft's dense cache shards by ITS kv_heads when
+        divisible; ``_pin`` falls back to replicated otherwise (a draft
+        is small by construction — replicating it is the documented
+        degradation, not an error)."""
+        self._pin(cache.lengths)
+        self._pin(cache.k, KV_POOL_SPEC)
+        self._pin(cache.v, KV_POOL_SPEC)
 
     def place_sampler(self, sampler) -> None:
         """All sampling lanes replicate: one logical decision stream
@@ -269,10 +272,6 @@ class ServingShard:
         spec = getattr(engine, "spec", None)
         if spec is not None:
             self.place_model(spec.model)
-            # the draft's contiguous cache shards by ITS kv_heads when
-            # divisible; _pin falls back to replicated otherwise (a
-            # draft is small by construction — replicating it is the
-            # documented degradation, not an error)
-            self.place_cache(spec.cache)
+            self.place_draft_cache(spec.cache)
             self.place_sampler(spec.sampler)
             self._pin(spec.proposals)

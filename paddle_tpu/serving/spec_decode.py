@@ -103,13 +103,13 @@ class SpecConfig:
 
 class SpecState:
     """Per-engine speculative-decoding state: the draft model, its own
-    contiguous KV pool (sharing the engine's slot table — slot ``i`` of
-    the draft cache mirrors slot ``i`` of the target's), the draft
+    dense per-slot KV cache (sharing the engine's slot table — slot ``i``
+    of the draft cache mirrors slot ``i`` of the target's), the draft
     :class:`DeviceSampler` (proposal params/keys/token lanes), and the
     ``[slots, k]`` proposals lane the verify step consumes.
 
-    The draft cache is contiguous regardless of the engine's layout —
-    it is small by construction (draft model × max_seq) and holds no
+    The draft cache is a dense :class:`KVCache`, not a second pool — it
+    is small by construction (draft model × max_seq) and holds no
     shareable prefixes worth paging; its ``max_seq`` carries ``k``
     positions of headroom so a near-capacity round's draft steps never
     clamp a write onto a live position.

@@ -157,7 +157,7 @@ def fleet_chaos(serving_model):
     plan = ServingFaultPlan().add("serving.r1.decode", at_call=2, times=2)
     tracer = RequestTracer()
     fleet = Fleet(serving_model, num_replicas=3, num_slots=2, max_seq=32,
-                  min_bucket=16, kv_layout="paged", block_size=16,
+                  min_bucket=16, block_size=16,
                   eject_after_failures=2, max_redispatch=2,
                   fault_plan=plan, tracer=tracer)
     fleet.warmup()

@@ -116,10 +116,10 @@ def test_serving_mode_emits_json_line():
     assert out["serving_spec_tokens_per_sec"] > 0
     assert out["serving_nospec_tokens_per_sec"] > 0
     # paged KV + prefix reuse (ISSUE 5): the shared-prefix workload must
-    # actually hit the cache, and both layouts report TTFT side by side
+    # actually hit the cache
     assert out["serving_prefix_hit_rate"] > 0
     assert out["serving_kv_blocks_in_use"] > 0
-    assert out["ttft_ms_paged"] > 0 and out["ttft_ms_contiguous"] > 0
+    assert out["ttft_ms_paged"] > 0 and "ttft_ms_contiguous" not in out
     assert out["paged_engine_state"] == "active"
     # fleet failover smoke (ISSUE 6): the scripted replica kill must have
     # actually happened (>= 1 redispatch), the fleet must have healed

@@ -150,7 +150,7 @@ def test_bf16_engine_serves_within_a_tolerance_the_fp8_control_exceeds():
     9e-3 below its best.  0.003 lies between, several times from either."""
     TOL = 0.003
     model, tree, d = seeded("bfloat16")
-    eng = inference.create_engine(model, kv_layout="paged", block_size=BLOCK,
+    eng = inference.create_engine(model, block_size=BLOCK,
                                   min_bucket=16, max_seq=64, num_slots=4)
     eng.warmup()
     rng = np.random.default_rng(3)
@@ -259,24 +259,21 @@ def _refusals():
 
     draft = dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
     return {
-        "contiguous": (dict(kv_layout="contiguous"), "kv_layout='paged' only"),
-        "mesh": (dict(kv_layout="paged", block_size=BLOCK, min_bucket=16,
-                      mesh=serving_mesh(2)), "no kv_heads axis to shard"),
-        "speculation": (dict(kv_layout="paged", block_size=BLOCK,
-                             min_bucket=16,
-                             speculation=SpecConfig(draft_model=draft, k=2)),
+        "mesh": (dict(mesh=serving_mesh(2)), "no kv_heads axis to shard"),
+        "speculation": (dict(speculation=SpecConfig(draft_model=draft, k=2)),
                         "no latent form"),
     }
 
 
-@pytest.mark.parametrize("what", ["contiguous", "mesh", "speculation"])
+@pytest.mark.parametrize("what", ["mesh", "speculation"])
 def test_the_latent_pool_refuses_what_it_has_no_form_for(what):
     from paddle_tpu.serving import Engine
 
     kwargs, says = _refusals()[what]
     model = dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
     with pytest.raises(ValueError, match=says):
-        Engine(model, num_slots=2, max_seq=64, **kwargs)
+        Engine(model, num_slots=2, max_seq=64, block_size=BLOCK,
+               min_bucket=16, **kwargs)
 
 
 @pytest.mark.parametrize("family", ["gpt", "llama"])
@@ -292,7 +289,7 @@ def test_kv_models_state_their_cache_and_get_the_buffers_they_had(family):
     assert model.cache_spec() == CacheSpec.kv(cfg.num_hidden_layers, kv,
                                               cfg.head_dim)
     eng = Engine(model, num_slots=2, max_seq=32, min_bucket=8,
-                 kv_layout="paged", block_size=8)
+                 block_size=8)
     shape = (2 * 4 + 1, 8, kv, 128)
     assert [tuple(b.shape) for b in eng.cache.k] == \
         [shape] * cfg.num_hidden_layers

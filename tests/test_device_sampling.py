@@ -208,7 +208,7 @@ class TestDeviceSampler:
 
         paddle.seed(0)
         eng = Engine(GPTForCausalLM(gpt_tiny()), num_slots=2,
-                     max_seq=32, min_bucket=8)
+                     max_seq=32, min_bucket=8, block_size=8)
         eng.warmup()
         sp = dict(max_new_tokens=5,
                   sampling=SamplingParams(temperature=1.0, top_k=12,

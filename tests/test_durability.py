@@ -64,6 +64,7 @@ def _mk_engine(model, tmp=None, journal=None, **kw):
     kw.setdefault("num_slots", 2)
     kw.setdefault("max_seq", 32)
     kw.setdefault("min_bucket", 8)
+    kw.setdefault("block_size", 8)
     if journal is None and tmp is not None:
         journal = RequestJournal(str(tmp))
     return Engine(model, journal=journal, **kw)
@@ -524,7 +525,7 @@ class TestEngineHotSwap:
         own.eval()
         j = RequestJournal(str(tmp_path))
         eng = Engine(own, num_slots=2, max_seq=32, min_bucket=8,
-                     kv_layout="paged", block_size=8, journal=j)
+                     block_size=8, journal=j)
         eng.warmup()
         prompt = list(range(20))
         eng.generate([prompt], max_new_tokens=5)
@@ -567,7 +568,7 @@ class TestFleetDurability:
                                                tmp_path):
         j = RequestJournal(str(tmp_path))
         fleet = Fleet(model, num_replicas=2, num_slots=2, max_seq=32,
-                      min_bucket=8, kv_layout="paged", block_size=8,
+                      min_bucket=8, block_size=8,
                       journal=j)
         fleet.warmup()
         assert fleet.weights_isolated
@@ -610,7 +611,7 @@ class TestFleetDurability:
 
     def test_weight_isolation_replicas_own_buffers(self, model):
         fleet = Fleet(model, num_replicas=2, num_slots=2, max_seq=32,
-                      min_bucket=8)
+                      min_bucket=8, block_size=8)
         p0 = fleet.replicas[0].engine.model.parameters()[0]
         p1 = fleet.replicas[1].engine.model.parameters()[0]
         assert p0 is not p1               # isolated buffers...
@@ -623,7 +624,7 @@ class TestFleetDurability:
         terminal.  Refused, like the engine-level guard."""
         j = RequestJournal(str(tmp_path))
         fleet = Fleet(model, num_replicas=1, num_slots=2, max_seq=32,
-                      min_bucket=8, journal=j)
+                      min_bucket=8, block_size=8, journal=j)
         fleet.warmup()
         fleet.submit([1, 2, 3], max_new_tokens=6)
         fleet.step()
@@ -634,7 +635,7 @@ class TestFleetDurability:
     def test_fleet_recover_exactly_once(self, model, tmp_path):
         j = RequestJournal(str(tmp_path))
         fleet = Fleet(model, num_replicas=1, num_slots=2, max_seq=32,
-                      min_bucket=8, journal=j)
+                      min_bucket=8, block_size=8, journal=j)
         fleet.warmup()
         done = fleet.submit([1, 2, 3], max_new_tokens=2)
         fleet.run()
@@ -646,7 +647,7 @@ class TestFleetDurability:
 
         j2 = RequestJournal(str(tmp_path))
         fleet2 = Fleet(model, num_replicas=1, num_slots=2, max_seq=32,
-                       min_bucket=8, journal=j2)
+                       min_bucket=8, block_size=8, journal=j2)
         fleet2.warmup()
         info = fleet2.recover()
         assert info["replayed"] == 2
@@ -722,7 +723,8 @@ from paddle_tpu.serving import Engine, RequestJournal, SamplingParams
 
 paddle.seed(0)
 eng = Engine(GPTForCausalLM(gpt_tiny()), num_slots=2, max_seq=32,
-             min_bucket=8, journal=RequestJournal(sys.argv[1]))
+             min_bucket=8, block_size=8,
+             journal=RequestJournal(sys.argv[1]))
 eng.warmup()
 rs = np.random.RandomState(5)
 prompts = [rs.randint(0, 128, (L,)).tolist() for L in (6, 11, 14)]
@@ -751,7 +753,7 @@ paddle.seed(0)
 j = RequestJournal(sys.argv[1])
 pend = j.pending()
 eng = Engine(GPTForCausalLM(gpt_tiny()), num_slots=2, max_seq=32,
-             min_bucket=8, journal=j)
+             min_bucket=8, block_size=8, journal=j)
 eng.warmup()
 misses0 = eng.metrics.compile_misses
 info = eng.recover()

@@ -382,7 +382,7 @@ class TestFleetResilience:
         plan = ServingFaultPlan().add("serving.r0.decode", at_call=4,
                                       times=2)
         fleet = Fleet(gpt, num_replicas=2, num_slots=1, max_seq=32,
-                      min_bucket=16, kv_layout="paged", block_size=16,
+                      min_bucket=16, block_size=16,
                       eject_after_failures=2, max_redispatch=2,
                       fault_plan=plan)
         fleet.warmup()

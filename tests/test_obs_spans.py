@@ -169,7 +169,7 @@ def engine_run(serving_model):
     a prefix; returns ``(engine, rows of the run, requests)``."""
     tr = RequestTracer()
     eng = Engine(serving_model, num_slots=4, max_seq=64, min_bucket=8,
-                 kv_layout="paged", block_size=8, tracer=tr)
+                 block_size=8, tracer=tr)
     eng.warmup()
     rng = np.random.default_rng(0)
     prefix = rng.integers(1, 100, (16,))
@@ -225,7 +225,7 @@ def test_decode_chunks_on_the_step_span_is_the_kernels_work_list(
 
     monkeypatch.setattr(pk, "DECODE_CHUNK_TOKENS", 16)
     eng = Engine(serving_model, num_slots=4, max_seq=64, min_bucket=8,
-                 kv_layout="paged", block_size=8)
+                 block_size=8)
     eng.warmup()
     ct = eng._decode_chunk_tokens
     assert ct == eng.cache.decode_chunk_tokens() == 16
@@ -252,7 +252,7 @@ def test_decode_chunks_on_the_step_span_is_the_kernels_work_list(
     assert host_n == device_n == [7, 7, 9, 9, 9]
     # a reference engine has no work list, and says nothing
     ref = Engine(serving_model, num_slots=2, max_seq=64, min_bucket=8,
-                 kv_layout="paged", block_size=8, kernel="reference")
+                 block_size=8, kernel="reference")
     ref._build_steps()
     assert ref._decode_chunk_tokens is None
 
@@ -551,7 +551,7 @@ def engine_program(one_chip, program, layers=1):
         hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
     model.to(dtype="bfloat16")
     eng = Engine(model, num_slots=32, max_seq=1024, min_bucket=32,
-                 kv_layout="paged", block_size=16, num_kv_blocks=513,
+                 block_size=16, num_kv_blocks=513,
                  kernel="pallas")
     eng.cache._interpret = False          # the kernels as the chip runs them
     eng._build_steps()
@@ -623,7 +623,7 @@ def test_latent_pool_programs_move_no_layer_buffer_on_the_chip(
         vocab_size=8192, num_hidden_layers=2, held_experts=(0, 32),
         max_position_embeddings=1024, dtype="bfloat16"))
     eng = Engine(model, num_slots=32, max_seq=1024, min_bucket=256,
-                 kv_layout="paged", block_size=16, kernel="pallas")
+                 block_size=16, kernel="pallas")
     assert [tuple(b.shape) for b in eng.cache.buffers()] == \
         [(2049, 16, 1, 640)] * 2
     eng.cache._interpret = False          # the kernels as the chip runs them

@@ -35,7 +35,7 @@ def peng(gpt):
     ordering tests control it explicitly); reused across tests with
     metrics asserted as deltas."""
     eng = Engine(gpt, num_slots=2, max_seq=32, min_bucket=16,
-                 kv_layout="paged", block_size=16,
+                 block_size=16,
                  max_preemptions=2, priority_aging_s=30.0)
     eng.warmup()
     return eng
@@ -350,7 +350,7 @@ class TestPreemption:
         release, so its resume prefills only the uncached tail bucket —
         measurably cheaper than its original prefill."""
         eng = Engine(gpt, num_slots=2, max_seq=32, min_bucket=16,
-                     kv_layout="paged", block_size=16, num_kv_blocks=4,
+                     block_size=16, num_kv_blocks=4,
                      max_preemptions=2, priority_aging_s=30.0)
         eng.warmup()
         warm = eng.metrics.compile_misses

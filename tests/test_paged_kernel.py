@@ -283,7 +283,7 @@ PROMPT_LENGTHS = (5, 13, 21, 9, 25, 3)   # 25+6 fits max_seq=32
 
 def _run_engine(model, kernel, tracer=None):
     eng = Engine(model, num_slots=4, max_seq=32, min_bucket=8,
-                 kv_layout="paged", block_size=8, kernel=kernel,
+                 block_size=8, kernel=kernel,
                  tracer=tracer)
     eng.warmup()
     warm = eng.metrics.compile_misses
@@ -348,11 +348,10 @@ class TestEngineKernelPath:
         paddle.seed(0)
         m = GPTForCausalLM(gpt_tiny())
         with pytest.raises(ValueError):
-            Engine(m, num_slots=2, max_seq=32, kv_layout="paged",
-                   block_size=8, kernel="bogus")
-        # contiguous ignores the kernel flag (jnp oracle only)
+            Engine(m, num_slots=2, max_seq=32, block_size=8, kernel="bogus")
+        # no argument: the Pallas kernels
         eng = Engine(m, num_slots=2, max_seq=32)
-        assert eng.kernel == "reference"
+        assert eng.kernel == eng.cache.kernel == "pallas"
 
 
 # -- the latent (MLA) kernels and the grouped expert kernel -------------------

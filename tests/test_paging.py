@@ -20,10 +20,9 @@ from paddle_tpu.models import (
     GPTForCausalLM, LlamaForCausalLM, gpt_tiny, llama_tiny,
 )
 from paddle_tpu.serving import (
-    AllocatorError, BlockAllocator, Engine, KVCache, PagedCacheContext,
+    AllocatorError, BlockAllocator, Engine, PagedCacheContext,
     PagedKVCache, PrefixCache,
 )
-from paddle_tpu.serving.kv_cache import CacheContext
 
 
 @pytest.fixture(scope="module")
@@ -260,8 +259,8 @@ def _paged_generate(model, cfg, kv_heads, prompt, steps, *, slot, cache,
 
 class TestPagedCacheParity:
     """Eager parity of the paged paths against full recompute, for GPT
-    and GQA-Llama (ISSUE 5 satellite), plus slot-churn parity for BOTH
-    cache layouts and the copy-on-extend path."""
+    and GQA-Llama (ISSUE 5 satellite), plus slot-churn parity and the
+    copy-on-extend path."""
 
     def _mk_cache(self, cfg, kv_heads, num_slots=2):
         return PagedKVCache(num_slots=num_slots,
@@ -291,7 +290,9 @@ class TestPagedCacheParity:
         assert llama.config.n_kv_heads < llama.config.num_attention_heads
         self._check(llama, llama.config, llama.config.n_kv_heads)
 
-    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
+    # one case: ``[paged]`` is the id earlier PRs' records know it by (its
+    # dense twin is tests/test_serving.py::test_slot_reuse_after_retire)
+    @pytest.mark.parametrize("layout", ["paged"])
     def test_slot_churn_parity(self, gpt, llama, layout):
         """Retire then re-admit into the SAME slot: cached decode logits
         must match the full-recompute reference for GPT and GQA-Llama —
@@ -304,49 +305,19 @@ class TestPagedCacheParity:
             rs = np.random.RandomState(7)
             long_p = rs.randint(0, cfg.vocab_size, (12,)).tolist()
             short_p = rs.randint(0, cfg.vocab_size, (4,)).tolist()
-            if layout == "paged":
-                cache = self._mk_cache(cfg, kv_heads)
-                for prompt in (long_p, short_p):   # longer tenant first
-                    got, ids = _paged_generate(
-                        model, cfg, kv_heads, prompt, 3, slot=1,
-                        cache=cache, bucket=16)
-                    L = len(prompt)
-                    ref = _full_logits(model, (prompt + ids)[:-1])
-                    for i, sl in enumerate(got):
-                        np.testing.assert_allclose(
-                            sl, ref[L - 1 + i], atol=2e-4, rtol=2e-4)
-                    _assert_greedy_chain(model, prompt, ids)
-                    cache.release_slot(1)          # retire: churn the slot
-                assert cache.check_invariants() == []
-            else:
-                cache = KVCache(num_slots=2,
-                                num_layers=cfg.num_hidden_layers,
-                                max_seq=32, num_kv_heads=kv_heads,
-                                head_dim=cfg.head_dim)
-                for prompt in (long_p, short_p):
-                    L = len(prompt)
-                    ids = np.zeros((1, 16), np.int64)
-                    ids[0, :L] = prompt
-                    with paddle.no_grad():
-                        ctx = CacheContext(
-                            cache, "prefill",
-                            slot=paddle.to_tensor(np.int32(1)),
-                            length=paddle.to_tensor(np.int32(L)))
-                        out = model(paddle.to_tensor(ids), cache_ctx=ctx)
-                        cache.set_length(1, L)
-                        seq = list(prompt) + \
-                            [int(np.argmax(out.numpy()[0, L - 1]))]
-                        act = paddle.to_tensor(np.asarray([0, 1], np.int32))
-                        for _ in range(3):
-                            toks = np.zeros((2, 1), np.int64)
-                            toks[1, 0] = seq[-1]
-                            dctx = CacheContext(cache, "decode", active=act)
-                            lg = model(paddle.to_tensor(toks),
-                                       cache_ctx=dctx)
-                            cache.advance(act)
-                            seq.append(int(np.argmax(lg.numpy()[1, 0])))
-                    _assert_greedy_chain(model, prompt, seq[L:])
-                    cache.reset()                  # retire: churn the slot
+            cache = self._mk_cache(cfg, kv_heads)
+            for prompt in (long_p, short_p):       # longer tenant first
+                got, ids = _paged_generate(
+                    model, cfg, kv_heads, prompt, 3, slot=1,
+                    cache=cache, bucket=16)
+                L = len(prompt)
+                ref = _full_logits(model, (prompt + ids)[:-1])
+                for i, sl in enumerate(got):
+                    np.testing.assert_allclose(
+                        sl, ref[L - 1 + i], atol=2e-4, rtol=2e-4)
+                _assert_greedy_chain(model, prompt, ids)
+                cache.release_slot(1)              # retire: churn the slot
+            assert cache.check_invariants() == []
 
     def test_prefix_hit_decode_bitwise_matches_no_reuse(self, gpt):
         """With a shared prefix >= 2 blocks, the cached-hit tail prefill
@@ -505,7 +476,7 @@ class TestPoolStorageForm:
         from paddle_tpu.core.autograd import no_grad
 
         eng = Engine(gpt, num_slots=2, max_seq=16, min_bucket=8,
-                     kv_layout="paged", block_size=8, num_kv_blocks=129,
+                     block_size=8, num_kv_blocks=129,
                      kernel="reference")
         req = eng.add_request(list(range(5)), max_new_tokens=2)
         eng.run()
@@ -528,7 +499,7 @@ class TestPagedEngine:
     @pytest.fixture(scope="class")
     def pengine(self, gpt):
         eng = Engine(gpt, num_slots=2, max_seq=16, min_bucket=8,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
         eng.warmup()
         return eng
 
@@ -661,7 +632,7 @@ class TestPagedEngine:
         must shrink the hit, not blow up admission.  Runs the engine
         EAGERLY (to_static disabled) so no extra programs compile."""
         eng = Engine(gpt, num_slots=1, max_seq=32, min_bucket=8,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
         paddle.jit.enable_to_static(False)
         try:
             base = list(range(32))
@@ -681,21 +652,18 @@ class TestPagedEngine:
 
     def test_validation_rejects_impossible_prompts(self, gpt):
         eng = Engine(gpt, num_slots=1, max_seq=16, min_bucket=8,
-                     kv_layout="paged", block_size=8, num_kv_blocks=2)
+                     block_size=8, num_kv_blocks=2)
         # bucket_for(9..16) = 16 → 2 blocks, but only 1 usable block
         with pytest.raises(ValueError, match="KV blocks"):
             eng.add_request(list(range(12)))
         with pytest.raises(ValueError, match="block_size"):
-            Engine(gpt, max_seq=16, min_bucket=4, kv_layout="paged",
-                   block_size=8)
-        with pytest.raises(ValueError, match="kv_layout"):
-            Engine(gpt, max_seq=16, kv_layout="bogus")
+            Engine(gpt, max_seq=16, min_bucket=4, block_size=8)
 
     def test_health_flips_unhealthy_on_invariant_violation(self, gpt):
         """Allocator corruption is surfaced sticky via health(), never
         silent (ISSUE 5 satellite)."""
         eng = Engine(gpt, num_slots=1, max_seq=16, min_bucket=16,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
         eng.cache.allocator._ref[2] = -1            # simulate corruption
         h = eng.health()
         assert h["state"] == "unhealthy"
@@ -706,6 +674,79 @@ class TestPagedEngine:
 
         with pytest.raises(EngineStopped):
             eng.add_request([1, 2])
+
+
+def test_an_engine_given_no_cache_arguments_is_the_paged_one(gpt):
+    """``Engine(model)`` is the configuration the benchmark measures: the
+    pool, the prefix cache and the Pallas kernels, with greedy output that
+    of the no-cache recompute."""
+    eng = Engine(gpt, max_seq=32, min_bucket=16)
+    assert isinstance(eng.cache, PagedKVCache) and eng.block_size == 16
+    assert isinstance(eng.prefix_cache, PrefixCache)
+    assert eng.kernel == eng.cache.kernel == "pallas"
+    prompt = list(range(3, 24))
+    outs = eng.generate([prompt, prompt[:18] + [7, 8]], max_new_tokens=3)
+    for p, out in zip((prompt, prompt[:18] + [7, 8]), outs):
+        _assert_greedy_chain(gpt, p, out)
+    pg = eng.stats()["paging"]
+    assert pg["kv_layout"] == "paged" and pg["kernel"] == "pallas"
+    assert pg["blocks"]["total"] == eng.cache.num_blocks
+    assert pg["prefix"]["lookups"] == 2
+
+
+@pytest.mark.parametrize("family", ["kv", "latent"])
+@pytest.mark.parametrize("kwargs,says", [
+    (dict(kv_layout="contiguous"), "contiguous layout was removed"),
+    (dict(kv_layout="bogus"), "cache is always paged"),
+    (dict(kernel="auto"), "kernel must be 'reference' or 'pallas'"),
+], ids=["contiguous", "bogus", "kernel-auto"])
+def test_there_is_no_second_layout_and_no_auto_kernel(gpt, family, kwargs,
+                                                      says):
+    from paddle_tpu.models import deepseek_v3 as dm
+
+    model = gpt if family == "kv" else \
+        dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
+    with pytest.raises(ValueError, match=says):
+        Engine(model, max_seq=32, min_bucket=16, **kwargs)
+    # the one word the benchmark's mix files still pass
+    Engine(model, max_seq=32, min_bucket=16, kv_layout="paged")
+
+
+def test_no_source_selects_a_kv_layout():
+    """Structural, over the source: nothing under ``paddle_tpu/`` reads a
+    ``kv_layout`` attribute or names the parameter, but ``Engine.__init__``
+    (its signature and its one check) and the ``"kv_layout": "paged"``
+    operators read in ``stats()``; no comparison anywhere tests a layout
+    name."""
+    import ast
+    import os
+
+    root = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "paddle_tpu")
+    uses = {}
+    for dirpath, _dirs, files in os.walk(root):
+        for name in files:
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, name)
+            with open(path) as f:
+                tree = ast.parse(f.read())
+            rel = os.path.relpath(path, root)
+            for node in ast.walk(tree):
+                if isinstance(node, ast.Attribute):
+                    assert node.attr != "kv_layout", (rel, node.lineno)
+                elif isinstance(node, (ast.Name, ast.arg, ast.keyword)):
+                    word = getattr(node, "id", None) or node.arg
+                    if word == "kv_layout":
+                        uses.setdefault(rel, []).append(
+                            type(node).__name__)
+                elif isinstance(node, ast.Compare):
+                    consts = [c.value for c in ast.walk(node)
+                              if isinstance(c, ast.Constant)]
+                    assert "contiguous" not in consts, (rel, node.lineno)
+    # the signature, the comparison, the error message's f-string
+    assert uses == {os.path.join("serving", "engine.py"):
+                    ["arg", "Name", "Name"]}, uses
 
 
 class TestShutdownReleasesPinnedBlocks:
@@ -720,7 +761,7 @@ class TestShutdownReleasesPinnedBlocks:
         prefix cache and a second mid-decode whose admission PINNED the
         cached block (refcount 2: cache + slot)."""
         eng = Engine(gpt, num_slots=2, max_seq=16, min_bucket=8,
-                     kv_layout="paged", block_size=8)
+                     block_size=8)
         eng.warmup()
         rs = np.random.RandomState(5)
         shared = rs.randint(0, 128, (8,)).tolist()      # 1 whole block

@@ -199,7 +199,8 @@ class TestEngineChurn:
     output identical to the no-cache reference generation."""
 
     def test_gpt_zero_recompile_churn_and_greedy_parity(self, gpt):
-        eng = Engine(gpt, num_slots=3, max_seq=32, min_bucket=8)
+        eng = Engine(gpt, num_slots=3, max_seq=32, min_bucket=8,
+                     block_size=8)
         assert eng.buckets == [8, 16, 32]
         eng.warmup()
         warm_misses = eng.metrics.compile_misses

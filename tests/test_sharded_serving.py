@@ -3,13 +3,14 @@
 The acceptance bar is BITWISE: an ``Engine(mesh=serving_mesh(2))`` on a
 host-device mesh (conftest forces 8 CPU devices) must produce greedy
 output identical to the single-chip engine — for GPT (MHA) and Llama
-(GQA), paged and contiguous — at zero steady-state recompiles, and every
+(GQA) — at zero steady-state recompiles, and every
 engine subsystem (speculation, preempt/resume, journal recovery, fleet
 hot swap) must survive sharding unchanged.  Mesh size 1 must degenerate
 to the unsharded engine exactly.
 
-Budget discipline: single-chip baseline outputs are computed once per
-(family, layout) and cached module-wide; every sharded engine is slim
+Budget discipline: single-chip baseline outputs (the unsharded
+``kernel="reference"`` engine: no mesh, no Pallas kernel, no draft) are
+computed once per family and cached module-wide; every sharded engine is slim
 (2 slots, ONE 16-wide prefill bucket, 3 prompts, 6 new tokens — prompt
 lengths chosen to cross a block_size=8 boundary while prompt+decode
 still fits the single bucket).  Tier-1 critical:
@@ -27,17 +28,15 @@ from paddle_tpu.serving import (
     Engine, Fleet, RequestJournal, SpecConfig, serving_mesh,
     mesh_shape_key,
 )
-from paddle_tpu.serving.sharding import (
-    KV_LAYER_SPEC, KV_POOL_SPEC, ServingShard,
-)
+from paddle_tpu.serving.sharding import KV_LAYER_SPEC, ServingShard
 
 _FAMILIES = {
     "gpt": (GPTForCausalLM, gpt_tiny),
     "llama": (LlamaForCausalLM, llama_tiny),
 }
 
-ENGINE_KW = dict(num_slots=2, max_seq=16, min_bucket=16)
-PAGED_KW = dict(kv_layout="paged", block_size=8, num_kv_blocks=24)
+ENGINE_KW = dict(num_slots=2, max_seq=16, min_bucket=16)   # one block a slot
+PAGED_KW = dict(ENGINE_KW, block_size=8, num_kv_blocks=24)
 MAX_NEW = 6
 
 _rs = np.random.RandomState(3)
@@ -62,13 +61,6 @@ def _clone(src):
     return m
 
 
-def _kw(layout):
-    kw = dict(ENGINE_KW)
-    if layout == "paged":
-        kw.update(PAGED_KW)
-    return kw
-
-
 def _assert_greedy_chain(model, prompt, out_ids):
     """``out_ids`` must BE the no-cache greedy generation for ``prompt``
     (one full causal forward per check — no extra engine warmup)."""
@@ -83,16 +75,17 @@ def _assert_greedy_chain(model, prompt, out_ids):
 
 @pytest.fixture(scope="module")
 def baseline(models):
-    """Single-chip greedy outputs, computed once per (family, layout)."""
+    """Single-chip greedy outputs of the ``kernel="reference"`` engine,
+    computed once per family."""
     cache = {}
 
-    def get(tag, layout):
-        key = (tag, layout)
-        if key not in cache:
-            eng = Engine(_clone(models[tag]), **_kw(layout))
+    def get(tag):
+        if tag not in cache:
+            eng = Engine(_clone(models[tag]), kernel="reference",
+                         **PAGED_KW)
             eng.warmup()
-            cache[key] = eng.generate(PROMPTS, max_new_tokens=MAX_NEW)
-        return cache[key]
+            cache[tag] = eng.generate(PROMPTS, max_new_tokens=MAX_NEW)
+        return cache[tag]
 
     return get
 
@@ -103,30 +96,26 @@ def baseline(models):
 
 class TestShardedParity:
     @pytest.mark.parametrize("tag", ["gpt", "llama"])
-    @pytest.mark.parametrize("layout", ["contiguous", "paged"])
+    # one case: ``[paged-*]`` are the ids earlier PRs' records know
+    @pytest.mark.parametrize("layout", ["paged"])
     def test_sharded_bitwise_parity(self, models, baseline, tag, layout):
-        """model-axis-2 greedy decode == single-chip, both layouts, MHA
-        and GQA (llama_tiny: 2 kv heads, one whole GQA group per shard),
-        with zero steady-state compile misses."""
-        eng = Engine(_clone(models[tag]), mesh=serving_mesh(2),
-                     **_kw(layout))
+        """model-axis-2 greedy decode == single-chip, MHA and GQA
+        (llama_tiny: 2 kv heads, one whole GQA group per shard), with
+        zero steady-state compile misses."""
+        eng = Engine(_clone(models[tag]), mesh=serving_mesh(2), **PAGED_KW)
         eng.warmup()
         warm = eng.metrics.compile_misses
         out = eng.generate(PROMPTS, max_new_tokens=MAX_NEW)
-        assert out == baseline(tag, layout)
+        assert out == baseline(tag)
         assert eng.metrics.compile_misses == warm
-        # the sharded state really is sharded: kv_heads (dim 3 of the
-        # contiguous pool, dim 2 of each of the paged pool's per-layer
-        # buffers) split over the model axis, every other dim whole (JAX
-        # drops the trailing Nones of the stored spec)
-        if layout == "paged":
-            bufs, want, heads = (*eng.cache.k, *eng.cache.v), KV_LAYER_SPEC, 2
-        else:
-            bufs, want, heads = (eng.cache.k, eng.cache.v), KV_POOL_SPEC, 3
-        for buf in bufs:
+        # the sharded state really is sharded: kv_heads (dim 2 of each of
+        # the pool's per-layer buffers) split over the model axis, every
+        # other dim whole (JAX drops the trailing Nones of the stored
+        # spec)
+        for buf in (*eng.cache.k, *eng.cache.v):
             spec = tuple(buf._value().sharding.spec)
-            assert tuple(want)[:len(spec)] == spec
-            assert spec[heads] == "model"
+            assert tuple(KV_LAYER_SPEC)[:len(spec)] == spec
+            assert spec[2] == "model"
         snap = eng.stats()
         assert snap["sharding"] == {"mesh_shape": "model=2",
                                     "model_parallel": 2}
@@ -138,10 +127,10 @@ class TestShardedParity:
                      **ENGINE_KW)
         eng.warmup()
         assert eng.generate(PROMPTS, max_new_tokens=MAX_NEW) == \
-            baseline("gpt", "contiguous")
+            baseline("gpt")
         # size-1 axis filters out of every spec → fully replicated state
-        assert all(s is None
-                   for s in tuple(eng.cache.k._value().sharding.spec))
+        assert all(s is None for buf in eng.cache.buffers()
+                   for s in tuple(buf._value().sharding.spec))
         assert eng.mesh_shape == "model=1"
 
     def test_sharded_speculative_decoding_parity(self, models, baseline):
@@ -159,7 +148,7 @@ class TestShardedParity:
         eng.warmup()
         warm = eng.metrics.compile_misses
         out = eng.generate(PROMPTS, max_new_tokens=MAX_NEW)
-        assert out == baseline("gpt", "contiguous")
+        assert out == baseline("gpt")
         assert eng.metrics.compile_misses == warm
 
 
@@ -175,7 +164,7 @@ class TestShardedPreemption:
         cache, scheduler) drives all shards through the episode."""
         eng = Engine(_clone(models["gpt"]), mesh=serving_mesh(2),
                      max_preemptions=2, priority_aging_s=30.0,
-                     **_kw("paged"))
+                     **PAGED_KW)
         eng.warmup()
         warm = eng.metrics.compile_misses
         rs = np.random.RandomState(5)
@@ -233,7 +222,7 @@ class TestShardedRecovery:
         e2.run()
         got = {tuple(r.prompt_ids.tolist()): r.output_ids
                for r in info["requests"]}
-        want = baseline("gpt", "contiguous")
+        want = baseline("gpt")
         assert all(got[tuple(p)] == o for p, o in zip(PROMPTS, want))
         assert e2.metrics.compile_misses == warm
 
@@ -277,7 +266,7 @@ class TestShardGroups:
         compile-miss counter on every shard group and the fleet healthy
         throughout."""
         fleet = Fleet(_clone(models["gpt"]), num_replicas=2,
-                      shards_per_group=2, **_kw("paged"))
+                      shards_per_group=2, **PAGED_KW)
         fleet.warmup()
         rs = np.random.RandomState(11)
         reqs = [fleet.submit(rs.randint(0, 128, (L,)).tolist(),

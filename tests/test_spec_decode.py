@@ -4,8 +4,8 @@ device-side accept.
 The correctness bar mirrors the rest of the serving stack:
 
 - **Greedy is bitwise.**  A speculative engine's greedy output equals
-  the non-speculative engine's, token for token — GPT and GQA-Llama,
-  contiguous and paged — because every emitted greedy token IS the
+  the non-speculative engine's, token for token — GPT and GQA-Llama —
+  because every emitted greedy token IS the
   target argmax at its position, whatever the draft proposed.  The
   multi-accept path (self-speculation: draft == target) and the
   low-accept path (independent random draft) both pin it, at ZERO
@@ -48,7 +48,7 @@ from paddle_tpu.serving.sampling import (
 
 K = 3                      # draft tokens per round in every engine here
 ENG = dict(num_slots=2, max_seq=32, min_bucket=16)
-PAGED = dict(kv_layout="paged", block_size=8)
+PAGED = dict(block_size=8)
 
 rs = np.random.RandomState(0)
 PROMPTS = [rs.randint(0, 128, (L,)).tolist() for L in (5, 13, 9, 3)]
@@ -97,16 +97,16 @@ def llama_draft():
 
 @pytest.fixture(scope="module")
 def gpt_ref(gpt):
-    """Non-speculative greedy oracle (contiguous — PR 5 pins paged ==
-    contiguous, so one reference serves both speculative layouts)."""
-    eng = Engine(gpt, **ENG)
+    """Non-speculative greedy oracle: no draft, and the jnp gather
+    attention in place of the Pallas kernels."""
+    eng = Engine(gpt, **ENG, kernel="reference")
     eng.warmup()
     return eng
 
 
 @pytest.fixture(scope="module")
 def llama_ref(llama):
-    eng = Engine(llama, **ENG)
+    eng = Engine(llama, **ENG, kernel="reference")
     eng.warmup()
     return eng
 
@@ -146,8 +146,8 @@ class TestGreedyBitwise:
         assert st["verify_steps"] == st["rounds"]
         assert st["draft_steps"] == K * st["rounds"]
 
-    def test_gpt_contiguous_self_spec_full_accept(self, gpt, gpt_ref):
-        # contiguous layout × the full-accept regime in one engine:
+    def test_gpt_self_spec_full_accept(self, gpt, gpt_ref):
+        # the full-accept regime (on one-block slots: block_size 16):
         # draft == target means (near-)every proposal is accepted — the
         # multi-token advance + draft-KV-lockstep path, still bitwise
         base = _generate(gpt_ref)
@@ -161,18 +161,15 @@ class TestGreedyBitwise:
         assert st["accept_rate"] > 0.5      # budget caps trim the tail
         assert st["mean_accepted_per_round"] > 0
 
-    def test_llama_gqa_paged_and_contiguous(self, llama, llama_draft,
-                                            llama_ref):
+    def test_llama_gqa_paged(self, llama, llama_draft, llama_ref):
         assert llama.config.n_kv_heads < llama.config.num_attention_heads
         base = _generate(llama_ref, n=8)
-        for extra in (PAGED, {}):
-            eng = Engine(llama, **ENG, **extra,
-                         speculation=SpecConfig(draft_model=llama_draft,
-                                                k=K))
-            eng.warmup()
-            m0 = eng.metrics.compile_misses
-            assert _generate(eng, n=8) == base, extra
-            assert eng.metrics.compile_misses == m0
+        eng = Engine(llama, **ENG, **PAGED,
+                     speculation=SpecConfig(draft_model=llama_draft, k=K))
+        eng.warmup()
+        m0 = eng.metrics.compile_misses
+        assert _generate(eng, n=8) == base
+        assert eng.metrics.compile_misses == m0
 
     def test_eos_mid_round_stops_like_nospec(self, gpt_ref,
                                              gpt_spec_paged):

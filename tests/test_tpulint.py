@@ -293,14 +293,48 @@ class TestShapeManifest:
         assert committed["digest"] == fresh["digest"]
 
     def test_key_space_is_buckets_plus_one_per_layout(self, fresh):
-        # plain layouts: one prefill per bucket + ONE decode; the
-        # speculative layout replaces decode with one draft prefill per
-        # bucket + ONE draft decode + ONE verify (ISSUE 15)
-        for layout, sec in fresh["configs"].items():
+        # plain and tenancy engines: one prefill per bucket + ONE decode;
+        # the speculative engine replaces decode with one draft prefill
+        # per bucket + ONE draft decode + ONE verify (ISSUE 15)
+        for variant, sec in fresh["configs"].items():
             nb = len(sec["buckets"])
-            want = 2 * nb + 2 if layout == "speculative" else nb + 1
-            assert sec["programs"] == want, layout
+            want = 2 * nb + 2 if variant == "speculative" else nb + 1
+            assert sec["programs"] == want, variant
             assert sec["closure_probe"]["escapes"] == 0
+
+    def test_no_program_moved_when_the_contiguous_section_went(self, fresh):
+        """PR 28 deleted the contiguous engine and its section: every
+        other entry is what PR 27's manifest held — cache key, lifted
+        state inputs, writes — so no paged, speculative or tenancy
+        program changed.  (Re-pin deliberately when one does.)"""
+        prefill = ["8ab0ea60f44d916e", "f43a9a2797c18d5a",
+                   "ef542b13f3ada239", "276a4b7469556f87"]
+        draft = ["6eff3b93d510e624", "7b2b604ad9f0f2f8",
+                 "6615f12fbf7c5e97", "8fd8b55eebc4a9c6"]
+        buckets = (8, 16, 32, 64)
+
+        def plain(n_state, n_writes):
+            return {**{f"prefill[b={b}]": (k, n_state, n_writes)
+                       for b, k in zip(buckets, prefill)},
+                    "decode": ("a72890e51ed409ff", n_state, n_writes)}
+
+        spec = plain(39, 7)
+        del spec["decode"]
+        spec.update({f"draft_prefill[b={b}]": (k, 33, 4)
+                     for b, k in zip(buckets, draft)})
+        spec["draft_decode"] = ("bac3a9a1fbbb88c4", 37, 6)
+        spec["verify"] = ("f1b5bda326b4336a", 75, 11)
+        at_pr27 = {"paged": plain(39, 7), "speculative": spec,
+                   "tenancy": plain(60, 8)}
+        got = {variant: {name: (e["key_sha256"], e["n_state_inputs"],
+                                e["n_writes"])
+                         for name, e in sec["entries"].items()}
+               for variant, sec in fresh["configs"].items()}
+        assert got == at_pr27
+        assert fresh["fleet"]["programs_per_replica"] == {"paged": 5}
+        assert {k: v for shape in fresh["sharded"]["mesh_shapes"].values()
+                for k, v in shape.items()} == \
+            {"paged": {"programs": 5, "keys_equal_unsharded": True}}
 
     def test_entries_are_fully_specified(self, fresh):
         for sec in fresh["configs"].values():
@@ -340,7 +374,7 @@ class TestSanitizer:
         paddle.jit.enable_to_static(False)
         try:
             yield Engine(GPTForCausalLM(gpt_tiny()), num_slots=2,
-                         max_seq=32, min_bucket=8)
+                         max_seq=32, min_bucket=8, block_size=8)
         finally:
             paddle.jit.enable_to_static(True)
 

@@ -27,7 +27,7 @@ def traced(serving_model):
     preemption is forced; aging off so ordering is explicit)."""
     tr = RequestTracer()
     eng = Engine(serving_model, num_slots=1, max_seq=32, min_bucket=8,
-                 kv_layout="paged", block_size=8, priority_aging_s=None,
+                 block_size=8, priority_aging_s=None,
                  tracer=tr)
     eng.warmup()
     return eng, tr
@@ -181,7 +181,7 @@ class TestPreemptResumeSpans:
         second admission defers and the tracer records the pressure."""
         tr = RequestTracer()
         eng = Engine(serving_model, num_slots=2, max_seq=16,
-                     min_bucket=16, kv_layout="paged", block_size=8,
+                     min_bucket=16, block_size=8,
                      num_kv_blocks=3, max_preemptions=0, tracer=tr)
         # no warmup/compile needed: admission bookkeeping happens before
         # the prefill call, and we only step once with a doomed pool

@@ -8,7 +8,8 @@ turns it into a static proof with three steps:
 
 1. **Enumerate** the compiled-program key space from config: one
    prefill program per bucket (powers of two from ``min_bucket`` to
-   ``max_seq``) plus ONE decode program, for each KV layout.  Each
+   ``max_seq``) plus ONE decode program, for each engine variant
+   (plain, speculative, tenancy).  Each
    entry is built with ``StaticFunction.get_concrete_program`` — state
    discovery runs under ``jax.eval_shape`` and ``jax.jit`` is lazy, so
    enumeration performs **zero XLA compiles**.
@@ -54,7 +55,7 @@ CANONICAL = {
     "num_slots": 4,
     "max_seq": 64,
     "min_bucket": 8,
-    "block_size": 8,        # paged layout only
+    "block_size": 8,
     "fleet_replicas": 2,    # bench fleet smoke: 2 replicas
     # speculative section (ISSUE 15): the opt-in draft/verify key set —
     # a paged engine with speculation on replaces the decode key with
@@ -140,17 +141,18 @@ def _out_shapes(prog) -> List[List]:
             for o in outs]
 
 
-def _build_engine(kv_layout: str, cfg: dict, mesh=None):
+def _build_engine(variant: str, cfg: dict, mesh=None):
+    """``variant``: ``"paged"`` (the plain engine), ``"speculative"`` or
+    ``"tenancy"`` (the same engine with that feature on)."""
     from paddle_tpu.serving import Engine, JsonArrayGrammar, SpecConfig
 
     kwargs = dict(num_slots=cfg["num_slots"], max_seq=cfg["max_seq"],
-                  min_bucket=cfg["min_bucket"], mesh=mesh)
-    if kv_layout in ("paged", "speculative", "tenancy"):
-        kwargs.update(kv_layout="paged", block_size=cfg["block_size"])
-    if kv_layout == "speculative":
+                  min_bucket=cfg["min_bucket"],
+                  block_size=cfg["block_size"], mesh=mesh)
+    if variant == "speculative":
         kwargs.update(speculation=SpecConfig(
             draft_model=cfg["spec_draft"], k=cfg["spec_k"]))
-    if kv_layout == "tenancy":
+    if variant == "tenancy":
         kwargs.update(adapters=dict(cfg["adapters"]),
                       grammars={"json": JsonArrayGrammar(**cfg["grammar"])})
     eng = Engine(Engine.resolve_model(cfg["model"]), **kwargs)
@@ -166,11 +168,8 @@ def _prefill_args(eng, bucket: int, *, L: int = 1, slot: int = 0,
     from paddle_tpu.core.tensor import to_tensor
 
     ids = np.zeros((1, bucket), dtype=np.int64)
-    args = [to_tensor(ids), to_tensor(np.int32(slot)),
-            to_tensor(np.int32(L))]
-    if eng.kv_layout == "paged":
-        args.append(to_tensor(np.int32(start)))
-    return args
+    return [to_tensor(ids), to_tensor(np.int32(slot)),
+            to_tensor(np.int32(L)), to_tensor(np.int32(start))]
 
 
 def _decode_args(eng, *, n_active: int = 0):
@@ -186,8 +185,8 @@ def _decode_args(eng, *, n_active: int = 0):
 
 
 def _draft_prefill_args(eng, bucket: int, *, L: int = 1, slot: int = 0):
-    """Draft prefill is always full-prompt + contiguous (no prefix
-    cache, no ``start``), whatever the target layout."""
+    """Draft prefill is always full-prompt into the draft's dense cache
+    (no prefix cache, no ``start``)."""
     import numpy as np
     from paddle_tpu.core.tensor import to_tensor
 
@@ -215,7 +214,7 @@ def _verify_args(eng, *, n_active: int = 0, cap: int = 1):
     return _decode_args(eng, n_active=n_active) + [to_tensor(caps)]
 
 
-def enumerate_config(kv_layout: str, cfg: dict,
+def enumerate_config(variant: str, cfg: dict,
                      mesh=None) -> Tuple[dict, dict]:
     """Build every program the config admits; returns
     ``(manifest_section, key_index)`` where ``key_index`` maps each raw
@@ -226,7 +225,7 @@ def enumerate_config(kv_layout: str, cfg: dict,
 
     from paddle_tpu.core.autograd import no_grad
 
-    eng = _build_engine(kv_layout, cfg, mesh=mesh)
+    eng = _build_engine(variant, cfg, mesh=mesh)
     entries: Dict[str, dict] = {}
     key_index: Dict[tuple, str] = {}
     mesh_ctx = eng.shard.context() if eng.shard is not None \
@@ -238,7 +237,7 @@ def enumerate_config(kv_layout: str, cfg: dict,
             plan.append(("decode", eng._decode_fn, _decode_args(eng)))
         else:
             # speculation replaces the plain decode program: draft
-            # prefill per bucket (contiguous draft cache — no start
+            # prefill per bucket (dense draft cache — no start
             # argument), ONE draft decode, ONE verify
             plan.extend(
                 (f"draft_prefill[b={b}]", eng._draft_prefill_fn,
@@ -264,22 +263,20 @@ def enumerate_config(kv_layout: str, cfg: dict,
     n_prog = sum(len(fn.program_cache) for fn in fns)
     if n_prog != len(entries):
         raise AssertionError(
-            f"{kv_layout}: enumerated {len(entries)} entries but the "
+            f"{variant}: enumerated {len(entries)} entries but the "
             f"program cache holds {n_prog} — the key space is not what "
             "the enumeration thinks it is")
     section = {
-        "engine": {"kv_layout": kv_layout, "num_slots": cfg["num_slots"],
+        "engine": {"variant": variant, "num_slots": cfg["num_slots"],
                    "max_seq": cfg["max_seq"],
                    "min_bucket": cfg["min_bucket"],
-                   **({"block_size": cfg["block_size"]}
-                      if kv_layout in ("paged", "speculative", "tenancy")
-                      else {}),
+                   "block_size": cfg["block_size"],
                    **({"spec_draft": cfg["spec_draft"],
                        "spec_k": cfg["spec_k"]}
-                      if kv_layout == "speculative" else {}),
+                      if variant == "speculative" else {}),
                    **({"adapters": dict(cfg["adapters"]),
                        "grammar": dict(cfg["grammar"])}
-                      if kv_layout == "tenancy" else {})},
+                      if variant == "tenancy" else {})},
         "buckets": list(eng.buckets),
         "programs": len(entries),
         "entries": entries,
@@ -291,9 +288,8 @@ def probe_closure(eng, key_index: Dict[tuple, str]) -> List[str]:
     """Sweep runtime argument instances and return the (hopefully empty)
     list of instances whose cache key escapes the enumerated set.
 
-    Coverage: every prompt length 1..max_seq at both slot extremes (and
-    for paged, every block-aligned prefix-hit start inside the bucket),
-    plus every decode active-mask population 0..num_slots.  Keys depend
+    Coverage: every prompt length 1..max_seq at both slot extremes with
+    every block-aligned prefix-hit start inside the bucket, plus every decode active-mask population 0..num_slots.  Keys depend
     only on shape/dtype/stop_gradient, so this sweep is exhaustive over
     everything the engine can construct at runtime."""
     from paddle_tpu.core.autograd import no_grad
@@ -302,12 +298,9 @@ def probe_closure(eng, key_index: Dict[tuple, str]) -> List[str]:
     with no_grad():
         for L in range(1, eng.max_seq + 1):
             for slot in (0, eng.num_slots - 1):
-                starts = [0]
-                if eng.kv_layout == "paged":
-                    # prefix hits shrink the tail bucket: starts are
-                    # block-aligned, tail = L - start >= 1
-                    starts = range(0, L, eng.block_size)
-                for start in starts:
+                # prefix hits shrink the tail bucket: starts are
+                # block-aligned, tail = L - start >= 1
+                for start in range(0, L, eng.block_size):
                     bucket = eng.bucket_for(L - start)
                     args = _prefill_args(eng, bucket, L=L, slot=slot,
                                          start=start)
@@ -354,20 +347,19 @@ def probe_closure(eng, key_index: Dict[tuple, str]) -> List[str]:
 
 
 def build_manifest(cfg: dict = CANONICAL) -> dict:
-    """Enumerate + probe both KV layouts; raises on any closure escape
-    (an open key space must never be written as a 'proof')."""
+    """Enumerate + probe every engine variant; raises on any closure
+    escape (an open key space must never be written as a 'proof')."""
     configs = {}
-    for layout in ("contiguous", "paged", "speculative", "tenancy"):
-        section, (eng, key_index) = enumerate_config(layout, cfg)
+    for variant in ("paged", "speculative", "tenancy"):
+        section, (eng, key_index) = enumerate_config(variant, cfg)
         escapes = probe_closure(eng, key_index)
         if escapes:
             raise AssertionError(
-                f"shape closure VIOLATED for {layout} (the compiled-key "
+                f"shape closure VIOLATED for {variant} (the compiled-key "
                 f"set is open):\n  " + "\n  ".join(escapes[:10]))
         section["closure_probe"] = {
             "prefill_instances": 2 * sum(
                 len(range(0, L, eng.block_size))
-                if layout in ("paged", "speculative", "tenancy") else 1
                 for L in range(1, eng.max_seq + 1)),
             "decode_instances": (
                 eng.num_slots + 1 if eng.spec is None
@@ -376,7 +368,7 @@ def build_manifest(cfg: dict = CANONICAL) -> dict:
                 + (eng.num_slots + 1) * (eng.spec.k + 2)),
             "escapes": 0,
         }
-        configs[layout] = section
+        configs[variant] = section
     # tenancy flatness (ISSUE 20): adapter + grammar lanes must add
     # ZERO cache keys — the tenancy section's key set is byte-identical
     # to plain paged (lanes are lifted state: values, never shapes).
@@ -399,7 +391,7 @@ def build_manifest(cfg: dict = CANONICAL) -> dict:
         name: e["n_state_inputs"]
         - configs["paged"]["entries"][name]["n_state_inputs"]
         for name, e in configs["tenancy"]["entries"].items()}
-    # sharded sections (ISSUE 18): re-enumerate the plain layouts under
+    # sharded sections (ISSUE 18): re-enumerate the plain engine under
     # each canonical serving mesh shape.  The cache key excludes
     # sharding, so every section must be the SAME closed key set — any
     # difference means a sharded engine would compile keys the
@@ -410,26 +402,18 @@ def build_manifest(cfg: dict = CANONICAL) -> dict:
 
         mesh = serving_mesh(mp)
         mkey = mesh_shape_key(mesh)
-        layouts = {}
-        for layout in ("contiguous", "paged"):
-            section, (eng, key_index) = enumerate_config(
-                layout, cfg, mesh=mesh)
-            want = configs[layout]["entries"]
-            got = section["entries"]
-            if {n: e["key_sha256"] for n, e in got.items()} != \
-                    {n: e["key_sha256"] for n, e in want.items()}:
-                raise AssertionError(
-                    f"sharded {layout} @ {mkey}: compiled-key set "
-                    "differs from the unsharded enumeration — sharding "
-                    "must never widen the key space")
-            layouts[layout] = {"programs": section["programs"],
-                               "keys_equal_unsharded": True}
-        sharded[mkey] = layouts
-    # fleet replicas serve the plain layouts (speculation is a per-
-    # engine opt-in, not a fleet default): the multiplication note
-    # covers contiguous + paged only
-    per_replica = {k: v["programs"] for k, v in configs.items()
-                   if k in ("contiguous", "paged")}
+        section, _ = enumerate_config("paged", cfg, mesh=mesh)
+        if {n: e["key_sha256"] for n, e in section["entries"].items()} \
+                != paged_keys:
+            raise AssertionError(
+                f"sharded paged @ {mkey}: compiled-key set "
+                "differs from the unsharded enumeration — sharding "
+                "must never widen the key space")
+        sharded[mkey] = {"paged": {"programs": section["programs"],
+                                   "keys_equal_unsharded": True}}
+    # fleet replicas serve the plain engine (speculation is a per-
+    # engine opt-in, not a fleet default)
+    per_replica = {"paged": configs["paged"]["programs"]}
     manifest = {
         "_comment": [
             "Shape-closure proof for the serving engine's executable",
@@ -463,8 +447,8 @@ def build_manifest(cfg: dict = CANONICAL) -> dict:
         },
     }
     manifest["digest"] = _sha(sorted(
-        (layout, name, e["key_sha256"])
-        for layout, sec in configs.items()
+        (variant, name, e["key_sha256"])
+        for variant, sec in configs.items()
         for name, e in sec["entries"].items()))
     return manifest
 
@@ -473,34 +457,34 @@ def diff_manifests(committed: dict, fresh: dict) -> List[str]:
     """Entry-level drift between the committed manifest and a fresh
     enumeration; empty when identical where it matters."""
     problems: List[str] = []
-    for layout in sorted(set(committed.get("configs", {}))
+    for variant in sorted(set(committed.get("configs", {}))
                          | set(fresh["configs"])):
-        old = committed.get("configs", {}).get(layout, {}).get("entries", {})
-        new = fresh["configs"].get(layout, {}).get("entries", {})
+        old = committed.get("configs", {}).get(variant, {}).get("entries", {})
+        new = fresh["configs"].get(variant, {}).get("entries", {})
         for name in sorted(set(old) | set(new)):
             if name not in old:
-                problems.append(f"{layout}/{name}: NEW compile key "
+                problems.append(f"{variant}/{name}: NEW compile key "
                                 f"(sha {new[name]['key_sha256']}) — not "
                                 "in the committed manifest")
             elif name not in new:
-                problems.append(f"{layout}/{name}: compile key vanished "
+                problems.append(f"{variant}/{name}: compile key vanished "
                                 "(committed but no longer enumerated)")
             elif old[name] != new[name]:
                 changed = [k for k in new[name] if old[name].get(k)
                            != new[name][k]]
-                problems.append(f"{layout}/{name}: entry changed "
+                problems.append(f"{variant}/{name}: entry changed "
                                 f"({', '.join(changed)})")
         # the section's non-entry fields (engine config, buckets,
         # closure-probe counts) are part of the proof too — a
         # hand-edited block_size or probe count must not pass
         old_sec = {k: v for k, v in committed.get("configs", {})
-                   .get(layout, {}).items() if k != "entries"}
+                   .get(variant, {}).items() if k != "entries"}
         new_sec = {k: v for k, v in fresh["configs"]
-                   .get(layout, {}).items() if k != "entries"}
+                   .get(variant, {}).items() if k != "entries"}
         if old_sec != new_sec:
             changed = [k for k in sorted(set(old_sec) | set(new_sec))
                        if old_sec.get(k) != new_sec.get(k)]
-            problems.append(f"{layout}: config section drifted "
+            problems.append(f"{variant}: config section drifted "
                             f"({', '.join(changed)})")
     for field in ("version", "model", "sharded", "fleet"):
         if committed.get(field) != fresh.get(field):
