@@ -255,7 +255,9 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
     kernels compiled for the device (interpret mode only off-TPU) and are
     in the decode program's HLO, which on the chip moves no layer buffer of
     the KV pool (no ``copy``, ``transpose`` or ``slice`` of that size,
-    both pools aliased; the one-chip engine's program only).  Greedy
+    both pools aliased; the one-chip engine's program only), the
+    ``stats()["sampler"]`` counts (a greedy-only run after the mixed one
+    counts greedy steps only).  Greedy
     outputs must equal those of a second engine over the same model with
     ``kernel="reference"``.
     ``model_parallel`` serves through ``serving_mesh(model_parallel)``,
@@ -358,6 +360,20 @@ def serve_phase(model, *, max_seq: int, num_slots: int, block_size: int,
                 decode["alias_bytes"] >= pools
                 and decode["temp_bytes"] < layer_buf,
                 (decode["alias_bytes"], decode["temp_bytes"]))
+    # the sampler's way through each decode step, as the host counts it: a
+    # step with no sampled request computes no cut-off and draws nothing
+    smp = st["sampler"]
+    say(f"  sampler steps: {smp}")
+    c.check("the sampled requests' steps are counted",
+            smp["steps_sampled"] > 0, smp)
+    for p in prompts[:2]:
+        eng.add_request(p, max_new_tokens=4)
+    eng.run()
+    after = eng.stats()["sampler"]
+    say(f"  sampler steps after a greedy-only run: {after}")
+    c.check("a greedy-only run counts greedy steps only",
+            after["steps_greedy"] > smp["steps_greedy"]
+            and after["steps_sampled"] == smp["steps_sampled"], after)
     tokens = [list(map(int, r.output_ids)) for r in reqs]
     greedy = [tokens[i] for i in greedy_idx]
     out = {"phase": name, "attention_path": path,

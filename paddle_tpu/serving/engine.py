@@ -86,7 +86,7 @@ from .kv_cache import cache_spec_of
 from .metrics import ServingMetrics
 from .paging import PagedCacheContext, PagedKVCache
 from .prefix_cache import PrefixCache
-from .sampling import DeviceSampler, SamplingParams
+from .sampling import DeviceSampler, SamplingParams, sampler_path
 from .sanitize import SyncSanitizer
 from .tracing import NULL_TRACER, FlightRecorder, RequestTracer
 
@@ -641,6 +641,9 @@ class Engine:
         #: model without experts: ``stats()`` then has no ``"moe"``)
         self._moe = {"tokens": 0, "assignments_held": 0,
                      "experts_touched": 0, "layer_steps": 0}
+        #: decode steps by the way their program went through the
+        #: sampler (``sampling.sampler_path``)
+        self._sampler_steps = {"steps_greedy": 0, "steps_sampled": 0}
         self._watchdog = None
         self._arm_counter = 0
 
@@ -721,7 +724,8 @@ class Engine:
                     pool.clear_rows()
             cache.advance(active)
             toks = sampler.sample_all(
-                logits._value()[:, -1, :].astype(jnp.float32))
+                logits._value()[:, -1, :].astype(jnp.float32),
+                active._value())
             # a model with expert layers: their load rides behind the
             # tokens, in the one array the host pulls
             return Tensor._wrap(ctx.with_expert_counts(toks))
@@ -1866,6 +1870,13 @@ class Engine:
                 # known without asking the device
                 self._step_span.attrs["decode_chunks"] = sum(
                     req._seq_len // ct + 1 for req in self.running.values())
+            # the sampler's way through this step, by the rule its program
+            # applies to the running slots' lanes
+            path = sampler_path(
+                req.sampling for req in self.running.values())
+            self._sampler_steps["steps_" + path] += 1
+            if self._step_span is not None:
+                self._step_span.attrs["sampler_path"] = path
         san = self.sanitizer
         try:
             # the compiled step itself must not round-trip to host: the
@@ -2724,6 +2735,7 @@ class Engine:
             }
         if self._moe["layer_steps"]:
             snap["moe"] = dict(self._moe)
+        snap["sampler"] = dict(self._sampler_steps)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,
                                 "model_parallel": self.shard.mp}

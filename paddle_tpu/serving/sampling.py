@@ -9,6 +9,15 @@ the next step's inputs device-side — no per-token logits pull, which is
 what drives the sanitizer's ``serving_decode_host_transfers`` baseline
 from 1.0 to 0.0 (ROADMAP item 2).
 
+Top-k and top-p are two cut-offs in the order of the tempered logits, and
+nothing sorts the vocabulary for them: each is the largest value for which
+a count (tokens at or above it) or a mass (tokens above it) still reaches
+its mark, found bit by bit in 32 fused passes over ``[N, V]``
+(:func:`_largest_key`) — exact for any ``top_k`` and ``top_p``.  A call
+whose live rows are all greedy computes no cut-off and draws nothing (one
+argmax).  ``tests/test_device_sampling.py`` keeps the full sort every call
+once paid as the oracle: same kept set, same tokens.
+
 Speculative decoding rides the same lanes:
 :meth:`DeviceSampler.accept_speculative` performs a whole round's
 rejection-sampling acceptance in-graph (greedy: accept iff draft ==
@@ -40,7 +49,8 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 
-__all__ = ["SamplingParams", "sample", "device_sample", "DeviceSampler"]
+__all__ = ["SamplingParams", "sample", "device_sample", "DeviceSampler",
+           "sampler_path"]
 
 _NEG_INF = np.float32(-1e30)
 
@@ -123,36 +133,64 @@ def sample(logits: np.ndarray, params: SamplingParams,
     return int(rng.choice(p.shape[0], p=p))
 
 
+def sampler_path(params) -> str:
+    """Which way through :func:`device_sample` a step takes whose live
+    rows hold ``params`` (an iterable of :class:`SamplingParams`), by the
+    rule the program applies to its lanes: ``"greedy"`` (no live row
+    samples: no cut-off, no draw) or ``"sampled"``."""
+    return ("sampled" if any(p.temperature > 0 for p in params)
+            else "greedy")
+
+
+def _order_keys(z):
+    """``uint32`` keys whose unsigned order is the float order of ``z``
+    (``-0.0`` counted as ``0.0``)."""
+    b = jax.lax.bitcast_convert_type(jnp.where(z == 0.0, 0.0, z),
+                                     jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def _largest_key(holds, rows: int):
+    """Per row the largest ``uint32`` ``v`` with ``holds(v)`` (``[rows]``
+    keys → ``[rows]`` bool, true up to some ``v`` and false past it; 0
+    where it never holds), built bit by bit from the top: 32 fused
+    compare-and-reduce passes, and no order over the row."""
+    def bit(i, v):
+        up = v | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
+        return jnp.where(holds(up), up, v)
+    return jax.lax.fori_loop(0, 32, bit, jnp.zeros((rows,), jnp.uint32))
+
+
 def _device_masked_logits(logits, temps, top_ks, top_ps):
     """Tempered + top-k/top-p-masked logits ``[N, V]`` — the traced
     mirror of :func:`_host_masked_logits`, vectorized per row.
 
-    One full-vocab sort total: the top-p pass reuses the descending
-    ``z_desc`` (softmax is order-preserving and the top-k rule
-    ``z >= kth`` masks the same entries in sorted order).  Rows with
-    ``top_p >= 1`` skip the nucleus mask entirely — f32 ``cumsum``
-    saturates at 1.0 under a peaked distribution, which would otherwise
-    silently truncate the tail the host oracle keeps."""
-    V = logits.shape[-1]
+    Both restrictions are cut-offs in the order of ``z``, and both are
+    searched, not sorted for: the k-th largest value is the largest ``v``
+    that ``k`` or more tokens reach (tokens tied with it all stay), and
+    the nucleus keeps a token while the mass of the tokens *above* it is
+    ``< top_p`` (always the most probable one), so its cut-off is the
+    largest ``v`` with ``top_p`` or more mass above it.  Exact for any
+    ``top_k`` and ``top_p``, in float32.  Rows with ``top_p >= 1`` skip
+    the nucleus mask entirely — an f32 sum saturates at 1.0 under a
+    peaked distribution, which would otherwise silently truncate the
+    tail the host oracle keeps."""
+    N, V = logits.shape
     z = logits / temps[:, None]
+    keys = _order_keys(z)
     k = jnp.where(top_ks > 0, jnp.clip(top_ks, 1, V), V)
-    z_desc = jnp.sort(z, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(z_desc, (k - 1)[:, None], axis=1)
-    z = jnp.where(z >= kth, z, _NEG_INF)
-    # nucleus membership is computed over the sorted masked z, and the
-    # cut is carried back as a *z-space* threshold — exact (the same
-    # float values, softmax being order-preserving), where a p-space
-    # compare against a separately-computed softmax can miss by 1 ulp
-    z_desc = jnp.where(z_desc >= kth, z_desc, _NEG_INF)
-    p_desc = jax.nn.softmax(z_desc, axis=-1)
-    csum = jnp.cumsum(p_desc, axis=-1)
-    keep_n = jnp.sum((csum - p_desc) < top_ps[:, None], axis=-1)
-    z_thr = jnp.take_along_axis(z_desc, (keep_n - 1)[:, None], axis=1)
-    return jnp.where((z >= z_thr) | (top_ps[:, None] >= 1.0),
+    kth = _largest_key(
+        lambda v: jnp.sum(keys >= v[:, None], axis=-1) >= k, N)
+    z = jnp.where(keys >= kth[:, None], z, _NEG_INF)
+    p = jax.nn.softmax(z, axis=-1)
+    cut = _largest_key(
+        lambda v: jnp.sum(jnp.where(keys > v[:, None], p, 0.0),
+                          axis=-1) >= top_ps, N)
+    return jnp.where((keys > cut[:, None]) | (top_ps[:, None] >= 1.0),
                      z, _NEG_INF)
 
 
-def device_sample(logits, temps, top_ks, top_ps, keys
+def device_sample(logits, temps, top_ks, top_ps, keys, live=None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sample one token per row, entirely on device (traced inside the
     compiled decode/prefill step).
@@ -164,22 +202,32 @@ def device_sample(logits, temps, top_ks, top_ps, keys
         top_ks: ``[N]`` int32 (``<= 0`` → unrestricted).
         top_ps: ``[N]`` float32 nucleus mass (``>= 1`` → unrestricted).
         keys:   ``[N, 2]`` uint32 per-row jax.random key state.
+        live:   ``[N]`` bool, the rows whose token is delivered (None:
+                all).  The other rows' lanes are stale, and choose
+                nothing: a call whose live rows are all greedy computes
+                no cut-off and draws nothing.
 
     Returns:
         ``(tokens [N] int32, new_keys [N, 2] uint32)`` — keys advance
-        once per call, so a re-seeded slot replays the same stream
-        (the preempt/resume determinism contract).
+        once per call for every row, outside any branch, so a re-seeded
+        slot replays the same stream whoever shares its batch (the
+        preempt/resume determinism contract).
     """
     logits = logits.astype(jnp.float32)
     greedy = temps <= 0.0
-    z = _device_masked_logits(logits, jnp.where(greedy, 1.0, temps),
-                              top_ks, top_ps)
+    sampled = ~greedy if live is None else live & ~greedy
     split = jax.vmap(jax.random.split)(keys)         # [N, 2, 2]
     new_keys, subkeys = split[:, 0], split[:, 1]
-    drawn = jax.vmap(jax.random.categorical)(subkeys, z)
-    tokens = jnp.where(greedy, jnp.argmax(logits, axis=-1),
-                       drawn).astype(jnp.int32)
-    return tokens, new_keys
+    top = jnp.argmax(logits, axis=-1)
+
+    def draw():
+        z = _device_masked_logits(logits, jnp.where(greedy, 1.0, temps),
+                                  top_ks, top_ps)
+        drawn = jax.vmap(jax.random.categorical)(subkeys, z)
+        return jnp.where(greedy, top, drawn)
+
+    tokens = jax.lax.cond(jnp.any(sampled), draw, lambda: top)
+    return tokens.astype(jnp.int32), new_keys
 
 
 class DeviceSampler:
@@ -308,11 +356,14 @@ class DeviceSampler:
         return tok[0]
 
     @jax.named_scope("sampler.sample")
-    def sample_all(self, logits):
+    def sample_all(self, logits, active):
         """Decode-side: sample every slot from ``[slots, V]`` logits;
         advances every key lane and rewrites the token lane (idle slots
         sample garbage that is never delivered — their lanes re-seed at
-        the next admission)."""
+        the next admission).  ``active`` is the step's ``[slots]`` mask of
+        running slots: an idle slot keeps the lanes of the request that
+        left it, and they must not choose the step's path
+        (:func:`device_sample`'s ``live``)."""
         logits = logits.astype(jnp.float32)
         if self.grammar is not None:
             gids = self.grammar_ids._value()
@@ -321,7 +372,7 @@ class DeviceSampler:
         toks, new_keys = device_sample(
             logits, self.temps._value(),
             self.top_ks._value(), self.top_ps._value(),
-            self.keys._value())
+            self.keys._value(), live=active > 0)
         self.keys._set_data(new_keys)
         self.tokens._set_data(toks)
         if self.grammar is not None:

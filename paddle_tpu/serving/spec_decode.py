@@ -230,7 +230,8 @@ class SpecState:
             logits = spec.model(tokens, cache_ctx=ctx)
             spec.cache.advance(active)
             prop = spec.sampler.sample_all(
-                logits._value()[:, -1, :].astype(jnp.float32))
+                logits._value()[:, -1, :].astype(jnp.float32),
+                active._value())
             jcol = j._value().astype(jnp.int32).reshape(())
             spec.proposals._set_data(jax.lax.dynamic_update_slice(
                 spec.proposals._value(), prop[:, None],

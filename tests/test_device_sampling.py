@@ -19,6 +19,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.serving import sampling
 from paddle_tpu.serving.sampling import (
     DeviceSampler, SamplingParams, device_sample, sample,
 )
@@ -219,3 +220,234 @@ class TestDeviceSampler:
         eng.run()
         assert a.output_ids == b.output_ids
         assert all(0 <= t < 128 for t in a.output_ids)
+
+
+# -- ISSUE 29: cut-offs searched, not sorted for ------------------------------
+#
+# The oracle is the sampler as it was before: one sort of the whole
+# vocabulary for every row of every call.  The sampler now searches both
+# cut-offs (the largest value a count or a mass still reaches its mark at,
+# bit by bit), and computes none where no live row samples; neither may
+# change a token.
+
+def _oracle_masked_logits(logits, temps, top_ks, top_ps):
+    """``_device_masked_logits`` as PR 28 had it, plus the distance of the
+    nucleus' compare from its cut: ``min |csum - p - top_p|`` of each
+    row."""
+    V = logits.shape[-1]
+    z = logits / temps[:, None]
+    k = jnp.where(top_ks > 0, jnp.clip(top_ks, 1, V), V)
+    z_desc = jnp.sort(z, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(z_desc, (k - 1)[:, None], axis=1)
+    z = jnp.where(z >= kth, z, sampling._NEG_INF)
+    z_desc = jnp.where(z_desc >= kth, z_desc, sampling._NEG_INF)
+    p_desc = jax.nn.softmax(z_desc, axis=-1)
+    csum = jnp.cumsum(p_desc, axis=-1)
+    keep_n = jnp.sum((csum - p_desc) < top_ps[:, None], axis=-1)
+    z_thr = jnp.take_along_axis(z_desc, (keep_n - 1)[:, None], axis=1)
+    margin = jnp.min(jnp.abs(csum - p_desc - top_ps[:, None]), axis=-1)
+    return jnp.where((z >= z_thr) | (top_ps[:, None] >= 1.0),
+                     z, sampling._NEG_INF), margin
+
+
+@jax.jit
+def _oracle_sample(logits, temps, top_ks, top_ps, keys):
+    """``device_sample`` as PR 28 had it."""
+    logits = logits.astype(jnp.float32)
+    greedy = temps <= 0.0
+    z, _ = _oracle_masked_logits(logits, jnp.where(greedy, 1.0, temps),
+                                 top_ks, top_ps)
+    split = jax.vmap(jax.random.split)(keys)
+    new_keys, subkeys = split[:, 0], split[:, 1]
+    drawn = jax.vmap(jax.random.categorical)(subkeys, z)
+    tokens = jnp.where(greedy, jnp.argmax(logits, axis=-1),
+                       drawn).astype(jnp.int32)
+    return tokens, new_keys
+
+
+_oracle_masked = jax.jit(_oracle_masked_logits)
+_new_masked = jax.jit(sampling._device_masked_logits)
+_new_sample = jax.jit(device_sample)
+
+VOCAB = 50304
+#: The nucleus compares a float32 sum of probabilities with ``top_p``; the
+#: search adds them in another order than the sort's running sum did, so
+#: the two may differ in the sum's last bits — by the error of adding V
+#: numbers pairwise, ``log2(V)`` ulp.  Only a row whose compare the oracle
+#: decides by less than that may come out with another kept set, and the
+#: tests say how many do: none on these seeds.
+CUT_ULPS = 16
+
+
+@pytest.fixture(scope="module")
+def rows():
+    """Seeded ``f32[8, 50304]`` logits, flat to peaked."""
+    rs = np.random.RandomState(29)
+    scale = np.asarray([0.5, 1, 2, 3, 4, 6, 8, 10], np.float32)[:, None]
+    return jnp.asarray(rs.randn(8, VOCAB).astype(np.float32) * scale)
+
+
+def _lanes(n, temp, top_k, top_p):
+    return (jnp.full((n,), temp, jnp.float32),
+            jnp.full((n,), top_k, jnp.int32),
+            jnp.full((n,), top_p, jnp.float32))
+
+
+def _kept(z):
+    return np.asarray(z) > -1e29
+
+
+def _far_from_the_cut(margin, top_p):
+    return (np.asarray(margin) > CUT_ULPS * np.spacing(np.float32(top_p))) \
+        | (top_p >= 1.0)
+
+
+@pytest.mark.parametrize("temp", [0.0, 0.8])
+@pytest.mark.parametrize("top_p", [0.5, 0.9, 1.0])
+@pytest.mark.parametrize("top_k", [0, 1, 3, 50, 128, 129, 4000])
+def test_kept_set_tokens_and_keys_equal_the_full_sorts(rows, top_k, top_p,
+                                                       temp):
+    temps, top_ks, top_ps = _lanes(8, temp, top_k, top_p)
+    tempered = jnp.where(temps <= 0, 1.0, temps)
+    want, margin = _oracle_masked(rows, tempered, top_ks, top_ps)
+    got = _new_masked(rows, tempered, top_ks, top_ps)
+    differ = (np.asarray(got) != np.asarray(want)).any(axis=1)
+    assert not (differ & _far_from_the_cut(margin, top_p)).any()
+    assert differ.sum() == 0                 # and none does on these seeds
+    keys = _keys(8, base=top_k)
+    toks, new_keys = _new_sample(rows, temps, top_ks, top_ps, keys)
+    o_toks, o_keys = _oracle_sample(rows, temps, top_ks, top_ps, keys)
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(o_toks))
+    np.testing.assert_array_equal(np.asarray(new_keys), np.asarray(o_keys))
+
+
+@pytest.mark.parametrize("tied", [3, 200])
+def test_ties_at_the_kth_value_keep_every_tied_token(tied):
+    """``z >= kth`` keeps every token tied with the k-th, three or two
+    hundred, and they all weigh in the nucleus."""
+    row = np.full((1, VOCAB), -4.0, np.float32)
+    row[0, [7, 9]] = [2.0, 1.5]
+    row[0, 100:100 + tied] = 1.0             # the 3rd value, `tied` times
+    lanes = _lanes(1, 1.0, 3, 0.9)
+    got = _kept(_new_masked(jnp.asarray(row), *lanes))[0]
+    want = _kept(_oracle_masked(jnp.asarray(row), *lanes)[0])[0]
+    assert got.sum() == 2 + tied and (got == want).all()
+    # a nucleus that ends above three tied tokens drops them all; two
+    # hundred carry the mass, and stay whole
+    lanes = _lanes(1, 1.0, 3, 0.3)
+    got = _kept(_new_masked(jnp.asarray(row), *lanes))[0]
+    want = _kept(_oracle_masked(jnp.asarray(row), *lanes)[0])[0]
+    assert (got == want).all() and got.sum() == (1 if tied == 3 else 202)
+    # without the nucleus the tie is kept whole too
+    lanes = _lanes(1, 1.0, 3, 1.0)
+    assert _kept(_new_masked(jnp.asarray(row), *lanes))[0].sum() == 2 + tied
+
+
+def test_signed_zeros_are_one_value():
+    """``-0.0 >= 0.0`` in the sort's order; the search's integer keys have
+    to agree."""
+    row = np.full((1, 256), -3.0, np.float32)
+    row[0, :4] = [0.0, -0.0, 1.0, -0.0]
+    for lanes in (_lanes(1, 1.0, 2, 1.0), _lanes(1, 1.0, 0, 0.7)):
+        got = _new_masked(jnp.asarray(row), *lanes)
+        want, _ = _oracle_masked(jnp.asarray(row), *lanes)
+        np.testing.assert_array_equal(_kept(got), _kept(want))
+        assert _kept(got)[0, :4].all()
+
+
+def test_a_row_with_fewer_than_k_legal_tokens(rows):
+    """A grammar mask puts ``-1e30`` into a row before the sampler sees it:
+    with 5 legal tokens and ``top_k`` 50 the k-th value is ``-1e30 / temp``,
+    as it was."""
+    legal = [3, 77, 1000, 20000, 50303]
+    row = np.full((1, VOCAB), sampling._NEG_INF, np.float32)
+    row[0, legal] = np.asarray(rows)[0, legal]
+    lanes = _lanes(1, 0.8, 50, 0.9)
+    want, _ = _oracle_masked(jnp.asarray(row), *lanes)
+    got = _new_masked(jnp.asarray(row), *lanes)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert set(np.flatnonzero(np.asarray(got)[0] > -1e29)) <= set(legal)
+    for base in range(4):
+        toks, _ = _new_sample(jnp.asarray(row), *lanes, _keys(1, base))
+        o_toks, _ = _oracle_sample(jnp.asarray(row), *lanes, _keys(1, base))
+        assert int(toks[0]) == int(o_toks[0]) and int(toks[0]) in legal
+
+
+@pytest.mark.parametrize("top_p", [0.7, 1.0])
+@pytest.mark.parametrize("top_k", [0, 3, 64, 100])
+def test_a_tiny_vocabulary(top_k, top_p):
+    """``V`` 64, under every ``top_k`` a mix sends but 3."""
+    rs = np.random.RandomState(64)
+    logits = jnp.asarray((rs.randn(8, 64) * 2).astype(np.float32))
+    lanes = _lanes(8, 0.8, top_k, top_p)
+    want, _ = _oracle_masked(logits, *lanes)
+    np.testing.assert_array_equal(
+        np.asarray(_new_masked(logits, *lanes)), np.asarray(want))
+    toks, keys = _new_sample(logits, *lanes, _keys(8))
+    o_toks, o_keys = _oracle_sample(logits, *lanes, _keys(8))
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(o_toks))
+    np.testing.assert_array_equal(np.asarray(keys), np.asarray(o_keys))
+
+
+def test_an_open_nucleus_beside_them_leaves_the_other_rows_tokens(rows):
+    """A row's cut-offs are its own: one row with no ``top_k`` (the request
+    that made every step sort) changes no other row's token."""
+    temps, top_ks, top_ps = _lanes(8, 0.8, 50, 0.9)
+    keys = _keys(8, base=5)
+    alone, k_alone = _new_sample(rows, temps, top_ks, top_ps, keys)
+    beside, k_beside = _new_sample(rows, temps, top_ks.at[0].set(0),
+                                   top_ps, keys)
+    np.testing.assert_array_equal(np.asarray(beside)[1:],
+                                  np.asarray(alone)[1:])
+    np.testing.assert_array_equal(np.asarray(k_beside), np.asarray(k_alone))
+
+
+GREEDY, K50, NUCLEUS = (0.0, 0, 1.0), (0.8, 50, 0.9), (0.8, 0, 0.9)
+
+
+@pytest.fixture
+def cutoffs_run(monkeypatch):
+    """One entry for each time the cut-offs are RUN (a conditional runs one
+    of its branches)."""
+    ran = []
+    real = sampling._device_masked_logits
+
+    def spy(*args):
+        jax.debug.callback(lambda: ran.append("sampled"))
+        return real(*args)
+
+    monkeypatch.setattr(sampling, "_device_masked_logits", spy)
+    return ran
+
+
+@pytest.mark.parametrize("lanes, live, want", [
+    ([GREEDY] * 4, [1, 1, 1, 1], "greedy"),
+    ([GREEDY, K50, GREEDY, GREEDY], [1, 1, 1, 0], "sampled"),
+    ([K50, NUCLEUS, GREEDY, GREEDY], [1, 1, 1, 1], "sampled"),
+    # an idle row keeps the lanes of the request that left it
+    ([GREEDY, K50, NUCLEUS, GREEDY], [1, 0, 0, 1], "greedy"),
+    # a greedy row's top_k / top_p lanes are never read
+    ([(0.0, 0, 0.9), (0.0, 4000, 0.5), GREEDY, GREEDY], [1, 1, 1, 1],
+     "greedy"),
+], ids=["all_greedy", "top_k_50", "open_nucleus", "idle_sampled_lanes",
+        "greedy_with_lanes"])
+def test_no_cutoff_is_computed_unless_a_live_row_samples(rows, cutoffs_run,
+                                                         lanes, live, want):
+    """What the program runs, against what the host says of the same rows
+    (``sampler_path``, the ``engine.step`` span's attribute), and the live
+    rows' tokens against the full sort's."""
+    temps, top_ks, top_ps = (jnp.asarray(c, dt) for c, dt in zip(
+        zip(*lanes), (jnp.float32, jnp.int32, jnp.float32)))
+    keys = _keys(4, base=11)
+    toks, new_keys = device_sample(rows[:4], temps, top_ks, top_ps, keys,
+                                   live=jnp.asarray(live) > 0)
+    jax.effects_barrier()
+    assert (cutoffs_run or ["greedy"]) == [want]
+    assert sampling.sampler_path(
+        SamplingParams(temperature=t, top_k=k, top_p=p)
+        for (t, k, p), on in zip(lanes, live) if on) == want
+    o_toks, o_keys = _oracle_sample(rows[:4], temps, top_ks, top_ps, keys)
+    on = np.asarray(live) > 0
+    np.testing.assert_array_equal(np.asarray(toks)[on],
+                                  np.asarray(o_toks)[on])
+    np.testing.assert_array_equal(np.asarray(new_keys), np.asarray(o_keys))

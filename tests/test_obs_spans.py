@@ -257,6 +257,58 @@ def test_decode_chunks_on_the_step_span_is_the_kernels_work_list(
     assert ref._decode_chunk_tokens is None
 
 
+def test_sampler_path_on_the_step_span_is_the_way_the_program_went(
+        serving_model, monkeypatch):
+    """``sampler_path`` is told on the host from the running requests'
+    parameters; the decode program chooses from its lanes and the ``active``
+    mask it is handed.  Step by step they agree, and ``stats()["sampler"]``
+    is their sum.  The slot a sampled request leaves keeps its lanes, and
+    asks for nothing."""
+    import jax
+
+    from paddle_tpu.serving import SamplingParams, sampling
+
+    ran, real_masked = [], sampling._device_masked_logits
+
+    def spy(*args):
+        jax.debug.callback(lambda: ran.append("sampled"))
+        return real_masked(*args)
+
+    monkeypatch.setattr(sampling, "_device_masked_logits", spy)
+    eng = Engine(serving_model, num_slots=4, max_seq=64, min_bucket=8,
+                 block_size=8)
+    eng.warmup()
+    assert eng.stats()["sampler"] == {"steps_greedy": 0, "steps_sampled": 0}
+    device, real = [], eng._step_call
+
+    def told(point, fn, *args, **kw):
+        if point != "serving.decode":
+            return real(point, fn, *args, **kw)
+        del ran[:]
+        out = real(point, fn, *args, **kw)
+        out.numpy()                          # the step's callbacks have run
+        jax.effects_barrier()
+        device.append((ran or ["greedy"])[0])
+        return out
+
+    monkeypatch.setattr(eng, "_step_call", told)
+    rng = np.random.default_rng(2)
+    t = spans.clock()
+    # a decode step less than max_new_tokens each: the first is the prefill's
+    reqs = [eng.add_request(rng.integers(1, 100, (9,)), max_new_tokens=n,
+                            sampling=sp) for n, sp in (
+        (8, None),
+        (5, SamplingParams(temperature=0.8, top_k=50, top_p=0.9, seed=1)),
+        (3, SamplingParams(temperature=0.8, top_p=0.9, seed=2)))]
+    eng.run()
+    assert all(r.finished for r in reqs)
+    steps = [r[ATTRS] for r in rows_since(t) if r[NAME] == "engine.step"]
+    host = [a["sampler_path"] for a in steps if "sampler_path" in a]
+    assert host == device == ["sampled"] * 4 + ["greedy"] * 3
+    assert eng.stats()["sampler"] == {"steps_greedy": 3, "steps_sampled": 4}
+    assert eng.flight.peek("x")["events"][-1]["sampler_path"] == "greedy"
+
+
 def test_admit_spans_carry_the_request(engine_run):
     _eng, rows, reqs = engine_run
     kids = kids_of(rows)
@@ -596,6 +648,30 @@ def test_engine_programs_move_no_layer_buffer_of_the_pool_on_the_chip(
     assert chip_smoke.pool_sized_moves(hlo, layer_buf) == []
     assert mem.alias_size_in_bytes >= pools
     assert mem.temp_size_in_bytes < layer_buf
+
+
+def vocab_sorts(hlo: str, vocab: int) -> list:
+    """The ``sort`` instructions of an optimized HLO module that order rows
+    ``vocab`` wide, and the selections (``TopK``) the chip sorts them for."""
+    return [ln.strip()[:120] for ln in hlo.splitlines()
+            if re.search(rf"\[(\d+,)*{vocab}\]\S*\)? sort\(", ln)
+            or 'custom_call_target="TopK"' in ln]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_programs_do_not_sort_the_vocabulary(one_chip, program):
+    """The sampler searches its two cut-offs in fused passes over
+    ``[slots, V]``; until PR 29 every decode step sorted those rows (half
+    of the chat cell's device step).  A program that sorts them is told."""
+    import jax
+    import jax.numpy as jnp
+
+    plain = jax.jit(lambda z: jnp.sort(z, axis=-1)).lower(
+        jax.ShapeDtypeStruct((8, 4096), jnp.float32,
+                             sharding=one_chip)).compile().as_text()
+    assert len(vocab_sorts(plain, 4096)) == 1
+    _eng, compiled = engine_program(one_chip, program)
+    assert vocab_sorts(compiled.as_text(), 50304) == []
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
