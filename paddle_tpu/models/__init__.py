@@ -18,3 +18,6 @@ from .llama import (
 from .deepseek_v3 import (
     DeepseekV3Config, DeepseekV3ForCausalLM, deepseek_v3_tiny,
 )
+from .keye_vl2 import (
+    KeyeVL2Config, KeyeVL2ForCausalLM, keye_vl2_tiny,
+)

@@ -49,19 +49,16 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..core import rng as rng_mod
-from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from ..nn.layer_base import Layer
 from ..nn.layer.container import LayerList
+from .held_experts import (EXPERTS_SCOPE, F32, ROUTE_SCOPE,  # noqa: F401
+                           held_experts_forward, _interpret, _Normal, _rms,
+                           _swiglu)
 
-#: named scopes of this family's work in a compiled program's op names
-ROUTE_SCOPE = "moe.route"
-EXPERTS_SCOPE = "moe.experts"
+#: named scope of this family's own work in a compiled program's op names
 ABSORB_SCOPE = "mla.absorb"
-
-F32 = jnp.float32
 
 
 @dataclass
@@ -117,33 +114,6 @@ def deepseek_v3_tiny(**kw) -> DeepseekV3Config:
     return DeepseekV3Config(**kw)
 
 
-class _Normal(I.Initializer):
-    """normal(0, std) drawn in the dtype it is asked for."""
-
-    def __init__(self, std: float):
-        self.std = std
-
-    def __call__(self, shape, dtype):
-        dt = dtype_mod.convert_dtype(dtype)
-        return jax.random.normal(rng_mod.next_key(), tuple(shape), dt) \
-            * jnp.asarray(self.std, dt)
-
-
-def _interpret() -> bool:
-    from ..ops.pallas import use_pallas
-
-    return not use_pallas()
-
-
-def _rms(x, g, eps):
-    """RMSNorm in float32; the result in the gain's dtype (the dtype the
-    next matmul's weights are in)."""
-    x32 = x.astype(F32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                            + eps)
-    return (y * g.astype(F32)).astype(g.dtype)
-
-
 def _rope(x, pos, theta: float):
     """Interleaved rotary on ``x [B, S, ..., D]`` at positions ``pos [B, S]``:
     pairs ``(2i, 2i+1)`` to halves, then rotate-half; float32 inside, the
@@ -157,15 +127,6 @@ def _rope(x, pos, theta: float):
     x1, x2 = x32[..., 0::2], x32[..., 1::2]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
-
-
-def _swiglu(x, w_gu, w_d):
-    """``(silu(x W_g) * x W_u) W_d``; the result float32 (it is added to the
-    residual stream)."""
-    gu = jnp.dot(x, w_gu)
-    f = gu.shape[-1] // 2
-    return jnp.dot(jax.nn.silu(gu[..., :f]) * gu[..., f:], w_d,
-                   preferred_element_type=F32)
 
 
 def causal_latent_attention(q_lat, lat, *, scale: float, dv: int):
@@ -278,36 +239,6 @@ def route(x, w_r, bias, *, top_k: int, scale: float):
     _, chosen = jax.lax.top_k(s + bias.astype(F32)[None, :], top_k)
     w = jnp.take_along_axis(s, chosen, axis=1)
     return chosen, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
-
-
-def held_experts_forward(x, chosen, weights, live, w_gu, w_d, *,
-                         held: Tuple[int, int], interpret: bool):
-    """The held experts' part of the layer's output for ``x [T, h]``
-    (float32), and the load: ``(y [T, h], assignments_held,
-    experts_touched)``.  Every
-    assignment of a live token to a held expert is computed; the others
-    add nothing."""
-    from ..ops.pallas.moe_kernel import moe_grouped_matmul
-
-    T, k = chosen.shape
-    G = held[1] - held[0]
-    local = chosen - held[0]
-    mine = (local >= 0) & (local < G) & live[:, None]
-    key = jnp.where(mine, local, G).reshape(-1)       # G = "not here", last
-    order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)[:G]
-    rows = jnp.take(x, order // k, axis=0)            # [T*k, h] by expert
-    gu = moe_grouped_matmul(rows, w_gu, sizes, interpret=interpret)
-    f = gu.shape[-1] // 2
-    y = moe_grouped_matmul(jax.nn.silu(gu[:, :f]) * gu[:, f:], w_d, sizes,
-                           interpret=interpret)
-    # back to token order: row ``back[t * k + j]`` is token t's j-th choice
-    back = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    y = jnp.take(y, back, axis=0).reshape(T, k, -1)
-    y = jnp.einsum("tkh,tk->th", y.astype(F32),
-                   jnp.where(mine, weights, 0.0))
-    return y, jnp.sum(sizes), jnp.sum(sizes > 0)
 
 
 class DeepseekV3MoE(Layer):

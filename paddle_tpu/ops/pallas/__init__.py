@@ -207,7 +207,8 @@ def rotary_embedding(q, k, cos, sin, position_ids=None):
     ``position_ids`` (``[B, S]`` int, optional) selects per-token rows of
     the cos/sin tables instead of assuming positions ``0..S-1`` — the
     position-offset path KV-cache decode needs (each slot's single query
-    token sits at that slot's own sequence offset).
+    token sits at that slot's own sequence offset).  The rotation is
+    computed in the tables' float32 and returned in the inputs' dtype.
     """
 
     def _rot(x):
@@ -221,7 +222,7 @@ def rotary_embedding(q, k, cos, sin, position_ids=None):
             s_b = s[pos][:, :, None, :]
             q_out = qa * c_b + _rot(qa) * s_b
             k_out = ka * c_b + _rot(ka) * s_b
-            return q_out, k_out
+            return q_out.astype(qa.dtype), k_out.astype(ka.dtype)
 
         return apply_op("rotary_embedding", _primal_pos,
                         [q, k, cos, sin, position_ids], n_outs=2)
@@ -232,6 +233,9 @@ def rotary_embedding(q, k, cos, sin, position_ids=None):
         s_b = s[None, :, None, :]
         q_out = qa * c_b + _rot(qa) * s_b
         k_out = ka * c_b + _rot(ka) * s_b
-        return q_out, k_out
+        # the float32 tables promote: back to the activations' dtype, so that
+        # bf16 queries reach the paged kernels as bf16 (float32 ones of 32
+        # heads x 128 overflowed the prefill kernel's VMEM on a v5e)
+        return q_out.astype(qa.dtype), k_out.astype(ka.dtype)
 
     return apply_op("rotary_embedding", _primal, [q, k, cos, sin], n_outs=2)

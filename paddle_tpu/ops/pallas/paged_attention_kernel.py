@@ -270,6 +270,65 @@ def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, *,
     return jnp.where((active > 0)[:, None, None, None], out, 0)
 
 
+# -- prefill write: whole blocks into a layer buffer, in place ----------------
+
+def _write_blocks_kernel(ids_ref, upd_hbm, _pool_in, pool_out, sem):
+    def copy(j):
+        return pltpu.make_async_copy(upd_hbm.at[j], pool_out.at[ids_ref[j]],
+                                     sem.at[0])
+
+    def start(j, carry):
+        copy(j).start()
+        return carry
+
+    def wait(j, carry):
+        copy(j).wait()
+        return carry
+
+    n = upd_hbm.shape[0]
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+
+
+def blocks_need_kernel_write(kv_heads: int, itemsize: int) -> bool:
+    """Whether a tail bucket's blocks are written into a layer buffer
+    ``[num_blocks, block_size, kv_heads, lanes]`` by :func:`write_blocks`
+    rather than by an XLA scatter.  Where ``kv_heads`` fills whole sublane
+    tiles (16 rows of bfloat16, 8 of float32), or is 1, XLA:TPU scatters
+    into the buffer as the kernels read it.  For a few heads (4 or 8 KV heads
+    under grouped queries) it gives its scatter a layout with the *block's
+    tokens* on the sublanes and converts the whole buffer there and back in
+    every prefill program (two copies of a layer buffer a side a layer:
+    seen in the program compiled for a v5e)."""
+    return kv_heads > 1 and kv_heads % (32 // itemsize) != 0
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def write_blocks(pool, upd, block_ids, *, interpret=False):
+    """``pool`` with its blocks ``block_ids [n]`` overwritten by ``upd [n,
+    block_size, kv_heads, lanes]``, in place (the result aliases ``pool``):
+    one DMA a block from ``upd`` to where the table says, the buffer in the
+    kernels' own row-major form on both sides of the call."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(1,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA((1,))],
+    )
+    return pl.pallas_call(
+        _write_blocks_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="kv_block_write",
+    )(block_ids.astype(jnp.int32), upd.astype(pool.dtype), pool)
+
+
 # -- fused prefill: cached prefix + causal tail in one kernel scope ---------
 
 def _prefill_kernel(row_ref, start_ref, q_ref, k_ref, v_ref, o_ref,

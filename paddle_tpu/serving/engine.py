@@ -473,19 +473,24 @@ class Engine:
             raise ValueError(
                 f"kv_layout={kv_layout!r}: the contiguous layout was "
                 f"removed and the cache is always paged (drop the argument)")
-        if spec.kind == "latent":
-            # one vector a token has no per-KV-head axis: nothing to
-            # shard by head or to verify
+        if spec.kind in ("latent", "indexed"):
+            # one vector a token has no per-KV-head axis to shard by, the
+            # three-sided pool's indexer side has none either, and neither
+            # has a form of the verify window
+            caches, no_mesh = {
+                "latent": ("caches one latent vector a token",
+                           "the latent pool has no kv_heads axis to shard"),
+                "indexed": ("caches K, V and an indexer key a token",
+                            "the indexed pool is not sharded")}[spec.kind]
             refused = [what for what, asked in (
-                ("a serving mesh of more than one device (the latent pool "
-                 "has no kv_heads axis to shard)",
+                (f"a serving mesh of more than one device ({no_mesh})",
                  mesh is not None and mesh.size > 1),
-                ("speculation= (the verify window has no latent form)",
+                (f"speculation= (the verify window has no {spec.kind} form)",
                  speculation is not None)) if asked]
             if refused:
                 raise ValueError(
-                    f"{type(model).__name__} caches one latent vector a "
-                    f"token and cannot serve with " + "; ".join(refused))
+                    f"{type(model).__name__} {caches} and cannot serve "
+                    f"with " + "; ".join(refused))
         self.kernel = kernel
         self.block_size = int(block_size)
         self.prefix_lookup_timeout_s = float(prefix_lookup_timeout_s)
@@ -641,6 +646,12 @@ class Engine:
         #: model without experts: ``stats()`` then has no ``"moe"``)
         self._moe = {"tokens": 0, "assignments_held": 0,
                      "experts_touched": 0, "layer_steps": 0}
+        #: decode-step selection of a model whose attention runs under an
+        #: indexer (zeros otherwise: ``stats()`` then has no ``"sparse"``):
+        #: tokens attended to and tokens cached, summed over running slots
+        #: and averaged over the layers
+        self._sparse = {"steps": 0, "selected": 0, "context": 0,
+                        "prefills": 0, "prefill_context": 0}
         #: decode steps by the way their program went through the
         #: sampler (``sampling.sampler_path``)
         self._sampler_steps = {"steps_greedy": 0, "steps_sampled": 0}
@@ -1537,6 +1548,16 @@ class Engine:
         try:
             with _spans.span("engine.prefill",
                              bucket=int(args[0].shape[1])) as sp:
+                topk = self.cache_spec.topk
+                if topk:
+                    # the indexed path's condition, by the program's own
+                    # rule: the tokens the tail is scored against (0: the
+                    # dense path, a prompt of ``topk`` tokens or fewer)
+                    n = len(req.prompt_ids)
+                    scored = n if n > topk else 0
+                    sp.attrs["dsa_context"] = scored
+                    self._sparse["prefills"] += scored > 0
+                    self._sparse["prefill_context"] += scored
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -1914,12 +1935,30 @@ class Engine:
         with _spans.span("engine.pull") as pull:
             toks = out.numpy()                   # [slots] int32
         now = pull.t1            # the step's latency runs on the spans' stamps
-        if len(toks) > self.num_slots:           # a model with experts
-            self._note_experts(toks[self.num_slots:])
+        extra = toks[self.num_slots:]   # what the model's layers counted
+        if self.cache_spec.kind == "indexed":
+            extra, sel = extra[:-3], extra[-3:]
+            self._note_selection(sel)
+        if len(extra):                           # a model with experts
+            self._note_experts(extra)
         with _spans.span("engine.deliver") as sp:
             ran = len(self.running)
             self._deliver_pulled(toks, now, now - t0)
             sp.attrs["retired"] = ran - len(self.running)
+
+    def _note_selection(self, counts) -> None:
+        """The decode step's selection, as its program counted it (sums over
+        the running slots and the layers): a layer's mean summed into
+        ``stats()["sparse"]``, and the step's own on its span.  Equal
+        numbers mean the dense path ran."""
+        selected, context, layers = (int(c) for c in counts)
+        selected, context = selected // layers, context // layers
+        sp = self._sparse
+        sp["steps"] += 1
+        sp["selected"] += selected
+        sp["context"] += context
+        if self._step_span is not None:
+            self._step_span.set(dsa_selected=selected, dsa_context=context)
 
     def _note_experts(self, counts) -> None:
         """The decode step's expert load, as its program counted it: summed
@@ -2735,6 +2774,8 @@ class Engine:
             }
         if self._moe["layer_steps"]:
             snap["moe"] = dict(self._moe)
+        if self.cache_spec.kind == "indexed":
+            snap["sparse"] = dict(self._sparse)
         snap["sampler"] = dict(self._sampler_steps)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,

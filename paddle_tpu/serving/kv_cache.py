@@ -59,12 +59,18 @@ class CacheSpec:
     K/V-caching model states two sides of ``(kv_heads, head_dim)``
     (``kind="kv"``); a latent-attention model states ONE side of one "head"
     whose width is the latent vector's (``kind="latent"``): key and value at
-    once, shared by every query head.  The ``kind`` names the attention
-    calls the model makes on its cache context."""
+    once, shared by every query head.  A model whose attention runs under a
+    learned indexer states THREE: K and V per KV head and the indexer's one
+    key a token (``kind="indexed"``; the third side has its own shape).  The
+    ``kind`` names the attention calls the model makes on its cache
+    context."""
 
     num_layers: int
     sides: Tuple[Tuple[int, int], ...]
     kind: str = "kv"
+    #: ``kind="indexed"``: the tokens a query keeps; a context longer than
+    #: this takes the indexed path
+    topk: int = 0
 
     @classmethod
     def kv(cls, num_layers: int, kv_heads: int, head_dim: int) -> "CacheSpec":
@@ -74,6 +80,13 @@ class CacheSpec:
     @classmethod
     def latent(cls, num_layers: int, width: int) -> "CacheSpec":
         return cls(int(num_layers), ((1, int(width)),), "latent")
+
+    @classmethod
+    def indexed(cls, num_layers: int, kv_heads: int, head_dim: int,
+                index_dim: int, topk: int) -> "CacheSpec":
+        return cls(int(num_layers),
+                   ((int(kv_heads), int(head_dim)),) * 2
+                   + ((1, int(index_dim)),), "indexed", int(topk))
 
 
 def cache_spec_of(model) -> CacheSpec:
@@ -258,6 +271,9 @@ class CacheContext:
     #: int32 scalars an expert layer reports (:meth:`note_experts`), one
     #: pair a layer, in trace order
     expert_counts: Optional[list] = None
+    #: int32 scalars an indexed-attention layer reports
+    #: (:meth:`note_selection`), one pair a layer
+    selection_counts: Optional[list] = None
 
     def __post_init__(self):
         if self.mode not in ("prefill", "decode", "verify"):
@@ -303,15 +319,25 @@ class CacheContext:
                                             keepdims=False)
 
     def with_expert_counts(self, tokens):
-        """``tokens [slots]`` followed by ``[assignments_held,
-        experts_touched, expert layers]`` of this call when the model has
-        expert layers; ``tokens`` as they are when it has none."""
-        if not self.expert_counts:
-            return tokens
-        held, touched = (sum(c) for c in zip(*self.expert_counts))
-        return jnp.concatenate([tokens, jnp.stack([
-            held, touched, jnp.int32(len(self.expert_counts))
-        ]).astype(tokens.dtype)])
+        """``tokens [slots]`` followed by what the model's layers counted in
+        this call: ``[assignments_held, experts_touched, expert layers]``
+        when it has expert layers, then ``[selected, context, indexed
+        layers]`` when its attention runs under an indexer; ``tokens`` as
+        they are when it has neither."""
+        for counts in (self.expert_counts, self.selection_counts):
+            if counts:
+                a, b = (sum(c) for c in zip(*counts))
+                tokens = jnp.concatenate([tokens, jnp.stack(
+                    [a, b, jnp.int32(len(counts))]).astype(tokens.dtype)])
+        return tokens
+
+    def note_selection(self, selected, context) -> None:
+        """An indexed-attention layer's decode step (traced int32 scalars):
+        the tokens its running slots attended to, and the tokens they had
+        cached."""
+        if self.selection_counts is None:
+            self.selection_counts = []
+        self.selection_counts.append((selected, context))
 
     def note_experts(self, assignments_held, experts_touched) -> None:
         """An expert layer's load in this call (traced int32 scalars): the

@@ -47,6 +47,8 @@ from ..ops.cached_attention import (
     block_prefill_attention, cached_attention, gather_block_kv,
     paged_decode_attention, paged_prefill_attention, verify_attention,
 )
+from ..ops.pallas.paged_attention_kernel import (
+    blocks_need_kernel_write, write_blocks)
 from .kv_cache import CacheContext, _as_i32
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheContext",
@@ -450,10 +452,11 @@ class PagedKVCache:
 
     # -- traced state ops (CacheContext surface) --------------------------
 
-    def _to_lanes(self, upd, dtype):
+    def _to_lanes(self, upd, dtype, lanes: Optional[int] = None):
         """New entries ``[..., heads, width]`` in the pool's dtype and lane
-        width."""
-        pad = [(0, 0)] * (upd.ndim - 1) + [(0, self.lane_dim - upd.shape[-1])]
+        width (``lanes``: a side's own, where the sides differ)."""
+        lanes = self.lane_dim if lanes is None else lanes
+        pad = [(0, 0)] * (upd.ndim - 1) + [(0, lanes - upd.shape[-1])]
         return jnp.pad(upd.astype(dtype), pad)
 
     def _layer(self, layer_idx: int) -> List[Tensor]:
@@ -488,10 +491,16 @@ class PagedKVCache:
         block_ids = jax.lax.dynamic_slice_in_dim(row, st // bs, n_blocks)
         for buf, new in zip(self._layer(layer_idx), news):
             arr = buf._value()
-            upd = self._to_lanes(new._value()[0], arr.dtype)  # [S, Hkv, Dp]
+            upd = self._to_lanes(new._value()[0], arr.dtype,
+                                 arr.shape[-1])             # [S, Hkv, Dp]
             upd = upd.reshape(n_blocks, bs, *upd.shape[1:])
             with jax.named_scope(KV_WRITE_SCOPE):
-                buf._set_data(arr.at[block_ids].set(upd))
+                if self.kernel == "pallas" and blocks_need_kernel_write(
+                        arr.shape[2], arr.dtype.itemsize):
+                    buf._set_data(write_blocks(arr, upd, block_ids,
+                                               interpret=self._interpret))
+                else:
+                    buf._set_data(arr.at[block_ids].set(upd))
 
     def set_length(self, slot, length) -> None:
         s = _as_i32(slot).reshape(())
@@ -514,7 +523,8 @@ class PagedKVCache:
         layers = []
         for buf, new in zip(self._layer(layer_idx), news):
             arr = buf._value()
-            upd = self._to_lanes(new._value()[:, 0], arr.dtype)
+            upd = self._to_lanes(new._value()[:, 0], arr.dtype,
+                                 arr.shape[-1])
             with jax.named_scope(KV_WRITE_SCOPE):
                 arr = arr.at[block_ids, off].set(upd)
             buf._set_data(arr)
@@ -572,37 +582,139 @@ class PagedKVCache:
                                 dv=dv)
         return Tensor._wrap(out[None])
 
-    def decode_write(self, layer_idx: int, k, v
-                     ) -> Tuple[Tensor, Tensor, Tensor]:
-        """Reference decode read: token write, then gather each slot's
-        sequence back contiguous — the same ``([slots, T, Hkv, D],
-        lengths)`` triple the dense cache hands
-        ``ops.cached_attention``, with ``T = max_blocks_per_slot *
-        block_size``."""
-        k_layer, v_layer, tbl, lens = self._decode_token_write(
-            layer_idx, k, v)
-        return (Tensor._wrap(self.gather(k_layer, tbl)),
-                Tensor._wrap(self.gather(v_layer, tbl)),
-                Tensor._wrap(lens))
+    # -- the indexed pool's calls (K, V and the indexer's key) ---------------
+
+    def indexed_prefill_write(self, layer_idx: int, slot, k, v, k_idx,
+                              start) -> None:
+        """``prefill_write`` of the three sides: ``k``/``v [1, S, Hkv, D]``
+        and the indexer's key ``k_idx [1, S, Di]``."""
+        self._prefill_write(
+            layer_idx, slot,
+            (k, v, Tensor._wrap(k_idx._value()[:, :, None, :])), start)
+
+    def _index_operands(self, layer_idx: int, q_idx, w):
+        """This layer's indexer pool as the kernels take it (``[num_blocks,
+        block_size, lanes]``: the one "head" dropped) and ``q_idx [..., Hi,
+        Di]`` zero-padded to its lanes, ``w`` float32."""
+        pool = self.sides[2][layer_idx]._value()
+        pool = pool.reshape(pool.shape[0], pool.shape[1], pool.shape[3])
+        return (pool, self._to_lanes(q_idx, q_idx.dtype, pool.shape[-1]),
+                w.astype(jnp.float32))
+
+    def indexed_decode_attention(self, layer_idx: int, q, k, v, q_idx, k_idx,
+                                 w, active, *, topk: int):
+        """One decode step of attention under the indexer for this layer:
+        write each slot's K, V and indexer key, then attend.  While every
+        running slot's context is ``topk`` tokens or fewer the selection is
+        everything and the step is :meth:`decode_attention`'s dense read;
+        otherwise each slot's query scores its cached indexer keys, keeps
+        the ``topk`` best, and attends over those rows only.  Returns
+        ``(out [slots, 1, H, D], selected, context)``: the tokens the
+        running slots attended to and had cached (int32 scalars)."""
+        from ..ops.pallas import dsa_attention_kernel as dsa
+
+        k_l, v_l, _i_l, tbl, lens = self._decode_token_write(
+            layer_idx, k, v, Tensor._wrap(k_idx._value()[:, :, None, :]))
+        act = _as_i32(active)
+        context = jnp.sum(jnp.where(act > 0, lens + 1, 0))
+        D = q.shape[3]
+
+        def dense():
+            return self._dense_decode_read(q, k_l, v_l, tbl, lens,
+                                           act)._value(), context
+
+        def indexed():
+            pool, qi, wi = self._index_operands(
+                layer_idx, q_idx._value()[:, 0], w._value()[:, 0])
+            out, n = dsa.indexed_decode(
+                self._to_lanes(q._value()[:, 0], q.dtype, k_l.shape[-1]), qi,
+                wi, k_l, v_l, pool, tbl, lens, act, topk=topk,
+                heads=q_idx.shape[2], scale=D ** -0.5, kernel=self.kernel,
+                interpret=self._interpret)
+            return (out[:, None, :, :D].astype(q.dtype),
+                    jnp.sum(jnp.where(act > 0, n, 0)))
+
+        longest = jnp.max(jnp.where(act > 0, lens, 0))
+        out, selected = jax.lax.cond(longest < topk, dense, indexed)
+        return Tensor._wrap(out), selected, context
+
+    def indexed_prefill_attention(self, layer_idx: int, slot, q, q_idx, w,
+                                  start, length, *, topk: int):
+        """Tail queries ``q [1, S, H, D]`` over the slot's whole block row
+        under the indexer.  A prompt of ``topk`` tokens or fewer selects
+        everything: the dense :meth:`PagedCacheContext.prefill_attention`
+        read; a longer one scores, cuts and attends under causal AND
+        selected.  Returns ``[1, S, H, D]``."""
+        from ..ops.pallas import dsa_attention_kernel as dsa
+
+        st = _as_i32(start).reshape(())
+        ln = _as_i32(length).reshape(())
+        k_l, v_l = (self.sides[i][layer_idx] for i in (0, 1))
+        row = jax.lax.dynamic_index_in_dim(
+            self.block_tables._value(), _as_i32(slot).reshape(()), axis=0,
+            keepdims=False)
+        D = q.shape[3]
+
+        def dense():
+            return self.dense_prefill_attention(
+                layer_idx, slot, q, Tensor._wrap(st))._value()
+
+        def indexed():
+            pool, qi, wi = self._index_operands(
+                layer_idx, q_idx._value()[0], w._value()[0])
+            out = dsa.indexed_prefill(
+                self._to_lanes(q._value()[0], q.dtype, k_l.shape[-1]), qi, wi,
+                k_l._value(), v_l._value(), pool, row, st, ln, topk=topk,
+                heads=q_idx.shape[2], scale=D ** -0.5, kernel=self.kernel,
+                interpret=self._interpret)
+            return out[None, :, :, :D].astype(q.dtype)
+
+        return Tensor._wrap(jax.lax.cond(ln <= topk, dense, indexed))
+
+    def dense_prefill_attention(self, layer_idx: int, slot, q, start):
+        """Tail queries ``q [1, S, H, D]`` at ``start ..`` over the slot's
+        whole block row (cached prefix + freshly-written tail) of this
+        layer's K and V under the absolute-position causal mask.
+        ``kernel="pallas"`` streams the block row through the fused
+        prefix+tail kernel instead of gathering a contiguous copy first."""
+        k_layer, v_layer = (self.sides[i][layer_idx] for i in (0, 1))
+        tbl = self.block_tables._value()
+        s = _as_i32(slot).reshape(())
+        if self.kernel == "pallas":
+            row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0,
+                                               keepdims=False)      # [MB]
+            return paged_prefill_attention(
+                q, k_layer, v_layer, Tensor._wrap(row), start,
+                interpret=self._interpret, mesh=self.mesh)
+        row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)           # [1, MB]
+        return block_prefill_attention(
+            q, Tensor._wrap(self.gather(k_layer._value(), row)),
+            Tensor._wrap(self.gather(v_layer._value(), row)), start)
 
     def decode_attention(self, layer_idx: int, q, k, v, active):
         """One decode step of attention for this layer: write the token,
-        then attend.  ``kernel="pallas"`` consumes the block table inside
-        the flash-decoding kernel (no materialized contiguous K/V), which
-        visits the ``active`` slots only and returns zero rows for the
-        others; ``"reference"`` gathers and runs the jnp oracle over every
-        slot — identical on the active rows, asserted in
+        then attend (:meth:`_dense_decode_read`)."""
+        k_layer, v_layer, tbl, lens = self._decode_token_write(
+            layer_idx, k, v)
+        return self._dense_decode_read(q, k_layer, v_layer, tbl, lens,
+                                       _as_i32(active))
+
+    def _dense_decode_read(self, q, k_layer, v_layer, tbl, lens, active):
+        """Attention of ``q [slots, 1, H, D]`` over each slot's whole window
+        in the post-write layer buffers.  ``kernel="pallas"`` consumes the
+        block table inside the flash-decoding kernel (no materialized
+        contiguous K/V), which visits the ``active`` slots only and returns
+        zero rows for the others; ``"reference"`` gathers and runs the jnp
+        oracle over every slot — identical on the active rows, asserted in
         tests/test_paged_kernel.py."""
         if self.kernel == "pallas":
-            k_layer, v_layer, tbl, lens = self._decode_token_write(
-                layer_idx, k, v)
             return paged_decode_attention(
                 q, Tensor._wrap(k_layer), Tensor._wrap(v_layer),
-                Tensor._wrap(tbl), Tensor._wrap(lens),
-                Tensor._wrap(_as_i32(active)),
+                Tensor._wrap(tbl), Tensor._wrap(lens), Tensor._wrap(active),
                 interpret=self._interpret, mesh=self.mesh)
-        k_full, v_full, lens = self.decode_write(layer_idx, k, v)
-        return cached_attention(q, k_full, v_full, lens)
+        return cached_attention(
+            q, Tensor._wrap(self.gather(k_layer, tbl)),
+            Tensor._wrap(self.gather(v_layer, tbl)), Tensor._wrap(lens))
 
     def decode_chunk_tokens(self) -> Optional[int]:
         """Tokens one work item of this pool's Pallas decode kernel attends
@@ -722,6 +834,26 @@ class PagedCacheContext(CacheContext):
         return _as_i32(self.start if self.start is not None else 0
                        ).reshape(())
 
+    # -- the indexed pool: one write and two attention calls ----------------
+
+    def write_prefill_indexed(self, k, v, k_idx) -> None:
+        self.cache.indexed_prefill_write(self.layer_idx, self.slot, k, v,
+                                         k_idx, self._prefill_start())
+
+    def indexed_prefill_attention(self, q, q_idx, w, *, topk: int):
+        return self.cache.indexed_prefill_attention(
+            self.layer_idx, self.slot, q, q_idx, w, self._prefill_start(),
+            self.length, topk=topk)
+
+    def indexed_decode_attention(self, q, k, v, q_idx, k_idx, w, *,
+                                 topk: int):
+        if self.mode != "decode":
+            raise ValueError("the indexed pool has no verify form")
+        out, selected, context = self.cache.indexed_decode_attention(
+            self.layer_idx, q, k, v, q_idx, k_idx, w, self.active, topk=topk)
+        self.note_selection(selected, context)
+        return out
+
     # -- the latent pool: one write and two attention calls -----------------
 
     def write_prefill_latent(self, lat) -> None:
@@ -761,21 +893,7 @@ class PagedCacheContext(CacheContext):
         """Tail queries attending over the slot's whole block table
         (cached prefix + freshly-written tail) with an absolute-position
         causal mask.  GQA expansion happens inside the op, like the
-        decode kernel.  ``kernel="pallas"`` streams the block row through
-        the fused prefix+tail kernel instead of gathering a contiguous
-        copy first."""
-        s = _as_i32(self.slot).reshape(())
-        tbl = self.cache.block_tables._value()
-        start = self.start if self.start is not None else 0
-        k_layer = self.cache.k[self.layer_idx]
-        v_layer = self.cache.v[self.layer_idx]
-        if self.cache.kernel == "pallas":
-            row = jax.lax.dynamic_index_in_dim(
-                tbl, s, axis=0, keepdims=False)              # [MB]
-            return paged_prefill_attention(
-                q, k_layer, v_layer, Tensor._wrap(row), start,
-                interpret=self.cache._interpret, mesh=self.cache.mesh)
-        row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)   # [1, MB]
-        k_all = Tensor._wrap(self.cache.gather(k_layer._value(), row))
-        v_all = Tensor._wrap(self.cache.gather(v_layer._value(), row))
-        return block_prefill_attention(q, k_all, v_all, start)
+        decode kernel (:meth:`PagedKVCache.dense_prefill_attention`)."""
+        return self.cache.dense_prefill_attention(
+            self.layer_idx, self.slot, q,
+            self.start if self.start is not None else 0)

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
+from ..ops.threshold_search import (largest_key as _largest_key,
+                                     order_keys as _order_keys)
 
 __all__ = ["SamplingParams", "sample", "device_sample", "DeviceSampler",
            "sampler_path"]
@@ -140,25 +142,6 @@ def sampler_path(params) -> str:
     samples: no cut-off, no draw) or ``"sampled"``."""
     return ("sampled" if any(p.temperature > 0 for p in params)
             else "greedy")
-
-
-def _order_keys(z):
-    """``uint32`` keys whose unsigned order is the float order of ``z``
-    (``-0.0`` counted as ``0.0``)."""
-    b = jax.lax.bitcast_convert_type(jnp.where(z == 0.0, 0.0, z),
-                                     jnp.uint32)
-    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
-
-
-def _largest_key(holds, rows: int):
-    """Per row the largest ``uint32`` ``v`` with ``holds(v)`` (``[rows]``
-    keys → ``[rows]`` bool, true up to some ``v`` and false past it; 0
-    where it never holds), built bit by bit from the top: 32 fused
-    compare-and-reduce passes, and no order over the row."""
-    def bit(i, v):
-        up = v | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
-        return jnp.where(holds(up), up, v)
-    return jax.lax.fori_loop(0, 32, bit, jnp.zeros((rows,), jnp.uint32))
 
 
 def _device_masked_logits(logits, temps, top_ks, top_ps):

@@ -736,3 +736,138 @@ def test_latent_pool_programs_move_no_layer_buffer_on_the_chip(
         assert re.search(r"%" + kernel + r"(\.\d+)? = ", hlo), kernel
     assert chip_smoke.pool_sized_moves(hlo, layer_buf) == []
     assert mem.alias_size_in_bytes >= pool
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_indexed_pool_programs_move_no_layer_buffer_on_the_chip(
+        one_chip, program):
+    """The same proof for the three-sided pool of a model whose attention
+    runs under an indexer: Keye-VL-2.0-30B-A3B's widths (32 query / 4 KV
+    heads of 128, indexer 16 x 64 with ``topk`` 2,048, experts of 768 with
+    16 of 128 held; two layers, vocabulary cut to 8,192), 16 slots of 8,192
+    positions, block 16, an 8,193-block pool of THREE buffers a layer (K and
+    V ``[8193, 16, 4, 128]``, the indexer's key ``[8193, 16, 1, 128]``): the
+    decode program and the bucket-512 prefill program, with the dense and the
+    indexed branch each (``paged_*_attention`` beside ``dsa_index_scores`` +
+    ``dsa_sparse_decode`` / ``dsa_sparse_prefill``) and ``kv_block_write`` as
+    the chip runs them, hold no ``copy`` / ``transpose`` / ``slice`` of a
+    layer buffer's size (four KV heads of bfloat16 do not fill a sublane
+    tile: an XLA scatter of a tail's blocks converts the whole buffer there
+    and back), alias the whole pool, sort no context-long row, and hold no
+    float32 array of the bucket by the context."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import keye_vl2 as km
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = km.KeyeVL2ForCausalLM(km.KeyeVL2Config(
+        vocab_size=8192, num_hidden_layers=2, held_experts=(0, 16),
+        max_position_embeddings=8192, dtype="bfloat16"))
+    eng = Engine(model, num_slots=16, max_seq=8192, min_bucket=512,
+                 block_size=16, kernel="pallas")
+    assert [tuple(b.shape) for b in eng.cache.buffers()] == \
+        [(8193, 16, 4, 128)] * 4 + [(8193, 16, 1, 128)] * 2
+    eng.cache._interpret = False          # the kernels as the chip runs them
+    interpret, km._interpret = km._interpret, lambda: False
+    try:
+        eng._build_steps()
+        if program == "decode":
+            fn, args = eng._decode_fn, [np.zeros((16,), np.int32)]
+        else:
+            fn, args = eng._prefill_fn, [np.zeros((1, 512), np.int64),
+                                         np.int32(0), np.int32(1), np.int32(0)]
+            assert eng.cache.begin_sequence(0, [], 0, 512)
+        leaves = []
+        args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+        prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+        def on_chip(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        with no_grad():
+            prog.build(leaves)
+            sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+            compiled = prog.jitted_donate.lower(
+                [on_chip(t._value()) for t in leaves],
+                [on_chip(a) for a in sd], [on_chip(a) for a in sk]).compile()
+    finally:
+        km._interpret = interpret
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    pool = eng.cache.nbytes()
+    index_buf = int(eng.cache.sides[2][0]._value().nbytes)
+    assert eng.cache.layer_nbytes() == 8193 * 16 * 4 * 128 * 2
+    assert index_buf == 8193 * 16 * 128 * 2
+    kernels = {"decode": ("paged_decode_attention", "dsa_index_scores",
+                          "dsa_sparse_decode"),
+               "prefill": ("paged_prefill_attention", "dsa_index_scores",
+                           "dsa_sparse_prefill", "kv_block_write")}[program]
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert re.search(r"%" + kernel + r"(\.\d+)? = ", hlo), kernel
+    assert chip_smoke.pool_sized_moves(hlo, index_buf) == []
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < eng.cache.layer_nbytes()
+    assert vocab_sorts(hlo, 8192) == []       # the context's length, too
+    assert not re.search(r"f32\[(\d+,)*512,8192\]", hlo) or program == "prefill"
+    assert not re.search(r"f32\[(\d+,)*8192,8192\]", hlo)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_grouped_query_heads_of_128_compile_for_the_chip(one_chip, program):
+    """ROADMAP R-a, repaired in PR 30: a rotary decoder of 32 query / 8 KV
+    heads x 128 in bfloat16 through the paged engine.  Until then the rotary
+    returned float32 queries (``q * cos`` promotes) and the first prefill
+    bucket died in ``warmup()`` with ``RESOURCE_EXHAUSTED ... vmem ... 17.09M
+    and limit 16.00M``; and the prefill program's block scatter converted
+    each K/V layer buffer to a layout of XLA's own and back (8 heads of
+    bfloat16 do not fill a sublane tile).  Both programs compile for the
+    described v5e, the bucket-1024 prefill within the kernel's VMEM, and
+    move no layer buffer."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=8192, hidden_size=4096, intermediate_size=14336,
+        num_hidden_layers=1, num_attention_heads=32, num_key_value_heads=8,
+        max_position_embeddings=4096))
+    model.to(dtype="bfloat16")
+    eng = Engine(model, num_slots=8, max_seq=4096, min_bucket=512,
+                 block_size=16, kernel="pallas")
+    assert [tuple(b.shape) for b in eng.cache.buffers()] == \
+        [(2049, 16, 8, 128)] * 2
+    eng.cache._interpret = False          # the kernels as the chip runs them
+    eng._build_steps()
+    if program == "decode":
+        fn, args = eng._decode_fn, [np.zeros((8,), np.int32)]
+    else:
+        fn, args = eng._prefill_fn, [np.zeros((1, 1024), np.int64),
+                                     np.int32(0), np.int32(1), np.int32(0)]
+        assert eng.cache.begin_sequence(0, [], 0, 1024)
+    leaves = []
+    args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+    prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    with no_grad():
+        prog.build(leaves)
+        sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+        compiled = prog.jitted_donate.lower(
+            [on_chip(t._value()) for t in leaves], [on_chip(a) for a in sd],
+            [on_chip(a) for a in sk]).compile()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert re.search(r"%paged_" + program + r"_attention(\.\d+)? = ", hlo)
+    assert (re.search(r"%kv_block_write(\.\d+)? = ", hlo) is not None) \
+        == (program == "prefill")
+    assert chip_smoke.pool_sized_moves(hlo, eng.cache.layer_nbytes()) == []
+    assert mem.alias_size_in_bytes >= eng.cache.nbytes()
