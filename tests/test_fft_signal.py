@@ -379,14 +379,15 @@ class TestReviewRegressions2:
 
     def test_flash_supports_non_default_multiples(self):
         from paddle_tpu.ops.pallas.flash_attention_kernel import (
-            supports, _auto_block)
+            supports, flash_plan)
 
         # shapes that divided the old 128 blocks must stay supported
         for S in (768, 1536, 640):
             assert supports((2, S, 4, 64), (2, S, 4, 64)), S
-        assert _auto_block(1536, 1024) == 512
-        assert _auto_block(768, 512) == 256
-        assert _auto_block(1024, 1024) == 1024
+        # (block, sub-block) for bf16 heads of 64
+        assert flash_plan(1536, 64, 2)[:2] == (512, 256)
+        assert flash_plan(768, 64, 2)[:2] == (768, 256)
+        assert flash_plan(1024, 64, 2)[:2] == (1024, 256)
 
     def test_multinomial_entropy_exact(self):
         from paddle_tpu import distribution as D

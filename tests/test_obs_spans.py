@@ -542,6 +542,45 @@ def test_flash_kernels_are_named_and_the_accepted_patterns_still_match(
     assert not any(re.search(p, only) for p in kc.BACKWARD)
 
 
+def test_a_traced_flash_call_leaves_one_plan_mark():
+    """``attention.flash_plan``: once per trace of the forward, the sizes
+    the plan chose for the call's shape and the tiles its walk visits — the
+    engagement share of the causal skip (100 % visited: it did nothing)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import flash_attention_kernel as fk
+
+    def train(q, k, v):
+        def loss(q, k, v):
+            o = fk.flash_attention_fused(q, k, v, causal=True, interpret=True)
+            return (o.astype(jnp.float32) ** 2).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    x = jax.ShapeDtypeStruct((1, 1024, 16, 64), jnp.bfloat16)  # the cell's
+    t = spans.clock()
+    with spans.span("jit.trace", fn="train") as outer:
+        jax.eval_shape(train, x, x, x)
+    marks = [r for r in rows_since(t) if r[NAME] == "attention.flash_plan"]
+    assert len(marks) == 1
+    (mark,) = marks
+    assert mark[PARENT] == outer.sid and mark[START] == mark[END]
+    plan = fk.flash_plan(1024, 64, 2)
+    assert mark[ATTRS] == {
+        "seq": 1024, "head_dim": 64, "causal": 1, "block_q": plan.block_q,
+        "sub": plan.sub, "tiles_total": plan.tiles_total,
+        "tiles_visited": plan.tiles_visited,
+        "tiles_masked": plan.tiles_masked}
+    assert mark[ATTRS]["tiles_visited"] <= 0.75 * mark[ATTRS]["tiles_total"]
+    # a call that is not causal walks every tile and says so
+    t = spans.clock()
+    jax.eval_shape(lambda q, k, v: fk.flash_attention_fused(
+        q, k, v, causal=False, interpret=True), x, x, x)
+    (mark,) = [r for r in rows_since(t) if r[NAME] == "attention.flash_plan"]
+    assert mark[ATTRS]["tiles_visited"] == mark[ATTRS]["tiles_total"]
+    assert mark[ATTRS]["tiles_masked"] == 0 and mark[ATTRS]["causal"] == 0
+
+
 def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(
         one_chip):
     import jax

@@ -15,30 +15,79 @@ def _qkv(B=2, S=256, H=4, D=64, dtype=jnp.float32, seed=0):
     return mk(), mk(), mk()
 
 
+# (S, head_dim, causal, block_q, sub-block); None: the plan's own choice.
+# Float32 operands: the plan gives a grid step at most 512 rows, so S = 1024
+# is two blocks and S = 1536 three by itself.
+_PARITY = {
+    # the cases this test took over: defaults at [2, 256, 4, 64], a
+    # sequence shorter than every preferred size
+    "default-noncausal": (256, 64, False, None, None),
+    "default-causal": (256, 64, True, None, None),
+    "small-seq-block-clamp": (64, 64, True, None, None),
+    # one block: the block larger than and equal to the sub-block
+    "256-q>sub": (256, 64, True, 256, 64),
+    "256-q=sub": (256, 64, True, 256, 256),
+    "256-d128-q>sub": (256, 128, True, 256, 128),
+    "256-d128-q>sub-noncausal": (256, 128, False, 256, 64),
+    # several blocks: pairs above, on and under the diagonal, the clamped
+    # index_map
+    "256-blocks-q=sub": (256, 64, True, 64, 64),
+    "256-d128-blocks": (256, 128, True, 128, 64),
+    "256-d128-blocks-noncausal": (256, 128, False, 128, 64),
+    "512-d128-blocks": (512, 128, True, 256, 128),
+    "512-blocks-four": (512, 64, True, 128, 64),
+    "512-blocks-q=sub": (512, 64, True, 256, 256),
+    "512-d128-blocks-noncausal": (512, 128, False, 128, 64),
+    "512-blocks-q=sub-noncausal": (512, 64, False, 256, 256),
+    # the cell's sequence
+    "1024-default-two-blocks": (1024, 64, True, None, None),
+    "1024-one-block": (1024, 64, True, 1024, 256),
+    "1024-d128-blocks": (1024, 128, True, 256, 256),
+    "1024-d128-sub-512": (1024, 128, True, 1024, 512),
+    "1024-noncausal": (1024, 64, False, 512, 256),
+    # a sequence that is no power of two
+    "1536-default-three-blocks": (1536, 64, True, None, None),
+    "1536-d128-block-768": (1536, 128, True, 768, 128),
+    "1536-default-noncausal": (1536, 64, False, None, None),
+    "1536-d128-q=sub-noncausal": (1536, 128, False, 512, 512),
+    # bf16 operands on the MXU path's dtypes (1024 rows a block)
+    "bf16": (256, 64, True, None, None),
+    "bf16-blocks": (512, 128, True, 256, 64),
+}
+
+
+def _attn_and_grads(attn, q, k, v):
+    o, vjp = jax.vjp(attn, q, k, v)
+    return (o,) + vjp(v)            # cotangent: v, as (o * v).sum()'s
+
+
 class TestFlashAttention:
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_matches_oracle(self, causal):
-        q, k, v = _qkv()
-        o = flash_attention_fused(q, k, v, causal=causal, interpret=True)
-        ref = _sdpa_reference(q, k, v, None, None, 0.0, causal)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
-
-    @pytest.mark.parametrize("causal", [False, True])
-    def test_grads_match_oracle(self, causal):
-        q, k, v = _qkv()
-
-        def loss_fa(q, k, v):
-            return (flash_attention_fused(q, k, v, causal=causal,
-                                          interpret=True) * v).sum()
-
-        def loss_ref(q, k, v):
-            return (_sdpa_reference(q, k, v, None, None, 0.0, causal) * v).sum()
-
-        g1 = jax.grad(loss_fa, argnums=(0, 1, 2))(q, k, v)
-        g2 = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(g1, g2):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-4)
+    @pytest.mark.parametrize("case", list(_PARITY))
+    def test_output_and_grads_match_oracle(self, case):
+        """The output AND dq, dk, dv of every branch of the walk against the
+        XLA oracle."""
+        S, D, causal, block_q, sub = _PARITY[case]
+        old = case.startswith(("default", "small", "bf16"))
+        bf16 = case.startswith("bf16")
+        q, k, v = _qkv(B=2 if old else 1, S=S, H=4 if old else 1, D=D,
+                       dtype=jnp.bfloat16 if bf16 else jnp.float32)
+        got = _attn_and_grads(
+            lambda q, k, v: flash_attention_fused(
+                q, k, v, causal=causal, block_q=block_q, block_k=sub,
+                interpret=True), q, k, v)
+        want = _attn_and_grads(
+            lambda q, k, v: _sdpa_reference(q, k, v, None, None, 0.0,
+                                            causal), q, k, v)
+        for name, a, b, atol in zip(("out", "dq", "dk", "dv"), got, want,
+                                    (2e-5, 5e-4, 5e-4, 5e-4)):
+            assert a.dtype == q.dtype
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            if bf16:
+                # the output as absolute as it always was; a gradient, which
+                # sums bf16 products over the sequence, by its magnitude
+                atol = 3e-2 * (1.0 if name == "out"
+                               else max(1.0, float(np.abs(b).max())))
+            np.testing.assert_allclose(a, b, atol=atol, err_msg=name)
 
     def test_nondivisible_seq_raises(self):
         q, k, v = _qkv(S=100)
@@ -59,19 +108,107 @@ class TestFlashAttention:
         with pytest.raises(ValueError):
             flash_attention_fused(q, k, v, interpret=True)
 
-    def test_small_seq_block_clamp(self):
-        q, k, v = _qkv(S=64)
-        o = flash_attention_fused(q, k, v, causal=True, interpret=True)
-        ref = _sdpa_reference(q, k, v, None, None, 0.0, True)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(ref), atol=2e-5)
 
-    def test_bf16(self):
-        q, k, v = _qkv(dtype=jnp.bfloat16)
-        o = flash_attention_fused(q, k, v, causal=True, interpret=True)
-        ref = _sdpa_reference(q, k, v, None, None, 0.0, True)
-        assert o.dtype == jnp.bfloat16
-        np.testing.assert_allclose(
-            np.asarray(o, np.float32), np.asarray(ref, np.float32), atol=3e-2)
+# (S, block_q, sub)
+_PLANS = [(1024, 1024, 256), (1024, 1024, 128), (1024, 1024, 512),
+          (1024, 512, 256), (1024, 256, 256), (1024, 128, 128),
+          (1536, 512, 256), (1536, 768, 128), (640, 640, 128),
+          (768, 768, 256), (4096, 1024, 256), (256, 128, 64), (96, 96, 96)]
+
+
+class TestFlashPlan:
+    """The plan function alone: what a causal call's walk visits."""
+
+    @pytest.mark.parametrize("S,block_q,sub", _PLANS)
+    def test_walk_visits_exactly_the_squares_on_or_under_the_diagonal(
+            self, S, block_q, sub):
+        from paddle_tpu.ops.pallas.flash_attention_kernel import (
+            _walk, flash_plan)
+
+        n, blocks = block_q // sub, S // block_q
+        # square (query sub-block, key sub-block) -> masked?, as the
+        # kernels' walks visit them: the forward and bwd_dkv walk a pair's
+        # key sub-blocks, bwd_dq its query sub-blocks
+        by_keys, by_queries = {}, {}
+        for iq in range(blocks):
+            for ik in range(iq + 1):                  # pairs not skipped
+                diagonal = iq == ik
+                for j, first in _walk(n, diagonal):
+                    for other in range(first, n):
+                        square = (iq * n + other, ik * n + j)
+                        assert square not in by_keys
+                        by_keys[square] = diagonal and other == first
+                    # bwd_dq: query sub-block j meets keys 0 .. j (all)
+                    for other in range(j + 1 if diagonal else n):
+                        square = (iq * n + j, ik * n + other)
+                        assert square not in by_queries
+                        by_queries[square] = diagonal and other == j
+        want = {}
+        for i in range(S // sub):
+            for j in range(S // sub):
+                rows = (i * sub, (i + 1) * sub - 1)
+                cols = (j * sub, (j + 1) * sub - 1)
+                if rows[1] >= cols[0]:          # an entry with row >= col
+                    # masked iff an entry with row < col too
+                    want[(i, j)] = rows[0] < cols[1]
+        assert by_keys == want and by_queries == want
+        plan = flash_plan(S, 64, 2, True, block_q, sub)
+        assert plan[:3] == (block_q, sub, S // block_q)
+        assert plan.tiles_total == (S // sub) ** 2
+        assert plan.tiles_visited == len(want)
+        assert plan.tiles_masked == sum(want.values())
+        full = flash_plan(S, 64, 2, False, block_q, sub)
+        assert full.tiles_visited == full.tiles_total and \
+            full.tiles_masked == 0
+
+    def test_the_cells_shape_visits_at_most_three_quarters(self):
+        from paddle_tpu.ops.pallas.flash_attention_kernel import flash_plan
+
+        plan = flash_plan(1024, 64, 2)        # GPT-2 345M: bf16, 16 x 64
+        assert plan.block_q == 1024           # one grid step a head
+        assert plan.tiles_visited / plan.tiles_total <= 0.75
+        assert 0 < plan.tiles_masked < plan.tiles_visited
+
+    def test_sizes_follow_the_operands_bytes_and_the_head_width(self):
+        from paddle_tpu.ops.pallas.flash_attention_kernel import flash_plan
+
+        assert flash_plan(4096, 128, 2).block_q == 1024
+        assert flash_plan(4096, 128, 4).block_q == 512    # f32 halves it
+        assert flash_plan(4096, 256, 2).block_q == 512    # two lane rows
+        assert flash_plan(1536, 64, 4).block_q == 512     # 3 x 512
+        assert flash_plan(1536, 64, 2).block_q == 512
+        # a piece is whole 128-lane columns, or the block
+        assert flash_plan(320, 64, 2)[:2] == (320, 320)
+        assert flash_plan(640, 64, 2)[:2] == (640, 128)
+        # what fills VMEM is float32 whatever the operands: 1-byte operands
+        # take no more rows than 2-byte ones
+        assert flash_plan(4096, 128, 1).block_q == 1024
+        assert flash_plan(4096, 64, 1).block_q == 1024
+
+    @pytest.mark.parametrize("S", [96, 300, 520, 640, 768, 1536, 516, 700,
+                                   1100])
+    def test_supports_holds_whatever_the_operands_bytes(self, S):
+        """The dispatch guard sees shapes only: what it accepts has a plan
+        at every element size, so a call behind it never raises."""
+        from paddle_tpu.ops.pallas.flash_attention_kernel import (
+            flash_plan, supports)
+
+        shape = (2, S, 4, 64)
+        if supports(shape, shape):
+            for itemsize in (1, 2, 4):
+                assert S % flash_plan(S, 64, itemsize).block_q == 0
+        else:
+            assert S in (516, 700, 1100)
+            with pytest.raises(ValueError):
+                flash_plan(S, 64, 4)
+
+    def test_sizes_that_do_not_divide_are_refused(self):
+        from paddle_tpu.ops.pallas.flash_attention_kernel import flash_plan
+
+        with pytest.raises(ValueError):
+            flash_plan(512, 64, 2, True, 128, 96)     # sub !| block
+        with pytest.raises(ValueError):
+            flash_plan(512, 64, 2, True, 384, 128)    # block !| S
 
 
 class TestFlashPerShard:

@@ -71,8 +71,9 @@ def make_flash_runners(block_q=None, block_k=None, B=8, S=1024, H=16, D=64):
     """Jitted (run_fwd, run_bwd, q, k, v) timing harnesses for the Pallas
     flash kernel at the bench shapes: iters-step scan with per-iteration
     input perturbation (defeats CSE) and full-output sum|.| consumption
-    (defeats DCE — see mxu_probe).  Shared by step_ablation and
-    flash_sweep so the timing recipe cannot drift between tools."""
+    (defeats DCE — see mxu_probe).  ``block_q`` / ``block_k`` pin the
+    kernel's query block and sub-block (``flash_plan``) to time a size
+    alone; left out, the sizes follow the shape."""
     from functools import partial
 
     import jax
