@@ -21,3 +21,6 @@ from .deepseek_v3 import (
 from .keye_vl2 import (
     KeyeVL2Config, KeyeVL2ForCausalLM, keye_vl2_tiny,
 )
+from .evabyte import (
+    EvaByteConfig, EvaByteForCausalLM, evabyte_tiny,
+)

@@ -86,6 +86,7 @@ from .kv_cache import cache_spec_of
 from .metrics import ServingMetrics
 from .paging import PagedCacheContext, PagedKVCache
 from .prefix_cache import PrefixCache
+from .window_cache import WindowedKVCache, WindowedPrefixCache
 from .sampling import DeviceSampler, SamplingParams, sampler_path
 from .sanitize import SyncSanitizer
 from .tracing import NULL_TRACER, FlightRecorder, RequestTracer
@@ -332,6 +333,11 @@ class Engine:
         num_kv_blocks: pool size; default
             ``num_slots * max_seq / block_size + 1`` (every slot at
             ``max_seq`` plus the reserved scratch block).
+        num_summary_blocks: blocks of the summary group, one a window,
+            of a model whose cache is ``CacheSpec.windowed`` (then
+            ``num_kv_blocks`` sizes the exact group, default two windows
+            a slot and one cold prompt); default every slot at
+            ``max_seq``.  Refused for any other model.
         enable_prefix_cache: hash whole prompt blocks host-side and
             serve repeated prefixes from refcounted shared blocks,
             shrinking the prefill to the uncached tail bucket.
@@ -414,6 +420,7 @@ class Engine:
                  kernel: str = "pallas",
                  block_size: int = 16,
                  num_kv_blocks: Optional[int] = None,
+                 num_summary_blocks: Optional[int] = None,
                  enable_prefix_cache: bool = True,
                  prefix_lookup_timeout_s: float = 0.25,
                  max_preemptions: int = 2,
@@ -460,11 +467,11 @@ class Engine:
         if priority_aging_s is not None and priority_aging_s <= 0:
             raise ValueError("priority_aging_s must be > 0 (or None to "
                              "disable aging)")
-        self.buckets = self._make_buckets()
         # the pool is built from what the model says it caches, not from
         # head counts read off its config
         spec = cache_spec_of(model)
         self.cache_spec = spec
+        self.buckets = self._make_buckets()
         kv_heads = spec.sides[0][0]
         if cache_dtype is None:
             params = model.parameters()
@@ -473,15 +480,18 @@ class Engine:
             raise ValueError(
                 f"kv_layout={kv_layout!r}: the contiguous layout was "
                 f"removed and the cache is always paged (drop the argument)")
-        if spec.kind in ("latent", "indexed"):
+        if spec.kind in ("latent", "indexed", "windowed"):
             # one vector a token has no per-KV-head axis to shard by, the
-            # three-sided pool's indexer side has none either, and neither
-            # has a form of the verify window
+            # three-sided pool's indexer side has none either, the summary
+            # group's tables and allocator are not placed on a mesh, and
+            # none has a form of the verify window
             caches, no_mesh = {
                 "latent": ("caches one latent vector a token",
                            "the latent pool has no kv_heads axis to shard"),
                 "indexed": ("caches K, V and an indexer key a token",
-                            "the indexed pool is not sharded")}[spec.kind]
+                            "the indexed pool is not sharded"),
+                "windowed": ("caches an exact window and chunk summaries",
+                             "the summary group is not sharded")}[spec.kind]
             refused = [what for what, asked in (
                 (f"a serving mesh of more than one device ({no_mesh})",
                  mesh is not None and mesh.size > 1),
@@ -503,14 +513,28 @@ class Engine:
             raise ValueError(
                 f"block_size {self.block_size} must divide "
                 f"max_seq {self.max_seq}")
-        self.cache = PagedKVCache(
-            num_slots=self.num_slots, num_layers=spec.num_layers,
-            max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
-            block_size=self.block_size, num_blocks=num_kv_blocks,
-            kernel=self.kernel)
-        self.prefix_cache = (
-            PrefixCache(self.cache.allocator, self.block_size)
-            if enable_prefix_cache else None)
+        pool = dict(num_slots=self.num_slots, num_layers=spec.num_layers,
+                    max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
+                    block_size=self.block_size, num_blocks=num_kv_blocks,
+                    kernel=self.kernel)
+        if spec.kind == "windowed":
+            # a second group beside the K/V pool: a window's summaries a
+            # block, its own allocator and table, and a prefix cache that
+            # hits by whole windows first
+            self.cache = WindowedKVCache(
+                window=spec.window, chunk=spec.chunk,
+                num_summary_blocks=num_summary_blocks, **pool)
+            self.prefix_cache = (WindowedPrefixCache(self.cache)
+                                 if enable_prefix_cache else None)
+        else:
+            if num_summary_blocks is not None:
+                raise ValueError(
+                    f"num_summary_blocks: {type(model).__name__} keeps no "
+                    f"summary group")
+            self.cache = PagedKVCache(**pool)
+            self.prefix_cache = (
+                PrefixCache(self.cache.allocator, self.block_size)
+                if enable_prefix_cache else None)
         self.name = name or f"engine-{next(_engine_counter)}"
         self.metrics = ServingMetrics(self.name, num_slots=self.num_slots)
         self.metrics.health_cb = self.health
@@ -642,6 +666,8 @@ class Engine:
         #: tokens a work item of the paged decode kernel covers (set with
         #: the programs; None: no work list, no ``decode_chunks``)
         self._decode_chunk_tokens: Optional[int] = None
+        #: work items a slot with so many cached tokens costs (set with it)
+        self._decode_items = None
         #: decode-step load of the model's expert layers (empty for a
         #: model without experts: ``stats()`` then has no ``"moe"``)
         self._moe = {"tokens": 0, "assignments_held": 0,
@@ -655,17 +681,26 @@ class Engine:
         #: decode steps by the way their program went through the
         #: sampler (``sampling.sampler_path``)
         self._sampler_steps = {"steps_greedy": 0, "steps_sampled": 0}
+        #: a windowed cache's decode steps (rows the running slots attended
+        #: to, a layer's count) and windows published, by where
+        self._eva = {"steps": 0, "exact_rows": 0, "summary_rows": 0,
+                     "context": 0, "windows_published_decode": 0,
+                     "windows_published_prefill": 0, "prefill_windows": 0}
+        self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
 
     # -- compiled steps ----------------------------------------------------
 
     def _make_buckets(self) -> List[int]:
+        # a windowed cache prefills a window at a time (``_tail_end``): no
+        # tail is longer
+        top = self.cache_spec.window or self.max_seq
         b, out = self.min_bucket, []
-        while b < self.max_seq:
+        while b < top:
             out.append(b)
             b *= 2
-        out.append(self.max_seq)
+        out.append(top)
         return out
 
     def bucket_for(self, prompt_len: int) -> int:
@@ -675,7 +710,7 @@ class Engine:
         for b in self.buckets:
             if prompt_len <= b:
                 return b
-        return self.max_seq
+        return self.buckets[-1]
 
     def _build_steps(self) -> None:
         """Compile-cached prefill/decode programs.  Built lazily so the
@@ -686,6 +721,7 @@ class Engine:
         pool = self.adapter_pool
         if self.spec is None:
             self._decode_chunk_tokens = cache.decode_chunk_tokens()
+            self._decode_items = cache.decode_items_fn()
 
         def _prefill_rows(slot):
             # this prefill's slot selects its adapter lane: a [1] row id
@@ -741,8 +777,19 @@ class Engine:
             # tokens, in the one array the host pulls
             return Tensor._wrap(ctx.with_expert_counts(toks))
 
+        def publish_step(slot, window):
+            # a decode step closed ``window`` of ``slot``: its summaries from
+            # its exact blocks into the slot's summary block, layer by layer
+            for i, (phi, mu) in enumerate(model.summary_params()):
+                cache.publish(i, slot, window, True, phi._value(),
+                              mu._value())
+            return Tensor._wrap(jnp.zeros((), jnp.int32))
+
         self._prefill_fn = jit_mod.to_static(prefill_step)
         self._warmers = [("prefill", self._warm_prefill)]
+        if self.cache_spec.kind == "windowed":
+            self._publish_fn = jit_mod.to_static(publish_step)
+            self._warmers.append(("publish", self._warm_publish))
         if self.spec is None:
             self._decode_fn = jit_mod.to_static(decode_step)
             self._warmers.append(("decode", self._warm_decode))
@@ -788,6 +835,11 @@ class Engine:
         # the host-side table and block-copy programs of a growing
         # sequence, which no warm-up prefill reaches
         self.cache.warm_host_programs()
+
+    def _warm_publish(self, buckets) -> None:
+        # slot 0's rows point at the scratch blocks of both groups
+        self._call_counted(self._publish_fn, to_tensor(np.int32(0)),
+                           to_tensor(np.int32(0)))
 
     def _warm_draft_prefill(self, buckets) -> None:
         for b in buckets:
@@ -987,6 +1039,10 @@ class Engine:
         # of fresh blocks; a prompt that can never fit the pool is
         # rejected up front instead of deferring forever
         need = self.bucket_for(req.prompt_ids.size) // self.block_size
+        if 0 < self.cache_spec.window < req.prompt_ids.size:
+            # a windowed cache prefills a window at a time (``_tail_end``):
+            # a piece's bucket, and the next piece's beside it
+            need *= 2
         usable = self.cache.num_blocks - self.cache.allocator.reserved
         if need > usable:
             return (f"prompt needs {need} KV blocks "
@@ -1541,7 +1597,8 @@ class Engine:
             return 0, []
         return hit_tokens, blocks
 
-    def _prefill_call(self, req: Request, *args):
+    def _prefill_call(self, req: Request, *args, start: int = 0,
+                      end: Optional[int] = None):
         """One compiled prefill with the bounded retry; exhausted retries
         retire ``req`` as failed and return None (shared by both KV
         layouts so the retire semantics cannot diverge)."""
@@ -1558,6 +1615,8 @@ class Engine:
                     sp.attrs["dsa_context"] = scored
                     self._sparse["prefills"] += scored > 0
                     self._sparse["prefill_context"] += scored
+                if self.cache_spec.kind == "windowed":
+                    self._note_prefill_windows(sp, start, end)
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -1569,9 +1628,21 @@ class Engine:
                          kind="replica")
             return None
 
+    def _tail_end(self, start: int, L: int) -> int:
+        """Where the prefill program that starts at ``start`` ends: the
+        prompt's end — or, for a windowed cache and a tail longer than a
+        window, the end of ``start``'s window.  Such a prompt is prefilled a
+        window at a time: each program closes its window, publishes it and
+        lets its exact blocks go before the next one starts, so a cold
+        prompt of any length holds two windows of exact blocks at most and
+        takes no bucket above the window's."""
+        W = self.cache_spec.window
+        return (start // W + 1) * W if W and L - start > W else L
+
     def _paged_prefill(self, req: Request, L: int):
         """Paged admission: prefix lookup, block assignment, tail-bucket
-        prefill.  Returns ``(status, first_token, bucket, prefix_hit)``
+        prefill (of a windowed cache: a program a piece, ``_tail_end``).
+        Returns ``(status, first_token, bucket, prefix_hit)``
         with status ``"ok" | "deferred" | "failed"`` (``deferred`` = the
         pool cannot supply the tail blocks right now and the slot was
         left untouched; ``failed`` = the request was already retired);
@@ -1583,13 +1654,35 @@ class Engine:
             sp.attrs["hit_tokens"] = P
         if not ok:
             return "deferred", None, bucket, P
-        ids = np.zeros((1, bucket), dtype=np.int64)
-        ids[0, :L - P] = req.prompt_ids[P:]
-        last = self._prefill_call(
-            req, to_tensor(ids), to_tensor(np.int32(req.slot)),
-            to_tensor(np.int32(L)), to_tensor(np.int32(P)))
-        if last is None:
-            return "failed", None, bucket, P
+        start = P
+        while True:
+            end = self._tail_end(start, L)
+            ids = np.zeros((1, bucket), dtype=np.int64)
+            ids[0, :end - start] = req.prompt_ids[start:end]
+            last = self._prefill_call(
+                req, to_tensor(ids), to_tensor(np.int32(req.slot)),
+                to_tensor(np.int32(end)), to_tensor(np.int32(start)),
+                start=start, end=end)
+            if last is None:
+                return "failed", None, bucket, P
+            if self.cache_spec.kind == "windowed":
+                # the windows the tail closed were published inside its
+                # program: their exact blocks go, before the next piece takes
+                # its own and before the prompt is registered
+                self.cache.release_windows(req.slot, end)
+            if end == L:
+                break
+            # the next piece; its first token is sampled from the slot's
+            # lanes as staged at admission, whatever the pieces before drew
+            start, bucket = end, self.bucket_for(
+                self._tail_end(end, L) - end)
+            if not self.cache.extend_tail(req.slot, start, bucket):
+                self._retire(req, "failed",
+                             error="KV block pool exhausted: no blocks for "
+                                   f"the prompt's piece at {start}")
+                return "failed", None, bucket, P
+            self.sampler.stage_slot(req.slot, req.sampling,
+                                    self._seed_for(req))
         if self.prefix_cache is not None:
             # make this prompt's whole blocks hittable by later requests
             # (hit blocks are refreshed, new full tail blocks registered)
@@ -1607,25 +1700,35 @@ class Engine:
         ``(hit_tokens, bucket, assigned)``; ``assigned`` False = the pool
         cannot supply the tail blocks right now."""
         P, shared = self._prefix_lookup(req)
-        bucket = self.bucket_for(L - P)
+        bucket = self.bucket_for(self._tail_end(P, L) - P)
         # a PARTIAL hit can push prefix + padded tail past the slot's
         # block table (e.g. hit 8 of a 32-token prompt with buckets
         # {8,16,32}: 1 + 32/8 = 5 blocks on a 4-block table) — drop hit
         # blocks from the end until the padded tail fits; the remaining
         # hit is still a contiguous prefix
-        while shared and (len(shared) + bucket // self.block_size
-                          > self.cache.max_blocks_per_slot):
-            shared = shared[:-1]
-            P -= self.block_size
-            bucket = self.bucket_for(L - P)
+        while P and (P + bucket > self.max_seq):
+            P, shared = self.cache.shorten_hit(shared)
+            bucket = self.bucket_for(self._tail_end(P, L) - P)
         if self.prefix_cache is not None and req._defers == 0:
             # one logical lookup per request (deferral retries re-look-up
             # for freshness but don't re-count), credited with only the
             # hit span that is ACTUALLY reused post-cap — discarded and
             # raising lookups land here as P == 0, i.e. a plain miss
             self.prefix_cache.record_lookup(L, P)
+        extra = {}
+        if self.cache_spec.kind == "windowed":
+            # a summary block for every window the request's whole life can
+            # close, and the exact group left with what the running
+            # sequences (and this one) may still grow by: no sequence fails
+            # for a block while decoding
+            # (and, of a prompt prefilled in pieces, the next piece's window)
+            bs = self.block_size
+            extra = dict(total=L + req.max_new_tokens, reserve=sum(
+                -(-(r.max_new_tokens - len(r.output_ids)) // bs) + 1
+                for r in (*self.running.values(), req))
+                + (self._tail_end(P, L) < L) * self.cache.window_blocks)
         return P, bucket, self.cache.begin_sequence(req.slot, shared, P,
-                                                    bucket)
+                                                    bucket, **extra)
 
     def _admit(self, req: Request) -> Optional[bool]:
         """Prefill ``req`` into its pre-assigned slot.  Never raises for
@@ -1830,6 +1933,10 @@ class Engine:
         are copied-on-extend.  A slot the pool cannot serve fails (the
         engine and its batch continue)."""
         for slot, req in list(self.running.items()):
+            if self._publish_fn is not None \
+                    and self.cache.windows_pending(slot, req._seq_len) > 0 \
+                    and not self._publish_window(req):
+                continue
             try:
                 ok = self.cache.ensure_capacity(slot, req._seq_len)
             except Exception as e:       # noqa: BLE001 — accounting bug
@@ -1845,6 +1952,49 @@ class Engine:
                              error="KV block pool exhausted: no block "
                                    f"free for position {req._seq_len} "
                                    "(even after prefix-cache eviction)")
+
+    def _publish_window(self, req: Request) -> bool:
+        """The last decode step ended a window of ``req``'s slot: run the
+        publishing program (the window's summaries from its exact blocks into
+        the slot's summary block), then let the window's exact blocks go.
+        A failing program fails the request, not the engine."""
+        window = self.cache.published(req.slot)
+        try:
+            with _spans.span("engine.publish_window", slot=req.slot,
+                             window=window) as sp:
+                self._step_call("serving.publish", self._publish_fn,
+                                to_tensor(np.int32(req.slot)),
+                                to_tensor(np.int32(window)), span=sp)
+                sp.attrs["exact_blocks_released"] = \
+                    self.cache.release_windows(req.slot, req._seq_len)
+        except Exception as e:           # noqa: BLE001 — isolation boundary
+            self._retire(req, "failed", kind="replica",
+                         error=f"publishing window {window} failed: "
+                               f"{type(e).__name__}: {e}")
+            return False
+        self._eva["windows_published_decode"] += 1
+        if self._step_span is not None:
+            self._step_span.attrs["eva_windows_published"] = \
+                self._step_span.attrs.get("eva_windows_published", 0) + 1
+        return True
+
+    def _note_prefill_windows(self, sp, start: int, L: int) -> None:
+        """What the prefill of ``[start, L)`` does to the windows, by the
+        program's own rule: the windows its rows lie in, and the ones it
+        closes."""
+        W = self.cache_spec.window
+        rows_a_window = W // self.cache_spec.chunk
+        touched = (L - 1) // W - start // W + 1
+        closed = L // W - start // W
+        # rows the tail's real queries attend to (each: its place in its
+        # window, and the summary rows of the windows before it), and the
+        # distinct rows behind them: the prefill kernel's needed work
+        i = np.arange(start, L, dtype=np.int64)
+        sp.set(eva_windows=touched, eva_windows_published=closed,
+               eva_rows=int(np.sum(i % W + 1 + i // W * rows_a_window)),
+               eva_keys=L - start // W * W + (L - 1) // W * rows_a_window)
+        self._eva["prefill_windows"] += touched
+        self._eva["windows_published_prefill"] += closed
 
     def _decode(self) -> None:
         """One decode step (or, with speculation on, one ROUND: k draft
@@ -1890,7 +2040,8 @@ class Engine:
                 # the kernel's work list, counted where the lengths are
                 # known without asking the device
                 self._step_span.attrs["decode_chunks"] = sum(
-                    req._seq_len // ct + 1 for req in self.running.values())
+                    self._decode_items(req._seq_len)
+                    for req in self.running.values())
             # the sampler's way through this step, by the rule its program
             # applies to the running slots' lanes
             path = sampler_path(
@@ -1939,6 +2090,9 @@ class Engine:
         if self.cache_spec.kind == "indexed":
             extra, sel = extra[:-3], extra[-3:]
             self._note_selection(sel)
+        elif self.cache_spec.kind == "windowed":
+            extra, rows = extra[:-4], extra[-4:]
+            self._note_rows(rows)
         if len(extra):                           # a model with experts
             self._note_experts(extra)
         with _spans.span("engine.deliver") as sp:
@@ -1959,6 +2113,23 @@ class Engine:
         sp["context"] += context
         if self._step_span is not None:
             self._step_span.set(dsa_selected=selected, dsa_context=context)
+
+    def _note_rows(self, counts) -> None:
+        """The decode step's rows, as its program counted them (sums over the
+        running slots and the layers): a layer's count summed into
+        ``stats()["eva"]``, and the step's own on its span."""
+        exact, summary, context, layers = (int(c) for c in counts)
+        exact, summary, context = (n // layers
+                                   for n in (exact, summary, context))
+        ev = self._eva
+        ev["steps"] += 1
+        ev["exact_rows"] += exact
+        ev["summary_rows"] += summary
+        ev["context"] += context
+        if self._step_span is not None:
+            self._step_span.set(eva_exact_rows=exact,
+                                eva_summary_rows=summary, eva_context=context)
+            self._step_span.attrs.setdefault("eva_windows_published", 0)
 
     def _note_experts(self, counts) -> None:
         """The decode step's expert load, as its program counted it: summed
@@ -2776,6 +2947,12 @@ class Engine:
             snap["moe"] = dict(self._moe)
         if self.cache_spec.kind == "indexed":
             snap["sparse"] = dict(self._sparse)
+        if self.cache_spec.kind == "windowed":
+            snap["eva"] = dict(
+                self._eva,
+                exact_blocks_in_use=self.cache.blocks_in_use(),
+                summary_blocks_in_use=self.cache.summary_blocks_in_use(),
+                exact_blocks_released=self.cache.exact_blocks_released)
         snap["sampler"] = dict(self._sampler_steps)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,

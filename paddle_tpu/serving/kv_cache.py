@@ -61,7 +61,13 @@ class CacheSpec:
     whose width is the latent vector's (``kind="latent"``): key and value at
     once, shared by every query head.  A model whose attention runs under a
     learned indexer states THREE: K and V per KV head and the indexer's one
-    key a token (``kind="indexed"``; the third side has its own shape).  The
+    key a token (``kind="indexed"``; the third side has its own shape).  A
+    model that keeps exact K and V only inside the current **window** and,
+    for every window passed, one learned summary key and value a **chunk**
+    states the K/V sides and the two sizes (``kind="windowed"``): the pool
+    then has a second *group* — summary blocks of one window's
+    ``window // chunk`` rows each, with an allocator and a table of their
+    own — and the exact group's blocks are released behind the window.  The
     ``kind`` names the attention calls the model makes on its cache
     context."""
 
@@ -71,6 +77,9 @@ class CacheSpec:
     #: ``kind="indexed"``: the tokens a query keeps; a context longer than
     #: this takes the indexed path
     topk: int = 0
+    #: ``kind="windowed"``: positions of a window and of a chunk of it
+    window: int = 0
+    chunk: int = 0
 
     @classmethod
     def kv(cls, num_layers: int, kv_heads: int, head_dim: int) -> "CacheSpec":
@@ -87,6 +96,14 @@ class CacheSpec:
         return cls(int(num_layers),
                    ((int(kv_heads), int(head_dim)),) * 2
                    + ((1, int(index_dim)),), "indexed", int(topk))
+
+    @classmethod
+    def windowed(cls, num_layers: int, kv_heads: int, head_dim: int,
+                 window: int, chunk: int) -> "CacheSpec":
+        if window % chunk:
+            raise ValueError(f"chunk {chunk} must divide window {window}")
+        return cls(int(num_layers), ((int(kv_heads), int(head_dim)),) * 2,
+                   "windowed", window=int(window), chunk=int(chunk))
 
 
 def cache_spec_of(model) -> CacheSpec:
@@ -274,6 +291,9 @@ class CacheContext:
     #: int32 scalars an indexed-attention layer reports
     #: (:meth:`note_selection`), one pair a layer
     selection_counts: Optional[list] = None
+    #: int32 scalars a windowed-attention layer reports (:meth:`note_rows`),
+    #: one triple a layer: exact rows, summary rows, context
+    row_counts: Optional[list] = None
 
     def __post_init__(self):
         if self.mode not in ("prefill", "decode", "verify"):
@@ -322,14 +342,24 @@ class CacheContext:
         """``tokens [slots]`` followed by what the model's layers counted in
         this call: ``[assignments_held, experts_touched, expert layers]``
         when it has expert layers, then ``[selected, context, indexed
-        layers]`` when its attention runs under an indexer; ``tokens`` as
-        they are when it has neither."""
-        for counts in (self.expert_counts, self.selection_counts):
+        layers]`` when its attention runs under an indexer, or ``[exact rows,
+        summary rows, context, windowed layers]`` when it keeps a window and
+        summaries; ``tokens`` as they are when it has none of them."""
+        for counts in (self.expert_counts, self.selection_counts,
+                       self.row_counts):
             if counts:
-                a, b = (sum(c) for c in zip(*counts))
+                sums = [sum(c) for c in zip(*counts)]
                 tokens = jnp.concatenate([tokens, jnp.stack(
-                    [a, b, jnp.int32(len(counts))]).astype(tokens.dtype)])
+                    sums + [jnp.int32(len(counts))]).astype(tokens.dtype)])
         return tokens
+
+    def note_rows(self, exact, summary, context) -> None:
+        """A windowed-attention layer's decode step (traced int32 scalars):
+        the exact positions and the summary rows its running slots attended
+        to, and the tokens those slots had."""
+        if self.row_counts is None:
+            self.row_counts = []
+        self.row_counts.append((exact, summary, context))
 
     def note_selection(self, selected, context) -> None:
         """An indexed-attention layer's decode step (traced int32 scalars):

@@ -793,6 +793,18 @@ class PagedKVCache:
         preempted request's resume is a cheap prefix hit)."""
         return self._slot_blocks[slot]
 
+    def shorten_hit(self, shared: List[int]) -> Tuple[int, List[int]]:
+        """A prefix hit less its last block: ``(tokens, blocks)``."""
+        return (len(shared) - 1) * self.block_size, shared[:-1]
+
+    def decode_items_fn(self) -> Optional[Callable[[int], int]]:
+        """``f(seq_len)``: work items a layer a running slot with ``seq_len``
+        cached tokens costs a decode step (None: no work list).  Made once
+        with the programs: the engine calls it for every running slot of
+        every step."""
+        ct = self.decode_chunk_tokens()
+        return None if ct is None else (lambda seq_len: seq_len // ct + 1)
+
     def layer_nbytes(self) -> int:
         """Bytes of one layer's buffer of the first side (K and V are
         alike), pad lanes included."""
@@ -811,6 +823,8 @@ class PagedKVCache:
         seen = {}
         for slot, owned in enumerate(self._slot_blocks):
             for b in owned:
+                if b == SCRATCH_BLOCK:          # released behind a window
+                    continue
                 seen.setdefault(b, []).append(slot)
                 if self.allocator.refcount(b) < 1:
                     out.append(f"slot {slot} holds freed block {b}")
@@ -852,6 +866,21 @@ class PagedCacheContext(CacheContext):
         out, selected, context = self.cache.indexed_decode_attention(
             self.layer_idx, q, k, v, q_idx, k_idx, w, self.active, topk=topk)
         self.note_selection(selected, context)
+        return out
+
+    # -- the windowed pool: exact window + summaries, two attention calls ----
+
+    def windowed_prefill_attention(self, q, k, v, phi, mu):
+        return self.cache.windowed_prefill_attention(
+            self.layer_idx, self.slot, q, k, v, phi, mu,
+            self._prefill_start(), self.length)
+
+    def windowed_decode_attention(self, q, k, v):
+        if self.mode != "decode":
+            raise ValueError("the windowed pool has no verify form")
+        out, exact, summary, context = self.cache.windowed_decode_attention(
+            self.layer_idx, q, k, v, self.active)
+        self.note_rows(exact, summary, context)
         return out
 
     # -- the latent pool: one write and two attention calls -----------------

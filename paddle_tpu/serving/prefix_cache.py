@@ -125,7 +125,8 @@ class PrefixCache:
         self.hit_tokens_total += int(hit_tokens)
 
     def lookup(self, prompt: Sequence[int], count: bool = True,
-               salt: bytes = b"") -> Tuple[int, List[int]]:
+               salt: bytes = b"", first_block: int = 0,
+               max_blocks: Optional[int] = None) -> Tuple[int, List[int]]:
         """Longest cached prefix of ``prompt``: ``(n_tokens, block_ids)``.
 
         Walks the hash chain over whole prompt blocks, stopping at the
@@ -134,14 +135,16 @@ class PrefixCache:
         but takes NO references — the caller refs the blocks it actually
         admits a sequence onto.  ``count=False`` skips the hit-rate
         counters — the engine counts via :meth:`record_lookup` instead,
-        after it has decided whether the result is actually used."""
+        after it has decided whether the result is actually used.
+        ``first_block``: the walk starts at that block of the prompt (the
+        blocks before it are another group's to cover) and takes at most
+        ``max_blocks``; the tokens returned are those of the walk alone."""
         prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
         if count:
             self.lookups += 1
             self.lookup_tokens_total += int(prompt.size)
-        max_hit = max(0, (int(prompt.size) - 1) // self.block_size)
         block_ids: List[int] = []
-        for key in self._keys_for(prompt, max_hit, salt):
+        for key in self._span(prompt, salt, first_block, max_blocks):
             e = self._entries.get(key)
             if e is None:
                 break
@@ -153,7 +156,17 @@ class PrefixCache:
             self.hit_tokens_total += len(block_ids) * self.block_size
         return len(block_ids) * self.block_size, block_ids
 
-    def probe(self, prompt: Sequence[int], salt: bytes = b"") -> int:
+    def _span(self, prompt: np.ndarray, salt: bytes, first_block: int,
+              max_blocks: Optional[int]) -> List[bytes]:
+        """Chain keys of the blocks a walk may take: from ``first_block``,
+        at most ``max_blocks``, and never the prompt's last token's."""
+        stop = max(0, (int(prompt.size) - 1) // self.block_size)
+        if max_blocks is not None:
+            stop = min(stop, first_block + max_blocks)
+        return self._keys_for(prompt, stop, salt)[first_block:]
+
+    def probe(self, prompt: Sequence[int], salt: bytes = b"",
+              first_block: int = 0, max_blocks: Optional[int] = None) -> int:
         """Side-effect-free longest-cached-prefix length in TOKENS: no
         LRU refresh, no hit/lookup counters, no references taken.  The
         fleet router's affinity probe — it may interrogate every
@@ -161,16 +174,15 @@ class PrefixCache:
         recency order and hit-rate gauges should move (they do, at
         admission, through the real :meth:`lookup`)."""
         prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
-        max_hit = max(0, (int(prompt.size) - 1) // self.block_size)
         n = 0
-        for key in self._keys_for(prompt, max_hit, salt):
+        for key in self._span(prompt, salt, first_block, max_blocks):
             if key not in self._entries:
                 break
             n += 1
         return n * self.block_size
 
     def register(self, prompt: Sequence[int], block_ids: Sequence[int],
-                 salt: bytes = b"") -> int:
+                 salt: bytes = b"", first_block: int = 0) -> int:
         """Make ``prompt``'s whole blocks hittable by later requests.
 
         ``block_ids`` must cover the prompt's full blocks in order (the
@@ -178,11 +190,17 @@ class PrefixCache:
         chain key are left as-is (first writer wins — the bytes are
         bitwise-identical by construction); each newly-registered block
         takes one allocator ref on behalf of the cache.  Returns how many
-        new entries were created."""
+        new entries were created.  ``first_block``: registration starts at
+        that block (a chain of its own: the blocks before it are another
+        group's) and ends at the first block the slot has released (id 0)."""
         prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
         n_full = min(int(prompt.size) // self.block_size, len(block_ids))
         created, parent = 0, None
-        for depth, key in enumerate(self._keys_for(prompt, n_full, salt)):
+        keys = self._keys_for(prompt, n_full, salt)
+        for depth in range(first_block, n_full):
+            key = keys[depth]
+            if not block_ids[depth]:
+                break
             e = self._entries.get(key)
             if e is not None:
                 self._entries.move_to_end(key)

@@ -257,6 +257,58 @@ def test_decode_chunks_on_the_step_span_is_the_kernels_work_list(
     assert ref._decode_chunk_tokens is None
 
 
+def test_windowed_cache_spans_carry_rows_windows_and_the_work_list():
+    """A model that keeps an exact window and chunk summaries
+    (``evabyte_tiny``: windows of 32 in chunks of 4): ``engine.step`` carries
+    the rows its decode program counted and the windows published before it,
+    ``decode_chunks`` is the windowed kernel's own work list (a summary block
+    a window passed, then the chunks of the slot's place in its window),
+    ``engine.prefill`` (one a window of a cold prompt) carries the windows its
+    piece touched and closed, and
+    ``engine.publish_window`` lies inside ``engine.prepare_decode`` around
+    the publishing program."""
+    from paddle_tpu.models.evabyte import EvaByteForCausalLM, evabyte_tiny
+    from paddle_tpu.ops.pallas import eva_attention_kernel as eva
+
+    paddle.seed(0)
+    model = EvaByteForCausalLM(evabyte_tiny())
+    model.eval()
+    eng = Engine(model, num_slots=2, max_seq=128, min_bucket=8, block_size=8)
+    eng.warmup(buckets=[32])
+    arr = eng.cache.sides[0][0]._value()
+    ct = eva.exact_chunk_tokens(arr.shape, arr.dtype.itemsize, 32)
+    t = spans.clock()
+    req = eng.add_request(np.random.default_rng(2).integers(1, 60, (60,)),
+                          max_new_tokens=8)
+    eng.run()
+    assert req.finished
+    rows = rows_since(t)
+    kids = kids_of(rows)
+    steps = [r for r in rows if r[NAME] == "engine.step"
+             and "eva_context" in r[ATTRS]]
+    assert len(steps) == 7
+    for i, st in enumerate(steps):
+        a, pos = st[ATTRS], 60 + i
+        assert (a["eva_exact_rows"], a["eva_summary_rows"],
+                a["eva_context"]) == (pos % 32 + 1, pos // 32 * 8, pos + 1)
+        assert a["decode_chunks"] == pos // 32 + (pos % 32) // ct + 1 == int(
+            eva.decode_items(np.int32(pos), window=32, chunk_tokens=ct))
+        assert a["eva_windows_published"] == (pos == 64)
+    # the cold 60-token prompt went in a window at a time
+    fills = [r[ATTRS] for r in rows if r[NAME] == "engine.prefill"]
+    assert [(a["bucket"], a["eva_windows"], a["eva_windows_published"])
+            for a in fills] == [(32, 1, 1), (32, 1, 0)]
+    (pub,) = [r for r in rows if r[NAME] == "engine.publish_window"]
+    assert pub[ATTRS]["window"] == 1
+    assert pub[ATTRS]["exact_blocks_released"] == 4
+    prepare = [r for r in rows if r[NAME] == "engine.prepare_decode"
+               and pub in kids.get(r[SID], [])]
+    assert len(prepare) == 1
+    ev = eng.stats()["eva"]
+    assert (ev["windows_published_decode"], ev["windows_published_prefill"],
+            ev["steps"]) == (1, 1, 7)
+
+
 def test_sampler_path_on_the_step_span_is_the_way_the_program_went(
         serving_model, monkeypatch):
     """``sampler_path`` is told on the host from the running requests'
@@ -910,3 +962,88 @@ def test_grouped_query_heads_of_128_compile_for_the_chip(one_chip, program):
         == (program == "prefill")
     assert chip_smoke.pool_sized_moves(hlo, eng.cache.layer_nbytes()) == []
     assert mem.alias_size_in_bytes >= eng.cache.nbytes()
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "publish"])
+def test_windowed_pool_programs_move_no_layer_buffer_on_the_chip(
+        one_chip, program):
+    """The same proof for the two-group pool of a model that keeps an exact
+    window and chunk summaries: EvaByte's widths (32 heads of 128 with as
+    many KV heads, SwiGLU of 11,008, windows of 2,048 in chunks of 16,
+    vocabulary 320; two layers), 16 slots of 32,768 positions, block 16, a
+    641-block exact group (K and V ``[641, 16, 32, 128]`` a layer) and a
+    33-block summary group (``[33, 128, 32, 128]``): the decode program
+    (``eva_paged_decode`` and no other attention kernel: no fork a layer),
+    the bucket-512 prefill program (the tail's write, the window it may
+    close published, ``eva_paged_prefill``) and the publishing program, as
+    the chip runs them, hold no ``copy`` / ``transpose`` / ``slice`` of a
+    layer buffer's size of either group — nor of a projection's weights,
+    which stored input-major were copied in every program — and alias every
+    buffer they write: the exact group in decode, both in prefill, the
+    summary group in the publishing program."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import evabyte as em
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = em.EvaByteForCausalLM(em.EvaByteConfig(num_hidden_layers=2,
+                                                   dtype="bfloat16"))
+    eng = Engine(model, num_slots=16, max_seq=32768, min_bucket=512,
+                 block_size=16, num_kv_blocks=641, num_summary_blocks=33,
+                 kernel="pallas")
+    assert [tuple(b.shape) for b in eng.cache.buffers()] == \
+        [(641, 16, 32, 128)] * 4
+    assert [tuple(b.shape) for b in eng.cache.summary_buffers()] == \
+        [(33, 128, 32, 128)] * 4
+    eng.cache._interpret = False          # the kernels as the chip runs them
+    eng._build_steps()
+    if program == "decode":
+        fn, args = eng._decode_fn, [np.zeros((16,), np.int32)]
+    elif program == "publish":
+        fn, args = eng._publish_fn, [np.int32(0), np.int32(0)]
+    else:
+        fn, args = eng._prefill_fn, [np.zeros((1, 512), np.int64),
+                                     np.int32(0), np.int32(1), np.int32(0)]
+        assert eng.cache.begin_sequence(0, None, 0, 512)
+    leaves = []
+    args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+    prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+    def on_chip(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    with no_grad():
+        prog.build(leaves)
+        sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+        compiled = prog.jitted_donate.lower(
+            [on_chip(t._value()) for t in leaves], [on_chip(a) for a in sd],
+            [on_chip(a) for a in sk]).compile()
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    exact = sum(int(b._value().nbytes) for b in eng.cache.buffers())
+    summary = sum(int(b._value().nbytes)
+                  for b in eng.cache.summary_buffers())
+    assert eng.cache.nbytes() == exact + summary
+    assert eng.cache.layer_nbytes() == 641 * 16 * 32 * 128 * 2
+    summary_buf = 33 * 128 * 32 * 128 * 2
+    kernels = {"decode": ("eva_paged_decode",),
+               "prefill": ("eva_paged_prefill",), "publish": ()}[program]
+    for kernel in kernels:
+        assert re.search(r"%" + kernel + r"(\.\d+)? = ", hlo), kernel
+    assert not re.search(r"%paged_decode_attention(\.\d+)? = ", hlo)
+    # a projection's weights are 4096 x 4096 x 2 B = 32 MiB, under both
+    assert chip_smoke.pool_sized_moves(hlo, 4096 * 4096 * 2) == []
+    written = {"decode": exact, "prefill": exact + summary,
+               "publish": summary}[program]
+    assert mem.alias_size_in_bytes >= written
+    assert mem.temp_size_in_bytes < eng.cache.layer_nbytes()
+    assert summary_buf < eng.cache.layer_nbytes()
+    # the scopes of the two halves, in the ops' names
+    scopes = {"decode": ("eva.attend", "kv.write"),
+              "prefill": ("eva.attend", "eva.summarise", "kv.write"),
+              "publish": ("eva.summarise",)}[program]
+    for scope in scopes:
+        assert scope in hlo, scope
