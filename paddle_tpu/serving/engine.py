@@ -686,6 +686,11 @@ class Engine:
         self._eva = {"steps": 0, "exact_rows": 0, "summary_rows": 0,
                      "context": 0, "windows_published_decode": 0,
                      "windows_published_prefill": 0, "prefill_windows": 0}
+        #: a latent pool's prefills: the (query, key) pairs of the admitted
+        #: prompts' real tokens, by the form the prefill program attends to
+        #: them in
+        self._latent = {"prefills": 0, "pairs_upprojected": 0,
+                        "pairs_absorbed": 0}
         self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
@@ -1617,6 +1622,8 @@ class Engine:
                     self._sparse["prefill_context"] += scored
                 if self.cache_spec.kind == "windowed":
                     self._note_prefill_windows(sp, start, end)
+                if self.cache_spec.kind == "latent":
+                    self._note_prefill_pairs(sp, start, end)
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -1995,6 +2002,20 @@ class Engine:
                eva_keys=L - start // W * W + (L - 1) // W * rows_a_window)
         self._eva["prefill_windows"] += touched
         self._eva["windows_published_prefill"] += closed
+
+    def _note_prefill_pairs(self, sp, start: int, L: int) -> None:
+        """The (query, key) pairs of the tail ``[start, L)``'s real tokens,
+        by the latent prefill program's own rule: a key of the tail itself
+        is in the program's hands and is attended to up-projected (the
+        causal square), a cached one lies in the pool and is attended to
+        absorbed (the rectangle under ``start``)."""
+        n = L - start
+        square, rectangle = n * (n + 1) // 2, n * start
+        sp.set(latent_pairs_upprojected=square,
+               latent_pairs_absorbed=rectangle)
+        self._latent["prefills"] += 1
+        self._latent["pairs_upprojected"] += square
+        self._latent["pairs_absorbed"] += rectangle
 
     def _decode(self) -> None:
         """One decode step (or, with speculation on, one ROUND: k draft
@@ -2947,6 +2968,8 @@ class Engine:
             snap["moe"] = dict(self._moe)
         if self.cache_spec.kind == "indexed":
             snap["sparse"] = dict(self._sparse)
+        if self.cache_spec.kind == "latent":
+            snap["latent"] = dict(self._latent)
         if self.cache_spec.kind == "windowed":
             snap["eva"] = dict(
                 self._eva,

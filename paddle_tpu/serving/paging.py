@@ -540,46 +540,56 @@ class PagedKVCache:
                             (Tensor._wrap(lat._value()[:, :, None, :]),),
                             start)
 
-    def _latent_call(self, layer_idx: int, name: str, q_lat, *args, **kw):
-        """``mla_<name>`` (the Pallas kernel, or its jnp oracle under
-        ``kernel="reference"``) on this layer's pool as the kernels take it
-        (``[num_blocks, block_size, lanes]``: the one "head" dropped) with
-        ``q_lat [..., H, width]`` zero-padded to the pool's lanes."""
-        from ..ops.pallas import mla_attention_kernel as mla
-
+    def _latent_pool(self, layer_idx: int):
+        """This layer's pool as the latent kernels take it: ``[num_blocks,
+        block_size, lanes]``, the one "head" dropped."""
         pool = self.sides[0][layer_idx]._value()
-        pool = pool.reshape(pool.shape[0], pool.shape[1], pool.shape[3])
-        q = self._to_lanes(q_lat, q_lat.dtype)
-        if self.kernel == "pallas":
-            return getattr(mla, f"mla_paged_{name}")(
-                q, pool, *args, interpret=self._interpret, **kw)
-        return getattr(mla, f"mla_{name}_reference")(q, pool, *args, **kw)
+        return pool.reshape(pool.shape[0], pool.shape[1], pool.shape[3])
 
     def latent_decode_attention(self, layer_idx: int, q_lat, lat, active, *,
                                 scale: float, dv: int):
         """One decode step of absorbed latent attention for this layer:
         write each slot's vector ``lat [slots, 1, width]``, then
-        ``q_lat [slots, 1, H, width]`` attends over each active slot's
-        window.  Returns ``[slots, 1, H, dv]``."""
+        ``q_lat [slots, 1, H, width]`` (zero-padded to the pool's lanes
+        here) attends over each active slot's window: ``mla_paged_decode``,
+        or its jnp oracle under ``kernel="reference"``.  Returns
+        ``[slots, 1, H, dv]``."""
+        from ..ops.pallas import mla_attention_kernel as mla
+
         _pool, tbl, lens = self._decode_token_write(
             layer_idx, Tensor._wrap(lat._value()[:, :, None, :]))
-        out = self._latent_call(layer_idx, "decode", q_lat._value()[:, 0],
-                                tbl, lens, _as_i32(active), scale=scale,
-                                dv=dv)
+        q = q_lat._value()[:, 0]
+        args = (self._to_lanes(q, q.dtype), self._latent_pool(layer_idx), tbl,
+                lens, _as_i32(active))
+        if self.kernel == "pallas":
+            out = mla.mla_paged_decode(*args, scale=scale, dv=dv,
+                                       interpret=self._interpret)
+        else:
+            out = mla.mla_decode_reference(*args, scale=scale, dv=dv)
         return Tensor._wrap(out[:, None])
 
-    def latent_prefill_attention(self, layer_idx: int, slot, q_lat, start,
-                                 length, *, scale: float, dv: int):
-        """Tail queries ``q_lat [1, S, H, width]`` over the slot's whole
-        block row (cached prefix + the tail just written).  Returns
-        ``[1, S, H, dv]``."""
+    def latent_prefill_attention(self, layer_idx: int, slot, q, lat, w_uk,
+                                 w_uv, start, length, *, scale: float):
+        """Tail queries ``q [1, S, H, nope + rope]`` over the tail's own
+        latents ``lat [1, S, width]`` (just written; attended to in the
+        up-projected form, from the program's hands) and the ``start``
+        cached tokens of the slot's block row (absorbed, off the pool):
+        ``mla_prefill``, or its one-softmax oracle under
+        ``kernel="reference"``.  Returns ``[1, S, H, v]``, through ``W^V``."""
+        from ..ops.pallas import mla_attention_kernel as mla
+
         row = jax.lax.dynamic_index_in_dim(
             self.block_tables._value(), _as_i32(slot).reshape(()), axis=0,
             keepdims=False)
-        out = self._latent_call(layer_idx, "prefill", q_lat._value()[0], row,
-                                _as_i32(start).reshape(()),
-                                _as_i32(length).reshape(()), scale=scale,
-                                dv=dv)
+        args = (self._latent_pool(layer_idx), row, _as_i32(start).reshape(()),
+                _as_i32(length).reshape(()))
+        if self.kernel == "pallas":
+            out = mla.mla_prefill(q._value()[0], lat._value()[0], w_uk, w_uv,
+                                  *args, scale=scale,
+                                  interpret=self._interpret)
+        else:
+            out = mla.mla_prefill_oracle(q._value()[0], w_uk, w_uv, *args,
+                                         scale=scale)
         return Tensor._wrap(out[None])
 
     # -- the indexed pool's calls (K, V and the indexer's key) ---------------
@@ -889,10 +899,10 @@ class PagedCacheContext(CacheContext):
         self.cache.latent_prefill_write(self.layer_idx, self.slot, lat,
                                         self._prefill_start())
 
-    def latent_prefill_attention(self, q_lat, *, scale: float, dv: int):
+    def latent_prefill_attention(self, q, lat, w_uk, w_uv, *, scale: float):
         return self.cache.latent_prefill_attention(
-            self.layer_idx, self.slot, q_lat, self._prefill_start(),
-            self.length, scale=scale, dv=dv)
+            self.layer_idx, self.slot, q, lat, w_uk, w_uv,
+            self._prefill_start(), self.length, scale=scale)
 
     def latent_decode_attention(self, q_lat, lat, *, scale: float, dv: int):
         if self.mode != "decode":

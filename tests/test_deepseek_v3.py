@@ -181,6 +181,54 @@ def test_bf16_engine_serves_within_a_tolerance_the_fp8_control_exceeds():
     assert worst <= TOL < control, (worst, control)
 
 
+def test_cold_warm_and_long_tail_admissions_serve_the_cache_free_tokens():
+    """Three admissions through the engine's Pallas path in float32: a cold
+    prompt (every pair up-projected: the causal square), a short question
+    behind the 48 tokens it left cached, and a long tail behind 16 of them
+    (both: the square up-projected, the rectangle under the prefix absorbed,
+    merged).  Each serves the greedy tokens of the model's cache-free
+    forward (one masked absorbed softmax), and its ``engine.prefill`` span
+    carries the pairs of its real tokens by form."""
+    import time
+
+    from paddle_tpu.obs import spans as _spans
+
+    model, _tree, _d = seeded("float32", max_position_embeddings=256)
+    eng = inference.create_engine(model, block_size=BLOCK, min_bucket=16,
+                                  max_seq=128, num_slots=4)
+    assert eng.kernel == "pallas"
+    eng.warmup()
+    rng = np.random.default_rng(9)
+    doc = rng.integers(0, 512, (48,), dtype=np.int32)
+    prompts = [np.concatenate([doc, rng.integers(0, 512, (n,), np.int32)])
+               for n in (5, 7)]
+    prompts.append(np.concatenate([doc[:16],
+                                   rng.integers(0, 512, (60,), np.int32)]))
+    t0 = time.perf_counter()
+    reqs = []
+    for p in prompts:                 # one at a time: the next one's hit is
+        reqs.append(eng.add_request(p, max_new_tokens=6))   # what this left
+        eng.run()
+    st = eng.stats()
+    assert not any(st["failures"].values())
+    for p, r in zip(prompts, reqs):
+        out = np.asarray(r.output_ids, np.int32)
+        assert len(out) == 6
+        full = np.concatenate([p, out])
+        lg = np.asarray(model(paddle.to_tensor(full[None]))._value())[0]
+        rows = lg[len(p) - 1:len(full) - 1]
+        assert (rows.max(-1) - rows[np.arange(6), out]).max() <= 1e-4
+    fills = [(r[4]["bucket"], r[4]["latent_pairs_upprojected"],
+              r[4]["latent_pairs_absorbed"])
+             for r in _spans.snapshot(t0) if r[0] == "engine.prefill"]
+    assert fills == [(64, 53 * 54 // 2, 0), (16, 7 * 8 // 2, 7 * 48),
+                     (64, 60 * 61 // 2, 60 * 16)]
+    assert st["latent"] == {
+        "prefills": 3, "pairs_upprojected": sum(f[1] for f in fills),
+        "pairs_absorbed": sum(f[2] for f in fills)}
+    assert eng.health()["kv_block_invariants"] == "ok"
+
+
 # -- (c) the share test -------------------------------------------------------
 
 def test_the_four_shares_and_the_shared_expert_once_are_the_whole_layer():

@@ -774,9 +774,10 @@ def test_latent_pool_programs_move_no_layer_buffer_on_the_chip(
     vocabulary cut to 8,192), 32 slots, block 16, a 2,049-block pool of ONE
     buffer a layer ``[2049, 16, 1, 640]``: the decode program and the
     bucket-256 prefill program, with ``mla_paged_decode`` /
-    ``mla_paged_prefill`` and ``moe_grouped_matmul`` as the chip runs them,
-    hold no ``copy`` / ``transpose`` / ``slice`` of a layer buffer's size
-    and alias the whole pool."""
+    ``mla_flash_prefill`` + ``mla_paged_prefill`` (the tail over itself, and
+    over the cached prefix behind a ``start > 0``) and ``moe_grouped_matmul``
+    as the chip runs them, hold no ``copy`` / ``transpose`` / ``slice`` of a
+    layer buffer's size and alias the whole pool."""
     import jax
 
     import chip_smoke
@@ -821,9 +822,13 @@ def test_latent_pool_programs_move_no_layer_buffer_on_the_chip(
     hlo, mem = compiled.as_text(), compiled.memory_analysis()
     pool, layer_buf = eng.cache.nbytes(), eng.cache.layer_nbytes()
     assert layer_buf == 2049 * 16 * 640 * 2 and pool == 2 * layer_buf
-    # one attention call a layer, two grouped products in the expert layer
-    assert hlo.count(chip_smoke.PALLAS_CALL) == 2 + 2
-    for kernel in ("mla_paged_" + program, "moe_grouped_matmul"):
+    # one attention call a layer (two in a prefill: the flash pass, and the
+    # absorbed kernel in the branch a cached prefix takes), two grouped
+    # products in the expert layer
+    attention = {"decode": ["mla_paged_decode"],
+                 "prefill": ["mla_flash_prefill", "mla_paged_prefill"]}
+    assert hlo.count(chip_smoke.PALLAS_CALL) == 2 * len(attention[program]) + 2
+    for kernel in (*attention[program], "moe_grouped_matmul"):
         assert re.search(r"%" + kernel + r"(\.\d+)? = ", hlo), kernel
     assert chip_smoke.pool_sized_moves(hlo, layer_buf) == []
     assert mem.alias_size_in_bytes >= pool

@@ -375,6 +375,26 @@ def _latent_case(rs, *, slots=4, heads=4, width=40, bs=8, mb=40, nb=200):
     return jnp.asarray(pool), jnp.asarray(tbl), jnp.asarray(q)
 
 
+def _prefill_case(rs, bucket, start, dtype="float32", *, heads=4, nope=16,
+                  rope=8, rank=32, dv=16, bs=8):
+    """``(q, lat, w_uk, w_uv, pool, row)``: a tail bucket of ``bucket`` rows
+    behind ``start`` cached tokens of one slot — queries ``[q_nope |
+    q_rope]``, the tail's latents (written to the slot's blocks of the pool,
+    as ``write_prefill_latent`` leaves them) and the two up-projections."""
+    n = (start + bucket) // bs
+    ids = rs.permutation(n + 4)[:n] + 1             # block 0: scratch
+    lat = rs.randn(start + bucket, rank + rope)
+    pool = np.zeros((n + 5, bs, 128), np.float32)
+    pool[ids, :, :rank + rope] = lat.reshape(n, bs, -1)
+    row = np.zeros(n + 3, np.int32)
+    row[:n] = ids
+    q = rs.randn(bucket, heads, nope + rope)
+    w_uk, w_uv = rs.randn(heads, nope, rank) * 0.2, rs.randn(heads, rank,
+                                                             dv) * 0.2
+    return (*(jnp.asarray(a, dtype) for a in (q, lat[start:], w_uk, w_uv,
+                                               pool)), jnp.asarray(row))
+
+
 class TestLatentKernels:
     @pytest.mark.parametrize("lengths", [(5, 130, 0, 300), (255, 256, 0, 7)])
     def test_decode_matches_its_oracle_on_ragged_lengths(self, lengths):
@@ -401,25 +421,115 @@ class TestLatentKernels:
     @pytest.mark.parametrize("start,length", [(0, 50), (96, 160), (256, 266)])
     def test_prefill_matches_its_oracle_below_the_prompts_length(
             self, start, length):
-        """A 64-token tail bucket behind ``start`` cached tokens; the rows
-        past the prompt's real length are pad: a tile wholly of them is not
-        computed and comes back zero."""
+        """A 64-token tail bucket behind ``start`` cached tokens, both parts
+        (the tail over itself up-projected, the prefix absorbed, merged)
+        against one absorbed softmax over the slot's whole block row; the
+        rows past the prompt's real length are pad: a tile of the absorbed
+        kernel wholly of them is not computed and comes back zero (a piece
+        of the flash pass: the test below)."""
         from paddle_tpu.ops.pallas import mla_attention_kernel as mk
 
-        rs = np.random.RandomState(1)
-        pool, tbl, _ = _latent_case(rs)
-        q = np.zeros((64, 4, 128), np.float32)
-        q[..., :40] = rs.randn(64, 4, 40)
-        q = jnp.asarray(q)
-        kw = dict(scale=0.2, dv=32)
-        got = mk.mla_paged_prefill(q, pool, tbl[1], start, length,
-                                   interpret=True, **kw)
-        want = mk.mla_prefill_reference(q, pool, tbl[1], start, length, **kw)
+        q, lat, w_uk, w_uv, pool, row = _prefill_case(
+            np.random.RandomState(1), 64, start)
+        got = mk.mla_prefill(q, lat, w_uk, w_uv, pool, row, start, length,
+                             scale=0.2, interpret=True)
+        want = mk.mla_prefill_oracle(q, w_uk, w_uv, pool, row, start, length,
+                                     scale=0.2)
         real = length - start
         np.testing.assert_allclose(np.asarray(got[:real]),
                                    np.asarray(want[:real]), atol=2e-5, rtol=0)
         if real <= 32:
-            assert not np.asarray(got[32:]).any()
+            q_lat = mk.absorb_queries(q[..., :16], q[..., 16:], w_uk, 128)
+            o, lse = mk.mla_paged_prefill(q_lat, pool, row, start, length,
+                                          scale=0.2, dv=32, interpret=True)
+            assert not np.asarray(o[32:]).any()
+            assert (np.asarray(lse[32:]) == mk.NEG_INF).all()
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("start", [0, 48])
+    @pytest.mark.parametrize("bucket", [32, 64, 256, 1024])
+    def test_composed_prefill_equals_one_absorbed_softmax(
+            self, bucket, start, dtype):
+        """Buckets from 32 to 1,024 rows (one to four pieces of the flash
+        pass's walk), cold and behind a block-aligned cached prefix, a real
+        length seven rows short of the bucket; bf16 operands against the
+        float32 oracle on the same rounded numbers."""
+        from paddle_tpu.ops.pallas import mla_attention_kernel as mk
+
+        case = _prefill_case(np.random.RandomState(bucket + start), bucket,
+                             start, dtype)
+        q, lat, w_uk, w_uv, pool, row = case
+        length = start + bucket - 7
+        got = mk.mla_prefill(q, lat, w_uk, w_uv, pool, row, start, length,
+                             scale=0.2, interpret=True)
+        assert got.dtype == q.dtype and got.shape == (bucket, 4, 16)
+        q, w_uk, w_uv, pool = (a.astype(jnp.float32)
+                               for a in (q, w_uk, w_uv, pool))
+        want = mk.mla_prefill_oracle(q, w_uk, w_uv, pool, row, start, length,
+                                     scale=0.2)
+        np.testing.assert_allclose(
+            np.asarray(got[:bucket - 7], np.float32),
+            np.asarray(want[:bucket - 7]),
+            atol=2e-5 if dtype == "float32" else 3e-2, rtol=0)
+        assert np.isfinite(np.asarray(got, np.float32)).all()
+
+    @pytest.mark.parametrize("S,block,sub,real", [
+        (256, 64, 32, 256),       # four blocks a head: pairs under, on and
+        (256, 128, 32, 100),      # above the diagonal; blocks of pad rows
+        (128, 128, 128, 128),     # one piece: the masked square alone
+        (512, 256, 128, 300),
+    ])
+    def test_flash_pass_matches_a_plain_causal_softmax(self, S, block, sub,
+                                                       real):
+        """The up-projected pass alone, sizes pinned so that a head takes
+        several blocks: output and log-sum-exp below the real length, zero
+        and ``NEG_INF`` in the pieces wholly past it."""
+        from paddle_tpu.ops.pallas import mla_attention_kernel as mk
+
+        rs = np.random.RandomState(S + sub)
+        H, nope, rope, dv = 2, 16, 8, 16
+        q = jnp.asarray(rs.randn(S, H, nope + rope), jnp.float32)
+        k = jnp.asarray(rs.randn(S, H, nope), jnp.float32)
+        kr = jnp.asarray(rs.randn(S, rope), jnp.float32)
+        v = jnp.asarray(rs.randn(S, H, dv), jnp.float32)
+        o_t, lse = mk.mla_flash_prefill(
+            q.transpose(1, 2, 0), k.transpose(1, 0, 2), kr,
+            v.transpose(1, 2, 0), real, scale=0.2, block=block, sub=sub,
+            interpret=True)
+        s = (jnp.einsum("qhd,khd->hqk", q[..., :nope], k)
+             + jnp.einsum("qhd,kd->hqk", q[..., nope:], kr)) * 0.2
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool))[None], s, -jnp.inf)
+        want = jnp.einsum("hqk,khd->hdq", jax.nn.softmax(s, axis=-1), v)
+        np.testing.assert_allclose(np.asarray(o_t[..., :real]),
+                                   np.asarray(want[..., :real]), atol=2e-5,
+                                   rtol=0)
+        np.testing.assert_allclose(
+            np.asarray(lse[:, :real]),
+            np.asarray(jax.nn.logsumexp(s, axis=-1)[:, :real]), atol=2e-5,
+            rtol=0)
+        dead = -(-real // sub) * sub
+        assert not np.asarray(o_t[..., dead:]).any()
+        assert (np.asarray(lse[:, dead:]) == mk.NEG_INF).all()
+
+    @pytest.mark.parametrize("n1,n2", [(40, 24), (1, 300), (128, 1)])
+    def test_merged_partials_are_one_softmax_over_both_regions(self, n1, n2):
+        from paddle_tpu.ops.pallas import mla_attention_kernel as mk
+
+        rs = np.random.RandomState(n1)
+        s = jnp.asarray(rs.randn(6, 4, n1 + n2) * 3, jnp.float32)
+        v = jnp.asarray(rs.randn(n1 + n2, 16), jnp.float32)
+        parts = []
+        for sl in (slice(0, n1), slice(n1, None)):
+            parts += [jax.nn.softmax(s[..., sl], axis=-1) @ v[sl],
+                      jax.nn.logsumexp(s[..., sl], axis=-1)]
+        got = mk.merge_partials(*parts)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(jax.nn.softmax(s, axis=-1) @ v),
+            atol=2e-6, rtol=0)
+        # rows that neither part computed (pad rows: zero, NEG_INF) stay zero
+        dead = jnp.full((6, 4), mk.NEG_INF), jnp.zeros((6, 4, 16))
+        assert not np.asarray(mk.merge_partials(
+            dead[1], dead[0], dead[1], dead[0])).any()
 
 
 @pytest.mark.parametrize("rows,sizes", [
