@@ -58,6 +58,9 @@ def set_grad_enabled(mode: bool):
     _state.enabled = bool(mode)
 
 
+_NO_SCOPE = contextlib.nullcontext()   # an eager node's backward opens none
+
+
 class TapeNode:
     """One recorded differentiable op (reference: GradNodeBase + captured
     TensorWrappers).  Holds the vjp closure (residuals live inside it), strong
@@ -65,12 +68,17 @@ class TapeNode:
     collected by the python GC once user refs drop)."""
 
     __slots__ = ("vjp_fn", "primal_fn", "input_arrays", "inputs", "outputs",
-                 "name", "released", "materialize", "input_edges",
+                 "name", "released", "materialize", "input_edges", "scope",
                  "__weakref__")
 
     def __init__(self, vjp_fn, inputs, outputs, name="", materialize=True,
-                 primal_fn=None, input_arrays=None):
+                 primal_fn=None, input_arrays=None, scope=None):
         self.vjp_fn = vjp_fn
+        # the name-scope path the op was recorded under while ``to_static``
+        # traced (``gpt/layers/3/attn``; None in eager mode): its backward
+        # runs under ``transpose(<that path>)``, so the compiled program's
+        # op names give a layer its backward as they give it its forward
+        self.scope = scope
         # pure function of the diff inputs' ARRAYS (non-diff args baked),
         # kept so grad(create_graph=True) can replay the subgraph as one
         # differentiable jax function — the stored vjp closure alone bakes
@@ -201,15 +209,17 @@ def backward(tensors, grad_tensors=None, retain_graph: bool = False):
                     "modified by an in-place operation, so its backward "
                     "routing is no longer valid; clone() it before the "
                     "in-place op")
-        in_cts = node.vjp_fn(tuple(cts) if len(cts) > 1 else cts[0])
-        for t, g in zip(node.inputs, in_cts):
-            if g is None:
-                continue
-            if t._grad_node is None:
-                if not t.stop_gradient:
-                    t._accumulate_grad(g)
-            else:
-                _accum(cot, keep, t, g)
+        with (_NO_SCOPE if node.scope is None
+              else jax.named_scope(f"transpose({node.scope})")):
+            in_cts = node.vjp_fn(tuple(cts) if len(cts) > 1 else cts[0])
+            for t, g in zip(node.inputs, in_cts):
+                if g is None:
+                    continue
+                if t._grad_node is None:
+                    if not t.stop_gradient:
+                        t._accumulate_grad(g)
+                else:
+                    _accum(cot, keep, t, g)
         if not retain_graph:
             node.release()
 

@@ -15,9 +15,11 @@ from typing import Any, Callable, List, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.extend.source_info_util import current_name_stack
 
 from . import dtype as dtype_mod
 from .autograd import TapeNode, is_grad_enabled
+from . import tensor as tensor_mod
 from .tensor import Tensor
 from .flags import get_flag
 
@@ -141,6 +143,8 @@ def apply_op(
         name=name,
         primal_fn=_primal_on_diff,
         input_arrays=[arrays[i] for i in diff_idx],
+        scope=(None if tensor_mod._trace_hook is None
+               else str(current_name_stack())),
     )
     for t in outs_list:
         t._grad_node = node

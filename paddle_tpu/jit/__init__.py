@@ -232,6 +232,17 @@ class StaticFunction:
             try:
                 with compiled:
                     out = prog(leaves)
+                    # the handle of the executable the next call runs, from
+                    # jax's own cache: what ``scope_map()`` reads on request,
+                    # also once the program itself is gone
+                    # (``jit.trace.ProgramText``).  Where the first call
+                    # changed the state's placement (a step under a mesh:
+                    # single-device arrays in, mesh-placed arrays out) this
+                    # compiles the signature the second call would compile
+                    try:
+                        prog.text()
+                    except Exception:    # noqa: BLE001 — asked again, and
+                        pass             # raised to the asker, on request
             finally:
                 if _compile_listeners:
                     _notify_compile(self, key, attrs,

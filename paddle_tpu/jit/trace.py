@@ -23,7 +23,9 @@ stack (SURVEY.md §2.3) — which, TPU-native, collapse into ``jax.jit``
 """
 from __future__ import annotations
 
+import collections
 import re
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -267,12 +269,55 @@ def program_name(fn) -> str:
     return re.sub(r"\W", "_", str(name)) or "program"
 
 
+class ProgramText:
+    """What a program's scope map is read from, and what outlives the
+    program for its sake: the name of its HLO module and the jax
+    ``Compiled`` of the signature it ran.  The optimized text is asked of
+    the executable, parsed and kept only when :meth:`scope_map` is first
+    called; that is one ``jit.scope_map`` span in the ring."""
+
+    __slots__ = ("module", "compiled", "_map")
+
+    def __init__(self, module: str, compiled):
+        self.module, self.compiled, self._map = module, compiled, None
+
+    def scope_map(self) -> dict:
+        if self._map is None:
+            from ..obs import hlo_cost, spans
+
+            with spans.span("jit.scope_map", module=self.module) as sp:
+                self._map = hlo_cost.scope_map(self.compiled.as_text())
+                rows = self._map["instructions"].values()
+                sp.set(instructions=len(rows), scoped=sum(
+                    1 for scope, _d in rows if scope != hlo_cost.UNSCOPED))
+        return self._map
+
+
+#: every program ``build`` has built and that is still alive — the registry
+#: keeps none alive — and the texts of the last few that ran and are gone
+#: (a driver may drop its step before anybody reads the profile it ran under)
+_built: "weakref.WeakSet[CompiledProgram]" = weakref.WeakSet()
+_gone: "collections.deque[ProgramText]" = collections.deque(maxlen=32)
+
+
+def program_texts(modules) -> List[ProgramText]:
+    """The :class:`ProgramText` of every registered program whose HLO
+    module is named in ``modules``: live programs first (one that never ran
+    is lowered and compiled for it), then those that are gone."""
+    live = [p.text() for p in list(_built) if p.module in modules]
+    return live + [t for t in list(_gone) if t.module in modules]
+
+
 class CompiledProgram:
     """One (input-spec → XLA executable) entry (reference: ConcreteProgram +
     cached InterpreterCore, executor_cache.cc)."""
 
     def __init__(self, fn, args_tree, kwargs_tree, donate=True):
         self.fn = fn
+        #: the name of the program's HLO module (``jit_decode_step``): what
+        #: a profiler trace's ``XLA Modules`` line calls its executions
+        self.module = "jit_" + program_name(fn)
+        self._text: Optional[ProgramText] = None
         self.args_tree = args_tree
         self.kwargs_tree = kwargs_tree
         self.donate = donate
@@ -376,6 +421,8 @@ class CompiledProgram:
         # for the differentiable path (vjp residuals may alias state bufs)
         self.jitted = jax.jit(program)
         self.jitted_donate = jax.jit(program, donate_argnums=(1,))
+        self._ran = self.jitted_donate if self.donate else self.jitted
+        _built.add(self)
         return self
 
     def _split_state(self, state_arrays):
@@ -388,11 +435,8 @@ class CompiledProgram:
         XLA memory analysis + optimized HLO text (shares jax's executable
         cache with normal calls — cheap after the first run).  Powers the
         multichip gate's per-config stats (collective bytes, peak HBM)."""
-        state_arrays = [k.current() for k in self.state_keys]
-        sd, sk = self._split_state(state_arrays)
-        run = self.jitted_donate if self.donate else self.jitted
-        lowered = run.lower(self._last_arg_arrays, sd, sk)
-        compiled = lowered.compile()
+        compiled = self._compiled(
+            self.jitted_donate if self.donate else self.jitted)
         out = {"hlo": compiled.as_text()}
         try:
             ma = compiled.memory_analysis()
@@ -418,6 +462,31 @@ class CompiledProgram:
             pass
         return out
 
+    def _compiled(self, run):
+        """The jax ``Compiled`` of ``run`` at the last call's arguments and
+        the state as it is now: the signature the next call runs.  Where
+        that is the signature already run, jax hands back the lowering and
+        the executable it cached (milliseconds); where the first call
+        changed the state's placement, this is the next call's compile."""
+        sd, sk = self._split_state([k.current() for k in self.state_keys])
+        return run.lower(self._last_arg_arrays, sd, sk).compile()
+
+    def text(self) -> ProgramText:
+        """The program's :class:`ProgramText`, taken once: at its first
+        call (``StaticFunction``, a program-cache miss) or when first asked.
+        From then on the text outlives the program: when the program is
+        collected it moves to the registry's short list of those gone."""
+        if self._text is None:
+            self._text = ProgramText(self.module, self._compiled(self._ran))
+            weakref.finalize(self, _gone.append, self._text).atexit = False
+        return self._text
+
+    def scope_map(self) -> dict:
+        """``{"module", "instructions": {name: (scope, direction)}}`` of the
+        program's own optimized HLO (``obs.hlo_cost.scope_map``): built on
+        request, kept with the program's text."""
+        return self.text().scope_map()
+
     def _writeback(self, write_arrays):
         for k, none_at_build, arr in zip(
                 self.write_keys, self.write_none_mask, write_arrays):
@@ -440,7 +509,8 @@ class CompiledProgram:
         )
         if not outer_diff:
             sd, sk = self._split_state(state_arrays)
-            run = self.jitted_donate if self.donate else self.jitted
+            run = self._ran = \
+                self.jitted_donate if self.donate else self.jitted
             out_arrays, write_arrays = run(arg_arrays, sd, sk)
             if get_flag("check_nan_inf"):
                 from ..core import error_guard
@@ -452,6 +522,7 @@ class CompiledProgram:
 
         # pure-forward program: dispatch through the tape so outer backward
         # flows into args and lifted parameters (reference: run_program grad)
+        self._ran = self.jitted
         n_out = _count_tensor_leaves(self.out_tree)
         n_args = len(arg_tensors)
         state_wrappers = []

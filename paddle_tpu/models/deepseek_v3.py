@@ -62,9 +62,9 @@ from ..nn import initializer as I
 from ..nn.layer_base import Layer
 from ..nn.layer.container import LayerList
 from ..ops.pallas.mla_attention_kernel import ABSORB_SCOPE, absorb_queries
-from .held_experts import (EXPERTS_SCOPE, F32, ROUTE_SCOPE,  # noqa: F401
-                           held_experts_forward, _interpret, _Normal, _rms,
-                           _swiglu)
+from .held_experts import (EMBED_SCOPE, EXPERTS_SCOPE, F32,  # noqa: F401
+                           HEAD_SCOPE, ROUTE_SCOPE, held_experts_forward,
+                           _interpret, _Normal, _rms, _swiglu)
 
 
 @dataclass
@@ -331,8 +331,9 @@ class DeepseekV3Model(Layer):
         # norm (cast to the weights' dtype) and adds a float32 result, so
         # the stream's own rounding does not pile up layer by layer and
         # reach the router, whose top-k flips on a near tie
-        h = jnp.take(self.embed_tokens._value(), input_ids, axis=0
-                     ).astype(F32)
+        with jax.named_scope(EMBED_SCOPE):
+            h = jnp.take(self.embed_tokens._value(), input_ids, axis=0
+                         ).astype(F32)
         for i, layer in enumerate(self.layers):
             if cache_ctx is not None:
                 cache_ctx.layer_idx = i
@@ -362,9 +363,10 @@ class DeepseekV3ForCausalLM(Layer):
         ids = input_ids._value() if isinstance(input_ids, Tensor) \
             else jnp.asarray(input_ids)
         h = self.model(ids.astype(jnp.int32), cache_ctx)
-        if cache_ctx is not None:
-            # prefill: the head sees the one row the engine samples from
-            h = cache_ctx.select_last(Tensor._wrap(h))._value()
-        h = _rms(h, self.model.norm._value(), self.config.rms_norm_eps)
-        return Tensor._wrap(jnp.dot(h, self.lm_head._value(),
-                                    preferred_element_type=F32))
+        with jax.named_scope(HEAD_SCOPE):
+            if cache_ctx is not None:
+                # prefill: the head sees the one row the engine samples from
+                h = cache_ctx.select_last(Tensor._wrap(h))._value()
+            h = _rms(h, self.model.norm._value(), self.config.rms_norm_eps)
+            return Tensor._wrap(jnp.dot(h, self.lm_head._value(),
+                                        preferred_element_type=F32))

@@ -43,7 +43,7 @@ from ..nn.layer_base import Layer
 from ..nn.layer.container import LayerList
 from ..ops.pallas.eva_attention_kernel import (ATTEND_SCOPE, NEG_INF,
                                                chunk_summaries)
-from .held_experts import F32, _Normal
+from .held_experts import EMBED_SCOPE, F32, HEAD_SCOPE, _Normal
 from .keye_vl2 import _angles, _rotate_half
 
 
@@ -228,8 +228,9 @@ class EvaByteModel(Layer):
     def forward(self, input_ids, cache_ctx=None):
         """``input_ids [B, S]`` (raw) -> final hidden states ``[B, S, h]``
         (raw, float32, not yet normed): the residual stream is float32."""
-        h = jnp.take(self.embed_tokens._value(), input_ids, axis=0
-                     ).astype(F32)
+        with jax.named_scope(EMBED_SCOPE):
+            h = jnp.take(self.embed_tokens._value(), input_ids, axis=0
+                         ).astype(F32)
         for i, layer in enumerate(self.layers):
             if cache_ctx is not None:
                 cache_ctx.layer_idx = i
@@ -273,15 +274,16 @@ class EvaByteForCausalLM(Layer):
         ids = (input_ids._value() if isinstance(input_ids, Tensor)
                else jnp.asarray(input_ids)).astype(jnp.int32)
         h = self.model(ids, cache_ctx)
-        if cache_ctx is not None:
-            # prefill: the head sees the one row the engine samples from
-            h = cache_ctx.select_last(Tensor._wrap(h))._value()
-        h = _rms_unit(h, self.model.norm._value(), c.rms_norm_eps)
-        head = self.lm_head._value()
-        if not all_heads:
-            head = head[:, :c.vocab_size]            # predictor 0
-        logits = jnp.dot(h, head, preferred_element_type=F32)
-        if all_heads:
-            logits = logits.reshape(*logits.shape[:2], c.num_pred_heads,
-                                    c.vocab_size)
-        return Tensor._wrap(logits)
+        with jax.named_scope(HEAD_SCOPE):
+            if cache_ctx is not None:
+                # prefill: the head sees the one row the engine samples from
+                h = cache_ctx.select_last(Tensor._wrap(h))._value()
+            h = _rms_unit(h, self.model.norm._value(), c.rms_norm_eps)
+            head = self.lm_head._value()
+            if not all_heads:
+                head = head[:, :c.vocab_size]            # predictor 0
+            logits = jnp.dot(h, head, preferred_element_type=F32)
+            if all_heads:
+                logits = logits.reshape(*logits.shape[:2], c.num_pred_heads,
+                                        c.vocab_size)
+            return Tensor._wrap(logits)

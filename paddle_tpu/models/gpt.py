@@ -27,6 +27,7 @@ from dataclasses import dataclass
 from typing import Optional
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -46,6 +47,7 @@ from ..distributed.fleet.utils.recompute import recompute
 from ..distributed.sharding_spec import (
     BATCH_AXES, MODEL_AXIS, SEQ_AXIS, mark_sharding, set_param_spec,
 )
+from .held_experts import EMBED_SCOPE, HEAD_SCOPE
 
 
 @dataclass
@@ -234,7 +236,8 @@ class GPTModel(Layer):
                 # (None from the draft's dense cache — default 0..S-1)
                 position_ids = cache_ctx.prefill_positions(
                     input_ids.shape[-1])
-        h = self.embeddings(input_ids, position_ids)
+        with jax.named_scope(EMBED_SCOPE):
+            h = self.embeddings(input_ids, position_ids)
         for i, layer in enumerate(self.layers):
             if cache_ctx is not None:
                 cache_ctx.layer_idx = i
@@ -243,7 +246,8 @@ class GPTModel(Layer):
                 h = recompute(layer, h)
             else:
                 h = layer(h)
-        return self.final_ln(h)
+        with jax.named_scope(HEAD_SCOPE):
+            return self.final_ln(h)
 
 
 class GPTForCausalLM(Layer):
@@ -271,12 +275,13 @@ class GPTForCausalLM(Layer):
 
     def forward(self, input_ids, position_ids=None, cache_ctx=None):
         h = self.gpt(input_ids, position_ids, cache_ctx=cache_ctx)
-        if self.lm_head is not None:
-            logits = self.lm_head(h)
-        else:
-            w = self.gpt.embeddings.word_embeddings.weight
-            logits = h.matmul(w.t())
-        return mark_sharding(logits, _act_spec(last=MODEL_AXIS))
+        with jax.named_scope(HEAD_SCOPE):
+            if self.lm_head is not None:
+                logits = self.lm_head(h)
+            else:
+                w = self.gpt.embeddings.word_embeddings.weight
+                logits = h.matmul(w.t())
+            return mark_sharding(logits, _act_spec(last=MODEL_AXIS))
 
     def compute_loss(self, input_ids, labels, loss_mask=None,
                      position_ids=None, ignore_index: int = -100):

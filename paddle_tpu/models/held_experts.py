@@ -27,6 +27,10 @@ from ..nn import initializer as I
 #: named scopes of an expert layer's work in a compiled program's op names
 ROUTE_SCOPE = "moe.route"
 EXPERTS_SCOPE = "moe.experts"
+#: and of what every decoder does outside its layers (``gpt.py`` too): the
+#: token embedding, and the final norm to the logits
+EMBED_SCOPE = "model.embed"
+HEAD_SCOPE = "model.head"
 
 F32 = jnp.float32
 

@@ -54,8 +54,9 @@ from ..nn.layer.container import LayerList
 from ..ops.pallas.dsa_attention_kernel import (ATTEND_SCOPE, INDEX_SCOPE,
                                                SELECT_SCOPE)
 from ..ops.threshold_search import kth_largest_key, order_keys
-from .held_experts import (EXPERTS_SCOPE, F32, ROUTE_SCOPE,
-                           held_experts_forward, _interpret, _Normal, _rms)
+from .held_experts import (EMBED_SCOPE, EXPERTS_SCOPE, F32, HEAD_SCOPE,
+                           ROUTE_SCOPE, held_experts_forward, _interpret,
+                           _Normal, _rms)
 
 
 @dataclass
@@ -330,8 +331,9 @@ class KeyeVL2Model(Layer):
         # norm and adds a float32 result, so the stream's own rounding does
         # not reach the router and the indexer, whose choices flip on a
         # near tie
-        h = (jnp.take(self.embed_tokens._value(), input_ids, axis=0)
-             if inputs_embeds is None else inputs_embeds).astype(F32)
+        with jax.named_scope(EMBED_SCOPE):
+            h = (jnp.take(self.embed_tokens._value(), input_ids, axis=0)
+                 if inputs_embeds is None else inputs_embeds).astype(F32)
         for i, layer in enumerate(self.layers):
             if cache_ctx is not None:
                 cache_ctx.layer_idx = i
@@ -370,9 +372,10 @@ class KeyeVL2ForCausalLM(Layer):
             ids, cache_ctx,
             None if inputs_embeds is None else raw(inputs_embeds),
             None if position_ids is None else raw(position_ids))
-        if cache_ctx is not None:
-            # prefill: the head sees the one row the engine samples from
-            h = cache_ctx.select_last(Tensor._wrap(h))._value()
-        h = _rms(h, self.model.norm._value(), self.config.rms_norm_eps)
-        return Tensor._wrap(jnp.dot(h, self.lm_head._value(),
-                                    preferred_element_type=F32))
+        with jax.named_scope(HEAD_SCOPE):
+            if cache_ctx is not None:
+                # prefill: the head sees the one row the engine samples from
+                h = cache_ctx.select_last(Tensor._wrap(h))._value()
+            h = _rms(h, self.model.norm._value(), self.config.rms_norm_eps)
+            return Tensor._wrap(jnp.dot(h, self.lm_head._value(),
+                                        preferred_element_type=F32))

@@ -9,11 +9,14 @@ TPU-native design: parameters are ordinary framework Tensors holding jax.Arrays
 from __future__ import annotations
 
 import collections
+import re
 from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
+from ..core import tensor as tensor_mod
 from ..core.tensor import Tensor, to_tensor
 from ..core import dtype as dtype_mod
 from . import initializer as I
@@ -45,6 +48,27 @@ class Parameter(Tensor):
 
 # global per-class name counters for full_name() parity
 _layer_name_counters: Dict[str, int] = collections.defaultdict(int)
+
+_NOT_SCOPE = re.compile(r"[^A-Za-z0-9_.]")
+
+
+def _held_as(parent, sublayer, name) -> None:
+    """The key under which ``parent`` holds ``sublayer`` is the scope the
+    sublayer's calls open while ``to_static`` traces (``Layer.__call__``):
+    ``gpt``, ``attn``, ``qkv_proj``.  A list or dict of layers is never
+    called itself (it has no ``forward``), so its members read its name
+    before their own: ``layers/3``.  A layer held twice reads the name
+    given last."""
+    if sublayer is None:
+        return
+    scope = _NOT_SCOPE.sub("_", str(name))
+    if type(parent).forward is Layer.forward \
+            and "_scope_name" in parent.__dict__:
+        scope = f"{parent.__dict__['_scope_name']}/{scope}"
+    object.__setattr__(sublayer, "_scope_name", scope)
+    if type(sublayer).forward is Layer.forward:
+        for key, member in sublayer._sub_layers.items():
+            _held_as(sublayer, member, key)
 
 
 class HookRemoveHelper:
@@ -106,6 +130,7 @@ class Layer:
         if sublayer is not None and not isinstance(sublayer, Layer):
             raise TypeError(f"add_sublayer expects Layer, got {type(sublayer)}")
         self._sub_layers[name] = sublayer
+        _held_as(self, sublayer, name)
         return sublayer
 
     def register_buffer(self, name: str, tensor: Optional[Tensor], persistable=True):
@@ -177,6 +202,7 @@ class Layer:
             if layers is None:
                 raise RuntimeError("call Layer.__init__ before assigning sublayers")
             layers[name] = value
+            _held_as(self, value, name)
             self.__dict__.pop(name, None)
         elif params is not None and name in params:
             if value is None:
@@ -375,7 +401,17 @@ class Layer:
             out = hook(self, inputs)
             if out is not None:
                 inputs = out if isinstance(out, tuple) else (out,)
-        outputs = self.forward(*inputs, **kwargs)
+        if tensor_mod._trace_hook is None:
+            outputs = self.forward(*inputs, **kwargs)
+        else:
+            # while ``to_static`` traces, a layer's call is a scope: every
+            # operation's op_name in the compiled program then carries the
+            # path of the layers it was built under (``gpt/h/3/attn/qkv_proj``),
+            # which ``obs.hlo_cost.scope_map`` reads back.  The scope exists
+            # at trace time only; an eager call pays the read above
+            with jax.named_scope(self.__dict__.get("_scope_name")
+                                 or type(self).__name__):
+                outputs = self.forward(*inputs, **kwargs)
         for hook in list(self._forward_post_hooks.values()):
             res = hook(self, inputs, outputs)
             if res is not None:

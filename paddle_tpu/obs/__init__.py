@@ -54,13 +54,13 @@ from .metrics import render_metrics, render_all_metrics  # noqa: F401
 from .train import (NULL_TIMELINE, StepTimeline,  # noqa: F401
                     validate_timeline)
 from .compile_ledger import CompileLedger  # noqa: F401
-from .hlo_cost import CostLedger  # noqa: F401
+from .hlo_cost import CostLedger, scope_maps  # noqa: F401
 
 __all__ = ["FlightRecorder", "chrome_trace", "write_chrome_trace",
            "jsonl_lines", "write_jsonl", "render_metrics",
            "render_all_metrics", "validate_trace", "StepTimeline",
            "NULL_TIMELINE", "validate_timeline", "CompileLedger",
-           "CostLedger"]
+           "CostLedger", "scope_maps"]
 
 
 def __getattr__(name):

@@ -44,7 +44,9 @@ from ..core.chip import chip_peaks
 
 __all__ = ["CostLedger", "count_hlo_ops", "opcode_sequence",
            "schedule_fingerprint", "analyze_static_fn",
-           "collective_exposure", "HLO_OPS", "COLLECTIVE_OPS"]
+           "collective_exposure", "HLO_OPS", "COLLECTIVE_OPS",
+           "scope_of", "scope_map", "scope_maps", "instructions", "UNSCOPED",
+           "STRUCTURAL_PARTS"]
 
 # one HLO instruction per line: `%name = <type> opcode(...)` — shared
 # with tools/perf_fingerprint.py (which imports these, so the tracked
@@ -182,6 +184,191 @@ def collective_exposure(hlo_text: str) -> dict:
         "exposed_bytes": int(sum(d["bytes"] for d in exposed)),
         "collectives": out,
     }
+
+
+# -- who an instruction belongs to -------------------------------------------
+# Every instruction of an optimized module carries, in its metadata, the
+# op_name JAX built it under: ``jit(<program>)/`` + the name-scope path
+# (``jax.named_scope``; a ``Layer`` call while ``to_static`` traces) with the
+# transforms wrapped around it + the primitive.  XLA:TPU keeps it on fusions
+# (their root's), on ``while`` / ``conditional`` and on the ops of their
+# bodies; a profiler trace's ``XLA Ops`` event carries the instruction's
+# *name* only, so name -> scope is the join that gives device time an owner
+# (docs/OBSERVABILITY.md "Device time by scope").
+
+#: the scope of an instruction no rule below gives one
+UNSCOPED = ""
+
+#: parts of an op_name that JAX adds for the program's structure, not for a
+#: scope somebody named: control flow and calls (what the five benchmark
+#: cells' programs contain, and their kin)
+STRUCTURAL_PARTS = frozenset((
+    "while", "body", "cond", "body_fun", "cond_fun", "closed_call",
+    "core_call", "pjit", "checkpoint", "remat", "rematted_computation",
+    "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_lin", "scan", "switch", "named_call"))
+_BRANCH = re.compile(r"branch_\d+_fun")
+_SCOPE_PART = re.compile(r"[A-Za-z0-9_.]+")
+_WRAPPED = re.compile(r"([A-Za-z_]\w*)\((.*)\)", re.S)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLEES = re.compile(
+    r"(?:calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.-]+)|branch_computations=\{([^}]*)\}")
+_COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.-]+) .*\{\s*$")
+_MODULE = re.compile(r"^HloModule ([\w.-]+)")
+_PERCENT_NAME = re.compile(r"%([\w.-]+)")
+
+
+def _top_level(path: str) -> List[str]:
+    """``path`` split on the ``/`` that lie outside every parenthesis."""
+    parts, depth, start = [], 0, 0
+    for i, ch in enumerate(path):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            parts.append(path[start:i])
+            start = i + 1
+    parts.append(path[start:])
+    return parts
+
+
+def _unwrap(parts: List[str], out: List[str]) -> bool:
+    """Appends to ``out`` the plain parts of ``parts`` with every transform
+    (``jvp(...)``, ``transpose(...)``, ``vmap(...)``, nested) taken off and
+    every ``jit(<op>)`` / ``pjit(<op>)`` dropped; true if a ``transpose(``
+    wrapped any of them."""
+    bwd = False
+    for part in parts:
+        m = _WRAPPED.fullmatch(part)
+        if m is None:
+            out.append(part)
+        elif m.group(1) not in ("jit", "pjit"):
+            bwd |= m.group(1) == "transpose"
+            bwd |= _unwrap(_top_level(m.group(2)), out)
+    return bwd
+
+
+def scope_of(op_name: str):
+    """``(scope, direction)`` of one instruction's op_name — **the rule**:
+
+    1. drop the leading ``jit(<program>)/`` (an op_name without it is no
+       path JAX built: ``sk[4]`` of a parameter, ``reduce_sum`` of a
+       reducer — :data:`UNSCOPED`), and where the inliner joined several
+       whole paths everything before the last ``jit(<program>)/`` (a path
+       joined to itself without it, ``a/b/a/b``, reads once);
+    2. drop the trailing primitive (the last part outside every parenthesis,
+       where it is a plain word: ``dot_general``, ``while``, ``mul``);
+    3. unwrap the transforms; ``direction`` is ``"bwd"`` if a ``transpose(``
+       wrapped any part (JAX's linear transpose, and the tape's backward
+       sweep, which runs a node under ``transpose(<its forward's path>)``),
+       else ``"fwd"``;
+    4. drop the parts JAX adds for structure (:data:`STRUCTURAL_PARTS`,
+       ``branch_N_fun``, ``jit(<op>)``) and whatever is no name of
+       ``[A-Za-z0-9_.]`` (an einsum's ``bqhd,bkhd->bhqk``);
+    5. what is left, joined by ``/``, is the scope
+       (``gpt/layers/3/attn/qkv_proj``, ``loss.streamed_ce``,
+       ``optimizer.adamw``); nothing left is :data:`UNSCOPED`."""
+    parts = _top_level(op_name)
+    if not parts[0].startswith("jit("):
+        return UNSCOPED, "fwd"       # a parameter's name, a reducer's op
+    # XLA's inliner joins a call site's op_name to its callee's, which is
+    # a whole path again (``jit(f)/a/jit(searchsorted)/jit(f)/a/.../lt``,
+    # once a trip of an unrolled search): the last whole path counts
+    parts = parts[len(parts) - parts[::-1].index(parts[0]):]
+    if parts and "(" not in parts[-1]:
+        parts = parts[:-1]
+    plain: List[str] = []
+    bwd = _unwrap(parts, plain)
+    kept = [p for p in plain
+            if _SCOPE_PART.fullmatch(p) and p not in STRUCTURAL_PARTS
+            and not _BRANCH.fullmatch(p)]
+    while kept and len(kept) % 2 == 0 \
+            and kept[:len(kept) // 2] == kept[len(kept) // 2:]:
+        kept = kept[:len(kept) // 2]      # a path joined to itself: once
+    return "/".join(kept), "bwd" if bwd else "fwd"
+
+
+def instructions(hlo_text: str) -> List[tuple]:
+    """``(computation, name, opcode, op_name or None, called computations,
+    operands)`` of every instruction of an HLO module's text, in text
+    order; ``operands`` are the ``%names`` its text mentions."""
+    out, comp = [], ""
+    for line in hlo_text.splitlines():
+        m = _DEF.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        rest = m.group(4)
+        named = _OP_NAME.search(rest)
+        callees = []
+        for one, many in _CALLEES.findall(rest):
+            callees += [one] if one else \
+                [c.strip().lstrip("%") for c in many.split(",") if c.strip()]
+        out.append((comp, m.group(1).lstrip("%"), m.group(3),
+                    named.group(1) if named else None, callees,
+                    _PERCENT_NAME.findall(rest.split(", metadata=")[0])))
+    return out
+
+
+def scope_map(hlo_text: str) -> dict:
+    """``{"module": <HloModule name>, "instructions": {name: (scope,
+    direction)}}`` over the instructions of **every** computation of an
+    optimized module (a loop's body and a conditional's branches execute as
+    device events of their own).  An instruction whose op_name JAX built
+    under the program reads :func:`scope_of`.  One without (a fusion whose
+    root lost it, a ``copy`` named after the parameter it copies, the
+    ``copy-start`` / ``copy-done`` of a weight moved ahead of its use)
+    takes the most frequent scoped reading among the instructions of the
+    computations it calls (``calls=``, ``body=``, ``branch_computations=``
+    ...), else the reading of the first instruction of its computation that
+    consumes it and has one, else it is :data:`UNSCOPED`."""
+    m = _MODULE.match(hlo_text)
+    rows = instructions(hlo_text)
+    got: Dict[str, tuple] = {}
+    members: Dict[str, List[str]] = {}
+    for comp, name, _opcode, op_name, _callees, _operands in rows:
+        members.setdefault(comp, []).append(name)
+        got[name] = scope_of(op_name) if op_name else (UNSCOPED, "fwd")
+    # innermost computations come first in the text, so one pass in text
+    # order resolves a chain (a conditional without a name whose branch is
+    # a fusion without a name)
+    for _comp, name, _opcode, op_name, callees, _operands in rows:
+        if (op_name or "").startswith("jit(") or not callees:
+            continue
+        votes: Dict[tuple, int] = {}
+        for c in callees:
+            for inner in members.get(c, ()):
+                if got[inner][0] != UNSCOPED:
+                    votes[got[inner]] = votes.get(got[inner], 0) + 1
+        if votes:
+            got[name] = max(votes, key=votes.get)
+    # a consumer comes after what it consumes, so one pass from the end
+    # resolves a chain (copy-start <- copy-done <- the fusion it feeds)
+    consumer: Dict[tuple, str] = {}
+    for comp, name, _opcode, op_name, _callees, operands in reversed(rows):
+        if got[name][0] == UNSCOPED and (comp, name) in consumer \
+                and not (op_name or "").startswith("jit("):
+            got[name] = got[consumer[comp, name]]
+        if got[name][0] != UNSCOPED:
+            for operand in operands:
+                consumer[comp, operand] = name  # the earliest one stays
+    return {"module": m.group(1) if m else "", "instructions": got}
+
+
+def scope_maps(modules) -> List[dict]:
+    """The :func:`scope_map` of every program this process built through
+    ``jit.to_static`` whose HLO module is named in ``modules`` (the names a
+    profile's ``XLA Modules`` line holds, without their ``(<id>)``): each
+    built on first request, a ``jit.scope_map`` span, and kept.  Programs
+    of one name (``jit_prefill_step``: one a bucket) give one map each; the
+    caller joins them."""
+    from ..jit.trace import program_texts
+
+    return [t.scope_map() for t in program_texts(frozenset(modules))]
 
 
 def _roofline(flops: float, bytes_accessed: float, chip: str) -> dict:
