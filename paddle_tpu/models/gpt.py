@@ -38,7 +38,7 @@ from ..nn.layer_base import Layer
 from ..nn.layer.common import Dropout, Embedding, Linear
 from ..nn.layer.container import LayerList
 from ..nn.layer.norm import LayerNorm
-from ..ops.pallas import flash_attention as _flash_attention
+from ..ops.pallas import flash_attention_qkv as _flash_attention_qkv
 from ..distributed.fleet.meta_parallel.parallel_layers.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     ParallelCrossEntropy,
@@ -126,26 +126,29 @@ class GPTAttention(Layer):
         qkv = self.qkv_proj(x)                                  # [B,S,3h]/mp
         qkv = qkv.reshape([B, S, self.n_heads, 3 * self.head_dim])
         qkv = mark_sharding(qkv, P(BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None))
-        q, k, v = qkv.split(3, axis=-1)                         # [B,S,H,D]
         if cache_ctx is None:
-            ctx = _flash_attention(
-                q, k, v, dropout_p=self.dropout_p, is_causal=True,
+            # nothing to cache: the kernels read q, k and v out of the
+            # projection as it is, no split stands between them
+            ctx = _flash_attention_qkv(
+                qkv, dropout_p=self.dropout_p, is_causal=True,
                 training=self.training)
-        elif cache_ctx.mode == "prefill":
-            # prompt forward writes K/V into the cache; attention routes
-            # through the context — gather-by-block-table with a
-            # cached-prefix mask over the engine's pool (the tail bucket
-            # attends onto shared blocks), ordinary causal for the
-            # speculative draft's dense cache
-            cache_ctx.write_prefill(k, v)
-            ctx = cache_ctx.prefill_attention(q, k, v)
-        else:               # decode (S == 1) or verify (S == k+1) window
-            # write + attend routed through the context: the pool
-            # streams blocks through the Pallas flash-decoding kernel
-            # instead of gathering a copy of each slot's sequence;
-            # verify mode routes the same call to the cache's W-token
-            # speculative window attention — models stay single-path
-            ctx = cache_ctx.decode_attention(q, k, v)
+        else:
+            q, k, v = qkv.split(3, axis=-1)                     # [B,S,H,D]
+            if cache_ctx.mode == "prefill":
+                # prompt forward writes K/V into the cache; attention
+                # routes through the context — gather-by-block-table with a
+                # cached-prefix mask over the engine's pool (the tail bucket
+                # attends onto shared blocks), ordinary causal for the
+                # speculative draft's dense cache
+                cache_ctx.write_prefill(k, v)
+                ctx = cache_ctx.prefill_attention(q, k, v)
+            else:           # decode (S == 1) or verify (S == k+1) window
+                # write + attend routed through the context: the pool
+                # streams blocks through the Pallas flash-decoding kernel
+                # instead of gathering a copy of each slot's sequence;
+                # verify mode routes the same call to the cache's W-token
+                # speculative window attention — models stay single-path
+                ctx = cache_ctx.decode_attention(q, k, v)
         ctx = mark_sharding(ctx, P(BATCH_AXES, SEQ_AXIS, MODEL_AXIS, None))
         ctx = ctx.reshape([B, S, self.n_heads * self.head_dim])
         return self.out_proj(ctx)

@@ -90,20 +90,47 @@ def _sdpa_reference(q, k, v, mask, dropout_key, dropout_p, is_causal):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
-def flash_on_mesh(q, k, v, causal, interpret=False):
-    """The Pallas flash kernel over ``[B, S, H, D]``, one call per
-    (batch, heads) shard of the global mesh — see :func:`per_shard`."""
+def _flash_per_shard(kernel, args, causal, interpret):
     from jax.sharding import PartitionSpec as P
 
     from ...distributed import mesh as _mesh_mod
     from ...distributed.sharding_spec import BATCH_AXES, MODEL_AXIS
-    from .flash_attention_kernel import flash_attention_fused
 
     spec = P(BATCH_AXES, None, MODEL_AXIS, None)
     return per_shard(
-        functools.partial(flash_attention_fused, causal=causal,
-                          interpret=interpret),
-        (q, k, v), (spec, spec, spec), _mesh_mod.get_global_mesh())
+        functools.partial(kernel, causal=causal, interpret=interpret),
+        args, (spec,) * len(args), _mesh_mod.get_global_mesh())
+
+
+def flash_on_mesh(q, k, v, causal, interpret=False):
+    """The Pallas flash kernel over ``[B, S, H, D]``, one call per
+    (batch, heads) shard of the global mesh — see :func:`per_shard`."""
+    from .flash_attention_kernel import flash_attention_fused
+
+    return _flash_per_shard(flash_attention_fused, (q, k, v), causal,
+                            interpret)
+
+
+def flash_qkv_on_mesh(qkv, causal, interpret=False):
+    """The same kernels reading a head-major fused projection
+    ``[B, S, H, 3·D]`` as it is (``[B, S, H, D]`` comes back), under the
+    same split of batch and heads."""
+    from .flash_attention_kernel import flash_attention_fused_qkv
+
+    return _flash_per_shard(flash_attention_fused_qkv, (qkv,), causal,
+                            interpret)
+
+
+def _ring_mesh(q_len, k_len):
+    """The global mesh where its live "sep" axis shards this sequence (the
+    ring's case), else None."""
+    from ...distributed import mesh as _mesh_mod
+
+    _m = _mesh_mod.get_global_mesh()
+    if _m is not None and _m.shape.get("sep", 1) > 1 \
+            and q_len % _m.shape["sep"] == 0 and q_len == k_len:
+        return _m
+    return None
 
 
 def flash_attention(query, key, value, attn_mask=None, dropout_p=0.0,
@@ -122,12 +149,8 @@ def flash_attention(query, key, value, attn_mask=None, dropout_p=0.0,
         # context parallelism: with a live "sep" axis the sequence is
         # sharded — run the ppermute ring instead of letting GSPMD
         # all-gather K/V (ops/ring_attention.py; beyond-reference)
-        from ...distributed import mesh as _mesh_mod
-
-        _m = _mesh_mod.get_global_mesh()
-        if _m is not None and _m.shape.get("sep", 1) > 1 \
-                and query.shape[1] % _m.shape["sep"] == 0 \
-                and query.shape[1] == key.shape[1]:
+        _m = _ring_mesh(query.shape[1], key.shape[1])
+        if _m is not None:
             from ..ring_attention import ring_flash_attention
 
             return ring_flash_attention(query, key, value,
@@ -160,6 +183,33 @@ def flash_attention(query, key, value, attn_mask=None, dropout_p=0.0,
     if key_arr is not None:
         args.append(key_arr)
     return apply_op("flash_attention", _primal, args)
+
+
+def flash_attention_qkv(qkv, dropout_p=0.0, is_causal=False, training=True):
+    """Self-attention of a caller that holds a head-major fused projection
+    ``[B, S, H, 3·D]`` (a head's q, k and v side by side) → ``[B, S, H, D]``.
+
+    Where :func:`flash_attention` would run the Pallas kernels, they read
+    q, k and v out of the projection as it is and write its gradient as one
+    array: no split, no join, no transpose stands between the projections'
+    matmuls and the kernels.  Everywhere else (another backend, dropout, a
+    sequence the kernels refuse, a live "sep" axis) the three are split off
+    and take :func:`flash_attention`'s path."""
+    B, S, H, D3 = qkv.shape
+    heads = (B, S, H, D3 // 3)
+    p = dropout_p if training else 0.0
+    if p == 0.0 and use_pallas() and _ring_mesh(S, S) is None:
+        from .flash_attention_kernel import supports
+
+        if supports(heads, heads):
+            def _primal(x):
+                with jax.named_scope(ATTN_SCOPE_PALLAS):
+                    return flash_qkv_on_mesh(x, causal=is_causal)
+
+            return apply_op("flash_attention", _primal, [qkv])
+    q, k, v = qkv.split(3, axis=-1)
+    return flash_attention(q, k, v, dropout_p=dropout_p, is_causal=is_causal,
+                           training=training)
 
 
 def fused_bias_dropout_residual_layer_norm(x, residual, bias=None,

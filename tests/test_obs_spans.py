@@ -17,8 +17,9 @@ from paddle_tpu.obs.train import StepTimeline
 from paddle_tpu.serving import Engine
 from paddle_tpu.serving.tracing import RequestTracer
 
-from chip_programs import (custom_call_lines, engine_program,  # noqa: F401
-                           kernel_lines, load_patterns, one_chip)
+from chip_programs import (attention_layer_program,  # noqa: F401
+                           custom_call_lines, engine_program, kernel_lines,
+                           load_patterns, moves_around_kernels, one_chip)
 
 NAME, START, END, PARENT, ATTRS, SID = range(6)
 
@@ -575,7 +576,9 @@ def test_a_traced_flash_call_leaves_one_plan_mark():
         "seq": 1024, "head_dim": 64, "causal": 1, "block_q": plan.block_q,
         "sub": plan.sub, "tiles_total": plan.tiles_total,
         "tiles_visited": plan.tiles_visited,
-        "tiles_masked": plan.tiles_masked}
+        "tiles_masked": plan.tiles_masked,
+        # the operands' form: [head_dim, S] heads, here of three tensors
+        "layout": "feature_major", "fused_qkv": 0}
     assert mark[ATTRS]["tiles_visited"] <= 0.75 * mark[ATTRS]["tiles_total"]
     # a call that is not causal walks every tile and says so
     t = spans.clock()
@@ -584,6 +587,56 @@ def test_a_traced_flash_call_leaves_one_plan_mark():
     (mark,) = [r for r in rows_since(t) if r[NAME] == "attention.flash_plan"]
     assert mark[ATTRS]["tiles_visited"] == mark[ATTRS]["tiles_total"]
     assert mark[ATTRS]["tiles_masked"] == 0 and mark[ATTRS]["causal"] == 0
+    # a caller that holds a fused projection says so, and nothing else moves
+    qkv = jax.ShapeDtypeStruct((1, 1024, 16, 192), jnp.bfloat16)
+    t = spans.clock()
+    jax.eval_shape(lambda x: jax.grad(lambda x: fk.flash_attention_fused_qkv(
+        x, causal=True, interpret=True).astype(jnp.float32).sum())(x), qkv)
+    (fused,) = [r for r in rows_since(t) if r[NAME] == "attention.flash_plan"]
+    assert fused[ATTRS] == {**marks[0][ATTRS], "fused_qkv": 1}
+
+
+def test_no_xla_op_stands_between_the_projections_and_the_flash_kernels(
+        one_chip, monkeypatch):
+    """One layer's attention at the train cell's call (GPT-2 345M's widths,
+    B 16, S 1,024, bf16; :func:`attention_layer_program`), forward and
+    backward, compiled for the described v5e: the kernels take heads as
+    ``[head_dim, S]`` blocks of the qkv projection's output as XLA:TPU lays
+    it out and write the out projection's input and the qkv gradient the
+    same way, so no ``copy`` / ``transpose`` / fusion of a head tensor's
+    size stands between a projection's matmul and a kernel (one joining
+    dq, dk, dv would be allowed: there is none, ``bwd_dq`` completes
+    ``bwd_dkv``'s array in place), lse is a lane-dense row a head and delta
+    never leaves the kernels."""
+    from paddle_tpu.ops.pallas import flash_attention_kernel as fk
+
+    compiled = attention_layer_program(one_chip, monkeypatch)
+    hlo = compiled.as_text()
+    head = 16 * 16 * 1024 * 64
+    lines = custom_call_lines(compiled)
+    kc = load_patterns("flash_attention")
+    fwd = [ln for ln in lines if any(re.search(p, ln) for p in kc.FORWARD)]
+    bwd = [ln for ln in lines if any(re.search(p, ln) for p in kc.BACKWARD)]
+    # the yardstick's reader halves the backward count: two kernels a layer
+    assert len(lines) == 3 and len(fwd) == 1 and len(bwd) == 2
+    assert [re.sub(r"\.\d+$", "", ln.split(" = ")[0]) for ln in lines] == [
+        "%" + fk.FWD_NAME, "%" + fk.BWD_DKV_NAME, "%" + fk.BWD_DQ_NAME]
+    moves = moves_around_kernels(hlo, lambda n: "pallas_flash" in n, head)
+    assert moves == []
+    # q, k and v are one operand, read three times; dq, dk and dv one result
+    operands = re.findall(r"(\w+\[[\d,]*\])\S* (%[\w.-]+)",
+                          fwd[0].split("custom-call(")[1].split(
+                              "), custom_call_target")[0])
+    assert len(operands) == 3 and len(set(operands)) == 1
+    assert operands[0][0] == "bf16[16,3072,1024]"
+    assert all(ln.split(" = ")[1].startswith("bf16[16,3072,1024]")
+               for ln in bwd)
+    assert 'output_to_operand_aliasing={{}: (6, {})}' in next(
+        ln for ln in hlo.splitlines() if fk.BWD_DQ_NAME + "." in ln
+        and "tpu_custom_call" in ln)
+    # no [.., S, 1] float32 array: lse is [B*H, 1, S], delta stays in VMEM
+    assert not re.search(r"f32\[[\d,]*1024,1\]", hlo)
+    assert "f32[256,1,1024]" in fwd[0]
 
 
 def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.ops.pallas import _sdpa_reference
-from paddle_tpu.ops.pallas.flash_attention_kernel import flash_attention_fused
+from paddle_tpu.ops.pallas.flash_attention_kernel import (
+    flash_attention_fused, flash_attention_fused_qkv)
 
 
 def _qkv(B=2, S=256, H=4, D=64, dtype=jnp.float32, seed=0):
@@ -61,6 +62,31 @@ def _attn_and_grads(attn, q, k, v):
     return (o,) + vjp(v)            # cotangent: v, as (o * v).sum()'s
 
 
+def _assert_close(got, want, dtype):
+    """out, dq, dk, dv against the oracle's: float32 by the tolerances the
+    parity table always had; bf16 the output as absolute as it always was,
+    a gradient, which sums bf16 products over the sequence, by its
+    magnitude."""
+    for name, a, b, atol in zip(("out", "dq", "dk", "dv"), got, want,
+                                (2e-5, 5e-4, 5e-4, 5e-4)):
+        assert a.dtype == dtype
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        if dtype == jnp.bfloat16:
+            atol = 3e-2 * (1.0 if name == "out"
+                           else max(1.0, float(np.abs(b).max())))
+        np.testing.assert_allclose(a, b, atol=atol, err_msg=name)
+
+
+# the feature-major kernels at the head widths, element sizes and sequence
+# lengths of the models that train through them: one block a head (256;
+# 1,024 in bf16) and several (1,024 in f32: two; 2,048: two or four)
+_FEATURE_MAJOR = [(D, dtype, S, causal)
+                  for D in (64, 128)
+                  for dtype in ("bfloat16", "float32")
+                  for S in (256, 1024, 2048)
+                  for causal in (True, False)]
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("case", list(_PARITY))
     def test_output_and_grads_match_oracle(self, case):
@@ -78,16 +104,91 @@ class TestFlashAttention:
         want = _attn_and_grads(
             lambda q, k, v: _sdpa_reference(q, k, v, None, None, 0.0,
                                             causal), q, k, v)
-        for name, a, b, atol in zip(("out", "dq", "dk", "dv"), got, want,
-                                    (2e-5, 5e-4, 5e-4, 5e-4)):
-            assert a.dtype == q.dtype
-            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
-            if bf16:
-                # the output as absolute as it always was; a gradient, which
-                # sums bf16 products over the sequence, by its magnitude
-                atol = 3e-2 * (1.0 if name == "out"
-                               else max(1.0, float(np.abs(b).max())))
-            np.testing.assert_allclose(a, b, atol=atol, err_msg=name)
+        _assert_close(got, want, q.dtype)
+
+    @pytest.mark.parametrize(
+        "D,dtype,S,causal", _FEATURE_MAJOR,
+        ids=[f"d{D}-{dtype}-{S}-{'causal' if c else 'full'}"
+             for D, dtype, S, c in _FEATURE_MAJOR])
+    def test_feature_major_kernels_match_oracle(self, D, dtype, S, causal):
+        """Forward and all three gradients of the ``[head_dim, S]`` kernels
+        at the plan's own sizes."""
+        q, k, v = _qkv(B=1, S=S, H=2 if S == 256 else 1, D=D,
+                       dtype=jnp.dtype(dtype))
+        got = _attn_and_grads(
+            lambda q, k, v: flash_attention_fused(
+                q, k, v, causal=causal, interpret=True), q, k, v)
+        want = _attn_and_grads(
+            lambda q, k, v: _sdpa_reference(q, k, v, None, None, 0.0,
+                                            causal), q, k, v)
+        _assert_close(got, want, q.dtype)
+
+    @pytest.mark.parametrize("S,D,dtype,causal,block_q,sub", [
+        (256, 64, "float32", True, None, None),       # one block a head
+        (512, 64, "float32", True, 128, 64),          # four: above, on, under
+        (512, 128, "float32", False, 256, 128),
+        (1024, 64, "bfloat16", True, None, None),     # the cell's call
+        (2048, 128, "bfloat16", True, None, None),    # two 1,024-row blocks
+    ])
+    def test_fused_projection_entry_equals_three_tensor_entry(
+            self, S, D, dtype, causal, block_q, sub):
+        """The kernels reading q, k, v out of a head-major ``[B, S, H, 3D]``
+        projection and writing its gradient as one array give, bit for bit,
+        what they give on the three tensors split off it: the entries
+        differ in index maps only."""
+        rs = np.random.RandomState(1)
+        B, H = (2, 3) if S == 256 else (1, 2)
+        qkv = jnp.asarray(rs.randn(B, S, H, 3 * D), jnp.dtype(dtype))
+        g = jnp.asarray(rs.randn(B, S, H, D), jnp.dtype(dtype))
+
+        def split(qkv):
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            return flash_attention_fused(q, k, v, causal=causal,
+                                         block_q=block_q, block_k=sub,
+                                         interpret=True)
+
+        def fused(qkv):
+            return flash_attention_fused_qkv(qkv, causal=causal,
+                                             block_q=block_q, block_k=sub,
+                                             interpret=True)
+
+        o3, vjp3 = jax.vjp(split, qkv)
+        o1, vjp1 = jax.vjp(fused, qkv)
+        assert o1.shape == (B, S, H, D) and o1.dtype == qkv.dtype
+        np.testing.assert_array_equal(np.asarray(o1, np.float32),
+                                      np.asarray(o3, np.float32))
+        (d3,), (d1,) = vjp3(g), vjp1(g)
+        assert d1.shape == qkv.shape and d1.dtype == qkv.dtype
+        np.testing.assert_array_equal(np.asarray(d1, np.float32),
+                                      np.asarray(d3, np.float32))
+
+    @pytest.mark.parametrize("fused", [False, True])
+    def test_lse_is_one_lane_dense_row_a_head(self, fused):
+        """The residual the forward leaves: ``[B·H, 1, S]`` float32, the
+        sequence on the lanes — no ``[.., S, 1]`` column anywhere."""
+        from paddle_tpu.ops.pallas.flash_attention_kernel import (
+            _fwd, flash_plan)
+
+        B, S, H, D = 2, 256, 3, 64
+        q, k, v = _qkv(B=B, S=S, H=H, D=D)
+
+        def feature_major(x):
+            return x.transpose(0, 2, 3, 1).reshape(B, H * D, S)
+
+        if fused:       # head n's q, k, v: row blocks 3n, 3n + 1, 3n + 2
+            x = (jnp.concatenate([q, k, v], -1).reshape(B, S, H * 3 * D)
+                 .transpose(0, 2, 1),)
+        else:
+            x = (feature_major(q), feature_major(k), feature_major(v))
+        o, lse = _fwd(x, head_dim=D, scale=D ** -0.5, causal=True,
+                      plan=flash_plan(S, D, 4), interpret=True)
+        assert o.shape == (B, H * D, S)
+        assert lse.shape == (B * H, 1, S) and lse.dtype == jnp.float32
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * D ** -0.5
+        logits = jnp.where(jnp.tril(jnp.ones((S, S), bool)), logits, -1e30)
+        want = jax.scipy.special.logsumexp(logits, axis=-1)      # [B,H,S]
+        np.testing.assert_allclose(np.asarray(lse).reshape(B, H, S),
+                                   np.asarray(want), atol=2e-5)
 
     def test_nondivisible_seq_raises(self):
         q, k, v = _qkv(S=100)
@@ -127,8 +228,9 @@ class TestFlashPlan:
 
         n, blocks = block_q // sub, S // block_q
         # square (query sub-block, key sub-block) -> masked?, as the
-        # kernels' walks visit them: the forward and bwd_dkv walk a pair's
-        # key sub-blocks, bwd_dq its query sub-blocks
+        # kernels' walks visit them, a pair's key sub-blocks one after the
+        # other; and the same squares read from the queries' end (query
+        # sub-block j meets the keys 0 .. j), as PR 31's bwd_dq walked them
         by_keys, by_queries = {}, {}
         for iq in range(blocks):
             for ik in range(iq + 1):                  # pairs not skipped
@@ -138,7 +240,7 @@ class TestFlashPlan:
                         square = (iq * n + other, ik * n + j)
                         assert square not in by_keys
                         by_keys[square] = diagonal and other == first
-                    # bwd_dq: query sub-block j meets keys 0 .. j (all)
+                    # from the other end: query sub-block j, keys 0 .. j
                     for other in range(j + 1 if diagonal else n):
                         square = (iq * n + j, ik * n + other)
                         assert square not in by_queries
@@ -198,7 +300,9 @@ class TestFlashPlan:
             for itemsize in (1, 2, 4):
                 assert S % flash_plan(S, 64, itemsize).block_q == 0
         else:
-            assert S in (516, 700, 1100)
+            # 520 = 8 x 65: one block at two bytes, but no block of whole
+            # 128-lane columns divides it at four
+            assert S in (516, 520, 700, 1100)
             with pytest.raises(ValueError):
                 flash_plan(S, 64, 4)
 
@@ -263,8 +367,9 @@ class TestPackageWiring:
         importlib.reload(paddle_tpu.ops.pallas.flash_attention_kernel)
         assert callable(pkg.flash_attention)
         # the models bind the function directly too
-        from paddle_tpu.models.gpt import _flash_attention
-        assert callable(_flash_attention)
+        from paddle_tpu.models.gpt import _flash_attention_qkv
+        from paddle_tpu.models.llama import _flash_attention
+        assert callable(_flash_attention_qkv) and callable(_flash_attention)
 
     def test_pallas_kernel_in_hlo_on_tpu(self):
         """On a real TPU backend the jitted attention must lower to the Pallas
