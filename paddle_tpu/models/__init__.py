@@ -24,3 +24,6 @@ from .keye_vl2 import (
 from .evabyte import (
     EvaByteConfig, EvaByteForCausalLM, evabyte_tiny,
 )
+from .mellum import (
+    MellumConfig, MellumForCausalLM, mellum_tiny,
+)

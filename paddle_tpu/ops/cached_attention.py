@@ -33,7 +33,7 @@ __all__ = ["cached_attention", "gather_block_kv",
            "paged_prefill_attention", "verify_attention"]
 
 
-def cached_attention(query, k_cache, v_cache, lengths, name=None):
+def cached_attention(query, k_cache, v_cache, lengths, window=0, name=None):
     """One decode step of attention for a batch of cache slots.
 
     Args:
@@ -44,6 +44,8 @@ def cached_attention(query, k_cache, v_cache, lengths, name=None):
         v_cache: ``[B, T, Hkv, D]`` — per-slot value cache.
         lengths: ``[B]`` int32 — index of the current token per slot; the
                  attention window is ``0..lengths[b]`` inclusive.
+        window:  0, or the keys a query reads: its own position and the
+                 ``window - 1`` before it.
 
     Returns:
         ``[B, 1, H, D]`` context tensor.
@@ -59,7 +61,10 @@ def cached_attention(query, k_cache, v_cache, lengths, name=None):
         scale = 1.0 / (D ** 0.5)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
         logits = logits.astype(jnp.float32)
-        valid = jnp.arange(T, dtype=ln.dtype)[None, :] <= ln[:, None]  # [B,T]
+        kpos = jnp.arange(T, dtype=ln.dtype)[None, :]
+        valid = kpos <= ln[:, None]                                   # [B,T]
+        if window:
+            valid &= kpos > ln[:, None] - window
         logits = jnp.where(valid[:, None, None, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -138,7 +143,8 @@ def gather_block_kv(pool_layer, block_tables):
     return g.reshape(B, MB * bs, *pool_layer.shape[2:])
 
 
-def block_prefill_attention(query, k_cache, v_cache, start, name=None):
+def block_prefill_attention(query, k_cache, v_cache, start, window=0,
+                            name=None):
     """Tail-bucket prefill attention against a block-gathered cache.
 
     The paged serving path prefills only the *uncached tail* of a prompt:
@@ -156,6 +162,8 @@ def block_prefill_attention(query, k_cache, v_cache, start, name=None):
                  the freshly-written tail.
         v_cache: ``[1, T, Hkv, D]`` — gathered values.
         start:   scalar int32 — absolute position of the first query.
+        window:  0, or the keys a query reads (``j <= i`` AND ``j > i -
+                 window``).
 
     Returns:
         ``[1, S, H, D]`` context tensor.  GQA kv heads are repeated
@@ -176,6 +184,8 @@ def block_prefill_attention(query, k_cache, v_cache, start, name=None):
         qpos = st + jnp.arange(S, dtype=jnp.int32)            # [S]
         kpos = jnp.arange(T, dtype=jnp.int32)                 # [T]
         valid = kpos[None, :] <= qpos[:, None]                # [S, T]
+        if window:
+            valid &= kpos[None, :] > qpos[:, None] - window
         logits = jnp.where(valid[None, None, :, :], logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -201,7 +211,8 @@ def _paged_per_shard(kernel, args, mesh):
 
 
 def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
-                           active, interpret=False, mesh=None, name=None):
+                           active, interpret=False, mesh=None, window=0,
+                           name=None):
     """Flash-decoding paged attention: the Pallas kernel path of the
     decode read (``ops.pallas.paged_attention_kernel``), consuming the
     block table *inside* the kernel — the fused replacement for
@@ -222,6 +233,7 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
                       CPU/tier-1 path; False compiles for real TPUs).
         mesh:         the mesh a sharded engine's pool lives on (None:
                       unsharded) — the kernel then runs per head shard.
+        window:       0, or the keys a query reads (the kernel's own).
 
     Returns:
         ``[B, 1, H, D]`` context, GQA expanded inside the kernel; zero
@@ -232,7 +244,7 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
     def _primal(q, kp, vp, tbl, ln, act):
         return _paged_per_shard(
             functools.partial(paged_decode_attention_kernel,
-                              interpret=interpret),
+                              window=window, interpret=interpret),
             (q, kp, vp, tbl, ln, act), mesh)
 
     return apply_op("paged_decode_attention", _primal,
@@ -240,7 +252,7 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
 
 
 def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
-                            interpret=False, mesh=None, name=None):
+                            interpret=False, mesh=None, window=0, name=None):
     """Fused cached-prefix + causal-tail prefill attention: the Pallas
     kernel path of the paged tail prefill, streaming the slot's block
     row straight off the pool — the fused replacement for
@@ -256,6 +268,7 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
         interpret: Pallas interpret mode (CPU/tier-1 path).
         mesh:      the mesh a sharded engine's pool lives on (None:
                    unsharded) — the kernel then runs per head shard.
+        window:    0, or the keys a query reads (the kernel's own).
 
     Returns:
         ``[1, S, H, D]`` context.
@@ -265,7 +278,7 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
     def _primal(q, kp, vp, row, st):
         return _paged_per_shard(
             functools.partial(paged_prefill_attention_kernel,
-                              interpret=interpret),
+                              window=window, interpret=interpret),
             (q, kp, vp, row, jnp.asarray(st).reshape(1)), mesh)
 
     return apply_op("paged_prefill_attention", _primal,

@@ -179,20 +179,25 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, q_ref, pool_ref,
         _finish(o_ref, acc_ref, l_ref)
 
 
-def decode_work_list(lengths, active, chunk_tokens: int, max_chunks: int):
+def decode_work_list(lengths, active, chunk_tokens: int, max_chunks: int,
+                     first=None):
     """``(slot, chunk, n)``: the (slot, chunk) pairs a decode step has to
     visit, slot-major, in the first ``n`` places of two ``[B * max_chunks]``
     arrays.  A slot with ``active == 0`` has none; an active one has the
-    chunks that intersect ``0..lengths[slot]``."""
+    chunks that intersect ``0..lengths[slot]`` — from chunk ``first[slot]``
+    on where a caller gives one (a layer that reads only the keys of a window
+    behind the query)."""
     B = lengths.shape[0]
-    per = jnp.where(active > 0,
-                    jnp.minimum(lengths // chunk_tokens + 1, max_chunks), 0)
+    last = jnp.minimum(lengths // chunk_tokens + 1, max_chunks)
+    if first is None:
+        first = jnp.zeros_like(last)
+    per = jnp.where(active > 0, last - first, 0)
     ends = jnp.cumsum(per)
     n = ends[-1]
     idx = jnp.arange(B * max_chunks, dtype=jnp.int32)
     slot = jnp.minimum(jnp.searchsorted(ends, idx, side="right"), B - 1
                        ).astype(jnp.int32)
-    chunk = idx - (ends - per)[slot]
+    chunk = idx - (ends - per)[slot] + first[slot]
     live = idx < n
     return (jnp.where(live, slot, 0), jnp.where(live, chunk, 0).astype(
         jnp.int32), n.astype(jnp.int32))

@@ -17,8 +17,10 @@ kernel, handed over in scalar prefetch, and their number is the grid's
 are never read — at decode the kernel reads the touched experts' weights and
 nothing else — and the tiles past the last group cost nothing.
 
-``K`` is taken whole (2,048 and 768 in the model served here: a ``[128, K]``
-tile of rows and a ``[K, 512]`` tile of weights are 0.5 and 2 MiB in bf16).
+``K`` is taken whole (2,048 and 768, or 2,304 and 896, in the models served
+here: a ``[128, K]`` tile of rows and a ``[K, 512]`` tile of weights are 0.5
+and 2 MiB in bf16; an N that 512 does not divide takes its largest divisor in
+whole lane tiles whose weights tile fits, 896 of 1,792).
 The ``pallas_call`` is named ``moe_grouped_matmul``, which is the name the
 device trace shows.
 """
@@ -33,8 +35,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: rows of a tile (a whole number of bf16 sublane tiles)
 TILE_M = 128
-#: columns of a tile when N is a multiple of it; else N whole
+#: columns of a tile when N is a multiple of it; else (:func:`_tile_n`) the
+#: most whole lane tiles that divide N and keep a ``[K, tn]`` tile of weights
+#: within :data:`RHS_TILE_BYTES`
 TILE_N = 512
+#: a weights tile is double-buffered under v5e's 16 MiB of scoped VMEM: N
+#: whole at ``[2304, 1792]`` of bf16 (8.3 MB a buffer) does not compile
+RHS_TILE_BYTES = 9 << 19
+
+
+def _tile_n(K: int, N: int, itemsize: int) -> int:
+    if N % TILE_N == 0:
+        return TILE_N
+    fits = [t for t in range(128, N + 1, 128)
+            if N % t == 0 and K * t * itemsize <= RHS_TILE_BYTES]
+    return max(fits, default=N)
 
 
 def _visits(group_sizes, tiles_m: int, tm: int):
@@ -89,7 +104,7 @@ def moe_grouped_matmul(lhs, rhs, group_sizes, *, interpret=False):
     M, K = lhs.shape
     G, _, N = rhs.shape
     tm = TILE_M
-    tn = TILE_N if N % TILE_N == 0 else N
+    tn = _tile_n(K, N, rhs.dtype.itemsize)
     pad = -M % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))

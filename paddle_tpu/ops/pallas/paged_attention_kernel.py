@@ -85,6 +85,11 @@ DECODE_VMEM_BYTES = 12 << 20
 #: body holds at once: keys, values, and the two products
 DECODE_F32_CHUNKS = 4
 
+#: query heads a KV head must serve for the decode kernel to take them as the
+#: rows of a matmul (a float32 sublane tile); fewer are multiplied a row at
+#: a time on the VPU (one row is no work for the MXU)
+MXU_QUERY_ROWS = 8
+
 #: query rows per prefill grid step (f32 q/out/acc tiles of 16 heads x
 #: 128 rows x 64->128 lanes are 1 MiB each; ~7 MiB of VMEM in all)
 PREFILL_Q_TILE = 128
@@ -119,7 +124,7 @@ def decode_chunk_tokens(block_size: int, max_blocks: int, kv_heads: int,
 
 def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
                    k_hbm, v_hbm, o_ref, k_ref, v_ref, sem, acc_ref, m_ref,
-                   l_ref, *, scale, cb, bs, mb):
+                   l_ref, *, scale, cb, bs, mb, window):
     i = pl.program_id(0)
     rep = q_ref.shape[1]
     ct = cb * bs
@@ -127,9 +132,11 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
     @pl.when(i < n_ref[0])                       # places past the list: idle
     def _item():
         b, c = slot_ref[i], chunk_ref[i]
-        length = len_ref[b]                      # window 0..length inclusive
+        length = len_ref[b]                      # keys lo..length inclusive
+        # a window layer's query reads the ``window`` keys up to its own
+        lo = jnp.maximum(length - (window - 1), 0) if window else 0
 
-        @pl.when(c == 0)
+        @pl.when(c == lo // ct)                  # the slot's first chunk
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -158,13 +165,18 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
             return carry
 
         live = jnp.minimum(length // bs - c * cb + 1, cb)
-        jax.lax.fori_loop(0, live, start, 0)
-        jax.lax.fori_loop(0, live, wait, 0)
+        head = jnp.maximum(lo // bs - c * cb, 0)  # blocks behind the window
+        jax.lax.fori_loop(head, live, start, 0)
+        jax.lax.fori_loop(head, live, wait, 0)
 
+        if rep >= MXU_QUERY_ROWS:
+            _grouped_update(c, ct, length, lo, q_ref, k_ref, v_ref, o_ref,
+                            acc_ref, m_ref, l_ref, scale)
+            return
         k = k_ref[...].astype(jnp.float32)       # [ct, Hkv, D]
         pos = c * ct + jax.lax.broadcasted_iota(
             jnp.int32, k.shape[:2] + (1,), 0)    # [ct, Hkv, 1]
-        valid = pos <= length
+        valid = (pos <= length) & (pos >= lo)
         # a weight of zero does not hide a NaN: masked values are dropped
         v = jnp.where(valid, v_ref[...].astype(jnp.float32), 0.0)
         for r in range(rep):                     # static: H // Hkv
@@ -187,8 +199,49 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
                                ).astype(o_ref.dtype)
 
 
+def _grouped_update(c, ct, length, lo, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                    m_ref, l_ref, scale):
+    """A work item's online-softmax update where a KV head serves a sublane
+    tile of query heads or more (grouped queries: 8 of them at 32 / 4): the
+    ``rep`` query rows of a KV head are the rows of ONE matmul against the
+    chunk's keys, and of one against its values, KV heads leading as in the
+    prefill kernel — where the loop below multiplies the chunk by one query
+    row at a time on the VPU, ``rep`` times over (17 us a 256-key chunk at
+    ``rep`` 8 on the chip, PERF.md section 6).  Scratch is ``[Hkv, rep, .]``
+    here."""
+    op = k_ref.dtype                             # the MXU's operands
+    k = jnp.swapaxes(k_ref[...].astype(jnp.float32), 0, 1)    # [Hkv, ct, D]
+    v = jnp.swapaxes(v_ref[...].astype(jnp.float32), 0, 1)
+    pos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (1, ct, 1), 1)
+    # a weight of zero does not hide a NaN: masked values are dropped
+    v = jnp.where((pos <= length) & (pos >= lo), v, 0.0)
+    q = jnp.swapaxes(q_ref[0], 0, 1)             # [Hkv, rep, D]
+    s = jnp.einsum("grd,gkd->grk", q.astype(op), k.astype(op),
+                   precision=jax.lax.Precision.DEFAULT,
+                   preferred_element_type=jnp.float32) * scale
+    kpos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (1, 1, ct), 2)
+    s = jnp.where((kpos <= length) & (kpos >= lo), s, NEG_INF)
+    m_prev = m_ref[:, :, 0:1]                    # [Hkv, rep, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+    p = jnp.exp(s - m_new)                       # [Hkv, rep, ct]
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_ref[:, :, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+        "grk,gkd->grd", p.astype(op), v.astype(op),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(c == length // ct)                  # the slot's last live chunk
+    def _finalize():                             # l > 0: position c*ct valid
+        o_ref[0] = jnp.swapaxes(acc_ref[...] / l_ref[:, :, 0:1], 0, 1
+                                ).astype(o_ref.dtype)
+
+
 def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
-                                  lengths, active, *, interpret=False):
+                                  lengths, active, *, window=0,
+                                  interpret=False):
     """One decode step of attention straight off the block pool.
 
     Args:
@@ -202,6 +255,12 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
         lengths:      ``[B]`` int32 current token index per slot
                       (attention window ``0..lengths[b]`` inclusive).
         active:       ``[B]`` int32, nonzero for the running slots.
+        window:       0, or the keys a query reads: its own position and
+                      the ``window - 1`` before it.  The work list then holds
+                      only the chunks that meet ``[lengths[b] - window + 1,
+                      lengths[b]]``, the chunk at the lower edge is masked,
+                      and no block wholly behind it is copied (its table
+                      entry may have been released).
 
     Returns:
         ``[B, 1, H, D]`` context; zero for slots that are not active.  No
@@ -212,14 +271,26 @@ def paged_decode_attention_kernel(q, k_pool, v_pool, block_tables,
     ct = decode_chunk_tokens(bs, block_tables.shape[1], Hkv, D,
                              k_pool.dtype.itemsize)
     return _decode_call(q, k_pool, v_pool, block_tables, lengths, active,
-                        chunk_tokens=ct, interpret=interpret)
+                        first_chunks(lengths, int(window), ct),
+                        chunk_tokens=ct, window=int(window),
+                        interpret=interpret)
+
+
+def first_chunks(lengths, window: int, chunk_tokens: int):
+    """The first chunk a slot's work list holds under ``window`` (None with
+    none: chunk 0): the one of its query's oldest key."""
+    if not window:
+        return None
+    return jnp.maximum(lengths.astype(jnp.int32) - (window - 1), 0) \
+        // chunk_tokens
 
 
 # jitted so that a model's layers, and the passes a program is traced in,
 # trace and lower the kernel once for their shapes and not once each
-@functools.partial(jax.jit, static_argnames=("chunk_tokens", "interpret"))
-def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, *,
-                 chunk_tokens, interpret):
+@functools.partial(jax.jit, static_argnames=("chunk_tokens", "window",
+                                             "interpret"))
+def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, first, *,
+                 chunk_tokens, window, interpret):
     B, _, H, head_dim = q.shape
     bs, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
@@ -229,19 +300,21 @@ def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, *,
     scale = 1.0 / (head_dim ** 0.5)
     q = _to_lanes(q, D)
     lengths = lengths.astype(jnp.int32)
-    slot, chunk, n = decode_work_list(lengths, active, ct, max_chunks)
+    slot, chunk, n = decode_work_list(lengths, active, ct, max_chunks, first)
     # a place past the list keeps the last item's slot: its index maps
     # move no query row in and, above all, no output row out
     slot = jnp.where(jnp.arange(slot.shape[0]) < n, slot,
                      slot[jnp.maximum(n - 1, 0)])
     kernel = functools.partial(_decode_kernel, scale=scale, cb=cb, bs=bs,
-                               mb=mb)
+                               mb=mb, window=window)
     # query head h = g * rep + r  ->  q_g[b, r, g]: kv head g lines up
     # with every one of its rep query heads without an in-kernel repeat
     q_g = q.reshape(B, Hkv, rep, D).transpose(0, 2, 1, 3)
     qo_spec = pl.BlockSpec((1, rep, Hkv, D),
                            lambda i, tbl, lens, sl, ch, n: (sl[i], 0, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    # the accumulators' leading dims: KV heads first where they batch matmuls
+    lead = (Hkv, rep) if rep >= MXU_QUERY_ROWS else (rep, Hkv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B * max_chunks,),                  # static: see the docstring
@@ -251,9 +324,9 @@ def _decode_call(q, k_pool, v_pool, block_tables, lengths, active, *,
             pltpu.VMEM((ct, Hkv, D), k_pool.dtype),
             pltpu.VMEM((ct, Hkv, D), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, cb)),
-            pltpu.VMEM((rep, Hkv, D), jnp.float32),
-            pltpu.VMEM((rep, Hkv, 128), jnp.float32),
-            pltpu.VMEM((rep, Hkv, 128), jnp.float32),
+            pltpu.VMEM(lead + (D,), jnp.float32),
+            pltpu.VMEM(lead + (128,), jnp.float32),
+            pltpu.VMEM(lead + (128,), jnp.float32),
         ],
     )
     o_g = pl.pallas_call(
@@ -331,8 +404,14 @@ def write_blocks(pool, upd, block_ids, *, interpret=False):
 
 # -- fused prefill: cached prefix + causal tail in one kernel scope ---------
 
+def _first_block(q0, window: int, block_size: int):
+    """The first block a query tile that starts at ``q0`` reads: the one that
+    holds the first key of its first query's window (block 0 with none)."""
+    return jnp.maximum(q0 - (window - 1), 0) // block_size if window else 0
+
+
 def _prefill_kernel(row_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, scale, block_size):
+                    acc_ref, m_ref, l_ref, *, scale, block_size, window, mb):
     t, i = pl.program_id(0), pl.program_id(1)
     nb = pl.num_programs(1)
     Hkv, rep, ts, _ = q_ref.shape
@@ -344,10 +423,13 @@ def _prefill_kernel(row_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[:] = jnp.zeros_like(l_ref)
 
     q0 = start_ref[0] + t * ts                   # tile's first abs position
+    # the grid's places count from the first block the tile reads: block 0,
+    # or with a window the block of the first query's oldest key
+    a = _first_block(q0, window, block_size) + i
     # the last live key position is the tile's last query's absolute
     # position; blocks wholly past it contribute nothing (pure prefix
     # blocks below `start` are always live — the fused cross-attention half)
-    live = i * block_size <= q0 + ts - 1
+    live = (a * block_size <= q0 + ts - 1) & (a < mb)
 
     @pl.when(live)
     def _compute():
@@ -356,8 +438,10 @@ def _prefill_kernel(row_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
         v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
         shape = (Hkv, ts, block_size)
         qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        kpos = i * block_size + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+        kpos = a * block_size + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
         mask = kpos <= qpos                      # abs-position causal mask
+        if window:
+            mask &= kpos > qpos - window
         for r in range(rep):                     # static: H // Hkv
             q = q_ref[:, r].astype(jnp.float32)  # [Hkv, ts, D]
             s = jnp.einsum("gqd,gkd->gqk", q, k,
@@ -393,7 +477,7 @@ def _q_tile(S: int) -> int:
 
 
 def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
-                                   *, interpret=False):
+                                   *, window=0, interpret=False):
     """Fused tail-bucket prefill attention straight off the block pool.
 
     The tail's S queries (absolute positions ``start..start+S-1``)
@@ -410,6 +494,13 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
         block_row: ``[max_blocks]`` int32 — the slot's block-table row.
         start:     ``[1]`` int32 — absolute position of the first query
                    (== cached prefix length, a block boundary).
+        window:    0, or the keys a query reads: ``j <= i`` AND ``j > i -
+                   window``.  A query tile's grid places then cover only the
+                   blocks from its first query's oldest key to its last
+                   query (``(window + tile) / block_size`` places and not the
+                   whole row), so no block wholly outside every query's
+                   window is visited — its table entry may have been
+                   released.
 
     Returns:
         ``[1, S, H, D]`` context.
@@ -421,17 +512,24 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
     ts = _q_tile(S)
     scale = 1.0 / (head_dim ** 0.5)
     q = _to_lanes(q, D)
+    window = int(window)
     kernel = functools.partial(_prefill_kernel, scale=scale,
-                               block_size=block_size)
+                               block_size=block_size, window=window, mb=MB)
+    # blocks a tile's places cover: the whole row, or a window and a tile
+    places = min(MB, (window + ts - 2) // block_size + 2) if window else MB
     # head-major queries, query head h = g * rep + r  ->  q_g[g, r]
     q_g = q[0].transpose(1, 0, 2).reshape(Hkv, rep, S, D)
-    kv_spec = pl.BlockSpec((1, block_size, Hkv, D),
-                           lambda t, i, row, st: (row[i], 0, 0, 0))
+
+    def kv_index(t, i, row, st):
+        a = _first_block(st[0] + t * ts, window, block_size) + i
+        return (row[jnp.minimum(a, MB - 1)], 0, 0, 0)
+
+    kv_spec = pl.BlockSpec((1, block_size, Hkv, D), kv_index)
     qo_spec = pl.BlockSpec((Hkv, rep, ts, D),
                            lambda t, i, row, st: (0, 0, t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S // ts, MB),
+        grid=(S // ts, places),
         in_specs=[qo_spec, kv_spec, kv_spec],
         out_specs=qo_spec,
         scratch_shapes=[

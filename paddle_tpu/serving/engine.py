@@ -86,6 +86,7 @@ from .kv_cache import cache_spec_of
 from .metrics import ServingMetrics
 from .paging import PagedCacheContext, PagedKVCache
 from .prefix_cache import PrefixCache
+from .group_cache import GroupedKVCache, GroupedPrefixCache
 from .window_cache import WindowedKVCache, WindowedPrefixCache
 from .sampling import DeviceSampler, SamplingParams, sampler_path
 from .sanitize import SyncSanitizer
@@ -338,6 +339,11 @@ class Engine:
             ``num_kv_blocks`` sizes the exact group, default two windows
             a slot and one cold prompt); default every slot at
             ``max_seq``.  Refused for any other model.
+        num_window_blocks: blocks of the group that keeps a window, of a
+            model whose cache is stated by layer (``CacheSpec.by_layer``:
+            then ``num_kv_blocks`` sizes the group that keeps every token);
+            default a window and a block a slot and two tails' room.
+            Refused for any other model.
         enable_prefix_cache: hash whole prompt blocks host-side and
             serve repeated prefixes from refcounted shared blocks,
             shrinking the prefill to the uncached tail bucket.
@@ -421,6 +427,7 @@ class Engine:
                  block_size: int = 16,
                  num_kv_blocks: Optional[int] = None,
                  num_summary_blocks: Optional[int] = None,
+                 num_window_blocks: Optional[int] = None,
                  enable_prefix_cache: bool = True,
                  prefix_lookup_timeout_s: float = 0.25,
                  max_preemptions: int = 2,
@@ -480,22 +487,28 @@ class Engine:
             raise ValueError(
                 f"kv_layout={kv_layout!r}: the contiguous layout was "
                 f"removed and the cache is always paged (drop the argument)")
-        if spec.kind in ("latent", "indexed", "windowed"):
+        #: the statement is by layer: a pool a group of layers
+        by_layer = bool(spec.layer_groups)
+        if by_layer or spec.kind in ("latent", "indexed", "windowed"):
             # one vector a token has no per-KV-head axis to shard by, the
-            # three-sided pool's indexer side has none either, the summary
+            # three-sided pool's indexer side has none either, a second
             # group's tables and allocator are not placed on a mesh, and
             # none has a form of the verify window
+            form = "by-layer" if by_layer else spec.kind
             caches, no_mesh = {
+                "by-layer": ("caches K and V by groups of layers, some only "
+                             "inside a window",
+                             "the groups' tables are not sharded"),
                 "latent": ("caches one latent vector a token",
                            "the latent pool has no kv_heads axis to shard"),
                 "indexed": ("caches K, V and an indexer key a token",
                             "the indexed pool is not sharded"),
                 "windowed": ("caches an exact window and chunk summaries",
-                             "the summary group is not sharded")}[spec.kind]
+                             "the summary group is not sharded")}[form]
             refused = [what for what, asked in (
                 (f"a serving mesh of more than one device ({no_mesh})",
                  mesh is not None and mesh.size > 1),
-                (f"speculation= (the verify window has no {spec.kind} form)",
+                (f"speculation= (the verify window has no {form} form)",
                  speculation is not None)) if asked]
             if refused:
                 raise ValueError(
@@ -517,7 +530,30 @@ class Engine:
                     max_seq=self.max_seq, sides=spec.sides, dtype=cache_dtype,
                     block_size=self.block_size, num_blocks=num_kv_blocks,
                     kernel=self.kernel)
-        if spec.kind == "windowed":
+        if num_window_blocks is not None and not by_layer:
+            raise ValueError(
+                f"num_window_blocks: {type(model).__name__} states no "
+                f"group that keeps a window")
+        if by_layer:
+            # a pool a group of layers, each with its allocator and table;
+            # retention, admission and the prefix hit are by group
+            if num_summary_blocks is not None:
+                raise ValueError(
+                    f"num_summary_blocks: {type(model).__name__} keeps no "
+                    f"summary group")
+            sizes = [num_window_blocks if g.window else num_kv_blocks
+                     for g in spec.groups]
+            self.cache = GroupedKVCache(
+                spec.groups, num_slots=self.num_slots, max_seq=self.max_seq,
+                dtype=cache_dtype, block_size=self.block_size,
+                num_blocks=sizes, kernel=self.kernel)
+            self.prefix_cache = (GroupedPrefixCache(self.cache)
+                                 if enable_prefix_cache else None)
+            #: what the step span reports of the groups without asking them
+            self._group_window = max(g.window for g in spec.groups)
+            self._group_blocks = [p.num_blocks - p.allocator.reserved
+                                  for p in self.cache.pools]
+        elif spec.kind == "windowed":
             # a second group beside the K/V pool: a window's summaries a
             # block, its own allocator and table, and a prefix cache that
             # hits by whole windows first
@@ -691,6 +727,12 @@ class Engine:
         #: them in
         self._latent = {"prefills": 0, "pairs_upprojected": 0,
                         "pairs_absorbed": 0}
+        #: a cache stated by layer: decode steps by what their layers read
+        #: (a layer's count of each kind, summed over the running slots) and
+        #: blocks its window groups let go of, by where
+        self._swa = {"steps": 0, "full_rows": 0, "window_rows": 0,
+                     "context": 0, "blocks_released_decode": 0,
+                     "blocks_released_prefill": 0}
         self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
@@ -698,9 +740,9 @@ class Engine:
     # -- compiled steps ----------------------------------------------------
 
     def _make_buckets(self) -> List[int]:
-        # a windowed cache prefills a window at a time (``_tail_end``): no
-        # tail is longer
-        top = self.cache_spec.window or self.max_seq
+        # a cache with a group that keeps a window prefills a long prompt
+        # in pieces (``_tail_end``): no tail is longer than its limit
+        top = min(self.cache_spec.tail_limit or self.max_seq, self.max_seq)
         b, out = self.min_bucket, []
         while b < top:
             out.append(b)
@@ -1044,11 +1086,11 @@ class Engine:
         # of fresh blocks; a prompt that can never fit the pool is
         # rejected up front instead of deferring forever
         need = self.bucket_for(req.prompt_ids.size) // self.block_size
-        if 0 < self.cache_spec.window < req.prompt_ids.size:
-            # a windowed cache prefills a window at a time (``_tail_end``):
-            # a piece's bucket, and the next piece's beside it
+        if 0 < self.cache_spec.tail_limit < req.prompt_ids.size:
+            # a prompt prefilled in pieces (``_tail_end``): a piece's
+            # bucket, and the next piece's beside it
             need *= 2
-        usable = self.cache.num_blocks - self.cache.allocator.reserved
+        usable = self.cache.usable_blocks()
         if need > usable:
             return (f"prompt needs {need} KV blocks "
                     f"(bucket {self.bucket_for(req.prompt_ids.size)}, "
@@ -1624,6 +1666,8 @@ class Engine:
                     self._note_prefill_windows(sp, start, end)
                 if self.cache_spec.kind == "latent":
                     self._note_prefill_pairs(sp, start, end)
+                if self.cache_spec.layer_groups:
+                    self._note_group_prefill(sp, start, end)
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -1642,9 +1686,16 @@ class Engine:
         window at a time: each program closes its window, publishes it and
         lets its exact blocks go before the next one starts, so a cold
         prompt of any length holds two windows of exact blocks at most and
-        takes no bucket above the window's."""
-        W = self.cache_spec.window
-        return (start // W + 1) * W if W and L - start > W else L
+        takes no bucket above the window's.  A cache stated by layer with a
+        group that keeps a window cuts a tail at its ``tail_limit``."""
+        W = self.cache_spec.tail_limit
+        if not W or L - start <= W:
+            return L
+        if self.cache_spec.kind == "windowed":
+            return (start // W + 1) * W
+        # a cache stated by layer: pieces of the limit, so that a group with
+        # a window holds the tail's blocks and one window's before them
+        return start + W
 
     def _paged_prefill(self, req: Request, L: int):
         """Paged admission: prefix lookup, block assignment, tail-bucket
@@ -1677,6 +1728,14 @@ class Engine:
                 # program: their exact blocks go, before the next piece takes
                 # its own and before the prompt is registered
                 self.cache.release_windows(req.slot, end)
+            # a group that keeps a window: the blocks behind the window of
+            # the sequence's next query go, now that the tail's first query
+            # has read them — after the last piece only as far as the longest
+            # hit of this very prompt (a resume's) would read, until the
+            # prompt is registered
+            self._swa["blocks_released_prefill"] += self.cache.release_behind(
+                req.slot, end if end < L
+                else (L - 1) // self.block_size * self.block_size)
             if end == L:
                 break
             # the next piece; its first token is sampled from the slot's
@@ -1696,9 +1755,15 @@ class Engine:
             try:
                 self.prefix_cache.register(
                     req.prompt_ids, self.cache.owned_blocks(req.slot),
-                    salt=self._tenant_salt(req))
+                    salt=self._tenant_salt(req),
+                    # a cache stated by layer: where this admission's hit
+                    # ended (the window group drops a run moved past)
+                    **({"hit_tokens": P} if self.cache_spec.layer_groups
+                       else {}))
             except Exception:            # noqa: BLE001 — isolation boundary
                 self.metrics.on_prefix_register_error()
+        self._swa["blocks_released_prefill"] += \
+            self.cache.release_behind(req.slot, L)
         return "ok", last, bucket, P
 
     def _assign_blocks(self, req: Request, L: int):
@@ -1714,7 +1779,13 @@ class Engine:
         # blocks from the end until the padded tail fits; the remaining
         # hit is still a contiguous prefix
         while P and (P + bucket > self.max_seq):
-            P, shared = self.cache.shorten_hit(shared)
+            if self.cache_spec.layer_groups:
+                # the end moves, and with it the blocks a window group needs
+                P, shared = self.prefix_cache.lookup(
+                    req.prompt_ids, count=False, salt=self._tenant_salt(req),
+                    max_tokens=P - self.block_size)
+            else:
+                P, shared = self.cache.shorten_hit(shared)
             bucket = self.bucket_for(self._tail_end(P, L) - P)
         if self.prefix_cache is not None and req._defers == 0:
             # one logical lookup per request (deferral retries re-look-up
@@ -1734,6 +1805,19 @@ class Engine:
                 -(-(r.max_new_tokens - len(r.output_ids)) // bs) + 1
                 for r in (*self.running.values(), req))
                 + (self._tail_end(P, L) < L) * self.cache.window_blocks)
+        elif self.cache_spec.layer_groups:
+            # each group left with what the running sequences may still take
+            # of it (a request is deferred, never failed, for want of either;
+            # the sequence's own need is added where its blocks are known)
+            needs = [self.cache.growth_needs(
+                r.slot, r.prompt_ids.size + r.max_new_tokens)
+                for r in self.running.values()]
+            # (and, of a prompt prefilled in pieces, the next piece's bucket)
+            more = 1 + (self._tail_end(P, L) < L) * (
+                self.buckets[-1] // self.block_size)
+            extra = dict(total=L + req.max_new_tokens,
+                         reserve=[more + sum(n) for n in zip(*needs)]
+                         if needs else [more] * len(self.cache.pools))
         return P, bucket, self.cache.begin_sequence(req.slot, shared, P,
                                                     bucket, **extra)
 
@@ -1945,6 +2029,10 @@ class Engine:
                     and not self._publish_window(req):
                 continue
             try:
+                # a group that keeps a window lets the blocks behind the
+                # next query's window go before it takes the next one
+                self._swa["blocks_released_decode"] += \
+                    self.cache.release_behind(slot, req._seq_len)
                 ok = self.cache.ensure_capacity(slot, req._seq_len)
             except Exception as e:       # noqa: BLE001 — accounting bug
                 self._mark_block_corruption(
@@ -2002,6 +2090,39 @@ class Engine:
                eva_keys=L - start // W * W + (L - 1) // W * rows_a_window)
         self._eva["prefill_windows"] += touched
         self._eva["windows_published_prefill"] += closed
+
+    def _note_group_rows(self) -> None:
+        """What this decode step's layers read, by the kernels' own rule and
+        from the lengths the host knows: a layer that keeps all reads a
+        slot's every token and the new one, a layer that keeps a window the
+        last ``window`` of them — a layer's count of each kind, summed over
+        the running slots — and each group's blocks that a live slot
+        holds."""
+        n = np.fromiter((r._seq_len + 1 for r in self.running.values()),
+                        np.int64, len(self.running))
+        full = int(n.sum())
+        window = int(np.minimum(n, self._group_window).sum())
+        sw = self._swa
+        sw["steps"] += 1
+        sw["full_rows"] += full
+        sw["window_rows"] += window
+        sw["context"] += full
+        if self._step_span is not None:
+            self._step_span.set(
+                swa_full_rows=full, swa_window_rows=window, swa_context=full,
+                swa_blocks_used=[p.allocator.used_blocks
+                                 for p in self.cache.pools],
+                swa_blocks=self._group_blocks)
+
+    def _note_group_prefill(self, sp, start: int, L: int) -> None:
+        """What the prefill of ``[start, L)`` must read in one layer of each
+        kind: ``rows``, the keys its real queries attend to summed over the
+        queries, and ``keys``, the distinct ones behind them."""
+        W = self._group_window
+        i = np.arange(start, L, dtype=np.int64)
+        sp.set(swa_full_rows=int(np.sum(i + 1)), swa_full_keys=L,
+               swa_window_rows=int(np.sum(np.minimum(i + 1, W))),
+               swa_window_keys=L - max(0, start - W + 1))
 
     def _note_prefill_pairs(self, sp, start: int, L: int) -> None:
         """The (query, key) pairs of the tail ``[start, L)``'s real tokens,
@@ -2063,6 +2184,8 @@ class Engine:
                 self._step_span.attrs["decode_chunks"] = sum(
                     self._decode_items(req._seq_len)
                     for req in self.running.values())
+            if self.cache_spec.layer_groups:
+                self._note_group_rows()
             # the sampler's way through this step, by the rule its program
             # applies to the running slots' lanes
             path = sampler_path(
@@ -2905,6 +3028,10 @@ class Engine:
             "copy_on_extends": self.cache.copy_on_extends,
             "prefix": (self.prefix_cache.stats()
                        if self.prefix_cache is not None else None),
+            # a cache stated by layer: a row a group ("blocks" above is the
+            # first group's, the one that keeps every token)
+            **({"groups": self.cache.group_stats()}
+               if self.cache_spec.layer_groups else {}),
         }
 
     def health(self) -> dict:
@@ -2976,6 +3103,12 @@ class Engine:
                 exact_blocks_in_use=self.cache.blocks_in_use(),
                 summary_blocks_in_use=self.cache.summary_blocks_in_use(),
                 exact_blocks_released=self.cache.exact_blocks_released)
+        if self.cache_spec.layer_groups:
+            snap["swa"] = dict(
+                self._swa, groups=self.cache.group_stats(),
+                deferred_by_group=list(self.cache.deferred_by),
+                hits_shortened=(self.prefix_cache.hits_shortened
+                                if self.prefix_cache is not None else 0))
         snap["sampler"] = dict(self._sampler_steps)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,

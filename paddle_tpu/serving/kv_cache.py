@@ -41,13 +41,29 @@ import jax.numpy as jnp
 from ..core.tensor import Tensor
 from ..core import dtype as dtype_mod
 
-__all__ = ["KVCache", "CacheContext", "CacheSpec", "cache_spec_of"]
+__all__ = ["KVCache", "CacheContext", "CacheSpec", "CacheGroup",
+           "cache_spec_of"]
 
 
 def _as_i32(x):
     if isinstance(x, Tensor):
         return x._value().astype(jnp.int32)
     return jnp.asarray(x, dtype=jnp.int32)
+
+
+@dataclass(frozen=True)
+class CacheGroup:
+    """One group of a cache statement: the layers that keep it, what each of
+    them keeps a token (``sides``, as :class:`CacheSpec` has them), and for
+    how long — ``window`` 0 keeps every token of a sequence, ``window=W``
+    only what a query at the sequence's next position can still read: the
+    ``W`` positions up to and including it.  A group is a pool of its own:
+    buffers for its layers alone, an allocator, a block table by absolute
+    position."""
+
+    layers: Tuple[int, ...]
+    sides: Tuple[Tuple[int, int], ...]
+    window: int = 0
 
 
 @dataclass(frozen=True)
@@ -69,7 +85,14 @@ class CacheSpec:
     ``window // chunk`` rows each, with an allocator and a table of their
     own — and the exact group's blocks are released behind the window.  The
     ``kind`` names the attention calls the model makes on its cache
-    context."""
+    context.
+
+    A model whose layers differ in what they keep states it **by layer**
+    (:meth:`by_layer`): a :class:`CacheGroup` for each kind of layer, every
+    layer in exactly one.  :attr:`groups` reads any statement that way: the
+    four kinds above are one group of every layer that keeps every token —
+    and ``windowed`` an exact group that keeps a window and a summary group
+    that keeps all."""
 
     num_layers: int
     sides: Tuple[Tuple[int, int], ...]
@@ -80,6 +103,46 @@ class CacheSpec:
     #: ``kind="windowed"``: positions of a window and of a chunk of it
     window: int = 0
     chunk: int = 0
+    #: the statement by layer (:meth:`by_layer`); empty: one statement for
+    #: every layer
+    layer_groups: Tuple[CacheGroup, ...] = ()
+
+    @property
+    def groups(self) -> Tuple[CacheGroup, ...]:
+        """The statement as groups, whatever its kind."""
+        if self.layer_groups:
+            return self.layer_groups
+        every = tuple(range(self.num_layers))
+        if self.kind == "windowed":
+            return (CacheGroup(every, self.sides, self.window),
+                    CacheGroup(every, self.sides))
+        return (CacheGroup(every, self.sides),)
+
+    @property
+    def tail_limit(self) -> int:
+        """The longest tail one prefill program takes (0: any).  A group
+        that keeps a window holds a tail's blocks beside the window before
+        it, so a longer prompt is prefilled in pieces: of a window
+        (``windowed``, whose pieces each close one), or of two windows."""
+        if self.kind == "windowed":
+            return self.window
+        return 2 * max((g.window for g in self.layer_groups), default=0)
+
+    @classmethod
+    def by_layer(cls, groups, kind: str = "kv") -> "CacheSpec":
+        """A statement by layer: ``groups`` of :class:`CacheGroup`, which
+        between them name every layer ``0..n-1`` once.  The attention calls
+        are ``kind``'s; which keys a call reads is its layer's group's."""
+        groups = tuple(CacheGroup(tuple(int(i) for i in g.layers),
+                                  tuple((int(h), int(w)) for h, w in g.sides),
+                                  int(g.window)) for g in groups)
+        named = sorted(i for g in groups for i in g.layers)
+        if not groups or named != list(range(len(named))):
+            raise ValueError(f"the groups' layers {named} are not every "
+                             f"layer 0..{len(named) - 1} once")
+        if any(g.window < 0 for g in groups):
+            raise ValueError("a group's window must be >= 0")
+        return cls(len(named), groups[0].sides, kind, layer_groups=groups)
 
     @classmethod
     def kv(cls, num_layers: int, kv_heads: int, head_dim: int) -> "CacheSpec":

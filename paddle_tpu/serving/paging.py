@@ -103,6 +103,10 @@ class BlockAllocator:
         self._free: deque = deque(range(self.reserved, self.num_blocks))
         self._ref = [0] * self.num_blocks
         self._cached = set()            # block ids retained by PrefixCache
+        #: cached blocks whose one ref is the prefix cache's (kept as the
+        #: refs and marks move, so that admission and the step span read the
+        #: pool's state without a walk over it)
+        self._idle = 0
         self.evict_cb: Optional[Callable[[int], int]] = None
         # counters
         self.allocs = 0
@@ -135,6 +139,8 @@ class BlockAllocator:
         if self._ref[b] < 1:
             raise AllocatorError(f"ref of free block {b}")
         self._ref[b] += 1
+        if self._ref[b] == 2 and b in self._cached:
+            self._idle -= 1
         return self._ref[b]
 
     def unref(self, block_id: int) -> int:
@@ -142,6 +148,8 @@ class BlockAllocator:
         if self._ref[b] < 1:
             raise AllocatorError(f"double free of block {b}")
         self._ref[b] -= 1
+        if self._ref[b] == 1 and b in self._cached:
+            self._idle += 1
         if self._ref[b] == 0:
             if b in self._cached:
                 raise AllocatorError(
@@ -165,16 +173,34 @@ class BlockAllocator:
     # -- prefix-cache bookkeeping -----------------------------------------
 
     def mark_cached(self, block_id: int) -> None:
-        self._cached.add(self._check_id(block_id))
+        b = self._check_id(block_id)
+        if b not in self._cached:
+            self._cached.add(b)
+            self._idle += self._ref[b] == 1
 
     def unmark_cached(self, block_id: int) -> None:
-        self._cached.discard(self._check_id(block_id))
+        b = self._check_id(block_id)
+        if b in self._cached:
+            self._cached.discard(b)
+            self._idle -= self._ref[b] == 1
 
     # -- introspection / invariants ---------------------------------------
 
     @property
     def free_blocks(self) -> int:
         return len(self._free)
+
+    @property
+    def idle_cached_blocks(self) -> int:
+        """Cached blocks no slot holds: what eviction could free."""
+        return self._idle
+
+    @property
+    def used_blocks(self) -> int:
+        """Blocks a live slot holds a ref on (``stats()["used"]``, kept as a
+        running count)."""
+        return (self.num_blocks - self.reserved - len(self._free)
+                - self._idle)
 
     def stats(self) -> dict:
         cached_idle = sum(1 for b in self._cached if self._ref[b] == 1)
@@ -213,6 +239,9 @@ class BlockAllocator:
         uncached_idle = [b for b in self._cached if self._ref[b] == 0]
         if uncached_idle:
             out.append(f"cached blocks {uncached_idle} with refcount 0")
+        if s["cached"] != self._idle:
+            out.append(f"idle cached blocks counted {self._idle}, "
+                       f"found {s['cached']}")
         return out
 
 
@@ -233,7 +262,8 @@ class PagedKVCache:
                  head_dim: Optional[int] = None, dtype="float32", *,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  kernel: str = "reference",
-                 sides: Optional[Sequence[Tuple[int, int]]] = None):
+                 sides: Optional[Sequence[Tuple[int, int]]] = None,
+                 window: int = 0):
         if sides is None:
             if num_kv_heads is None or head_dim is None:
                 raise ValueError("give num_kv_heads and head_dim, or sides")
@@ -258,6 +288,14 @@ class PagedKVCache:
         self.num_kv_heads, self.head_dim = self.side_shapes[0]
         self.block_size = int(block_size)
         self.max_blocks_per_slot = self.max_seq // self.block_size
+        #: retention (``window=``; not a subclass's window of another kind):
+        #: 0 keeps every token of a sequence; ``W`` keeps the keys
+        #: a query at the sequence's next position can read, its own and the
+        #: ``W - 1`` before it — the blocks wholly behind them are released
+        #: (:meth:`release_behind`) and the attention calls mask by ``W``
+        self.kv_window = int(window)
+        if self.kv_window < 0:
+            raise ValueError(f"window must be >= 0, got {window}")
         if num_blocks is None:
             # every slot at max_seq + the reserved scratch block; the
             # prefix cache then *saves* blocks relative to this baseline
@@ -298,6 +336,10 @@ class PagedKVCache:
             t.persistable = True
         #: blocks each slot owns one ref on, by table index order
         self._slot_blocks: List[List[int]] = [[] for _ in range(num_slots)]
+        #: leading entries of each slot's list released behind its window
+        #: (the scratch block stands in their place)
+        self._released = [0] * self.num_slots
+        self.blocks_released = 0
         self.copy_on_extends = 0
 
     def buffers(self) -> List[Tensor]:
@@ -324,12 +366,18 @@ class PagedKVCache:
             buf._set_data(arr.at[SCRATCH_BLOCK].set(arr[SCRATCH_BLOCK]))
 
     def begin_sequence(self, slot: int, shared_blocks: Sequence[int],
-                       prefix_len: int, tail_bucket: int) -> bool:
+                       prefix_len: int, tail_bucket: int, *,
+                       total: int = 0, reserve: int = 0) -> bool:
         """Assign storage for one admission: ref the shared prefix blocks
         and allocate fresh blocks covering the whole tail bucket.  The
         slot must be empty (freshly popped).  All-or-nothing: returns
         False (slot untouched) when the pool cannot supply the tail —
-        the scheduler defers the request instead of failing it."""
+        the scheduler defers the request instead of failing it.  Of a pool
+        with a window the hit's leading entries may be the scratch block
+        (blocks behind the window, which no hit needs).  ``reserve``: blocks
+        that must still be obtainable afterwards, beside what this sequence
+        of ``total`` tokens may itself grow by (:meth:`growth_need`): what
+        the running sequences may still take."""
         if self._slot_blocks[slot]:
             raise AllocatorError(f"slot {slot} already owns blocks "
                                  f"{self._slot_blocks[slot]}")
@@ -350,31 +398,109 @@ class PagedKVCache:
         # idle cached blocks under pressure, and an un-ref'd hit block is
         # exactly that — pinning first makes the lookup result immune to
         # being recycled into this same sequence's tail
-        owned = []
-        for b in shared_blocks:
-            self.allocator.ref(int(b))
-            owned.append(int(b))
+        owned = [int(b) for b in shared_blocks]
+        for b in owned:
+            if b != SCRATCH_BLOCK:
+                self.allocator.ref(b)
         fresh = self.allocator.alloc(n_tail)
+        if fresh is not None:
+            self._slot_blocks[slot] = owned + fresh
+            if reserve and self.available_blocks() < reserve \
+                    + self.growth_need(slot, total):
+                self._slot_blocks[slot] = []
+                for b in fresh:
+                    self.allocator.unref(b)
+                fresh = None
         if fresh is None:
             for b in owned:
-                self.allocator.unref(b)
+                if b != SCRATCH_BLOCK:
+                    self.allocator.unref(b)
             return False
-        owned.extend(fresh)
-        tbl = self.block_tables._value()
-        row = [SCRATCH_BLOCK] * self.max_blocks_per_slot
-        row[:len(owned)] = owned
-        self.block_tables._set_data(
-            tbl.at[slot].set(jnp.asarray(row, dtype=jnp.int32)))
-        self._slot_blocks[slot] = owned
+        self._released[slot] = next(
+            (i for i, b in enumerate(owned) if b != SCRATCH_BLOCK),
+            len(owned))
+        self._set_row(slot, owned + fresh)
         return True
+
+    def _set_row(self, slot: int, ids: Sequence[int],
+                 tables: Optional[Tensor] = None) -> None:
+        """``slot``'s row of ``tables`` (default: the block tables): ``ids``,
+        then the scratch block."""
+        tables = self.block_tables if tables is None else tables
+        row = [SCRATCH_BLOCK] * int(tables.shape[1])
+        row[:len(ids)] = ids
+        tables._set_data(tables._value().at[slot].set(
+            jnp.asarray(row, dtype=jnp.int32)))
+
+    def available_blocks(self) -> int:
+        """Blocks an allocation could get: free, or idle in the prefix
+        cache."""
+        return self.allocator.free_blocks + self.allocator.idle_cached_blocks
+
+    def usable_blocks(self) -> int:
+        """Blocks one admission's tail bucket may take at most."""
+        return self.num_blocks - self.allocator.reserved \
+            - (-(-self.kv_window // self.block_size) if self.kv_window else 0)
+
+    def growth_need(self, slot: int, total: int) -> int:
+        """Blocks ``slot``'s sequence may still take beyond those it holds,
+        if it grows to ``total`` tokens: the rest of its length — or, of a
+        pool with a window, of a window and the block being written, since a
+        block behind the window goes before the next one is taken."""
+        owned = self._slot_blocks[slot]
+        want = -(-int(total) // self.block_size)
+        if not self.kv_window:
+            return max(0, want - len(owned))
+        most = -(-self.kv_window // self.block_size) + 1
+        return max(0, min(want, most) - (len(owned) - self._released[slot]))
+
+    def extend_tail(self, slot: int, start: int, tail_bucket: int) -> bool:
+        """Fresh blocks for the positions ``[start, start + tail_bucket)``
+        that ``slot`` does not own yet: the next piece of a prompt that is
+        prefilled in pieces.  False (nothing taken) when the pool cannot
+        supply them."""
+        owned = self._slot_blocks[slot]
+        n = (start + tail_bucket) // self.block_size - len(owned)
+        if n > 0:
+            fresh = self.allocator.alloc(n)
+            if fresh is None:
+                return False
+            owned.extend(fresh)
+            self._set_row(slot, owned)
+        return True
+
+    def release_behind(self, slot: int, next_pos: int) -> int:
+        """Of a pool with a window: unreference ``slot``'s blocks that no
+        live position can read — every key in them lies more than ``window -
+        1`` positions behind ``next_pos``, the sequence's next query.  The
+        scratch block takes their place in the list and the table.  Returns
+        the blocks let go of (0 for a pool that keeps every token)."""
+        if not self.kv_window:
+            return 0
+        owned = self._slot_blocks[slot]
+        lo = self._released[slot]
+        hi = min(max(0, int(next_pos) - self.kv_window + 1) // self.block_size,
+                 len(owned))
+        if hi <= lo:
+            return 0
+        drop = owned[lo:hi]
+        owned[lo:hi] = [SCRATCH_BLOCK] * (hi - lo)
+        self._released[slot] = hi
+        self._set_row(slot, owned)
+        for b in drop:
+            self.allocator.unref(b)
+        self.blocks_released += len(drop)
+        return len(drop)
 
     def release_slot(self, slot: int) -> None:
         """Drop the slot's refs and point its table back at scratch.
         Idempotent (retire is the single exit path, but chaos paths may
         race a reset)."""
         owned, self._slot_blocks[slot] = self._slot_blocks[slot], []
+        self._released[slot] = 0
         for b in owned:
-            self.allocator.unref(b)
+            if b != SCRATCH_BLOCK:      # released behind a window
+                self.allocator.unref(b)
         if owned:
             self.block_tables._set_data(
                 self.block_tables._value().at[slot].set(
@@ -695,11 +821,13 @@ class PagedKVCache:
                                                keepdims=False)      # [MB]
             return paged_prefill_attention(
                 q, k_layer, v_layer, Tensor._wrap(row), start,
-                interpret=self._interpret, mesh=self.mesh)
+                interpret=self._interpret, mesh=self.mesh,
+                window=self.kv_window)
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)           # [1, MB]
         return block_prefill_attention(
             q, Tensor._wrap(self.gather(k_layer._value(), row)),
-            Tensor._wrap(self.gather(v_layer._value(), row)), start)
+            Tensor._wrap(self.gather(v_layer._value(), row)), start,
+            window=self.kv_window)
 
     def decode_attention(self, layer_idx: int, q, k, v, active):
         """One decode step of attention for this layer: write the token,
@@ -721,10 +849,12 @@ class PagedKVCache:
             return paged_decode_attention(
                 q, Tensor._wrap(k_layer), Tensor._wrap(v_layer),
                 Tensor._wrap(tbl), Tensor._wrap(lens), Tensor._wrap(active),
-                interpret=self._interpret, mesh=self.mesh)
+                interpret=self._interpret, mesh=self.mesh,
+                window=self.kv_window)
         return cached_attention(
             q, Tensor._wrap(self.gather(k_layer, tbl)),
-            Tensor._wrap(self.gather(v_layer, tbl)), Tensor._wrap(lens))
+            Tensor._wrap(self.gather(v_layer, tbl)), Tensor._wrap(lens),
+            window=self.kv_window)
 
     def decode_chunk_tokens(self) -> Optional[int]:
         """Tokens one work item of this pool's Pallas decode kernel attends
@@ -813,7 +943,13 @@ class PagedKVCache:
         with the programs: the engine calls it for every running slot of
         every step."""
         ct = self.decode_chunk_tokens()
-        return None if ct is None else (lambda seq_len: seq_len // ct + 1)
+        if ct is None:
+            return None
+        w = self.kv_window
+        if not w:
+            return lambda seq_len: seq_len // ct + 1
+        return lambda seq_len: seq_len // ct - max(0, seq_len - w + 1) // ct \
+            + 1
 
     def layer_nbytes(self) -> int:
         """Bytes of one layer's buffer of the first side (K and V are

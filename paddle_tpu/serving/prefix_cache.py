@@ -83,11 +83,17 @@ class _Entry:
 class PrefixCache:
     """Host-side chained-hash map from prompt blocks to pool block ids."""
 
-    def __init__(self, allocator, block_size: int):
+    def __init__(self, allocator, block_size: int, chained: bool = True):
         if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         self.allocator = allocator
         self.block_size = int(block_size)
+        #: False: entries keep no parent link, so every idle one is
+        #: evictable, oldest first — the cache of a group that lets blocks
+        #: go behind a window, whose runs of entries start in mid-prompt and
+        #: lose their *oldest* block first (the keys are still the chain
+        #: hashes from the prompt's start)
+        self.chained = bool(chained)
         self._entries: "OrderedDict[bytes, _Entry]" = OrderedDict()
         #: weight-version epoch: folded into every chain-hash root, so
         #: entries registered under an older epoch are unreachable by
@@ -204,7 +210,8 @@ class PrefixCache:
             e = self._entries.get(key)
             if e is not None:
                 self._entries.move_to_end(key)
-                parent = key
+                if self.chained:
+                    parent = key
                 continue
             self._entries[key] = _Entry(
                 block_id=int(block_ids[depth]), parent=parent, depth=depth)
@@ -212,7 +219,8 @@ class PrefixCache:
             self.allocator.mark_cached(int(block_ids[depth]))
             if parent is not None:
                 self._entries[parent].children += 1
-            parent = key
+            if self.chained:
+                parent = key
             created += 1
         return created
 

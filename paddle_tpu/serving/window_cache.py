@@ -96,18 +96,6 @@ class WindowedKVCache(PagedKVCache):
 
     # -- host-side slot lifecycle -----------------------------------------
 
-    def _set_row(self, tables: Tensor, slot: int, ids: Sequence[int]) -> None:
-        row = [SCRATCH_BLOCK] * int(tables.shape[1])
-        row[:len(ids)] = ids
-        tables._set_data(tables._value().at[slot].set(
-            jnp.asarray(row, dtype=jnp.int32)))
-
-    def available_blocks(self) -> int:
-        """Exact blocks an allocation could get: free, or idle in the prefix
-        cache."""
-        s = self.allocator.stats()
-        return s["free"] + s["cached"]
-
     def begin_sequence(self, slot: int, shared, prefix_len: int,
                        tail_bucket: int, *, total: int = 0,
                        reserve: int = 0) -> bool:
@@ -156,34 +144,16 @@ class WindowedKVCache(PagedKVCache):
         self._slot_blocks[slot] = owned
         self._slot_windows[slot] = windows + fresh_w
         self._published[slot] = len(windows)
-        self._set_row(self.block_tables, slot, owned)
-        self._set_row(self.summary_tables, slot, self._slot_windows[slot])
-        return True
-
-    def extend_tail(self, slot: int, start: int, tail_bucket: int) -> bool:
-        """Fresh exact blocks for the positions ``[start, start +
-        tail_bucket)`` that ``slot`` does not own yet: the next piece of a
-        prompt that is prefilled a window at a time.  False (nothing taken)
-        when the pool cannot supply them."""
-        owned = self._slot_blocks[slot]
-        n = (start + tail_bucket) // self.block_size - len(owned)
-        if n > 0:
-            fresh = self.allocator.alloc(n)
-            if fresh is None:
-                return False
-            owned.extend(fresh)
-            self._set_row(self.block_tables, slot, owned)
+        self._set_row(slot, owned)
+        self._set_row(slot, self._slot_windows[slot], self.summary_tables)
         return True
 
     def release_slot(self, slot: int) -> None:
-        # blocks released behind a window left a placeholder in the list
-        self._slot_blocks[slot] = [b for b in self._slot_blocks[slot]
-                                   if b != SCRATCH_BLOCK]
         held, self._slot_windows[slot] = self._slot_windows[slot], []
         for b in held:
             self.summary_allocator.unref(b)
         if held:
-            self._set_row(self.summary_tables, slot, [])
+            self._set_row(slot, [], self.summary_tables)
         self._published[slot] = 0
         super().release_slot(slot)
 
@@ -209,7 +179,7 @@ class WindowedKVCache(PagedKVCache):
         drop = [b for b in owned[lo:hi] if b != SCRATCH_BLOCK]
         owned[lo:hi] = [SCRATCH_BLOCK] * (hi - lo)
         self._published[slot] = done
-        self._set_row(self.block_tables, slot, owned)
+        self._set_row(slot, owned)
         for b in drop:
             self.allocator.unref(b)
         self.exact_blocks_released += len(drop)
@@ -233,7 +203,7 @@ class WindowedKVCache(PagedKVCache):
 
     def warm_host_programs(self) -> None:
         super().warm_host_programs()
-        self._set_row(self.summary_tables, 0, [])
+        self._set_row(0, [], self.summary_tables)
 
     def nbytes(self) -> int:
         return super().nbytes() + sum(int(b._value().nbytes)

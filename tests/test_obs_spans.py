@@ -1012,3 +1012,97 @@ def test_windowed_pool_programs_move_no_layer_buffer_on_the_chip(
               "publish": ("eva.summarise",)}[program]
     for scope in scopes:
         assert scope in hlo, scope
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill-2048"])
+def test_by_layer_pool_programs_move_no_layer_buffer_on_the_chip(
+        one_chip, program):
+    """The same proof for a cache stated by layer: Mellum2-12B-A2.5B's widths
+    (32 query / 4 KV heads of 128, hidden 2304, experts of 896 with 8 of 64
+    held, a window of 1,024; three sliding layers and one full layer,
+    vocabulary cut to 8,192), 32 slots of 32,768 positions, block 16, a
+    2,305-block full group (K and V ``[2305, 16, 4, 128]`` for ONE layer) and
+    a 385-block window group (``[385, 16, 4, 128]`` for three): the decode
+    program — one program for both kinds of layer, four calls of the one
+    decode kernel — and the bucket-256 and bucket-2,048 prefill programs
+    (``kv_block_write`` and ``paged_prefill_attention`` a layer, under a
+    window on three of them), as the chip runs them, hold no ``copy`` /
+    ``transpose`` / ``slice`` of a layer buffer's size of either group nor
+    of a projection's weights, and alias both groups whole."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import keye_vl2 as km
+    from paddle_tpu.models import mellum as mm
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = mm.MellumForCausalLM(mm.MellumConfig(
+        vocab_size=8192, num_hidden_layers=4, held_experts=(0, 8),
+        max_position_embeddings=32768, dtype="bfloat16"))
+    eng = Engine(model, num_slots=32, max_seq=32768, min_bucket=256,
+                 block_size=16, num_kv_blocks=2305, num_window_blocks=385,
+                 kernel="pallas")
+    full, win = eng.cache.pools
+    assert [tuple(b.shape) for b in full.buffers()] == [(2305, 16, 4, 128)] * 2
+    assert [tuple(b.shape) for b in win.buffers()] == [(385, 16, 4, 128)] * 6
+    assert eng.buckets == [256, 512, 1024, 2048]       # two windows at most
+    for p in eng.cache.pools:
+        p._interpret = False              # the kernels as the chip runs them
+    interpret, km._interpret = km._interpret, lambda: False
+    try:
+        eng._build_steps()
+        if program == "decode":
+            fn, args = eng._decode_fn, [np.zeros((32,), np.int32)]
+        else:
+            bucket = 2048 if program.endswith("2048") else 256
+            fn, args = eng._prefill_fn, [np.zeros((1, bucket), np.int64),
+                                         np.int32(0), np.int32(1), np.int32(0)]
+            assert eng.cache.begin_sequence(0, None, 0, bucket)
+        leaves = []
+        args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+        prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+        def on_chip(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        with no_grad():
+            prog.build(leaves)
+            sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+            compiled = prog.jitted_donate.lower(
+                [on_chip(t._value()) for t in leaves],
+                [on_chip(a) for a in sd], [on_chip(a) for a in sk]).compile()
+    finally:
+        km._interpret = interpret
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    assert eng.cache.nbytes() == (2305 * 2 + 385 * 6) * 16 * 4 * 128 * 2
+    kernels = {"decode": ("paged_decode_attention",)}.get(
+        program, ("paged_prefill_attention", "kv_block_write"))
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert len(re.findall(r"%" + kernel + r"(\.\d+)? = ", hlo)) >= 4, kernel
+    # nothing of either group's buffers' shape moves, nor of a q
+    # projection's weights' (the expert layer's rows of a 2,048 bucket,
+    # ``[16384, 2304]``, are larger than both and are its own to order)
+    moved = [m for m in chip_smoke.pool_sized_moves(hlo, win.layer_nbytes())
+             if any(shape in m for shape in (
+                 "[2305,16,4,128]", "[385,16,4,128]", "[4096,2304]",
+                 "[2304,4096]"))]
+    assert moved == []
+    assert mem.alias_size_in_bytes >= eng.cache.nbytes()
+    # the 2,048 bucket's expert rows ([16384, 2304] and [16384, 1792]) are
+    # larger than a layer buffer; no copy of a pool is among the temporaries
+    assert mem.temp_size_in_bytes < full.layer_nbytes() * (
+        8 if program.endswith("2048") else 1)
+    assert "kv.write" in hlo and "moe.experts" in hlo
+    # each layer's attention kernel is its layer's own device time (PR 36's
+    # map): one call a layer, whatever the layer's kind
+    from paddle_tpu.obs import hlo_cost
+
+    got = hlo_cost.scope_map(hlo)["instructions"]
+    kernel = kernels[0]
+    calls = re.findall(r"%(" + kernel + r"(?:\.\d+)?) = ", hlo)
+    assert sorted(got[n] for n in calls) == [
+        (f"MellumForCausalLM/model/layers/{i}/self_attn/{kernel}", "fwd")
+        for i in range(4)]

@@ -1,0 +1,174 @@
+"""The paged kernels under a window (ISSUE 38): decode and tail prefill
+against a numpy oracle written from the rule — query ``i`` reads keys ``j``
+with ``i - window < j <= i`` (``window`` 0: every ``j <= i``) — in interpret
+mode, with lengths on and off block and chunk edges, blocks behind the window
+released to the scratch block (as the window group's table has them), and the
+jnp reference path (``kernel="reference"``) against the same oracle.
+"""
+import numpy as np
+import pytest
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu.ops.cached_attention import (
+    block_prefill_attention, cached_attention, gather_block_kv,
+)
+from paddle_tpu.ops.pallas.mla_attention_kernel import decode_work_list
+from paddle_tpu.ops.pallas.paged_attention_kernel import (
+    _decode_call, first_chunks, paged_decode_attention_kernel,
+    paged_prefill_attention_kernel,
+)
+
+BS, HKV, H, D = 8, 2, 4, 16
+WINDOW = 40            # off the 8-token blocks and the 32-token chunks
+
+
+def _pools(rs, nb, hkv=HKV):
+    return (jnp.asarray(rs.randn(nb, BS, hkv, D), jnp.float32),
+            jnp.asarray(rs.randn(nb, BS, hkv, D), jnp.float32))
+
+
+def _oracle(q, k, v, qpos, window):
+    """``q [n, H, D]`` at absolute positions ``qpos [n]`` over the contiguous
+    ``k``/``v [T, HKV, D]``: float64, one softmax a row."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    k, v = (np.repeat(a, q.shape[1] // k.shape[1], axis=1) for a in (k, v))
+    kpos = np.arange(k.shape[0])
+    out = np.zeros_like(q)
+    for r, p in enumerate(qpos):
+        keep = kpos <= p
+        if window:
+            keep &= kpos > p - window
+        s = np.einsum("hd,khd->hk", q[r], k[keep]) / np.sqrt(D)
+        s = np.exp(s - s.max(axis=1, keepdims=True))
+        out[r] = np.einsum("hk,khd->hd", s / s.sum(axis=1, keepdims=True),
+                           v[keep])
+    return out
+
+
+def _table(rs, slots, mb, nb):
+    """Distinct blocks a slot, none the scratch block."""
+    return rs.permutation(np.arange(1, nb))[:slots * mb].reshape(slots, mb)
+
+
+def _released_behind(tbl, lengths, window):
+    """The table as a window group keeps it: blocks whose every key lies
+    behind the query's window point at the scratch block."""
+    tbl = np.array(tbl)
+    if window:
+        for b, n in enumerate(lengths):
+            tbl[b, :max(0, n - window + 1) // BS] = 0
+    return tbl
+
+
+#: on and off a block's (8) and a chunk's (32) edge, under and over a window
+LENGTHS = (0, 7, 8, 31, 32, 39, 40, 41, 63, 64, 100, 127)
+
+
+#: two query heads a KV head go a row at a time on the VPU, eight are the
+#: rows of one matmul (``MXU_QUERY_ROWS``)
+@pytest.mark.parametrize("heads", [H, 8 * HKV])
+@pytest.mark.parametrize("window", [0, WINDOW, 8, 1])
+def test_decode_kernel_against_oracle(window, heads):
+    rs = np.random.RandomState(window)
+    slots, mb = len(LENGTHS), 16
+    nb = slots * mb + 1
+    kp, vp = _pools(rs, nb)
+    tbl = _table(rs, slots, mb, nb)
+    lens = np.asarray(LENGTHS, np.int32)
+    q = jnp.asarray(rs.randn(slots, 1, heads, D), jnp.float32)
+    active = np.ones(slots, np.int32)
+    active[3] = 0
+    full = np.asarray(gather_block_kv(kp, jnp.asarray(tbl))), \
+        np.asarray(gather_block_kv(vp, jnp.asarray(tbl)))
+    want = np.stack([_oracle(q[b], full[0][b], full[1][b], [lens[b]], window)
+                     for b in range(slots)])
+    # a chunk of 4 blocks, so that lengths cross several chunks
+    got = _decode_call(q, kp, vp, jnp.asarray(
+        _released_behind(tbl, lens, window), jnp.int32), jnp.asarray(lens),
+        jnp.asarray(active), first_chunks(jnp.asarray(lens), window, 4 * BS),
+        chunk_tokens=4 * BS, window=window, interpret=True)
+    got = np.asarray(got)
+    assert np.all(got[3] == 0)                  # an idle slot's row
+    live = active > 0
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    # the entry the pool calls, with its own chunk size
+    got = np.asarray(paged_decode_attention_kernel(
+        q, kp, vp, jnp.asarray(tbl, jnp.int32), jnp.asarray(lens),
+        jnp.asarray(active), window=window, interpret=True))
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-5)
+    # and the jnp reference path
+    ref = cached_attention(
+        paddle.to_tensor(np.asarray(q)), paddle.to_tensor(full[0]),
+        paddle.to_tensor(full[1]), paddle.to_tensor(lens),
+        window=window).numpy()
+    np.testing.assert_allclose(ref[live], want[live], rtol=2e-5, atol=2e-5)
+
+
+def test_window_work_list_holds_only_chunks_that_meet_the_window():
+    lens = jnp.asarray([0, 31, 32, 100, 127, 50], jnp.int32)
+    active = jnp.asarray([1, 1, 1, 1, 1, 0], jnp.int32)
+    ct, mc = 32, 4
+    first = first_chunks(lens, WINDOW, ct)
+    slot, chunk, n = (np.asarray(a) for a in decode_work_list(
+        lens, active, ct, mc, first))
+    want = []
+    for b, (length, act) in enumerate(zip(np.asarray(lens),
+                                          np.asarray(active))):
+        lo = max(0, int(length) - WINDOW + 1)
+        want += [(b, c) for c in range(mc) if act
+                 and c * ct <= length and (c + 1) * ct - 1 >= lo]
+    assert int(n) == len(want)
+    assert list(zip(slot[:n], chunk[:n])) == want
+    # with no window every chunk up to the length is listed
+    slot0, chunk0, n0 = decode_work_list(lens, active, ct, mc)
+    assert int(n0) == sum(int(l) // ct + 1 for l, a in zip(
+        np.asarray(lens), np.asarray(active)) if a)
+
+
+@pytest.mark.parametrize("window", [0, WINDOW, 8])
+@pytest.mark.parametrize("start,S", [(0, 16), (0, 64), (48, 16), (104, 24),
+                                     (64, 64)])
+def test_prefill_kernel_against_oracle(window, start, S):
+    rs = np.random.RandomState(start + S + window)
+    mb = 24
+    nb = mb + 1
+    kp, vp = _pools(rs, nb)
+    row = _table(rs, 1, mb, nb)[0]
+    q = jnp.asarray(rs.randn(1, S, H, D), jnp.float32)
+    k, v = (np.asarray(gather_block_kv(p, jnp.asarray(row[None])))[0]
+            for p in (kp, vp))
+    want = _oracle(q[0], k, v, start + np.arange(S), window)
+    # blocks wholly behind the first query's window are released
+    released = _released_behind(row[None], [start], window)[0]
+    got = paged_prefill_attention_kernel(
+        q, kp, vp, jnp.asarray(released, jnp.int32),
+        jnp.asarray([start], jnp.int32), window=window, interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[0], want, rtol=2e-5,
+                               atol=2e-5)
+    ref = block_prefill_attention(
+        paddle.to_tensor(np.asarray(q)), paddle.to_tensor(k[None]),
+        paddle.to_tensor(v[None]), paddle.to_tensor(np.int32(start)),
+        window=window).numpy()
+    np.testing.assert_allclose(ref[0], want, rtol=2e-5, atol=2e-5)
+
+
+def test_prefill_places_cover_a_window_and_a_tile_not_the_row():
+    """With a window the kernel's grid is ``(window + tile) / block`` places
+    a query tile, whatever the row's length."""
+    import jax
+
+    S, mb = 16, 512
+    kp, vp = _pools(np.random.RandomState(0), 4)
+    row = jnp.zeros((mb,), jnp.int32)
+    q = jnp.zeros((1, S, H, D), jnp.float32)
+
+    def grid(window):
+        jaxpr = jax.make_jaxpr(lambda *a: paged_prefill_attention_kernel(
+            *a, window=window, interpret=True))(
+            q, kp, vp, row, jnp.zeros((1,), jnp.int32))
+        eqn = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"][0]
+        return tuple(eqn.params["grid_mapping"].grid)
+
+    assert grid(0) == (1, mb)
+    assert grid(WINDOW) == (1, (WINDOW + S - 2) // BS + 2)
