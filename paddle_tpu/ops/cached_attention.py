@@ -252,9 +252,10 @@ def paged_decode_attention(query, k_pool, v_pool, block_tables, lengths,
 
 
 def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
-                            interpret=False, mesh=None, window=0, name=None):
+                            interpret=False, mesh=None, window=0, name=None,
+                            length=None):
     """Fused cached-prefix + causal-tail prefill attention: the Pallas
-    kernel path of the paged tail prefill, streaming the slot's block
+    kernel path of the paged tail prefill, reading the slot's block
     row straight off the pool — the fused replacement for
     ``gather_block_kv`` + :func:`block_prefill_attention`.
 
@@ -269,17 +270,23 @@ def paged_prefill_attention(query, k_pool, v_pool, block_row, start,
         mesh:      the mesh a sharded engine's pool lives on (None:
                    unsharded) — the kernel then runs per head shard.
         window:    0, or the keys a query reads (the kernel's own).
+        length:    scalar int32 — the prompt's real length so far (rows
+                   ``length - start ..`` of the bucket are padding: the
+                   kernel visits no query tile without a real row and
+                   returns zeros there); None: every row is real.
 
     Returns:
         ``[1, S, H, D]`` context.
     """
     from .pallas.paged_attention_kernel import paged_prefill_attention_kernel
 
-    def _primal(q, kp, vp, row, st):
+    def _primal(q, kp, vp, row, st, *ln):
         return _paged_per_shard(
             functools.partial(paged_prefill_attention_kernel,
                               window=window, interpret=interpret),
-            (q, kp, vp, row, jnp.asarray(st).reshape(1)), mesh)
+            (q, kp, vp, row, jnp.asarray(st).reshape(1),
+             *map(jnp.asarray, ln)), mesh)
 
     return apply_op("paged_prefill_attention", _primal,
-                    [query, k_pool, v_pool, block_row, start])
+                    [query, k_pool, v_pool, block_row, start]
+                    + ([] if length is None else [length]))

@@ -1,5 +1,6 @@
 """Pallas TPU paged attention: block-table-consuming decode + fused
-cached-prefix/causal-tail prefill kernels for the paged serving path.
+cached-prefix/causal-tail prefill kernels for the paged serving path, both
+walking a work list of live chunks.
 
 Reference parity: the jnp formulation in ``ops.cached_attention``
 (``gather_block_kv`` + ``cached_attention`` for decode,
@@ -38,16 +39,35 @@ slot's K/V in HBM:
   masked in the scores and *values* past it are dropped before the product
   (a weight of zero does not hide a NaN), so nothing a dead block or the
   tail of the last live one holds reaches an output.
-- **prefill** (``paged_prefill_attention``): grid ``(S / q_tile,
-  max_blocks)``; each tile of the tail bucket's queries attends over the
-  slot's whole block row (shared prefix blocks + the freshly written
-  tail) in one kernel scope, streaming key blocks with an
-  absolute-position causal mask ``kpos <= start + s`` — the fused
-  replacement for the gather + two-phase mask of
-  ``block_prefill_attention``.  Queries and output are head-major
-  ``[H, S, D]`` so each head is a plain 2-D ``[q_tile, D] x [D,
-  block_size]`` matmul; the query tile bounds VMEM (the whole 1024
-  bucket at once needed 41 MiB against v5e's 16 MiB scoped limit).
+- **prefill** (``paged_prefill_attention``): the same form.  The grid is
+  a work list of (query tile, key chunk) items, tile-major and chunks
+  ascending, built in the wrapper (``prefill_work_list``) from ``start``, the
+  prompt's real ``length`` and ``window``: a tile that holds a real row has
+  the chunks from its first row's oldest key (chunk 0 with no window) to its
+  last *real* row; a tile of padding has none, is never visited, and its
+  output rows are zeros (the wrapper's, with the pad rows of the last real
+  tile).  Tile and chunk come from ``prefill_plan``, a function of the shapes
+  alone: the chunk is the decode kernel's (256 tokens at both cells'
+  pools), the tile the largest power-of-two divisor of the bucket whose
+  float32 score tile ``[Hkv, rep * tile, chunk]`` is at most 1 MiB (32 rows
+  at 4 KV heads of 8 query heads, 64 at 16 heads of their own), which keeps
+  the kernel inside 16 MiB of scoped VMEM up to 32 KV heads of 128.  The
+  pools stay in HBM; an item copies its chunk's blocks by table value, one
+  DMA a block and side, all started then all awaited in two ``fori_loop``s —
+  none past the tile's last real row, none wholly behind its first row's
+  window (a released table entry is never followed).  Queries reach the
+  kernel as ``[Hkv, tiles * rep * tile, D]`` in the pool's dtype, so a KV
+  head's ``rep * tile`` query rows are the rows of ONE matmul against the
+  chunk's keys and of one against its values (``precision=DEFAULT``, float32
+  statistics and accumulators); ``rep`` 1 is the same contraction with
+  fewer rows.  The absolute-position causal mask ``kpos <= qpos`` (and
+  ``kpos > qpos - window``) is the fused replacement for the gather +
+  two-phase mask of ``block_prefill_attention``; values no real row of the
+  tile may read are dropped before the product.  The grid is static
+  (``prefill_places``: tiles x the row's chunks, or x a window's), the live
+  count rides in scalar prefetch, and a place past it keeps the last item's
+  tile.  The entry is jitted on its static arguments, so a model's layers
+  trace and lower it once a (shape, window).
 
 GQA stays inside the kernels with no repeat: the wrappers lay queries
 out so kv head ``g`` serves query heads ``g * rep .. g * rep + rep - 1``
@@ -55,13 +75,14 @@ out so kv head ``g`` serves query heads ``g * rep .. g * rep + rep - 1``
 
 Both kernels run under ``interpret=True`` off-TPU so the CPU tier-1
 suite executes the exact kernel code path; shapes depend only on
-``(slots, block_size, max_blocks, heads, head_dim)`` — block ids,
-lengths, the active mask and the work list are *values*, so the serving
-engine's zero-recompile discipline holds unchanged.  All accumulation is
-f32 (matching the oracle's f32
-softmax); parity vs the jnp path is ~1e-6 in interpret mode, asserted in
-tests/test_paged_kernel.py.  On the chip f32 operands go through the
-MXU at its default (bf16-pass) precision, as the oracle's XLA einsums do.
+``(slots, block_size, max_blocks, heads, head_dim)`` and the bucket — block
+ids, lengths, ``start``, the active mask and the work lists are *values*, so
+the serving engine's zero-recompile discipline holds unchanged.  All
+accumulation is f32 (matching the oracle's f32 softmax); parity vs the jnp
+path is ~1e-6 in interpret mode on a float32 pool, asserted in
+tests/test_paged_kernel.py and tests/test_swa_kernels.py.  On the chip the
+MXU's operands are the pool's dtype (bf16) at its default precision, as the
+oracle's XLA einsums are.
 """
 from __future__ import annotations
 
@@ -90,9 +111,13 @@ DECODE_F32_CHUNKS = 4
 #: a time on the VPU (one row is no work for the MXU)
 MXU_QUERY_ROWS = 8
 
-#: query rows per prefill grid step (f32 q/out/acc tiles of 16 heads x
-#: 128 rows x 64->128 lanes are 1 MiB each; ~7 MiB of VMEM in all)
+#: query rows a prefill work item holds at most ...
 PREFILL_Q_TILE = 128
+#: ... and as many of them as keep the item's float32 score tile ``[kv_heads,
+#: rows a KV head, chunk]`` (and the weights of its shape) this small: with
+#: the query, output and accumulator tiles and the chunk's forms the kernel
+#: stays inside v5e's 16 MiB of scoped VMEM
+PREFILL_SCORE_BYTES = 1 << 20
 
 
 def _to_lanes(q, lanes: int):
@@ -404,87 +429,172 @@ def write_blocks(pool, upd, block_ids, *, interpret=False):
 
 # -- fused prefill: cached prefix + causal tail in one kernel scope ---------
 
-def _first_block(q0, window: int, block_size: int):
-    """The first block a query tile that starts at ``q0`` reads: the one that
-    holds the first key of its first query's window (block 0 with none)."""
-    return jnp.maximum(q0 - (window - 1), 0) // block_size if window else 0
+def prefill_plan(S: int, kv_heads: int, rep: int, lanes: int, itemsize: int,
+                 block_size: int, max_blocks: int):
+    """``(tile, chunk_tokens)`` of the tail-prefill kernel: the query rows a
+    work item multiplies and the keys it multiplies them against, from the
+    shapes alone (the engine's host counts its ``prefill_items_*`` with it and
+    :func:`prefill_tile_chunks`).
+
+    The chunk is the decode kernel's (:func:`decode_chunk_tokens`: whole
+    blocks, 256 tokens where its buffers fit).  The tile is the largest
+    power-of-two divisor of ``S`` up to :data:`PREFILL_Q_TILE` whose float32
+    score tile ``[kv_heads, rep * tile, chunk]`` stays within
+    :data:`PREFILL_SCORE_BYTES` (an ``S`` no such tile divides runs as one
+    tile): 32 rows at 4 KV heads of 8 query heads each, 64 at 16 heads of
+    their own."""
+    ct = decode_chunk_tokens(block_size, max_blocks, kv_heads, lanes,
+                             itemsize)
+    t = min(PREFILL_Q_TILE, S)
+    while t > 8 and (S % t or kv_heads * rep * t * ct * 4
+                     > PREFILL_SCORE_BYTES):
+        t //= 2
+    return (t if S % t == 0 else S), ct
 
 
-def _prefill_kernel(row_ref, start_ref, q_ref, k_ref, v_ref, o_ref,
-                    acc_ref, m_ref, l_ref, *, scale, block_size, window, mb):
-    t, i = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
-    Hkv, rep, ts, _ = q_ref.shape
+def prefill_places(S: int, tile: int, chunk_tokens: int, max_blocks: int,
+                   block_size: int, window: int) -> int:
+    """The static size of the prefill kernel's grid: a query tile's chunks
+    are the slot's whole row at most, and with a window those from its first
+    row's oldest key to its last row."""
+    chunks = -(-max_blocks * block_size // chunk_tokens)
+    if window:
+        chunks = min(chunks, (window + tile - 2) // chunk_tokens + 2)
+    return S // tile * chunks
 
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
 
-    q0 = start_ref[0] + t * ts                   # tile's first abs position
-    # the grid's places count from the first block the tile reads: block 0,
-    # or with a window the block of the first query's oldest key
-    a = _first_block(q0, window, block_size) + i
-    # the last live key position is the tile's last query's absolute
-    # position; blocks wholly past it contribute nothing (pure prefix
-    # blocks below `start` are always live — the fused cross-attention half)
-    live = (a * block_size <= q0 + ts - 1) & (a < mb)
+def prefill_tile_chunks(start, length, *, S: int, tile: int,
+                        chunk_tokens: int, window: int, xp=jnp):
+    """``(first, count)`` by query tile of the tail ``[start, length)`` in a
+    bucket of ``S`` rows: a tile with no real row has no chunk; a tile that
+    has one has those from its first row's oldest key (chunk 0 with no
+    window) to its last *real* row.  With ``xp=numpy`` the host counts what
+    the kernel will do by the same rule (``count.sum()`` work items)."""
+    q0 = start + xp.arange(S // tile, dtype=xp.int32) * tile
+    last = xp.minimum(q0 + tile, length) - 1      # the tile's last real row
+    first = (xp.maximum(q0 - (window - 1), 0) // chunk_tokens if window
+             else xp.zeros_like(q0))
+    return first, xp.where(q0 < length, last // chunk_tokens - first + 1, 0)
 
-    @pl.when(live)
-    def _compute():
-        # kv heads lead, so each contraction is a head-batched matmul
-        k = jnp.swapaxes(k_ref[0].astype(jnp.float32), 0, 1)  # [Hkv,BS,D]
-        v = jnp.swapaxes(v_ref[0].astype(jnp.float32), 0, 1)
-        shape = (Hkv, ts, block_size)
-        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        kpos = a * block_size + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
+
+def prefill_work_list(start, length, *, S: int, tile: int, chunk_tokens: int,
+                      window: int, places: int):
+    """``(tile, chunk, n)``: the (query tile, key chunk) pairs of
+    :func:`prefill_tile_chunks`, tile-major and chunks ascending, in the
+    first ``n`` of ``places`` places."""
+    first, per = prefill_tile_chunks(start, length, S=S, tile=tile,
+                                     chunk_tokens=chunk_tokens, window=window)
+    ends = jnp.cumsum(per)
+    n = ends[-1]
+    idx = jnp.arange(places, dtype=jnp.int32)
+    t = jnp.minimum(jnp.searchsorted(ends, idx, side="right"), S // tile - 1)
+    chunk = idx - (ends - per)[t] + first[t]
+    # a place past the list keeps the last item's tile: its index maps move
+    # no query tile in and, above all, no output tile out
+    t = jnp.where(idx < n, t, t[jnp.maximum(n - 1, 0)])
+    return (t.astype(jnp.int32), jnp.where(idx < n, chunk, 0).astype(
+        jnp.int32), n.astype(jnp.int32))
+
+
+def _prefill_kernel(row_ref, start_ref, len_ref, tile_ref, chunk_ref, n_ref,
+                    q_ref, k_hbm, v_hbm, o_ref, k_ref, v_ref, sem, acc_ref,
+                    m_ref, l_ref, *, scale, bs, mb, ts, window):
+    i = pl.program_id(0)
+    ct = k_ref.shape[0]
+    cb = ct // bs
+    rows = q_ref.shape[1]                        # rep * ts: row r * ts + j
+
+    @pl.when(i < n_ref[0])                       # places past the list: idle
+    def _item():
+        t, c = tile_ref[i], chunk_ref[i]
+        q0 = start_ref[0] + t * ts               # tile's first abs position
+        # the keys the tile's real rows may read: up to its last real row,
+        # from its first row's oldest key
+        hi = jnp.minimum(q0 + ts, len_ref[0]) - 1
+        lo = jnp.maximum(q0 - (window - 1), 0) if window else 0
+
+        @pl.when(c == lo // ct)                  # the tile's first chunk
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        # one copy a live block of the chunk and side, by table value, all
+        # started then all awaited (the decode kernel's pattern): no block
+        # past the tile's last real row, none wholly behind its first row's
+        # window (what the buffers hold there is dropped below)
+        def block_copies(j):
+            blk = row_ref[jnp.minimum(c * cb + j, mb - 1)]
+            row = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            return [pltpu.make_async_copy(pool.at[blk], buf.at[row],
+                                          sem.at[side, j])
+                    for side, (pool, buf) in enumerate(((k_hbm, k_ref),
+                                                        (v_hbm, v_ref)))]
+
+        def start(j, carry):
+            for cp in block_copies(j):
+                cp.start()
+            return carry
+
+        def wait(j, carry):
+            for cp in block_copies(j):
+                cp.wait()
+            return carry
+
+        live = jnp.minimum(hi // bs - c * cb + 1, cb)
+        head = jnp.maximum(lo // bs - c * cb, 0)
+        jax.lax.fori_loop(head, live, start, 0)
+        jax.lax.fori_loop(head, live, wait, 0)
+
+        op = k_ref.dtype                         # the MXU's operands
+        # kv heads lead: a KV head's rep * ts query rows are the rows of ONE
+        # matmul against the chunk's keys, and of one against its values
+        k = jnp.swapaxes(k_ref[...].astype(jnp.float32), 0, 1)  # [Hkv,ct,D]
+        v = jnp.swapaxes(v_ref[...].astype(jnp.float32), 0, 1)
+        pos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (1, ct, 1), 1)
+        # a weight of zero does not hide a NaN: values no real row of the
+        # tile may read (past the prompt, or in a block not copied) go
+        v = jnp.where((pos <= hi) & (pos >= lo), v, 0.0)
+        s = jnp.einsum("grd,gkd->grk", q_ref[...], k.astype(op),
+                       precision=jax.lax.Precision.DEFAULT,
+                       preferred_element_type=jnp.float32) * scale
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, ct), 0)
+        j = r & (ts - 1) if ts & (ts - 1) == 0 else r % ts
+        # a pad row of the tile reads what the last real row reads
+        qpos = jnp.minimum(q0 + j, hi)
+        kpos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (rows, ct), 1)
         mask = kpos <= qpos                      # abs-position causal mask
         if window:
             mask &= kpos > qpos - window
-        for r in range(rep):                     # static: H // Hkv
-            q = q_ref[:, r].astype(jnp.float32)  # [Hkv, ts, D]
-            s = jnp.einsum("gqd,gkd->gqk", q, k,
-                           preferred_element_type=jnp.float32) * scale
-            s = jnp.where(mask, s, NEG_INF)      # [Hkv, ts, BS]
-            m_prev = m_ref[:, r, :, 0:1]         # [Hkv, ts, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-            p = jnp.exp(s - m_new)               # [Hkv, ts, BS]
-            corr = jnp.exp(m_prev - m_new)       # [Hkv, ts, 1]
-            l_new = l_ref[:, r, :, 0:1] * corr + jnp.sum(p, axis=2,
-                                                         keepdims=True)
-            pv = jnp.einsum("gqk,gkd->gqd", p, v,
-                            preferred_element_type=jnp.float32)
-            acc_ref[:, r] = acc_ref[:, r] * corr + pv
-            m_ref[:, r] = jnp.broadcast_to(m_new, (Hkv, ts, 128))
-            l_ref[:, r] = jnp.broadcast_to(l_new, (Hkv, ts, 128))
+        s = jnp.where(mask[None], s, NEG_INF)    # [Hkv, rows, ct]
+        m_prev = m_ref[:, :, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        # a row that has met no key of its own yet keeps nothing
+        p = jnp.where(mask[None], jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_new = l_ref[:, :, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
+            "grk,gkd->grd", p.astype(op), v.astype(op),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(i == nb - 1)
-    def _finalize():
-        l = l_ref[:, :, :, 0:1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[:] = (acc_ref[:] / l).astype(o_ref.dtype)
-
-
-def _q_tile(S: int) -> int:
-    """Largest power-of-two tile <= PREFILL_Q_TILE dividing S (prefill
-    buckets are powers of two times ``min_bucket``; an S no such tile
-    divides runs as one tile)."""
-    t = min(PREFILL_Q_TILE, S)
-    while t > 8 and S % t:
-        t //= 2
-    return t if S % t == 0 else S
+        @pl.when(c == hi // ct)                  # the tile's last chunk
+        def _finalize():                         # l > 0: a row reads itself
+            o_ref[...] = (acc_ref[...] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
-                                   *, window=0, interpret=False):
+                                   length=None, *, window=0, interpret=False):
     """Fused tail-bucket prefill attention straight off the block pool.
 
-    The tail's S queries (absolute positions ``start..start+S-1``)
-    attend over the slot's whole block row — cached prefix blocks and
-    the freshly written tail — under one absolute-position causal mask,
-    streamed block by block with an online softmax (no gathered
-    contiguous K/V copy, no second masking phase).
+    The tail's real queries (absolute positions ``start..length-1``, the
+    first rows of the bucket's ``S``) attend over the slot's block row —
+    cached prefix blocks and the freshly written tail — under one
+    absolute-position causal mask, a work item a (query tile, key chunk)
+    pair with an online softmax (no gathered contiguous K/V copy, no second
+    masking phase).
 
     Args:
         q:         ``[1, S, H, D]`` tail queries.
@@ -492,60 +602,88 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
                    (``Dp >= D``, lanes past ``D`` zero).
         v_pool:    same for values.
         block_row: ``[max_blocks]`` int32 — the slot's block-table row.
-        start:     ``[1]`` int32 — absolute position of the first query
-                   (== cached prefix length, a block boundary).
+        start:     int32 scalar or ``[1]`` — absolute position of the first
+                   query (== cached prefix length, a block boundary).
+        length:    int32 scalar — the prompt's real length so far: rows
+                   ``length - start ..`` of the bucket are padding.  None:
+                   every row is real.
         window:    0, or the keys a query reads: ``j <= i`` AND ``j > i -
-                   window``.  A query tile's grid places then cover only the
-                   blocks from its first query's oldest key to its last
-                   query (``(window + tile) / block_size`` places and not the
-                   whole row), so no block wholly outside every query's
-                   window is visited — its table entry may have been
+                   window``.  A query tile's items then cover only the
+                   chunks from its first query's oldest key to its last real
+                   query, and no block wholly behind its first query's
+                   window is copied — its table entry may have been
                    released.
 
     Returns:
-        ``[1, S, H, D]`` context.
+        ``[1, S, H, D]`` context; the pad rows are zeros (a query tile with
+        no real row is never visited, whatever the pool holds past the
+        prompt).
     """
+    S, H = q.shape[1:3]
+    bs, Hkv, D = k_pool.shape[1:]
+    ts, ct = prefill_plan(S, Hkv, H // Hkv, D, k_pool.dtype.itemsize, bs,
+                          block_row.shape[0])
+    start = jnp.asarray(start, jnp.int32).reshape(())
+    return _prefill_call(
+        q, k_pool, v_pool, block_row, start,
+        start + S if length is None else jnp.asarray(
+            length, jnp.int32).reshape(()),
+        tile=ts, chunk_tokens=ct, window=int(window), interpret=interpret)
+
+
+# jitted like the decode kernel's entry: a model's layers trace and lower the
+# kernel once a (shape, window), not once each
+@functools.partial(jax.jit, static_argnames=(
+    "tile", "chunk_tokens", "window", "interpret"))
+def _prefill_call(q, k_pool, v_pool, block_row, start, length, *, tile,
+                  chunk_tokens, window, interpret):
     _, S, H, head_dim = q.shape
-    block_size, Hkv, D = k_pool.shape[1:]
+    bs, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
-    MB = block_row.shape[0]
-    ts = _q_tile(S)
+    mb = block_row.shape[0]
+    ts, ct = tile, chunk_tokens
+    tiles = S // ts
     scale = 1.0 / (head_dim ** 0.5)
-    q = _to_lanes(q, D)
-    window = int(window)
-    kernel = functools.partial(_prefill_kernel, scale=scale,
-                               block_size=block_size, window=window, mb=MB)
-    # blocks a tile's places cover: the whole row, or a window and a tile
-    places = min(MB, (window + ts - 2) // block_size + 2) if window else MB
-    # head-major queries, query head h = g * rep + r  ->  q_g[g, r]
-    q_g = q[0].transpose(1, 0, 2).reshape(Hkv, rep, S, D)
-
-    def kv_index(t, i, row, st):
-        a = _first_block(st[0] + t * ts, window, block_size) + i
-        return (row[jnp.minimum(a, MB - 1)], 0, 0, 0)
-
-    kv_spec = pl.BlockSpec((1, block_size, Hkv, D), kv_index)
-    qo_spec = pl.BlockSpec((Hkv, rep, ts, D),
-                           lambda t, i, row, st: (0, 0, t, 0))
+    places = prefill_places(S, ts, ct, mb, bs, window)
+    t, chunk, n = prefill_work_list(start, length, S=S, tile=ts,
+                                    chunk_tokens=ct, window=window,
+                                    places=places)
+    kernel = functools.partial(_prefill_kernel, scale=scale, bs=bs, mb=mb,
+                               ts=ts, window=window)
+    # query head h = g * rep + r, tile t, row j  ->  q_g[g, t, r * ts + j]:
+    # a tile's block is the rows of one matmul a KV head, no in-kernel repeat
+    q_g = _to_lanes(q[0], D).astype(k_pool.dtype).reshape(
+        tiles, ts, Hkv, rep, D).transpose(2, 0, 3, 1, 4).reshape(
+        Hkv, tiles * rep * ts, D)
+    qo_spec = pl.BlockSpec(
+        (Hkv, rep * ts, D), lambda i, row, st, ln, tl, ch, n: (0, tl[i], 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S // ts, places),
-        in_specs=[qo_spec, kv_spec, kv_spec],
+        num_scalar_prefetch=6,
+        grid=(places,),                          # static: see the docstring
+        in_specs=[qo_spec, pool_spec, pool_spec],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((Hkv, rep, ts, D), jnp.float32),
-            pltpu.VMEM((Hkv, rep, ts, 128), jnp.float32),
-            pltpu.VMEM((Hkv, rep, ts, 128), jnp.float32),
+            pltpu.VMEM((ct, Hkv, D), k_pool.dtype),
+            pltpu.VMEM((ct, Hkv, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, ct // bs)),
+            pltpu.VMEM((Hkv, rep * ts, D), jnp.float32),
+            pltpu.VMEM((Hkv, rep * ts, 128), jnp.float32),
+            pltpu.VMEM((Hkv, rep * ts, 128), jnp.float32),
         ],
     )
     o_g = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, rep, S, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q_g.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_prefill_attention",
-    )(block_row.astype(jnp.int32),
-      jnp.asarray(start, dtype=jnp.int32).reshape(1), q_g, k_pool, v_pool)
-    return o_g.reshape(H, S, D).transpose(1, 0, 2)[None, ..., :head_dim]
+    )(block_row.astype(jnp.int32), start.reshape(1), length.reshape(1), t,
+      chunk, n.reshape(1), q_g, k_pool, v_pool)
+    out = o_g.reshape(Hkv, tiles, rep, ts, D).transpose(1, 3, 0, 2, 4).reshape(
+        S, H, D)[..., :head_dim]
+    # the tiles never visited, and the pad rows of the last one that was
+    return jnp.where((jnp.arange(S) < length - start)[:, None, None], out,
+                     0)[None]

@@ -732,7 +732,9 @@ class Engine:
         #: blocks its window groups let go of, by where
         self._swa = {"steps": 0, "full_rows": 0, "window_rows": 0,
                      "context": 0, "blocks_released_decode": 0,
-                     "blocks_released_prefill": 0}
+                     "blocks_released_prefill": 0, "prefill_items_full": 0,
+                     "prefill_items_window": 0, "prefill_tile_rows": 0,
+                     "prefill_real_rows": 0}
         self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
@@ -2123,6 +2125,22 @@ class Engine:
         sp.set(swa_full_rows=int(np.sum(i + 1)), swa_full_keys=L,
                swa_window_rows=int(np.sum(np.minimum(i + 1, W))),
                swa_window_keys=L - max(0, start - W + 1))
+        # how the tail-prefill kernel gets there: a layer's work items by
+        # kind of layer, and the rows it multiplies against the rows asked
+        # for, from the kernel's own plan and list (nothing under
+        # ``kernel="reference"``, which has neither)
+        work = {"prefill_real_rows": L - start}
+        for pool in self.cache.pools:
+            got = pool.prefill_work(sp.attrs["bucket"], start, L,
+                                    self.config.num_attention_heads)
+            if got is None:
+                return
+            work["prefill_items_window" if pool.kv_window
+                 else "prefill_items_full"] = got[0]
+            work.setdefault("prefill_tile_rows", got[1])
+        sp.set(**work)
+        for k, v in work.items():
+            self._swa[k] += v
 
     def _note_prefill_pairs(self, sp, start: int, L: int) -> None:
         """The (query, key) pairs of the tail ``[start, L)``'s real tokens,

@@ -184,9 +184,10 @@ class GroupedKVCache:
         pool, i = self._where[layer_idx]
         pool.prefill_write(i, slot, k, v, start)
 
-    def dense_prefill_attention(self, layer_idx: int, slot, q, start):
+    def dense_prefill_attention(self, layer_idx: int, slot, q, start,
+                                length=None):
         pool, i = self._where[layer_idx]
-        return pool.dense_prefill_attention(i, slot, q, start)
+        return pool.dense_prefill_attention(i, slot, q, start, length)
 
     def decode_attention(self, layer_idx: int, q, k, v, active):
         pool, i = self._where[layer_idx]

@@ -793,7 +793,8 @@ class PagedKVCache:
 
         def dense():
             return self.dense_prefill_attention(
-                layer_idx, slot, q, Tensor._wrap(st))._value()
+                layer_idx, slot, q, Tensor._wrap(st),
+                Tensor._wrap(ln))._value()
 
         def indexed():
             pool, qi, wi = self._index_operands(
@@ -807,12 +808,15 @@ class PagedKVCache:
 
         return Tensor._wrap(jax.lax.cond(ln <= topk, dense, indexed))
 
-    def dense_prefill_attention(self, layer_idx: int, slot, q, start):
+    def dense_prefill_attention(self, layer_idx: int, slot, q, start,
+                                length=None):
         """Tail queries ``q [1, S, H, D]`` at ``start ..`` over the slot's
         whole block row (cached prefix + freshly-written tail) of this
         layer's K and V under the absolute-position causal mask.
-        ``kernel="pallas"`` streams the block row through the fused
-        prefix+tail kernel instead of gathering a contiguous copy first."""
+        ``kernel="pallas"`` reads the block row through the fused
+        prefix+tail kernel instead of gathering a contiguous copy first; told
+        the prompt's real ``length``, it visits no query tile of padding and
+        returns zeros there (the reference computes the pad rows)."""
         k_layer, v_layer = (self.sides[i][layer_idx] for i in (0, 1))
         tbl = self.block_tables._value()
         s = _as_i32(slot).reshape(())
@@ -822,7 +826,7 @@ class PagedKVCache:
             return paged_prefill_attention(
                 q, k_layer, v_layer, Tensor._wrap(row), start,
                 interpret=self._interpret, mesh=self.mesh,
-                window=self.kv_window)
+                window=self.kv_window, length=length)
         row = jax.lax.dynamic_index_in_dim(tbl, s, axis=0)           # [1, MB]
         return block_prefill_attention(
             q, Tensor._wrap(self.gather(k_layer._value(), row)),
@@ -951,6 +955,30 @@ class PagedKVCache:
         return lambda seq_len: seq_len // ct - max(0, seq_len - w + 1) // ct \
             + 1
 
+    def prefill_work(self, bucket: int, start: int, length: int,
+                     query_heads: int) -> Optional[Tuple[int, int]]:
+        """``(items, tile_rows)``: what one layer of this pool costs the
+        Pallas tail-prefill kernel for the tail ``[start, length)`` in a
+        ``bucket``-row program — the (query tile, key chunk) work items it
+        walks and the query rows it multiplies (whole tiles) — by the
+        kernel's own plan and list, on the host; None under
+        ``kernel="reference"``, which has no work list."""
+        if self.kernel != "pallas":
+            return None
+        import numpy as np
+
+        from ..ops.pallas import paged_attention_kernel as pk
+
+        arr = self.sides[0][0]._value()
+        _, bs, heads, lanes = arr.sharding.shard_shape(arr.shape)
+        ts, ct = pk.prefill_plan(bucket, heads, query_heads // arr.shape[2],
+                                 lanes, arr.dtype.itemsize, bs,
+                                 self.max_blocks_per_slot)
+        _, count = pk.prefill_tile_chunks(
+            np.int32(start), np.int32(length), S=bucket, tile=ts,
+            chunk_tokens=ct, window=self.kv_window, xp=np)
+        return int(count.sum()), -(-(length - start) // ts) * ts
+
     def layer_nbytes(self) -> int:
         """Bytes of one layer's buffer of the first side (K and V are
         alike), pad lanes included."""
@@ -1071,4 +1099,4 @@ class PagedCacheContext(CacheContext):
         decode kernel (:meth:`PagedKVCache.dense_prefill_attention`)."""
         return self.cache.dense_prefill_attention(
             self.layer_idx, self.slot, q,
-            self.start if self.start is not None else 0)
+            self.start if self.start is not None else 0, self.length)

@@ -465,6 +465,57 @@ def test_a_prefix_hit_of_two_kinds_gives_a_cold_runs_tokens(f32, tokens):
     assert eng.health()["kv_block_invariants"] == "ok"
 
 
+def test_a_tail_behind_a_hit_reports_the_prefill_kernels_work_items(f32,
+                                                                    tokens):
+    """A 9-token tail behind a 64-token cached document, in the 16 bucket:
+    the ``engine.prefill`` span carries, for one layer of each kind, the
+    work items of the tail-prefill kernel's own list on the same inputs and
+    the rows it multiplies against the rows asked for; ``stats()`` sums
+    them."""
+    from paddle_tpu.ops.pallas import paged_attention_kernel as pk
+
+    model, _tree, _d = f32
+    eng = engine(model)
+    doc = tokens[:64]
+    eng.add_request(doc, max_new_tokens=1)
+    eng.run()
+    before = dict(eng.stats()["swa"])
+    t0 = time.perf_counter()
+    h = eng.add_request(np.concatenate([doc, tokens[64:73]]),
+                        max_new_tokens=2)
+    eng.run()
+    assert h.finished and not h.error
+    (a,) = [r[4] for r in _spans.snapshot(t0) if r[0] == "engine.prefill"]
+    assert (a["bucket"], a["swa_full_keys"]) == (16, 73)
+    c = model.config
+    want = {"prefill_real_rows": 9}
+    for pool, key in zip(eng.cache.pools, ("prefill_items_full",
+                                           "prefill_items_window")):
+        _, bs, hkv, lanes = pool.sides[0][0].shape
+        mb = pool.max_blocks_per_slot
+        ts, ct = pk.prefill_plan(16, hkv, c.num_attention_heads // hkv,
+                                 lanes, 4, bs, mb)
+        _, _, n = pk.prefill_work_list(
+            jnp.int32(64), jnp.int32(73), S=16, tile=ts, chunk_tokens=ct,
+            window=pool.kv_window, places=pk.prefill_places(
+                16, ts, ct, mb, bs, pool.kv_window))
+        want[key] = int(n)
+        want.setdefault("prefill_tile_rows", -(-9 // ts) * ts)
+    assert want["prefill_items_full"] >= want["prefill_items_window"] >= 1
+    assert want["prefill_tile_rows"] >= 9
+    assert {k: a[k] for k in want} == want
+    after = eng.stats()["swa"]
+    assert {k: after[k] - before[k] for k in want} == want
+    # the reference path has no work list: its spans carry none
+    ref = engine(model, kernel="reference")
+    t0 = time.perf_counter()
+    ref.add_request(doc[:20], max_new_tokens=1)
+    ref.run()
+    (b,) = [r[4] for r in _spans.snapshot(t0) if r[0] == "engine.prefill"]
+    assert "swa_full_rows" in b and "prefill_items_full" not in b
+    assert ref.stats()["swa"]["prefill_items_full"] == 0
+
+
 def test_documents_made_resident_in_pieces_keep_their_last_windows(f32):
     """Three documents of 96 tokens, each served in growing pieces of 32 (as
     the resident driver does), through a window group too small for a window

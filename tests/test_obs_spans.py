@@ -1014,6 +1014,42 @@ def test_windowed_pool_programs_move_no_layer_buffer_on_the_chip(
         assert scope in hlo, scope
 
 
+@pytest.mark.parametrize("hkv,rep,hd,bucket,mb,window", [
+    (4, 8, 128, 256, 2048, 0),        # Mellum2's full layers: the ide tail
+    (4, 8, 128, 2048, 2048, 1024),    # its window layers: a resident piece
+    (4, 8, 128, 2048, 2048, 0),
+    (16, 1, 64, 32, 64, 0),           # GPT-2 345M: 64 wide in 128 lanes
+    (16, 1, 64, 1024, 64, 0),
+    (32, 1, 128, 256, 2048, 0),       # 32 KV heads of 128: ROADMAP R8's width
+    (32, 1, 128, 2048, 2048, 0),
+])
+def test_tail_prefill_kernel_fits_scoped_vmem_at_the_cells_widths(
+        one_chip, hkv, rep, hd, bucket, mb, window):
+    """The tail-prefill kernel alone, its tile and chunk from
+    :func:`prefill_plan`, compiles for the described v5e inside the default
+    16 MiB of scoped VMEM (it asks for no more) at the widths the cells run
+    it at — and at 32 KV heads of 128, where the kernel it replaced wanted
+    49.9 MB (its 16-key score tiles padded to 128 lanes)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import paged_attention_kernel as pk
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ts, ct = pk.prefill_plan(bucket, hkv, rep, 128, 2, 16, mb)
+    assert hkv * rep * ts * ct * 4 <= pk.PREFILL_SCORE_BYTES
+    assert ct % 16 == 0 and bucket % ts == 0 and ts >= 16
+    pool = sds((mb + 1, 16, hkv, 128), jnp.bfloat16)
+    (line,) = kernel_lines(
+        lambda q, k, v, row, st, ln: pk.paged_prefill_attention_kernel(
+            q, k, v, row, st, ln, window=window),
+        sds((1, bucket, hkv * rep, hd), jnp.bfloat16), pool, pool,
+        sds((mb,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32))
+    assert line.startswith("%paged_prefill_attention.")
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill", "prefill-2048"])
 def test_by_layer_pool_programs_move_no_layer_buffer_on_the_chip(
         one_chip, program):

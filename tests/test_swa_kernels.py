@@ -15,8 +15,9 @@ from paddle_tpu.ops.cached_attention import (
 )
 from paddle_tpu.ops.pallas.mla_attention_kernel import decode_work_list
 from paddle_tpu.ops.pallas.paged_attention_kernel import (
-    _decode_call, first_chunks, paged_decode_attention_kernel,
-    paged_prefill_attention_kernel,
+    _decode_call, _prefill_call, first_chunks, paged_decode_attention_kernel,
+    paged_prefill_attention_kernel, prefill_places, prefill_plan,
+    prefill_tile_chunks, prefill_work_list,
 )
 
 BS, HKV, H, D = 8, 2, 4, 16
@@ -153,22 +154,135 @@ def test_prefill_kernel_against_oracle(window, start, S):
     np.testing.assert_allclose(ref[0], want, rtol=2e-5, atol=2e-5)
 
 
+def _pallas_grids(jaxpr):
+    """The grids of every ``pallas_call`` under ``jaxpr``, inside jitted
+    entries too."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            out.append(tuple(e.params["grid_mapping"].grid))
+        for v in e.params.values():
+            if hasattr(v, "jaxpr"):
+                out += _pallas_grids(v.jaxpr)
+    return out
+
+
 def test_prefill_places_cover_a_window_and_a_tile_not_the_row():
-    """With a window the kernel's grid is ``(window + tile) / block`` places
-    a query tile, whatever the row's length."""
+    """The kernel's grid is a work list of (query tile, key chunk) items in a
+    static number of places.  With a window a tile has at most ``(window +
+    tile) / chunk + 2`` of them, whatever the row's length; with none, the
+    row's chunks."""
     import jax
 
-    S, mb = 16, 512
+    S = 64
     kp, vp = _pools(np.random.RandomState(0), 4)
-    row = jnp.zeros((mb,), jnp.int32)
     q = jnp.zeros((1, S, H, D), jnp.float32)
 
-    def grid(window):
+    def grid(window, mb):
         jaxpr = jax.make_jaxpr(lambda *a: paged_prefill_attention_kernel(
             *a, window=window, interpret=True))(
-            q, kp, vp, row, jnp.zeros((1,), jnp.int32))
-        eqn = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"][0]
-        return tuple(eqn.params["grid_mapping"].grid)
+            q, kp, vp, jnp.zeros((mb,), jnp.int32),
+            jnp.zeros((1,), jnp.int32), jnp.int32(S))
+        (g,) = _pallas_grids(jaxpr.jaxpr)
+        return g
 
-    assert grid(0) == (1, mb)
-    assert grid(WINDOW) == (1, (WINDOW + S - 2) // BS + 2)
+    for mb in (64, 512):
+        ts, ct = prefill_plan(S, HKV, H // HKV, D, 4, BS, mb)
+        assert (ts, ct) == (64, 256)             # the decode kernel's chunk
+        assert grid(0, mb) == (S // ts * (mb * BS // ct),)
+        assert grid(WINDOW, mb) == (S // ts * ((WINDOW + ts - 2) // ct + 2),)
+    # the items of a tail, by the host's count of the same list: the tiles
+    # that hold a real row times a window's chunks, however long the row is
+    ts, ct, mb = 16, 32, 512
+    places = prefill_places(S, ts, ct, mb, BS, WINDOW)
+    assert places == S // ts * ((WINDOW + ts - 2) // ct + 2)
+    for start in (0, 104, 2048, 4000):
+        for real in (1, 16, 37, 64):
+            n, n0 = (int(prefill_tile_chunks(
+                np.int32(start), np.int32(start + real), S=S, tile=ts,
+                chunk_tokens=ct, window=w, xp=np)[1].sum())
+                for w in (WINDOW, 0))
+            tiles = -(-real // ts)
+            assert tiles <= n <= tiles * ((WINDOW + ts) // ct + 2) <= places
+            assert n0 >= tiles * (start // ct + 1)      # the whole prefix
+
+
+@pytest.mark.parametrize("window", [0, WINDOW, 8])
+def test_prefill_work_list_equals_a_host_enumeration(window):
+    S, ts, ct, mb = 64, 16, 32, 48
+    places = prefill_places(S, ts, ct, mb, BS, window)
+    for start, length in [(0, 64), (0, 1), (96, 112), (104, 141), (64, 128),
+                          (40, 40), (320, 384)]:
+        want = []
+        for t in range(S // ts):
+            q0 = start + t * ts
+            if q0 >= length:                     # a tile of padding: no item
+                continue
+            last = min(q0 + ts, length) - 1
+            lo = max(0, q0 - window + 1) if window else 0
+            want += [(t, c) for c in range(mb * BS // ct)
+                     if c * ct <= last and (c + 1) * ct - 1 >= lo]
+        assert len(want) <= places
+        tile, chunk, n = (np.asarray(a) for a in prefill_work_list(
+            jnp.int32(start), jnp.int32(length), S=S, tile=ts,
+            chunk_tokens=ct, window=window, places=places))
+        assert int(n) == len(want)
+        assert list(zip(tile[:n], chunk[:n])) == want
+        # a place past the list keeps the last item's tile (with no item
+        # at all: one tile, whichever)
+        assert len(set(tile[n:])) <= 1
+        assert not want or set(tile[n:]) <= {want[-1][0]}
+        # the host's count, by the same rule in numpy
+        assert int(prefill_tile_chunks(
+            np.int32(start), np.int32(length), S=S, tile=ts, chunk_tokens=ct,
+            window=window, xp=np)[1].sum()) == len(want)
+
+
+@pytest.mark.parametrize("window", [0, WINDOW, 8])
+@pytest.mark.parametrize("rep", [1, 8])
+@pytest.mark.parametrize("start,S,real", [
+    (96, 256, 16),       # a short tail in a wide bucket, on a chunk boundary
+    (104, 64, 37),       # off it; the length ends inside a tile
+    (0, 64, 64),         # cold, every row real
+    (64, 32, None),      # no length given: every row is real
+])
+def test_prefill_kernel_with_a_real_length(window, rep, start, S, real):
+    """Tiles of 16 rows and chunks of 32 keys (4 blocks): the real rows
+    equal the oracle's, the pad rows are exactly zero, and neither a NaN past
+    the prompt's real length nor one in a block released behind the window
+    reaches an output row."""
+    rs = np.random.RandomState(start + S + window + rep)
+    hkv = 2
+    mb = 48
+    nb = mb + 1
+    kp, vp = _pools(rs, nb, hkv)
+    row = _table(rs, 1, mb, nb)[0]
+    q = jnp.asarray(rs.randn(1, S, hkv * rep, D), jnp.float32)
+    n = S if real is None else real
+    k, v = (np.asarray(gather_block_kv(p, jnp.asarray(row[None])))[0]
+            for p in (kp, vp))
+    want = _oracle(q[0, :n], k, v, start + np.arange(n), window)
+    # what no real row may read is poison: everything past the real length,
+    # and the scratch block the released entries point at
+    released = _released_behind(row[None], [start], window)[0]
+    poison = [(b, slice(None)) for b in row[(start + n - 1) // BS + 1:]]
+    poison += [(row[(start + n) // BS], slice((start + n) % BS, None))] \
+        if (start + n) % BS else []
+    poison += [(0, slice(None))]
+    for b, at in poison:
+        kp = kp.at[b, at].set(np.nan)
+        vp = vp.at[b, at].set(np.nan)
+    length = None if real is None else jnp.int32(start + real)
+    got = np.asarray(_prefill_call(
+        q, kp, vp, jnp.asarray(released, jnp.int32), jnp.int32(start),
+        jnp.int32(start + n), tile=16, chunk_tokens=32, window=window,
+        interpret=True))[0]
+    np.testing.assert_allclose(got[:n], want, rtol=2e-5, atol=2e-5)
+    assert not got[n:].any()                     # zeros, not NaN, not stale
+    # the public entry, its tile and chunk from the shapes
+    planned = np.asarray(paged_prefill_attention_kernel(
+        q, kp, vp, jnp.asarray(released, jnp.int32),
+        jnp.asarray([start], jnp.int32), length, window=window,
+        interpret=True))[0]
+    np.testing.assert_allclose(planned[:n], want, rtol=2e-5, atol=2e-5)
+    assert not planned[n:].any()
