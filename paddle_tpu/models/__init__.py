@@ -27,3 +27,6 @@ from .evabyte import (
 from .mellum import (
     MellumConfig, MellumForCausalLM, mellum_tiny,
 )
+from .lfm2 import (
+    Lfm2Config, Lfm2ForCausalLM, lfm2_tiny,
+)

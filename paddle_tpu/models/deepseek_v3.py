@@ -239,15 +239,15 @@ class DeepseekV3MLP(Layer):
         return _swiglu(x, self.gate_up_proj._value(), self.down_proj._value())
 
 
-def route(x, w_r, bias, *, top_k: int, scale: float):
+def route(x, w_r, bias, *, top_k: int, scale: float, eps: float = 1e-20):
     """``(chosen [T, k], weights [T, k])``: sigmoid scores in float32, the
-    top ``k`` of score + bias, weights normalised over the chosen and
-    scaled."""
+    top ``k`` of score + bias, weights normalised over the chosen (their sum
+    ``+ eps``: a family's published constant) and scaled."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(F32), w_r.astype(F32),
                                precision=jax.lax.Precision.HIGHEST))
     _, chosen = jax.lax.top_k(s + bias.astype(F32)[None, :], top_k)
     w = jnp.take_along_axis(s, chosen, axis=1)
-    return chosen, w / (jnp.sum(w, axis=1, keepdims=True) + 1e-20) * scale
+    return chosen, w / (jnp.sum(w, axis=1, keepdims=True) + eps) * scale
 
 
 class DeepseekV3MoE(Layer):

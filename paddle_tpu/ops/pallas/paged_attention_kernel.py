@@ -32,10 +32,14 @@ slot's K/V in HBM:
   ``paged_decode_roofline`` tells the kernel in a trace by its first two,
   the ``s32[slots, max_blocks]`` table and the ``s32[slots]`` lengths.  Rows
   of slots that are not active are never written; the wrapper zeroes them.
-  One query row per head is no work for the MXU, so scores and the weighted
-  sum are elementwise products reduced on the VPU with ``(Hkv, D)`` kept as
-  the minor dims throughout (Mosaic refuses the head-batched
-  ``einsum("hd,jhd->hj")`` this replaced).  Keys past a slot's length are
+  One query row per head is no work for the MXU, so where a KV head serves
+  fewer than :data:`MXU_QUERY_ROWS` (4) query heads — ``rep`` 1 or 2 — scores
+  and the weighted sum are elementwise products reduced on the VPU with
+  ``(Hkv, D)`` kept as the minor dims throughout (Mosaic refuses the
+  head-batched ``einsum("hd,jhd->hj")`` this replaced); from ``rep`` 4 on the
+  KV head's query heads are the rows of one matmul a chunk against its keys
+  and one against its values (``_grouped_update``: ``rep`` 4 and 8 both; at 4
+  the loop measured 6.7 us a chunk and the matmul 3.5).  Keys past a slot's length are
   masked in the scores and *values* past it are dropped before the product
   (a weight of zero does not hide a NaN), so nothing a dead block or the
   tail of the last live one holds reaches an output.
@@ -107,9 +111,14 @@ DECODE_VMEM_BYTES = 12 << 20
 DECODE_F32_CHUNKS = 4
 
 #: query heads a KV head must serve for the decode kernel to take them as the
-#: rows of a matmul (a float32 sublane tile); fewer are multiplied a row at
-#: a time on the VPU (one row is no work for the MXU)
-MXU_QUERY_ROWS = 8
+#: rows of a matmul; fewer are multiplied a row at a time on the VPU (one row
+#: is no work for the MXU).  By ``rep``, the query heads a KV head: **1** (16
+#: heads of their own) and 2 take the VPU loop; **4** (32 query / 8 KV heads
+#: of 64 in 128 lanes) and **8** (32 / 4 of 128) the matmul form — at 4 the
+#: loop costs 6.7 us a 256-key chunk and the matmul 3.5 (64 slots of 8,500
+#: keys: 14.6 against 7.6 ms a layer, PERF.md section 6, PR 40), at 8 it was
+#: 17 us (PR 38); 2 has not been timed
+MXU_QUERY_ROWS = 4
 
 #: query rows a prefill work item holds at most ...
 PREFILL_Q_TILE = 128
@@ -226,8 +235,9 @@ def _decode_kernel(tbl_ref, len_ref, slot_ref, chunk_ref, n_ref, q_ref,
 
 def _grouped_update(c, ct, length, lo, q_ref, k_ref, v_ref, o_ref, acc_ref,
                     m_ref, l_ref, scale):
-    """A work item's online-softmax update where a KV head serves a sublane
-    tile of query heads or more (grouped queries: 8 of them at 32 / 4): the
+    """A work item's online-softmax update where a KV head serves
+    :data:`MXU_QUERY_ROWS` query heads or more (grouped queries: 8 of them at
+    32 / 4, 4 at 32 / 8): the
     ``rep`` query rows of a KV head are the rows of ONE matmul against the
     chunk's keys, and of one against its values, KV heads leading as in the
     prefill kernel — where the loop below multiplies the chunk by one query

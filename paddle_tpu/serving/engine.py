@@ -210,6 +210,8 @@ class Request:
     # lifecycle (engine-managed)
     state: str = "queued"
     _defers: int = 0                     # paged admissions deferred so far
+    _hit_given_up: int = 0               # tokens its last prefix hit was
+    #                                      shortened by (a cache by layer)
     #: set when the scheduler evicted this request mid-flight to serve a
     #: higher-priority admission; the stream restarted from token 0 on
     #: resume (``preemptions`` counts the evictions)
@@ -344,6 +346,10 @@ class Engine:
             then ``num_kv_blocks`` sizes the group that keeps every token);
             default a window and a block a slot and two tails' room.
             Refused for any other model.
+        num_state_snapshots: rows of the snapshot pool of a group that
+            keeps state (``CacheGroup.state``), row 0 the zeros; default
+            what every slot's longest tail could write.  Refused for a
+            model that states no such group.
         enable_prefix_cache: hash whole prompt blocks host-side and
             serve repeated prefixes from refcounted shared blocks,
             shrinking the prefill to the uncached tail bucket.
@@ -428,6 +434,7 @@ class Engine:
                  num_kv_blocks: Optional[int] = None,
                  num_summary_blocks: Optional[int] = None,
                  num_window_blocks: Optional[int] = None,
+                 num_state_snapshots: Optional[int] = None,
                  enable_prefix_cache: bool = True,
                  prefix_lookup_timeout_s: float = 0.25,
                  max_preemptions: int = 2,
@@ -489,13 +496,18 @@ class Engine:
                 f"removed and the cache is always paged (drop the argument)")
         #: the statement is by layer: a pool a group of layers
         by_layer = bool(spec.layer_groups)
+        keeps_state = any(g.state for g in spec.groups)
         if by_layer or spec.kind in ("latent", "indexed", "windowed"):
             # one vector a token has no per-KV-head axis to shard by, the
             # three-sided pool's indexer side has none either, a second
             # group's tables and allocator are not placed on a mesh, and
             # none has a form of the verify window
-            form = "by-layer" if by_layer else spec.kind
+            form = "state" if keeps_state else \
+                "by-layer" if by_layer else spec.kind
             caches, no_mesh = {
+                "state": ("keeps a state of fixed size a slot in some "
+                          "layers, snapshots of it for the prefix cache",
+                          "the state and its snapshot pool are not sharded"),
                 "by-layer": ("caches K and V by groups of layers, some only "
                              "inside a window",
                              "the groups' tables are not sharded"),
@@ -534,6 +546,10 @@ class Engine:
             raise ValueError(
                 f"num_window_blocks: {type(model).__name__} states no "
                 f"group that keeps a window")
+        if num_state_snapshots is not None and not keeps_state:
+            raise ValueError(
+                f"num_state_snapshots: {type(model).__name__} states no "
+                f"group that keeps state")
         if by_layer:
             # a pool a group of layers, each with its allocator and table;
             # retention, admission and the prefix hit are by group
@@ -541,12 +557,14 @@ class Engine:
                 raise ValueError(
                     f"num_summary_blocks: {type(model).__name__} keeps no "
                     f"summary group")
-            sizes = [num_window_blocks if g.window else num_kv_blocks
+            sizes = [num_state_snapshots if g.state else
+                     num_window_blocks if g.window else num_kv_blocks
                      for g in spec.groups]
             self.cache = GroupedKVCache(
                 spec.groups, num_slots=self.num_slots, max_seq=self.max_seq,
                 dtype=cache_dtype, block_size=self.block_size,
-                num_blocks=sizes, kernel=self.kernel)
+                num_blocks=sizes, kernel=self.kernel,
+                max_tail=self.buckets[-1])
             self.prefix_cache = (GroupedPrefixCache(self.cache)
                                  if enable_prefix_cache else None)
             #: what the step span reports of the groups without asking them
@@ -735,6 +753,10 @@ class Engine:
                      "blocks_released_prefill": 0, "prefill_items_full": 0,
                      "prefill_items_window": 0, "prefill_tile_rows": 0,
                      "prefill_real_rows": 0}
+        #: a group that keeps state: slots whose state the decode steps
+        #: rewrote, and prefills by the state they started from
+        self._state = {"steps": 0, "slots": 0, "prefills": 0,
+                       "prefills_restored": 0, "hit_tokens_given_up": 0}
         self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
@@ -1626,6 +1648,7 @@ class Engine:
         partial-hit cap, so the gauge only ever credits blocks that are
         actually reused — a discarded (raising/over-budget) result is
         recorded as a plain miss there."""
+        req._hit_given_up = 0
         if self.prefix_cache is None:
             return 0, []
         t0 = time.perf_counter()
@@ -1634,6 +1657,7 @@ class Engine:
             hit_tokens, blocks = self.prefix_cache.lookup(
                 req.prompt_ids, count=False,
                 salt=self._tenant_salt(req))
+            given_up = getattr(self.prefix_cache, "last_given_up", 0)
         except Exception:                # noqa: BLE001 — isolation boundary
             self.metrics.on_prefix_lookup_error()
             return 0, []
@@ -1644,6 +1668,7 @@ class Engine:
             # synchronous and cannot be pre-empted)
             self.metrics.on_prefix_lookup_error()
             return 0, []
+        req._hit_given_up = given_up
         return hit_tokens, blocks
 
     def _prefill_call(self, req: Request, *args, start: int = 0,
@@ -1670,6 +1695,8 @@ class Engine:
                     self._note_prefill_pairs(sp, start, end)
                 if self.cache_spec.layer_groups:
                     self._note_group_prefill(sp, start, end)
+                    if self.cache.states:
+                        self._note_state_prefill(sp, req)
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -1786,6 +1813,7 @@ class Engine:
                 P, shared = self.prefix_cache.lookup(
                     req.prompt_ids, count=False, salt=self._tenant_salt(req),
                     max_tokens=P - self.block_size)
+                req._hit_given_up = self.prefix_cache.last_given_up
             else:
                 P, shared = self.cache.shorten_hit(shared)
             bucket = self.bucket_for(self._tail_end(P, L) - P)
@@ -1819,7 +1847,10 @@ class Engine:
                 self.buckets[-1] // self.block_size)
             extra = dict(total=L + req.max_new_tokens,
                          reserve=[more + sum(n) for n in zip(*needs)]
-                         if needs else [more] * len(self.cache.pools))
+                         if needs else [more] * len(self.cache.pools),
+                         # a state group plans its snapshots to the piece's
+                         # real end
+                         end=self._tail_end(P, L))
         return P, bucket, self.cache.begin_sequence(req.slot, shared, P,
                                                     bucket, **extra)
 
@@ -2115,6 +2146,18 @@ class Engine:
                 swa_blocks_used=[p.allocator.used_blocks
                                  for p in self.cache.pools],
                 swa_blocks=self._group_blocks)
+        if self.cache.states:
+            # the step rewrites the state of every running slot, in each of
+            # the state groups' layers, and of no other
+            self._state["steps"] += 1
+            self._state["slots"] += len(self.running)
+            if self._step_span is not None:
+                first = self.cache.states[0]
+                self._step_span.set(
+                    state_slots=len(self.running),
+                    state_snapshots_used=first.rows_in_use(),
+                    state_snapshots=first.num_blocks
+                    - first.allocator.reserved)
 
     def _note_group_prefill(self, sp, start: int, L: int) -> None:
         """What the prefill of ``[start, L)`` must read in one layer of each
@@ -2141,6 +2184,20 @@ class Engine:
         sp.set(**work)
         for k, v in work.items():
             self._swa[k] += v
+
+    def _note_state_prefill(self, sp, req: Request) -> None:
+        """What the program about to run does to the groups that keep state,
+        from the plan its admission wrote: the snapshot row it starts from
+        (0: the zeros, a cold prompt), the snapshots it writes, and the
+        tokens this admission's hit gave up for want of a snapshot or of a
+        window's blocks."""
+        row, written = self.cache.planned[0]
+        sp.set(state_row=row, state_snapshots_written=written,
+               state_hit_given_up=req._hit_given_up)
+        st = self._state
+        st["prefills"] += 1
+        st["prefills_restored"] += row > 0
+        st["hit_tokens_given_up"] += req._hit_given_up
 
     def _note_prefill_pairs(self, sp, start: int, L: int) -> None:
         """The (query, key) pairs of the tail ``[start, L)``'s real tokens,
@@ -3127,6 +3184,14 @@ class Engine:
                 deferred_by_group=list(self.cache.deferred_by),
                 hits_shortened=(self.prefix_cache.hits_shortened
                                 if self.prefix_cache is not None else 0))
+            if self.cache.states:
+                pc = self.prefix_cache
+                snap["state"] = dict(
+                    self._state, groups=[p.stats() for p in self.cache.states],
+                    hits_shortened=pc.hits_shortened if pc is not None else 0,
+                    snapshot_evictions=sum(c.evictions
+                                           for c in pc.state_chains)
+                    if pc is not None else 0)
         snap["sampler"] = dict(self._sampler_steps)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,

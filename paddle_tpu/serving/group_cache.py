@@ -28,35 +28,271 @@ several of them:
   positions before it — what the tail's first query reads.  Where the window
   group's blocks are gone the hit is shortened to the longest end that has
   them, possibly none.
+- **a state group** (``CacheGroup.state``, :class:`StatePool`) keeps nothing
+  a token: one buffer of fixed size a slot a layer, which every token
+  rewrites.  There is no block to share, so what a hit can reuse is a
+  **snapshot** of the state that a tail prefill wrote into a small pool of
+  rows at exactly the hit's end: a hit ends at the longest registered length
+  where the groups above have their blocks *and* every state group has a
+  snapshot, shortened otherwise.  The tail prefill reads its first state from
+  the pool inside its own program (row 0: zeros, a cold prompt) and writes
+  the slot's state from the prompt's real end.
 """
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from .kv_cache import CacheGroup
-from .paging import PagedKVCache, SCRATCH_BLOCK
+from ..core import dtype as dtype_mod
+from ..core.tensor import Tensor
+from .kv_cache import CacheGroup, _as_i32
+from .paging import BlockAllocator, PagedKVCache, SCRATCH_BLOCK
 from .prefix_cache import PrefixCache
 
-__all__ = ["GroupedKVCache", "GroupedPrefixCache"]
+__all__ = ["GroupedKVCache", "GroupedPrefixCache", "StatePool"]
 
-#: a hit: block ids by absolute position, one list a group (the scratch block
-#: where a group with a window needs none)
-GroupHit = Tuple[List[int], ...]
+#: a hit: block ids by absolute position, one list a group that keeps tokens
+#: (the scratch block where a group with a window needs none), then the
+#: snapshot row of each state group (0: none, the zeros)
+GroupHit = Tuple[object, ...]
+
+#: the snapshot row that is zeros and never written: a cold prompt's state
+ZERO_ROW = 0
+#: a tail prefill leaves a snapshot at every absolute position that is a
+#: multiple of this (and at its prompt's last whole block)
+SNAPSHOT_STRIDE = 256
+#: named scope of a state group's writes (the slot's state, the snapshots)
+STATE_WRITE_SCOPE = "state.write"
+
+
+class StatePool:
+    """A state group's storage: ``state [layers, rows, slots, width]``, the
+    buffer each slot keeps a layer — the last ``rows`` columns of a
+    ``width``-wide product — and ``snapshots [layers, rows, snapshot rows,
+    width]``, copies of it that tail prefills left at known lengths, with an
+    allocator of snapshot rows (row 0 is zeros and never written).  Slots and
+    snapshot rows lie on the tiled dims, so a decode step's shift is whole
+    tiles.
+
+    A prefill program is told what to do by the slot's **plan**, a row of
+    int32 the host writes at admission (device state like a block table):
+    the snapshot row it starts from (:data:`ZERO_ROW`: a cold prompt), then
+    the rows to write and the tail-relative ends to write them at (a row past
+    the pool: nothing is written)."""
+
+    def __init__(self, num_slots: int, num_layers: int,
+                 side: Tuple[int, int], dtype, *, block_size: int,
+                 max_tail: int, num_snapshots: Optional[int] = None):
+        self.num_slots, self.num_layers = int(num_slots), int(num_layers)
+        self.rows, self.width = int(side[0]), int(side[1])
+        self.block_size, self.stride = int(block_size), SNAPSHOT_STRIDE
+        if self.stride % self.block_size:
+            raise ValueError(f"block_size {block_size} must divide the "
+                             f"snapshot stride {self.stride}")
+        #: snapshots one prefill program writes at most: the stride's, and
+        #: the one at the prompt's last whole block
+        self.max_snaps = int(max_tail) // self.stride + 1
+        if num_snapshots is None:
+            num_snapshots = self.num_slots * (self.max_snaps + 1) + 1
+        self.num_blocks = int(num_snapshots)
+        if self.num_blocks < 2:
+            raise ValueError("a snapshot pool holds the zero row and one more "
+                             f"at least, got {num_snapshots}")
+        self.dtype = dtype_mod.convert_dtype(dtype)
+        self.allocator = BlockAllocator(self.num_blocks, reserved=1)
+        self.state = Tensor._wrap(jnp.zeros(
+            (self.num_layers, self.rows, self.num_slots, self.width),
+            self.dtype))
+        self.snapshots = Tensor._wrap(jnp.zeros(
+            (self.num_layers, self.rows, self.num_blocks, self.width),
+            self.dtype))
+        self.plan = Tensor._wrap(jnp.asarray(np.tile(
+            self._plan_row(ZERO_ROW, {}, 0), (self.num_slots, 1))))
+        for t in (self.state, self.snapshots, self.plan):
+            t.persistable = True
+        #: snapshot rows each slot holds a reference on: the one it started
+        #: from and the ones its prefill wrote
+        self._held: List[List[int]] = [[] for _ in range(self.num_slots)]
+        #: ``{absolute length: row}`` of what each slot's prefill wrote
+        self._wrote: List[Dict[int, int]] = [{} for _ in range(self.num_slots)]
+        self.snapshots_written = 0
+        #: snapshots a prefill went without because no row was free
+        self.snapshots_skipped = 0
+        #: admissions by what their first program started from
+        self.restored, self.cold = 0, 0
+
+    # -- host-side slot lifecycle ---------------------------------------------
+
+    def buffers(self) -> List[Tensor]:
+        return [self.state, self.snapshots]
+
+    def nbytes(self) -> int:
+        return sum(int(b._value().nbytes) for b in self.buffers())
+
+    def rows_in_use(self) -> int:
+        """Snapshot rows that hold a snapshot: a slot's or the cache's."""
+        return self.num_blocks - self.allocator.reserved \
+            - self.allocator.free_blocks
+
+    def snapshot_ends(self, start: int, end: int) -> List[int]:
+        """The lengths in ``(start, end]`` a tail prefill of ``[start, end)``
+        leaves a snapshot at, the most wanted first: the prompt's last whole
+        block (where a replay of this very prompt hits), then every
+        ``stride``-th absolute position, the farthest first."""
+        last = (end - 1) // self.block_size * self.block_size
+        ends = [last] if last > start else []
+        return ends + [e for e in range(end // self.stride * self.stride,
+                                        start, -self.stride) if e != last]
+
+    def _plan_row(self, first: int, at: Dict[int, int], start: int):
+        row = np.full((1 + 2 * self.max_snaps,), self.num_blocks, np.int32)
+        row[0] = first
+        row[1 + self.max_snaps:] = 0
+        for k, (end, snap) in enumerate(sorted(at.items())):
+            row[1 + k], row[1 + self.max_snaps + k] = snap, end - start
+        return row
+
+    def _set_plan(self, slot: int, first: int, at: Dict[int, int],
+                  start: int) -> None:
+        self.plan._set_data(self.plan._value().at[slot].set(
+            jnp.asarray(self._plan_row(first, at, start))))
+
+    def warm_host_programs(self) -> None:
+        self._set_plan(0, ZERO_ROW, {}, 0)
+
+    def begin_sequence(self, slot: int, first: int, start: int,
+                       end: Optional[int]) -> Tuple[int, int]:
+        """The plan of the prefill program over ``[start, end)`` of ``slot``
+        that starts from snapshot row ``first``: a row for each length of
+        :meth:`snapshot_ends` the allocator can give (idle snapshots are
+        evicted for them, oldest first; none is ever waited for).  ``end``
+        None: a warm-up, no snapshot.  Returns ``(first, rows planned)``."""
+        if self._held[slot]:
+            raise RuntimeError(f"slot {slot} already holds snapshot rows "
+                               f"{self._held[slot]}")
+        first = int(first)
+        if end is not None:
+            self.restored += first > ZERO_ROW
+            self.cold += first == ZERO_ROW
+        at: Dict[int, int] = {}
+        if first > ZERO_ROW:
+            # before the allocation: its eviction must not take the hit
+            self.allocator.ref(first)
+            self._held[slot].append(first)
+        for e in self.snapshot_ends(start, end) if end is not None else []:
+            got = self.allocator.alloc(1)
+            if got is None:
+                self.snapshots_skipped += 1
+                continue
+            at[e] = got[0]
+        self._held[slot].extend(at.values())
+        self._wrote[slot].update(at)
+        self.snapshots_written += len(at)
+        self._set_plan(slot, first, at, start)
+        return first, len(at)
+
+    def release_slot(self, slot: int) -> None:
+        held, self._held[slot] = self._held[slot], []
+        self._wrote[slot] = {}
+        for r in held:
+            self.allocator.unref(r)
+
+    def reset(self) -> None:
+        for slot in range(self.num_slots):
+            self.release_slot(slot)
+
+    def wrote(self, slot: int) -> Dict[int, int]:
+        return dict(self._wrote[slot])
+
+    def check_invariants(self) -> List[str]:
+        return self.allocator.check()
+
+    def stats(self) -> dict:
+        return {"snapshot_rows": self.num_blocks - self.allocator.reserved,
+                "snapshot_rows_in_use": self.rows_in_use(),
+                "snapshots_written": self.snapshots_written,
+                "snapshots_skipped": self.snapshots_skipped,
+                "restored": self.restored, "cold": self.cold}
+
+    # -- traced state ops -----------------------------------------------------
+
+    def prefill_update(self, layer_idx: int, slot, z, start, length):
+        """A tail prefill's columns ``z [1, S, width]`` at absolute positions
+        ``start ..`` behind the state the slot's plan names; writes the
+        slot's state from the tail's real end ``length`` (a bucket's pad rows
+        reach no state) and the planned snapshots; returns the taps: ``rows +
+        1`` views of ``ext [1, rows + S, width]``, tap ``k`` of position ``t``
+        column ``t - (rows - k)``."""
+        S = z.shape[1]
+        K = min(self.max_snaps, S // self.stride + 1)
+        s = _as_i32(slot).reshape(())
+        n = _as_i32(length).reshape(()) - _as_i32(start).reshape(())
+        plan = jax.lax.dynamic_index_in_dim(self.plan._value(), s, 0, False)
+        first = plan[0]
+        snaps, ends = plan[1:1 + K], \
+            plan[1 + self.max_snaps:1 + self.max_snaps + K]
+        st, pool = self.state._value(), self.snapshots._value()
+        ext = jnp.concatenate([pool[layer_idx, :, first][None],
+                               z.astype(self.dtype)], axis=1)
+        with jax.named_scope(STATE_WRITE_SCOPE):
+            # (the slot's column written by a select over the layer's slab:
+            # whole tiles, in place — a one-row write on the tiled dim makes
+            # XLA:TPU move the array to another layout and back)
+            mine = (jnp.arange(self.num_slots, dtype=jnp.int32)
+                    == s)[None, :, None]
+            cols = jnp.arange(self.rows, dtype=jnp.int32)
+            new = jnp.take(ext[0], n + cols, axis=0)            # [rows, w]
+            kept = jnp.take(ext[0], ends[:, None] + cols[None, :], axis=0)
+            st = st.at[layer_idx].set(
+                jnp.where(mine, new[:, None, :], st[layer_idx]))
+            for r in range(self.rows):
+                pool = pool.at[layer_idx, r, snaps].set(kept[:, r],
+                                                        mode="drop")
+            self.state._set_data(st)
+            self.snapshots._set_data(pool)
+        return [ext[:, k:k + S] for k in range(self.rows + 1)]
+
+    def decode_update(self, layer_idx: int, z, active):
+        """A decode step's columns ``z [slots, 1, width]``: the running
+        slots' state shifts by one, the others' is left as it is; returns
+        the taps."""
+        st = self.state._value()
+        cur = st[layer_idx]                              # [rows, slots, w]
+        ext = jnp.concatenate([cur, z[:, 0].astype(self.dtype)[None]], axis=0)
+        with jax.named_scope(STATE_WRITE_SCOPE):
+            live = (_as_i32(active) > 0)[None, :, None]
+            self.state._set_data(st.at[layer_idx].set(
+                jnp.where(live, ext[1:], cur)))
+        return [ext[k][:, None, :] for k in range(self.rows + 1)]
 
 
 class GroupedKVCache:
-    """The groups' pools behind the one cache's surface."""
+    """The groups' pools behind the one cache's surface: ``pools``, a
+    :class:`~.paging.PagedKVCache` each group that keeps tokens, and
+    ``states``, a :class:`StatePool` each group that keeps state.  A hit, a
+    slot's holdings and ``num_blocks`` are given in that order: the pools',
+    then the states'."""
 
     def __init__(self, groups: Sequence[CacheGroup], *, num_slots: int,
                  max_seq: int, dtype, block_size: int = 16,
                  num_blocks: Sequence[Optional[int]] = (),
-                 kernel: str = "reference"):
+                 kernel: str = "reference", max_tail: Optional[int] = None):
         sizes = list(num_blocks) + [None] * (len(groups) - len(num_blocks))
         self.groups = tuple(groups)
         self.pools: List[PagedKVCache] = []
+        self.states: List[StatePool] = []
+        by_group = []
         for g, n in zip(self.groups, sizes):
+            if g.state:
+                self.states.append(StatePool(
+                    num_slots, len(g.layers), g.sides[0], dtype,
+                    block_size=block_size, num_snapshots=n,
+                    max_tail=max_tail or max_seq))
+                by_group.append(self.states[-1])
+                continue
             if g.window and n is None:
                 # every slot a window and the block being written, and two
                 # of the longest tail beside a hit's window
@@ -66,30 +302,34 @@ class GroupedKVCache:
                 num_slots, len(g.layers), max_seq, sides=g.sides, dtype=dtype,
                 block_size=block_size, num_blocks=n, kernel=kernel,
                 window=g.window))
+            by_group.append(self.pools[-1])
         first = self.pools[0]
         for p in self.pools[1:]:
             p.lengths = first.lengths       # one sequence, one length
         #: layer -> (its group's pool, its index among the group's layers)
-        self._where = {layer: (p, i) for g, p in zip(self.groups, self.pools)
+        self._where = {layer: (p, i) for g, p in zip(self.groups, by_group)
                        for i, layer in enumerate(g.layers)}
         self.num_layers = len(self._where)
         self.block_size = first.block_size
         self.max_blocks_per_slot = first.max_blocks_per_slot
         self.kernel = kernel
         self.lengths = first.lengths
-        #: admissions each group refused for want of blocks
+        #: admissions each pool refused for want of blocks
         self.deferred_by = [0] * len(self.pools)
         #: what the engine's gauges and the first group's prefix chain read
         self.allocator = first.allocator
         self.num_blocks = first.num_blocks
+        #: ``(row started from, snapshot rows planned)`` a state group, of
+        #: the last program planned (the engine's prefill span reads it)
+        self.planned: List[Tuple[int, int]] = []
 
     # -- the pools as one ---------------------------------------------------
 
     def buffers(self):
-        return [b for p in self.pools for b in p.buffers()]
+        return [b for p in (*self.pools, *self.states) for b in p.buffers()]
 
     def nbytes(self) -> int:
-        return sum(p.nbytes() for p in self.pools)
+        return sum(p.nbytes() for p in (*self.pools, *self.states))
 
     @property
     def copy_on_extends(self) -> int:
@@ -101,33 +341,42 @@ class GroupedKVCache:
     def group_stats(self) -> List[dict]:
         """A group a row: its layers, its window, its blocks and how many a
         live slot holds."""
+        kept = [g for g in self.groups if not g.state]
         return [{"layers": len(g.layers), "window": g.window,
                  "blocks": p.num_blocks - p.allocator.reserved,
                  "used": p.allocator.used_blocks,
                  "cached_idle": p.allocator.idle_cached_blocks,
                  "released": p.blocks_released,
                  "alloc_failures": p.allocator.alloc_failures}
-                for g, p in zip(self.groups, self.pools)]
+                for g, p in zip(kept, self.pools)] + [
+            {"layers": p.num_layers, "state": [p.rows, p.width],
+             "blocks": p.num_blocks - p.allocator.reserved,
+             "used": p.allocator.used_blocks,
+             "cached_idle": p.allocator.idle_cached_blocks,
+             "alloc_failures": p.allocator.alloc_failures}
+            for p in self.states]
 
     def warm_host_programs(self) -> None:
-        for p in self.pools:
+        for p in (*self.pools, *self.states):
             p.warm_host_programs()
 
     def check_invariants(self) -> List[str]:
         return [f"group {i} (window {p.kv_window}): {v}"
                 for i, p in enumerate(self.pools)
-                for v in p.check_invariants()]
+                for v in p.check_invariants()] + [
+            f"state group {i}: {v}" for i, p in enumerate(self.states)
+            for v in p.check_invariants()]
 
     def decode_chunk_tokens(self) -> Optional[int]:
         return self.pools[0].decode_chunk_tokens()
 
     def decode_items_fn(self):
         """Work items a layer, the layers' mean: a group with a window lists
-        only the chunks that meet it."""
+        only the chunks that meet it, a state group none."""
         fns = [p.decode_items_fn() for p in self.pools]
         if fns[0] is None:
             return None
-        shares = [len(g.layers) / self.num_layers for g in self.groups]
+        shares = [p.num_layers / self.num_layers for p in self.pools]
         return lambda seq_len: round(sum(
             s * f(seq_len) for s, f in zip(shares, fns)), 2)
 
@@ -135,9 +384,14 @@ class GroupedKVCache:
 
     def begin_sequence(self, slot: int, shared, prefix_len: int,
                        tail_bucket: int, *, total: int = 0,
-                       reserve: Sequence[int] = ()) -> bool:
-        """One admission's storage from every group, all or nothing."""
-        shared = shared or ([],) * len(self.pools)
+                       reserve: Sequence[int] = (),
+                       end: Optional[int] = None) -> bool:
+        """One admission's storage from every pool, all or nothing; then the
+        state groups' plans for the program over ``[prefix_len, end)``
+        (snapshot rows as the allocator has them: never waited for).
+        ``end`` None: a warm-up, which plans no snapshot."""
+        shared = shared or ([],) * len(self.pools) \
+            + (ZERO_ROW,) * len(self.states)
         reserve = list(reserve) or [0] * len(self.pools)
         for i, (p, blocks, r) in enumerate(zip(self.pools, shared, reserve)):
             if not p.begin_sequence(slot, blocks, prefix_len, tail_bucket,
@@ -146,12 +400,21 @@ class GroupedKVCache:
                 for q in self.pools[:i]:
                     q.release_slot(slot)
                 return False
+        self.planned = [st.begin_sequence(slot, row, prefix_len, end)
+                        for st, row in zip(self.states,
+                                           shared[len(self.pools):])]
         return True
 
     def growth_needs(self, slot: int, total: int) -> List[int]:
         return [p.growth_need(slot, total) for p in self.pools]
 
     def extend_tail(self, slot: int, start: int, tail_bucket: int) -> bool:
+        """The blocks of the next piece of a prompt prefilled in pieces."""
+        if self.states:
+            raise NotImplementedError(
+                "a prompt prefilled in pieces beside a group that keeps "
+                "state: a tail prefill starts from a snapshot or from zeros, "
+                "not from the state the piece before left")
         return all(p.extend_tail(slot, start, tail_bucket)
                    for p in self.pools)
 
@@ -162,15 +425,18 @@ class GroupedKVCache:
         return sum(p.release_behind(slot, next_pos) for p in self.pools)
 
     def release_slot(self, slot: int) -> None:
-        for p in self.pools:
+        for p in (*self.pools, *self.states):
             p.release_slot(slot)
 
     def reset(self) -> None:
-        for p in self.pools:
+        for p in (*self.pools, *self.states):
             p.reset()
 
     def owned_blocks(self, slot: int) -> GroupHit:
-        return tuple(p.owned_blocks(slot) for p in self.pools)
+        """What the slot holds that a later prompt can hit: its blocks, a
+        list a pool, then ``{length: snapshot row}`` a state group."""
+        return tuple(p.owned_blocks(slot) for p in self.pools) \
+            + tuple(st.wrote(slot) for st in self.states)
 
     # -- traced state ops (the K/V pool's, by the layer's group) -------------
 
@@ -193,6 +459,14 @@ class GroupedKVCache:
         pool, i = self._where[layer_idx]
         return pool.decode_attention(i, q, k, v, active)
 
+    def state_prefill(self, layer_idx: int, slot, z, start, length):
+        pool, i = self._where[layer_idx]
+        return pool.prefill_update(i, slot, z, start, length)
+
+    def state_decode(self, layer_idx: int, z, active):
+        pool, i = self._where[layer_idx]
+        return pool.decode_update(i, z, active)
+
 
 class GroupedPrefixCache:
     """The prefix cache of a :class:`GroupedKVCache`: one
@@ -200,7 +474,9 @@ class GroupedPrefixCache:
     by the same chain hash from the prompt's start.  A group that keeps all
     is a chain (a hit is a contiguous prefix, leaves are evicted first); a
     group with a window keeps runs of entries that start in mid-prompt and is
-    evicted oldest first.  The engine's interface is the one cache's."""
+    evicted oldest first; a state group keeps single entries — a snapshot row
+    under the key of the block that ends at its length — evicted oldest first
+    too.  The engine's interface is the one cache's."""
 
     def __init__(self, cache: GroupedKVCache):
         self.cache = cache
@@ -208,9 +484,15 @@ class GroupedPrefixCache:
         self.chains = [PrefixCache(p.allocator, p.block_size,
                                    chained=not p.kv_window)
                        for p in cache.pools]
+        self.state_chains = [PrefixCache(st.allocator, cache.block_size,
+                                         chained=False)
+                             for st in cache.states]
         #: hits that ended short of what the groups that keep all had, for
-        #: want of a window's blocks
+        #: want of a window's blocks or of a snapshot, and the tokens they
+        #: gave up (in all, and in the last lookup)
         self.hits_shortened = 0
+        self.tokens_given_up = 0
+        self.last_given_up = 0
 
     @property
     def epoch(self) -> int:
@@ -249,15 +531,22 @@ class GroupedPrefixCache:
                 run = run + 1 if keys[end - 1] in chain._entries else 0
                 ok[end] = ok[end] and \
                     run >= end - self._first_needed(pool, end)
+        # and every state group a snapshot at exactly that length (none is
+        # needed at 0: the zeros)
+        for chain in self.state_chains:
+            for end in range(1, kept + 1):
+                ok[end] = ok[end] and keys[end - 1] in chain._entries
         end = max(e for e in range(kept + 1) if ok[e])
         return end, kept, keys
 
     def lookup(self, prompt, count: bool = True, salt: bytes = b"",
                max_tokens: Optional[int] = None):
-        """``(n_tokens, block ids by position a group)``; ``max_tokens``
-        caps the hit's end."""
+        """``(n_tokens, block ids by position a pool, then the snapshot row
+        a state group)``; ``max_tokens`` caps the hit's end."""
         end, kept, keys = self._walk(prompt, salt, max_tokens)
         self.hits_shortened += end < kept
+        self.last_given_up = (kept - end) * self.block_size
+        self.tokens_given_up += self.last_given_up
         hit = []
         for chain, pool in zip(self.chains, self.cache.pools):
             first = self._first_needed(pool, end)
@@ -268,6 +557,14 @@ class GroupedPrefixCache:
                 chain._entries.move_to_end(key)
                 ids.append(e.block_id)
             hit.append(ids)
+        for chain in self.state_chains:
+            row = ZERO_ROW
+            if end:
+                e = chain._entries[keys[end - 1]]
+                e.hits += 1
+                chain._entries.move_to_end(keys[end - 1])
+                row = e.block_id
+            hit.append(row)
         if count:
             self.record_lookup(len(prompt), end * self.block_size)
         return end * self.block_size, tuple(hit)
@@ -280,8 +577,10 @@ class GroupedPrefixCache:
 
     def register(self, prompt, owned: GroupHit, salt: bytes = b"",
                  hit_tokens: int = 0) -> int:
-        """The prompt's whole blocks of a group that keeps all; of a group
-        with a window the ones the slot still holds: its last window's.
+        """The prompt's whole blocks of a group that keeps all; of a state
+        group the snapshots the slot's prefill wrote, each at its length; of
+        a group with a window the blocks the slot still holds: its last
+        window's.
         ``hit_tokens``: where the hit this sequence was admitted behind
         ended.  A window group keeps the last window of what it has seen: the
         run of blocks that hit read is dropped (where no slot holds it) once
@@ -305,16 +604,19 @@ class GroupedPrefixCache:
                     if e is not None and \
                             pool.allocator.refcount(e.block_id) == 1:
                         chain._evict_one(key)
+        for chain, wrote in zip(self.state_chains, owned[len(self.chains):]):
+            n += chain.register_at(prompt, wrote, salt=salt)
         return n
 
     def bump_epoch(self) -> int:
-        return [c.bump_epoch() for c in self.chains][0]
+        return [c.bump_epoch()
+                for c in (*self.chains, *self.state_chains)][0]
 
     def clear(self) -> int:
-        return sum(c.clear() for c in self.chains)
+        return sum(c.clear() for c in (*self.chains, *self.state_chains))
 
     def __len__(self) -> int:
-        return sum(len(c) for c in self.chains)
+        return sum(len(c) for c in (*self.chains, *self.state_chains))
 
     def hit_rate(self) -> float:
         return self.chains[0].hit_rate()
@@ -324,4 +626,9 @@ class GroupedPrefixCache:
         s["group_entries"] = [len(c) for c in self.chains]
         s["group_evictions"] = [c.evictions for c in self.chains]
         s["hits_shortened"] = self.hits_shortened
+        s["tokens_given_up"] = self.tokens_given_up
+        if self.state_chains:
+            s["snapshot_entries"] = [len(c) for c in self.state_chains]
+            s["snapshot_evictions"] = [c.evictions
+                                       for c in self.state_chains]
         return s

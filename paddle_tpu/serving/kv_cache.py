@@ -59,11 +59,19 @@ class CacheGroup:
     only what a query at the sequence's next position can still read: the
     ``W`` positions up to and including it.  A group is a pool of its own:
     buffers for its layers alone, an allocator, a block table by absolute
-    position."""
+    position.
+
+    ``state=True`` is the third retention: nothing a token.  ``sides`` then
+    reads as the one buffer of fixed size a *slot* keeps a layer — ``(rows,
+    width)``: the last ``rows`` columns of a ``width``-wide product, which
+    every token shifts by one — with no table and no blocks.  What a prefix
+    hit can reuse of such a group is a **snapshot** of it that somebody kept
+    at exactly the hit's end (``group_cache.StatePool``)."""
 
     layers: Tuple[int, ...]
     sides: Tuple[Tuple[int, int], ...]
     window: int = 0
+    state: bool = False
 
 
 @dataclass(frozen=True)
@@ -92,7 +100,9 @@ class CacheSpec:
     layer in exactly one.  :attr:`groups` reads any statement that way: the
     four kinds above are one group of every layer that keeps every token —
     and ``windowed`` an exact group that keeps a window and a summary group
-    that keeps all."""
+    that keeps all.  A group may also keep **state** instead of tokens
+    (``CacheGroup.state``): a buffer of fixed size a slot, rewritten by every
+    token, which only a statement by layer can name."""
 
     num_layers: int
     sides: Tuple[Tuple[int, int], ...]
@@ -135,13 +145,20 @@ class CacheSpec:
         are ``kind``'s; which keys a call reads is its layer's group's."""
         groups = tuple(CacheGroup(tuple(int(i) for i in g.layers),
                                   tuple((int(h), int(w)) for h, w in g.sides),
-                                  int(g.window)) for g in groups)
+                                  int(g.window), bool(g.state))
+                       for g in groups)
         named = sorted(i for g in groups for i in g.layers)
         if not groups or named != list(range(len(named))):
             raise ValueError(f"the groups' layers {named} are not every "
                              f"layer 0..{len(named) - 1} once")
         if any(g.window < 0 for g in groups):
             raise ValueError("a group's window must be >= 0")
+        if any(g.state and (g.window or len(g.sides) != 1) for g in groups):
+            raise ValueError("a state group keeps one buffer a slot a layer "
+                             "and no window")
+        if groups[0].state:
+            raise ValueError("the first group counts the sequence's "
+                             "positions in blocks: it is no state group")
         return cls(len(named), groups[0].sides, kind, layer_groups=groups)
 
     @classmethod

@@ -268,8 +268,11 @@ class PagedKVCache:
             if num_kv_heads is None or head_dim is None:
                 raise ValueError("give num_kv_heads and head_dim, or sides")
             sides = ((num_kv_heads, head_dim),) * 2
-        if num_slots < 1 or num_layers < 1 or max_seq < 1:
-            raise ValueError("num_slots/num_layers/max_seq must be >= 1")
+        # (no layer: a pool that only counts a sequence's positions in
+        # blocks, beside groups that keep state and no token)
+        if num_slots < 1 or num_layers < 0 or max_seq < 1:
+            raise ValueError("num_slots/max_seq must be >= 1, num_layers "
+                             ">= 0")
         if kernel not in ("reference", "pallas"):
             raise ValueError(f"kernel must be 'reference' or 'pallas', "
                              f"got {kernel!r}")
@@ -1073,6 +1076,23 @@ class PagedCacheContext(CacheContext):
             raise ValueError("the latent pool has no verify form")
         return self.cache.latent_decode_attention(
             self.layer_idx, q_lat, lat, self.active, scale=scale, dv=dv)
+
+    # -- a state group: no token kept, one call ------------------------------
+
+    def shift_state(self, z):
+        """A state layer's columns ``z [B, S, width]`` (a raw array) through
+        its group's buffer: the taps ``[z[t - rows], .., z[t]]`` of every
+        position, ``rows + 1`` raw arrays ``[B, S, width]`` in the cache's
+        dtype — a prefill's first columns come from the state its slot's plan
+        names and its real end is written back; a decode step shifts the
+        running slots' state by its one column."""
+        if self.mode == "prefill":
+            return self.cache.state_prefill(
+                self.layer_idx, self.slot, z, self._prefill_start(),
+                self.length)
+        if self.mode != "decode":
+            raise ValueError("a state group has no verify form")
+        return self.cache.state_decode(self.layer_idx, z, self.active)
 
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v,

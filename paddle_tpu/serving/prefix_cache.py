@@ -224,6 +224,30 @@ class PrefixCache:
             created += 1
         return created
 
+    def register_at(self, prompt: Sequence[int], at, salt: bytes = b""
+                    ) -> int:
+        """Single entries of a cache that keeps no chain (``chained=False``):
+        ``at = {tokens: id}`` puts ``id`` under the key of the block that
+        *ends* at ``tokens`` (a whole number of blocks of ``prompt``) — what
+        is kept there is a property of the whole prefix up to that length,
+        not of the block.  First writer wins; returns the entries created."""
+        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
+        if not at:
+            return 0
+        keys = self._keys_for(prompt, max(at) // self.block_size, salt)
+        created = 0
+        for tokens, ident in sorted(at.items()):
+            key = keys[tokens // self.block_size - 1]
+            if key in self._entries:
+                self._entries.move_to_end(key)
+                continue
+            self._entries[key] = _Entry(block_id=int(ident), parent=None,
+                                        depth=tokens // self.block_size - 1)
+            self.allocator.ref(int(ident))
+            self.allocator.mark_cached(int(ident))
+            created += 1
+        return created
+
     # -- eviction ----------------------------------------------------------
 
     def _evictable(self) -> Optional[bytes]:

@@ -1142,3 +1142,105 @@ def test_by_layer_pool_programs_move_no_layer_buffer_on_the_chip(
     assert sorted(got[n] for n in calls) == [
         (f"MellumForCausalLM/model/layers/{i}/self_attn/{kernel}", "fwd")
         for i in range(4)]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill", "prefill-2048"])
+def test_state_pool_programs_move_no_buffer_on_the_chip(one_chip, program):
+    """The same proof for a cache with a state group: LFM2-24B-A2B's widths
+    (hidden 2048, 32 query / 8 KV heads of 64, a 3-tap filter, dense SwiGLU
+    of 11,776, experts of 1,536 with 8 of 64 held; conv, conv, attention,
+    conv: two dense and two expert layers, vocabulary cut to 8,192), 64
+    slots of 16,384 positions, block 16, a 2,305-block K/V group (``[2305,
+    16, 8, 128]`` for ONE layer, 64-wide heads in 128 lanes), a state array
+    ``[3, 2, 64, 2048]`` and a snapshot pool ``[3, 2, 4096, 2048]``: the
+    decode program and the bucket-256 and bucket-2,048 prefill programs, as
+    the chip runs them, hold no ``copy`` / ``transpose`` / ``slice`` of a
+    layer buffer's size, of the state array's or of the snapshot pool's, and
+    alias all three whole; the decode kernel at (8 KV heads, 4 query heads
+    each, 64 in 128 lanes) fits the default scoped VMEM (the compile asks for
+    no more); the conv operator's work lies under its layer's scope."""
+    import jax
+
+    import chip_smoke
+    from paddle_tpu.core.autograd import no_grad
+    from paddle_tpu.jit.trace import CompiledProgram, _flatten_io
+    from paddle_tpu.models import held_experts as he
+    from paddle_tpu.models import lfm2 as lm
+    from paddle_tpu.serving import Engine
+
+    paddle.seed(0)
+    model = lm.Lfm2ForCausalLM(lm.Lfm2Config(
+        vocab_size=8192, num_hidden_layers=4, held_experts=(0, 8),
+        max_position_embeddings=16384, dtype="bfloat16"))
+    eng = Engine(model, num_slots=64, max_seq=16384, min_bucket=256,
+                 block_size=16, num_kv_blocks=2305, num_state_snapshots=4096,
+                 kernel="pallas")
+    (kv,), (state,) = eng.cache.pools, eng.cache.states
+    assert [tuple(b.shape) for b in kv.buffers()] == [(2305, 16, 8, 128)] * 2
+    assert tuple(state.state.shape) == (3, 2, 64, 2048)
+    assert tuple(state.snapshots.shape) == (3, 2, 4096, 2048)
+    assert eng.buckets == [256, 512, 1024, 2048, 4096, 8192, 16384]
+    kv._interpret = False                 # the kernels as the chip runs them
+    interpret, he._interpret = he._interpret, lambda: False
+    lm_interpret, lm._interpret = lm._interpret, lambda: False
+    try:
+        eng._build_steps()
+        if program == "decode":
+            fn, args = eng._decode_fn, [np.zeros((64,), np.int32)]
+        else:
+            bucket = 2048 if program.endswith("2048") else 256
+            fn, args = eng._prefill_fn, [np.zeros((1, bucket), np.int64),
+                                         np.int32(0), np.int32(1), np.int32(0)]
+            assert eng.cache.begin_sequence(0, None, 0, bucket)
+        leaves = []
+        args_tree = _flatten_io([paddle.to_tensor(a) for a in args], leaves)
+        prog = CompiledProgram(fn._fn, args_tree, _flatten_io({}, leaves))
+
+        def on_chip(a):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+        with no_grad():
+            prog.build(leaves)
+            sd, sk = prog._split_state([k.current() for k in prog.state_keys])
+            compiled = prog.jitted_donate.lower(
+                [on_chip(t._value()) for t in leaves],
+                [on_chip(a) for a in sd], [on_chip(a) for a in sk]).compile()
+    finally:
+        he._interpret, lm._interpret = interpret, lm_interpret
+    hlo, mem = compiled.as_text(), compiled.memory_analysis()
+    state_bytes = 3 * 2 * 64 * 2048 * 2
+    assert eng.cache.nbytes() == 2305 * 2 * 16 * 8 * 128 * 2 \
+        + state_bytes * (1 + 64)
+    kernels = {"decode": ("paged_decode_attention",)}.get(
+        program, ("paged_prefill_attention", "kv_block_write"))
+    for kernel in kernels:                # one attention layer (K and V)
+        assert 1 <= len(re.findall(r"%" + kernel + r"(\.\d+)? = ", hlo)) <= 2
+    assert len(re.findall(r"%moe_grouped_matmul(\.\d+)? = ", hlo)) == 4
+    # nothing of the state array's size or more moves that has a pool's, the
+    # state's or the snapshot pool's shape (a 2,048 bucket's own rows are
+    # larger and are the program's to order)
+    moved = [m for m in chip_smoke.pool_sized_moves(hlo, state_bytes)
+             if any(shape in m for shape in (
+                 "[2305,16,8,128]", "[3,2,64,2048]", "[3,2,4096,2048]",
+                 "[2,64,2048]", "[2,4096,2048]"))]
+    assert moved == []
+    # (a decode step takes no snapshot: the pool is no operand of it)
+    assert mem.alias_size_in_bytes >= eng.cache.nbytes() - (
+        state.snapshots._value().nbytes if program == "decode" else 0)
+    assert ("[3,2,4096,2048]" in hlo) == (program != "decode")
+    # (the 2,048 bucket's dense rows, ``[2048, 23552]`` float32, are larger
+    # than the snapshot pool; no copy of a pool is among the temporaries)
+    assert mem.temp_size_in_bytes < state.snapshots._value().nbytes * (
+        3 if program.endswith("2048") else 1)
+    assert "kv.write" in hlo and "state.write" in hlo and "conv.mix" in hlo
+    from paddle_tpu.obs import hlo_cost
+
+    got = hlo_cost.scope_map(hlo)["instructions"]
+    scopes = {s for s, _d in got.values()}
+    root = "Lfm2ForCausalLM/model/layers"
+    for i in (0, 1, 3):
+        for part in ("in_proj", "out_proj", "conv.mix", "state.write"):
+            assert any(s.startswith(f"{root}/{i}/conv/{part}")
+                       for s in scopes), (i, part)
+    (call,) = re.findall(r"%(" + kernels[0] + r"(?:\.\d+)?) = ", hlo)
+    assert got[call] == (f"{root}/2/self_attn/{kernels[0]}", "fwd")

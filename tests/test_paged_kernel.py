@@ -111,8 +111,10 @@ class TestDecodeKernelParity:
                                         (1, 0, 1, 0, 1, 1),
                                         (0, 0, 0, 1, 0, 0)],
                              ids=["all", "some", "one"])
-    @pytest.mark.parametrize("hkv,h,d", [(4, 4, 64), (2, 8, 128)],
-                             ids=["mha64in128", "gqa128"])
+    @pytest.mark.parametrize("hkv,h,d", [(4, 4, 64), (2, 8, 128),
+                                         (2, 8, 64), (1, 8, 64)],
+                             ids=["mha64in128", "gqa128", "rep4x64in128",
+                                  "rep8x64in128"])
     def test_ragged_lengths_idle_slots_and_garbage_blocks(self, hkv, h, d,
                                                           active):
         """Six slots of 64 blocks of 16 (four chunks of 256 a row), idle
