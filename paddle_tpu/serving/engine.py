@@ -85,11 +85,12 @@ from ..obs import spans as _spans
 from .kv_cache import cache_spec_of
 from .metrics import ServingMetrics
 from .paging import PagedCacheContext, PagedKVCache
-from .prefix_cache import PrefixCache
+from .prefix_cache import ChainKeys, PrefixCache
 from .group_cache import GroupedKVCache, GroupedPrefixCache
 from .window_cache import WindowedKVCache, WindowedPrefixCache
 from .sampling import DeviceSampler, SamplingParams, sampler_path
 from .sanitize import SyncSanitizer
+from .staging import SlotStager
 from .tracing import NULL_TRACER, FlightRecorder, RequestTracer
 
 __all__ = ["Engine", "Request", "SamplingParams", "QueueFull",
@@ -254,6 +255,10 @@ class Request:
     t_finish: Optional[float] = None
     _seq_len: int = 0                # prompt + emitted tokens in the cache
     _cancel: bool = False
+    #: the prompt's prefix-cache chain keys, hashed by its first lookup and
+    #: read by the capped re-lookup, the registration and every retry of a
+    #: deferred or preempted admission (``prefix_cache.ChainKeys``)
+    _keys: ChainKeys = field(default_factory=ChainKeys, repr=False)
     _engine: Optional[object] = field(default=None, repr=False)
 
     @property
@@ -757,6 +762,13 @@ class Engine:
         #: rewrote, and prefills by the state they started from
         self._state = {"steps": 0, "slots": 0, "prefills": 0,
                        "prefills_restored": 0, "hit_tokens_given_up": 0}
+        #: the host's path of the admissions: walks over a prompt for its
+        #: chain keys and staging programs issued (``serving/staging``)
+        self._admission = {"admissions": 0, "key_passes": 0,
+                           "staging_programs": 0}
+        #: the one program that writes a slot's sampler lanes and table rows
+        #: (built with the steps, after the state is placed)
+        self._stager: Optional[SlotStager] = None
         self._publish_fn = None
         self._watchdog = None
         self._arm_counter = 0
@@ -857,6 +869,7 @@ class Engine:
             return Tensor._wrap(jnp.zeros((), jnp.int32))
 
         self._prefill_fn = jit_mod.to_static(prefill_step)
+        self._stager = SlotStager(sampler.lanes(), cache.tables())
         self._warmers = [("prefill", self._warm_prefill)]
         if self.cache_spec.kind == "windowed":
             self._publish_fn = jit_mod.to_static(publish_step)
@@ -1188,7 +1201,7 @@ class Engine:
             raise EngineStopped(
                 f"engine {self.name!r} is {self.state}: not admitting "
                 "new requests")
-        prompt = np.asarray(list(prompt_ids), dtype=np.int64).reshape(-1)
+        prompt = np.asarray(prompt_ids, dtype=np.int64).reshape(-1)
         if sampling is None:
             sampling = SamplingParams(temperature=temperature or 0.0)
         req = Request(prompt_ids=prompt, max_new_tokens=int(max_new_tokens),
@@ -1344,6 +1357,10 @@ class Engine:
         use = list(buckets or self.buckets)
         for _name, warm in self._warmers:
             warm(use)
+        # the staging program, like the cache's own host programs: slot 0's
+        # rows as they are, its lanes left alone (one shape, so one program
+        # whatever an admission stages later)
+        self._stager.stage(0, None, self.cache.table_rows(0))
         self.cache.reset()
         self.sampler.reset()             # warmup scribbled slot 0's lanes
         if self.adapter_pool is not None:
@@ -1501,7 +1518,8 @@ class Engine:
             try:
                 self.prefix_cache.register(victim.prompt_ids,
                                            self.cache.owned_blocks(slot),
-                                           salt=self._tenant_salt(victim))
+                                           salt=self._tenant_salt(victim),
+                                           keys=victim._keys)
             except Exception:            # noqa: BLE001 — isolation boundary
                 self.metrics.on_prefix_register_error()
         self._vacate(slot)
@@ -1656,7 +1674,7 @@ class Engine:
             self._fault("serving.prefix_lookup")
             hit_tokens, blocks = self.prefix_cache.lookup(
                 req.prompt_ids, count=False,
-                salt=self._tenant_salt(req))
+                salt=self._tenant_salt(req), keys=req._keys)
             given_up = getattr(self.prefix_cache, "last_given_up", 0)
         except Exception:                # noqa: BLE001 — isolation boundary
             self.metrics.on_prefix_lookup_error()
@@ -1726,9 +1744,27 @@ class Engine:
         # a window holds the tail's blocks and one window's before them
         return start + W
 
+    def _stage(self, req: Request, lanes: bool = True, **attrs) -> None:
+        """The one staging program: ``req``'s slot's table rows as the host
+        has them now and, with ``lanes``, its sampling lanes (parameters and
+        the key re-seeded from the request's seed: the compiled step samples
+        the first token on-device from exactly this state, identically on
+        first admission, preempt-resume and recovery replay)."""
+        with _spans.span("engine.stage", programs=1, **attrs):
+            self._stager.stage(
+                req.slot,
+                self.sampler.lane_rows(req.sampling, self._seed_for(req))
+                if lanes else None,
+                self.cache.table_rows(req.slot))
+        self._admission["staging_programs"] += 1
+
     def _paged_prefill(self, req: Request, L: int):
-        """Paged admission: prefix lookup, block assignment, tail-bucket
-        prefill (of a windowed cache: a program a piece, ``_tail_end``).
+        """Paged admission up to the last prefill's dispatch: prefix lookup,
+        block assignment, the slot staged by one program, tail-bucket
+        prefill (of a prompt prefilled in pieces: a staging program and a
+        prefill a piece, ``_tail_end``).  What an admission owes its prompt
+        afterwards — the registration, the blocks behind a window — waits
+        for the first token (``_register_prompt``).
         Returns ``(status, first_token, bucket, prefix_hit)``
         with status ``"ok" | "deferred" | "failed"`` (``deferred`` = the
         pool cannot supply the tail blocks right now and the slot was
@@ -1741,7 +1777,8 @@ class Engine:
             sp.attrs["hit_tokens"] = P
         if not ok:
             return "deferred", None, bucket, P
-        start = P
+        self._stage(req, piece=0)
+        start, piece = P, 0
         while True:
             end = self._tail_end(start, L)
             ids = np.zeros((1, bucket), dtype=np.int64)
@@ -1752,48 +1789,65 @@ class Engine:
                 start=start, end=end)
             if last is None:
                 return "failed", None, bucket, P
-            if self.cache_spec.kind == "windowed":
-                # the windows the tail closed were published inside its
-                # program: their exact blocks go, before the next piece takes
-                # its own and before the prompt is registered
-                self.cache.release_windows(req.slot, end)
-            # a group that keeps a window: the blocks behind the window of
-            # the sequence's next query go, now that the tail's first query
-            # has read them — after the last piece only as far as the longest
-            # hit of this very prompt (a resume's) would read, until the
-            # prompt is registered
-            self._swa["blocks_released_prefill"] += self.cache.release_behind(
-                req.slot, end if end < L
-                else (L - 1) // self.block_size * self.block_size)
             if end == L:
-                break
-            # the next piece; its first token is sampled from the slot's
+                return "ok", last, bucket, P
+            # the next piece.  What the piece before let go of goes first:
+            # the windows it closed were published inside its program (their
+            # exact blocks go before the next piece takes its own), and a
+            # group that keeps a window lets the blocks behind the next
+            # query's window go, now that the tail's first query has read
+            # them.  Then the piece's own blocks, and one program for all of
+            # it and the slot's lanes: its first token is sampled from the
             # lanes as staged at admission, whatever the pieces before drew
+            if self.cache_spec.kind == "windowed":
+                self.cache.release_windows(req.slot, end, write=False)
+            self._swa["blocks_released_prefill"] += \
+                self.cache.release_behind(req.slot, end, write=False)
             start, bucket = end, self.bucket_for(
                 self._tail_end(end, L) - end)
-            if not self.cache.extend_tail(req.slot, start, bucket):
+            if not self.cache.extend_tail(req.slot, start, bucket,
+                                          write=False):
                 self._retire(req, "failed",
                              error="KV block pool exhausted: no blocks for "
                                    f"the prompt's piece at {start}")
                 return "failed", None, bucket, P
-            self.sampler.stage_slot(req.slot, req.sampling,
-                                    self._seed_for(req))
-        if self.prefix_cache is not None:
-            # make this prompt's whole blocks hittable by later requests
-            # (hit blocks are refreshed, new full tail blocks registered)
-            try:
-                self.prefix_cache.register(
-                    req.prompt_ids, self.cache.owned_blocks(req.slot),
-                    salt=self._tenant_salt(req),
-                    # a cache stated by layer: where this admission's hit
-                    # ended (the window group drops a run moved past)
-                    **({"hit_tokens": P} if self.cache_spec.layer_groups
-                       else {}))
-            except Exception:            # noqa: BLE001 — isolation boundary
-                self.metrics.on_prefix_register_error()
-        self._swa["blocks_released_prefill"] += \
-            self.cache.release_behind(req.slot, L)
-        return "ok", last, bucket, P
+            piece += 1
+            self._stage(req, piece=piece)
+
+    def _register_prompt(self, req: Request, L: int, P: int) -> None:
+        """What an admission owes its prompt once the first token is out:
+        the blocks its last piece let go of, the prompt's whole blocks made
+        hittable by later requests (hit blocks are refreshed, new full tail
+        blocks registered), then the blocks behind the window of the
+        sequence's next query, and one staging program for the rows that
+        changed (the lanes left as the prefill advanced them)."""
+        slot, bs = req.slot, self.block_size
+        with _spans.span("engine.register") as sp:
+            released = 0
+            if self.cache_spec.kind == "windowed":
+                # the windows the last piece closed: before the registration
+                released += self.cache.release_windows(slot, L, write=False)
+            # a group that keeps a window: only as far as the longest hit of
+            # this very prompt (a resume's) would read, until the prompt is
+            # registered
+            behind = self.cache.release_behind(
+                slot, (L - 1) // bs * bs, write=False)
+            if self.prefix_cache is not None:
+                try:
+                    sp.attrs["entries"] = self.prefix_cache.register(
+                        req.prompt_ids, self.cache.owned_blocks(slot),
+                        salt=self._tenant_salt(req), keys=req._keys,
+                        # a cache stated by layer: where this admission's
+                        # hit ended (the window group drops a run moved past)
+                        **({"hit_tokens": P} if self.cache_spec.layer_groups
+                           else {}))
+                except Exception:        # noqa: BLE001 — isolation boundary
+                    self.metrics.on_prefix_register_error()
+            behind += self.cache.release_behind(slot, L, write=False)
+            self._swa["blocks_released_prefill"] += behind
+            sp.attrs["released"] = released + behind
+            if released + behind:
+                self._stage(req, lanes=False)
 
     def _assign_blocks(self, req: Request, L: int):
         """The host half of a paged admission: prefix lookup, the
@@ -1812,7 +1866,7 @@ class Engine:
                 # the end moves, and with it the blocks a window group needs
                 P, shared = self.prefix_cache.lookup(
                     req.prompt_ids, count=False, salt=self._tenant_salt(req),
-                    max_tokens=P - self.block_size)
+                    max_tokens=P - self.block_size, keys=req._keys)
                 req._hit_given_up = self.prefix_cache.last_given_up
             else:
                 P, shared = self.cache.shorten_hit(shared)
@@ -1851,8 +1905,10 @@ class Engine:
                          # a state group plans its snapshots to the piece's
                          # real end
                          end=self._tail_end(P, L))
-        return P, bucket, self.cache.begin_sequence(req.slot, shared, P,
-                                                    bucket, **extra)
+        # (the host's lists only: the slot's rows go to the device with its
+        # sampler lanes, in the one staging program)
+        return P, bucket, self.cache.begin_sequence(
+            req.slot, shared, P, bucket, write=False, **extra)
 
     def _admit(self, req: Request) -> Optional[bool]:
         """Prefill ``req`` into its pre-assigned slot.  Never raises for
@@ -1865,7 +1921,15 @@ class Engine:
                          prompt_tokens=int(req.prompt_ids.size)) as sp:
             sp.attrs["queue_wait_ms"] = round(
                 1e3 * (sp.t0 - req.t_enqueue), 3)
+            walked, staged = req._keys.passes, \
+                self._admission["staging_programs"]
             deferred = self._admit_into_slot(req, sp)
+            # walks over the prompt for its chain keys (0: a retry that
+            # found them) and staging programs, the registration's too
+            walked = req._keys.passes - walked
+            self._admission["key_passes"] += walked
+            sp.set(key_passes=walked, staging_programs=self._admission[
+                "staging_programs"] - staged)
             # "admitted" was set where the slot was occupied; a request
             # retired before that reports how (failed | cancelled)
             sp.attrs.setdefault(
@@ -1884,11 +1948,6 @@ class Engine:
             self._fail_deadline(req)
             return None
         L = int(req.prompt_ids.size)
-        # stage the slot's device sampling lanes (params + key re-seed)
-        # BEFORE the prefill dispatch: the compiled step samples the
-        # first token on-device from exactly this state
-        self.sampler.stage_slot(req.slot, req.sampling,
-                                self._seed_for(req))
         if self.adapter_pool is not None:
             # stage the slot's adapter lane id; a request whose adapter
             # vanished (unload) or moved on (hot-swap bumped the
@@ -1929,7 +1988,8 @@ class Engine:
         self.metrics.on_admit(bucket, L, len(self.queue))
         self.tracer.on_admitted(req, self.name, bucket, req.slot,
                                 prefix_hit)
-        self._deliver_first_token(req, tok_t, now)
+        self._admission["admissions"] += 1
+        self._deliver_first_token(req, tok_t, now, prefix_hit)
 
     def _spec_admit(self, req: Request, L: int) -> bool:
         """Draft-side half of a speculating admission: stage the draft
@@ -1959,12 +2019,16 @@ class Engine:
             return False
         return True
 
-    def _deliver_first_token(self, req: Request, tok_t, now: float
-                             ) -> None:
+    def _deliver_first_token(self, req: Request, tok_t, now: float,
+                             prefix_hit: int) -> None:
         """Stream delivery of the admission's on-device-sampled first
         token.  The only host copy is the token scalar itself — a
         per-admission (never per-decode-step) pull, outside the
-        hot-path dispatch functions."""
+        hot-path dispatch functions.  The prompt's bookkeeping
+        (``_register_prompt``) comes after the token is out and before the
+        request can retire: a request done at its first token leaves its
+        blocks hittable, and one whose callback failed registers
+        nothing."""
         with _spans.span("engine.first_token"):
             tok = int(tok_t.numpy())
         if self.journal is not None and req.journal_id is not None:
@@ -1975,6 +2039,7 @@ class Engine:
         if not self._emit_token(req, tok, now):
             return
         self.metrics.on_first_token(req.ttft_s, tenant=req.tenant)
+        self._register_prompt(req, int(req.prompt_ids.size), prefix_hit)
         if self._done_after_emit(req):
             self._retire(req)
 
@@ -3193,6 +3258,7 @@ class Engine:
                                            for c in pc.state_chains)
                     if pc is not None else 0)
         snap["sampler"] = dict(self._sampler_steps)
+        snap["admission"] = dict(self._admission)
         if self.shard is not None:
             snap["sharding"] = {"mesh_shape": self.mesh_shape,
                                 "model_parallel": self.shard.mp}

@@ -50,7 +50,7 @@ from ..core import dtype as dtype_mod
 from ..core.tensor import Tensor
 from .kv_cache import CacheGroup, _as_i32
 from .paging import BlockAllocator, PagedKVCache, SCRATCH_BLOCK
-from .prefix_cache import PrefixCache
+from .prefix_cache import ChainKeys, PrefixCache, as_tokens
 
 __all__ = ["GroupedKVCache", "GroupedPrefixCache", "StatePool"]
 
@@ -109,8 +109,11 @@ class StatePool:
         self.snapshots = Tensor._wrap(jnp.zeros(
             (self.num_layers, self.rows, self.num_blocks, self.width),
             self.dtype))
-        self.plan = Tensor._wrap(jnp.asarray(np.tile(
-            self._plan_row(ZERO_ROW, {}, 0), (self.num_slots, 1))))
+        #: the plan rows as the host wrote them last (the device's copy is
+        #: ``plan``)
+        self._plans = np.tile(self._plan_row(ZERO_ROW, {}, 0),
+                              (self.num_slots, 1))
+        self.plan = Tensor._wrap(jnp.asarray(self._plans))
         for t in (self.state, self.snapshots, self.plan):
             t.persistable = True
         #: snapshot rows each slot holds a reference on: the one it started
@@ -155,21 +158,16 @@ class StatePool:
             row[1 + k], row[1 + self.max_snaps + k] = snap, end - start
         return row
 
-    def _set_plan(self, slot: int, first: int, at: Dict[int, int],
-                  start: int) -> None:
-        self.plan._set_data(self.plan._value().at[slot].set(
-            jnp.asarray(self._plan_row(first, at, start))))
-
-    def warm_host_programs(self) -> None:
-        self._set_plan(0, ZERO_ROW, {}, 0)
-
     def begin_sequence(self, slot: int, first: int, start: int,
-                       end: Optional[int]) -> Tuple[int, int]:
+                       end: Optional[int], *,
+                       write: bool = True) -> Tuple[int, int]:
         """The plan of the prefill program over ``[start, end)`` of ``slot``
         that starts from snapshot row ``first``: a row for each length of
         :meth:`snapshot_ends` the allocator can give (idle snapshots are
         evicted for them, oldest first; none is ever waited for).  ``end``
-        None: a warm-up, no snapshot.  Returns ``(first, rows planned)``."""
+        None: a warm-up, no snapshot.  Returns ``(first, rows planned)``.
+        ``write=False``: the device's plan is left to the caller
+        (:meth:`plan_row`)."""
         if self._held[slot]:
             raise RuntimeError(f"slot {slot} already holds snapshot rows "
                                f"{self._held[slot]}")
@@ -191,8 +189,15 @@ class StatePool:
         self._held[slot].extend(at.values())
         self._wrote[slot].update(at)
         self.snapshots_written += len(at)
-        self._set_plan(slot, first, at, start)
+        self._plans[slot] = self._plan_row(first, at, start)
+        if write:
+            self.plan._set_data(
+                self.plan._value().at[slot].set(self._plans[slot]))
         return first, len(at)
+
+    def plan_row(self, slot: int) -> np.ndarray:
+        """``slot``'s plan as :meth:`begin_sequence` made it last."""
+        return self._plans[slot]
 
     def release_slot(self, slot: int) -> None:
         held, self._held[slot] = self._held[slot], []
@@ -357,7 +362,7 @@ class GroupedKVCache:
             for p in self.states]
 
     def warm_host_programs(self) -> None:
-        for p in (*self.pools, *self.states):
+        for p in self.pools:
             p.warm_host_programs()
 
     def check_invariants(self) -> List[str]:
@@ -385,7 +390,8 @@ class GroupedKVCache:
     def begin_sequence(self, slot: int, shared, prefix_len: int,
                        tail_bucket: int, *, total: int = 0,
                        reserve: Sequence[int] = (),
-                       end: Optional[int] = None) -> bool:
+                       end: Optional[int] = None,
+                       write: bool = True) -> bool:
         """One admission's storage from every pool, all or nothing; then the
         state groups' plans for the program over ``[prefix_len, end)``
         (snapshot rows as the allocator has them: never waited for).
@@ -395,34 +401,47 @@ class GroupedKVCache:
         reserve = list(reserve) or [0] * len(self.pools)
         for i, (p, blocks, r) in enumerate(zip(self.pools, shared, reserve)):
             if not p.begin_sequence(slot, blocks, prefix_len, tail_bucket,
-                                    total=total, reserve=r):
+                                    total=total, reserve=r, write=write):
                 self.deferred_by[i] += 1
                 for q in self.pools[:i]:
                     q.release_slot(slot)
                 return False
-        self.planned = [st.begin_sequence(slot, row, prefix_len, end)
+        self.planned = [st.begin_sequence(slot, row, prefix_len, end,
+                                          write=write)
                         for st, row in zip(self.states,
                                            shared[len(self.pools):])]
         return True
 
+    def tables(self) -> List[Tensor]:
+        """Every pool's block table, then every state group's plan."""
+        return [p.block_tables for p in self.pools] \
+            + [st.plan for st in self.states]
+
+    def table_rows(self, slot: int) -> List[np.ndarray]:
+        return [r for p in self.pools for r in p.table_rows(slot)] \
+            + [st.plan_row(slot) for st in self.states]
+
     def growth_needs(self, slot: int, total: int) -> List[int]:
         return [p.growth_need(slot, total) for p in self.pools]
 
-    def extend_tail(self, slot: int, start: int, tail_bucket: int) -> bool:
+    def extend_tail(self, slot: int, start: int, tail_bucket: int, *,
+                    write: bool = True) -> bool:
         """The blocks of the next piece of a prompt prefilled in pieces."""
         if self.states:
             raise NotImplementedError(
                 "a prompt prefilled in pieces beside a group that keeps "
                 "state: a tail prefill starts from a snapshot or from zeros, "
                 "not from the state the piece before left")
-        return all(p.extend_tail(slot, start, tail_bucket)
+        return all(p.extend_tail(slot, start, tail_bucket, write=write)
                    for p in self.pools)
 
     def ensure_capacity(self, slot: int, next_pos: int) -> bool:
         return all(p.ensure_capacity(slot, next_pos) for p in self.pools)
 
-    def release_behind(self, slot: int, next_pos: int) -> int:
-        return sum(p.release_behind(slot, next_pos) for p in self.pools)
+    def release_behind(self, slot: int, next_pos: int, *,
+                       write: bool = True) -> int:
+        return sum(p.release_behind(slot, next_pos, write=write)
+                   for p in self.pools)
 
     def release_slot(self, slot: int) -> None:
         for p in (*self.pools, *self.states):
@@ -504,15 +523,16 @@ class GroupedPrefixCache:
         return max(0, end * self.block_size - pool.kv_window + 1) \
             // self.block_size if pool.kv_window else 0
 
-    def _walk(self, prompt, salt: bytes, max_tokens: Optional[int]):
+    def _walk(self, prompt, salt: bytes, max_tokens: Optional[int],
+              memo: Optional[ChainKeys] = None):
         """``(end, kept, keys)``: the hit's end in blocks, the end the groups
         that keep all would allow, and the chain keys up to there."""
-        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
+        prompt = as_tokens(prompt)
         bs = self.block_size
         stop = max(0, (int(prompt.size) - 1) // bs)
         if max_tokens is not None:
             stop = min(stop, int(max_tokens) // bs)
-        keys = self.chains[0]._keys_for(prompt, stop, salt)
+        keys = self.chains[0]._keys_for(prompt, stop, salt, memo)
         kept = stop
         for chain, pool in zip(self.chains, self.cache.pools):
             if not pool.kv_window:
@@ -526,11 +546,12 @@ class GroupedPrefixCache:
         for chain, pool in zip(self.chains, self.cache.pools):
             if not pool.kv_window:
                 continue
-            run = 0
+            entries, behind, run = chain._entries, pool.kv_window - 1, 0
             for end in range(1, kept + 1):
-                run = run + 1 if keys[end - 1] in chain._entries else 0
-                ok[end] = ok[end] and \
-                    run >= end - self._first_needed(pool, end)
+                run = run + 1 if keys[end - 1] in entries else 0
+                # (``_first_needed(pool, end)``, in line: once a block)
+                if run < end - max(0, end * bs - behind) // bs:
+                    ok[end] = False
         # and every state group a snapshot at exactly that length (none is
         # needed at 0: the zeros)
         for chain in self.state_chains:
@@ -540,10 +561,13 @@ class GroupedPrefixCache:
         return end, kept, keys
 
     def lookup(self, prompt, count: bool = True, salt: bytes = b"",
-               max_tokens: Optional[int] = None):
+               max_tokens: Optional[int] = None,
+               keys: Optional[ChainKeys] = None):
         """``(n_tokens, block ids by position a pool, then the snapshot row
-        a state group)``; ``max_tokens`` caps the hit's end."""
-        end, kept, keys = self._walk(prompt, salt, max_tokens)
+        a state group)``; ``max_tokens`` caps the hit's end; ``keys``: the
+        prompt's :class:`~.prefix_cache.ChainKeys` where the caller keeps
+        them."""
+        end, kept, keys = self._walk(prompt, salt, max_tokens, keys)
         self.hits_shortened += end < kept
         self.last_given_up = (kept - end) * self.block_size
         self.tokens_given_up += self.last_given_up
@@ -576,7 +600,8 @@ class GroupedPrefixCache:
         self.chains[0].record_lookup(prompt_tokens, hit_tokens)
 
     def register(self, prompt, owned: GroupHit, salt: bytes = b"",
-                 hit_tokens: int = 0) -> int:
+                 hit_tokens: int = 0,
+                 keys: Optional[ChainKeys] = None) -> int:
         """The prompt's whole blocks of a group that keeps all; of a state
         group the snapshots the slot's prefill wrote, each at its length; of
         a group with a window the blocks the slot still holds: its last
@@ -593,19 +618,20 @@ class GroupedPrefixCache:
         for chain, pool, blocks in zip(self.chains, self.cache.pools, owned):
             first = next((i for i, b in enumerate(blocks)
                           if b != SCRATCH_BLOCK), len(blocks))
-            n += chain.register(prompt, blocks, salt=salt, first_block=first)
+            n += chain.register(prompt, blocks, salt=salt, first_block=first,
+                                keys=keys)
             if pool.kv_window and 0 < hit_end <= first:
-                keys = chain._keys_for(np.asarray(
-                    list(prompt), dtype=np.int64).reshape(-1), hit_end, salt)
+                moved_past = chain._keys_for(as_tokens(prompt), hit_end, salt,
+                                             keys)
                 # from where the run of a prompt that ended there starts:
                 # what the longest hit of that very prompt reads
-                for key in keys[self._first_needed(pool, hit_end - 1):]:
+                for key in moved_past[self._first_needed(pool, hit_end - 1):]:
                     e = chain._entries.get(key)
                     if e is not None and \
                             pool.allocator.refcount(e.block_id) == 1:
                         chain._evict_one(key)
         for chain, wrote in zip(self.state_chains, owned[len(self.chains):]):
-            n += chain.register_at(prompt, wrote, salt=salt)
+            n += chain.register_at(prompt, wrote, salt=salt, keys=keys)
         return n
 
     def bump_epoch(self) -> int:

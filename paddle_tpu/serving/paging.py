@@ -40,6 +40,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.tensor import Tensor
 from ..core import dtype as dtype_mod
@@ -370,7 +371,8 @@ class PagedKVCache:
 
     def begin_sequence(self, slot: int, shared_blocks: Sequence[int],
                        prefix_len: int, tail_bucket: int, *,
-                       total: int = 0, reserve: int = 0) -> bool:
+                       total: int = 0, reserve: int = 0,
+                       write: bool = True) -> bool:
         """Assign storage for one admission: ref the shared prefix blocks
         and allocate fresh blocks covering the whole tail bucket.  The
         slot must be empty (freshly popped).  All-or-nothing: returns
@@ -380,7 +382,11 @@ class PagedKVCache:
         (blocks behind the window, which no hit needs).  ``reserve``: blocks
         that must still be obtainable afterwards, beside what this sequence
         of ``total`` tokens may itself grow by (:meth:`growth_need`): what
-        the running sequences may still take."""
+        the running sequences may still take.  ``write=False`` (here and in
+        :meth:`extend_tail`, :meth:`release_behind`): the host's lists
+        change and the device's table is left to the caller, who writes the
+        slot's :meth:`table_rows` with whatever else it stages (the engine's
+        one program an admission)."""
         if self._slot_blocks[slot]:
             raise AllocatorError(f"slot {slot} already owns blocks "
                                  f"{self._slot_blocks[slot]}")
@@ -422,18 +428,33 @@ class PagedKVCache:
         self._released[slot] = next(
             (i for i, b in enumerate(owned) if b != SCRATCH_BLOCK),
             len(owned))
-        self._set_row(slot, owned + fresh)
+        if write:
+            self._set_row(slot, owned + fresh)
         return True
+
+    @staticmethod
+    def _row(ids: Sequence[int], width: int) -> np.ndarray:
+        """A table row: ``ids``, then the scratch block."""
+        row = np.full((width,), SCRATCH_BLOCK, dtype=np.int32)
+        row[:len(ids)] = ids
+        return row
 
     def _set_row(self, slot: int, ids: Sequence[int],
                  tables: Optional[Tensor] = None) -> None:
         """``slot``'s row of ``tables`` (default: the block tables): ``ids``,
         then the scratch block."""
         tables = self.block_tables if tables is None else tables
-        row = [SCRATCH_BLOCK] * int(tables.shape[1])
-        row[:len(ids)] = ids
         tables._set_data(tables._value().at[slot].set(
-            jnp.asarray(row, dtype=jnp.int32)))
+            self._row(ids, int(tables.shape[1]))))
+
+    def tables(self) -> List[Tensor]:
+        """The ``[slots, ...]`` int32 tables a slot has a row of, in the
+        order of :meth:`table_rows`."""
+        return [self.block_tables]
+
+    def table_rows(self, slot: int) -> List[np.ndarray]:
+        """``slot``'s row of every table as the host's lists have it now."""
+        return [self._row(self._slot_blocks[slot], self.max_blocks_per_slot)]
 
     def available_blocks(self) -> int:
         """Blocks an allocation could get: free, or idle in the prefix
@@ -457,7 +478,8 @@ class PagedKVCache:
         most = -(-self.kv_window // self.block_size) + 1
         return max(0, min(want, most) - (len(owned) - self._released[slot]))
 
-    def extend_tail(self, slot: int, start: int, tail_bucket: int) -> bool:
+    def extend_tail(self, slot: int, start: int, tail_bucket: int, *,
+                    write: bool = True) -> bool:
         """Fresh blocks for the positions ``[start, start + tail_bucket)``
         that ``slot`` does not own yet: the next piece of a prompt that is
         prefilled in pieces.  False (nothing taken) when the pool cannot
@@ -469,10 +491,12 @@ class PagedKVCache:
             if fresh is None:
                 return False
             owned.extend(fresh)
-            self._set_row(slot, owned)
+            if write:
+                self._set_row(slot, owned)
         return True
 
-    def release_behind(self, slot: int, next_pos: int) -> int:
+    def release_behind(self, slot: int, next_pos: int, *,
+                       write: bool = True) -> int:
         """Of a pool with a window: unreference ``slot``'s blocks that no
         live position can read — every key in them lies more than ``window -
         1`` positions behind ``next_pos``, the sequence's next query.  The
@@ -489,7 +513,8 @@ class PagedKVCache:
         drop = owned[lo:hi]
         owned[lo:hi] = [SCRATCH_BLOCK] * (hi - lo)
         self._released[slot] = hi
-        self._set_row(slot, owned)
+        if write:
+            self._set_row(slot, owned)
         for b in drop:
             self.allocator.unref(b)
         self.blocks_released += len(drop)
@@ -505,10 +530,7 @@ class PagedKVCache:
             if b != SCRATCH_BLOCK:      # released behind a window
                 self.allocator.unref(b)
         if owned:
-            self.block_tables._set_data(
-                self.block_tables._value().at[slot].set(
-                    jnp.full((self.max_blocks_per_slot,), SCRATCH_BLOCK,
-                             dtype=jnp.int32)))
+            self._set_row(slot, [])
         self.lengths._set_data(
             self.lengths._value().at[slot].set(jnp.int32(0)))
 
@@ -563,11 +585,7 @@ class PagedKVCache:
             return 0
         drop = owned[keep:]
         del owned[keep:]
-        tbl = self.block_tables._value()
-        row = jnp.asarray(
-            [SCRATCH_BLOCK] * self.max_blocks_per_slot, dtype=jnp.int32)
-        row = row.at[:len(owned)].set(jnp.asarray(owned, dtype=jnp.int32))
-        self.block_tables._set_data(tbl.at[slot].set(row))
+        self._set_row(slot, owned)
         for b in drop:
             self.allocator.unref(b)
         return len(drop)
@@ -968,8 +986,6 @@ class PagedKVCache:
         ``kernel="reference"``, which has no work list."""
         if self.kernel != "pallas":
             return None
-        import numpy as np
-
         from ..ops.pallas import paged_attention_kernel as pk
 
         arr = self.sides[0][0]._value()

@@ -55,20 +55,52 @@ from __future__ import annotations
 import hashlib
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-__all__ = ["PrefixCache"]
+__all__ = ["PrefixCache", "ChainKeys", "as_tokens"]
 
 _ROOT = b"paddle-tpu-prefix-root"
 
 
-def _chain_hash(parent: bytes, tokens: np.ndarray) -> bytes:
-    h = hashlib.blake2b(digest_size=16)
-    h.update(parent)
-    h.update(np.ascontiguousarray(tokens, dtype=np.int64).tobytes())
-    return h.digest()
+def as_tokens(prompt) -> np.ndarray:
+    """``prompt`` as the flat ``int64`` array the keys are hashed from: a
+    request's ``prompt_ids`` as it is, anything else converted once."""
+    return np.asarray(prompt, dtype=np.int64).reshape(-1)
+
+
+def _chain(prompt: np.ndarray, root: bytes, block_size: int,
+           n_blocks: int) -> List[bytes]:
+    """The chain keys of ``prompt``'s first ``n_blocks`` blocks: block ``i``'s
+    is ``H(key[i-1] || its tokens as int64)``, the first one's parent
+    ``root``.  The prompt's bytes are taken once and hashed by slices."""
+    buf = memoryview(np.ascontiguousarray(prompt, dtype=np.int64)).cast("B")
+    step = 8 * block_size
+    keys, parent = [], root
+    for lo in range(0, n_blocks * step, step):
+        h = hashlib.blake2b(parent, digest_size=16)
+        h.update(buf[lo:lo + step])
+        parent = h.digest()
+        keys.append(parent)
+    return keys
+
+
+class ChainKeys:
+    """One prompt's chain keys, kept between the calls that read them: a
+    request holds one, and its lookup, its capped re-lookup, its registration
+    and every retry of a deferred or preempted admission pass it as
+    ``keys=``.  The keys are kept by block size under the root (epoch and
+    salt) they were hashed from; a call under another root — the weights
+    were swapped, the tenant's adapter moved — drops them and walks the
+    prompt again.  ``passes`` counts those walks."""
+
+    __slots__ = ("root", "by_block", "passes")
+
+    def __init__(self):
+        self.root: Optional[bytes] = None
+        self.by_block: Dict[int, List[bytes]] = {}
+        self.passes = 0
 
 
 @dataclass
@@ -111,13 +143,24 @@ class PrefixCache:
     # -- lookup / register -------------------------------------------------
 
     def _keys_for(self, prompt: np.ndarray, n_blocks: int,
-                  salt: bytes = b"") -> List[bytes]:
-        bs, keys = self.block_size, []
-        parent = _ROOT + self.epoch.to_bytes(8, "little") + salt
-        for i in range(n_blocks):
-            parent = _chain_hash(parent, prompt[i * bs:(i + 1) * bs])
-            keys.append(parent)
-        return keys
+                  salt: bytes = b"",
+                  keys: Optional[ChainKeys] = None) -> List[bytes]:
+        """Chain keys of ``prompt``'s first ``n_blocks`` blocks under this
+        cache's epoch and ``salt``; read from ``keys`` where the caller keeps
+        them (all of the prompt's whole blocks are hashed on the first call
+        under a root, so no later call hashes again)."""
+        root = _ROOT + self.epoch.to_bytes(8, "little") + salt
+        if keys is None:
+            return _chain(prompt, root, self.block_size, n_blocks)
+        if keys.root != root:
+            keys.root, keys.by_block = root, {}
+            keys.passes += 1
+        have = keys.by_block.get(self.block_size)
+        if have is None:
+            have = keys.by_block[self.block_size] = _chain(
+                prompt, root, self.block_size,
+                int(prompt.size) // self.block_size)
+        return have[:n_blocks]
 
     def record_lookup(self, prompt_tokens: int, hit_tokens: int) -> None:
         """Count one logical lookup toward the hit-rate gauges.  The
@@ -132,7 +175,8 @@ class PrefixCache:
 
     def lookup(self, prompt: Sequence[int], count: bool = True,
                salt: bytes = b"", first_block: int = 0,
-               max_blocks: Optional[int] = None) -> Tuple[int, List[int]]:
+               max_blocks: Optional[int] = None,
+               keys: Optional[ChainKeys] = None) -> Tuple[int, List[int]]:
         """Longest cached prefix of ``prompt``: ``(n_tokens, block_ids)``.
 
         Walks the hash chain over whole prompt blocks, stopping at the
@@ -144,13 +188,15 @@ class PrefixCache:
         after it has decided whether the result is actually used.
         ``first_block``: the walk starts at that block of the prompt (the
         blocks before it are another group's to cover) and takes at most
-        ``max_blocks``; the tokens returned are those of the walk alone."""
-        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
+        ``max_blocks``; the tokens returned are those of the walk alone.
+        ``keys``: the prompt's :class:`ChainKeys`, where the caller keeps
+        them."""
+        prompt = as_tokens(prompt)
         if count:
             self.lookups += 1
             self.lookup_tokens_total += int(prompt.size)
         block_ids: List[int] = []
-        for key in self._span(prompt, salt, first_block, max_blocks):
+        for key in self._span(prompt, salt, first_block, max_blocks, keys):
             e = self._entries.get(key)
             if e is None:
                 break
@@ -163,13 +209,14 @@ class PrefixCache:
         return len(block_ids) * self.block_size, block_ids
 
     def _span(self, prompt: np.ndarray, salt: bytes, first_block: int,
-              max_blocks: Optional[int]) -> List[bytes]:
+              max_blocks: Optional[int],
+              keys: Optional[ChainKeys] = None) -> List[bytes]:
         """Chain keys of the blocks a walk may take: from ``first_block``,
         at most ``max_blocks``, and never the prompt's last token's."""
         stop = max(0, (int(prompt.size) - 1) // self.block_size)
         if max_blocks is not None:
             stop = min(stop, first_block + max_blocks)
-        return self._keys_for(prompt, stop, salt)[first_block:]
+        return self._keys_for(prompt, stop, salt, keys)[first_block:]
 
     def probe(self, prompt: Sequence[int], salt: bytes = b"",
               first_block: int = 0, max_blocks: Optional[int] = None) -> int:
@@ -179,7 +226,7 @@ class PrefixCache:
         replica's cache per dispatch, and only the chosen replica's
         recency order and hit-rate gauges should move (they do, at
         admission, through the real :meth:`lookup`)."""
-        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
+        prompt = as_tokens(prompt)
         n = 0
         for key in self._span(prompt, salt, first_block, max_blocks):
             if key not in self._entries:
@@ -188,7 +235,8 @@ class PrefixCache:
         return n * self.block_size
 
     def register(self, prompt: Sequence[int], block_ids: Sequence[int],
-                 salt: bytes = b"", first_block: int = 0) -> int:
+                 salt: bytes = b"", first_block: int = 0,
+                 keys: Optional[ChainKeys] = None) -> int:
         """Make ``prompt``'s whole blocks hittable by later requests.
 
         ``block_ids`` must cover the prompt's full blocks in order (the
@@ -199,12 +247,12 @@ class PrefixCache:
         new entries were created.  ``first_block``: registration starts at
         that block (a chain of its own: the blocks before it are another
         group's) and ends at the first block the slot has released (id 0)."""
-        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
+        prompt = as_tokens(prompt)
         n_full = min(int(prompt.size) // self.block_size, len(block_ids))
         created, parent = 0, None
-        keys = self._keys_for(prompt, n_full, salt)
+        chain = self._keys_for(prompt, n_full, salt, keys)
         for depth in range(first_block, n_full):
-            key = keys[depth]
+            key = chain[depth]
             if not block_ids[depth]:
                 break
             e = self._entries.get(key)
@@ -224,20 +272,20 @@ class PrefixCache:
             created += 1
         return created
 
-    def register_at(self, prompt: Sequence[int], at, salt: bytes = b""
-                    ) -> int:
+    def register_at(self, prompt: Sequence[int], at, salt: bytes = b"",
+                    keys: Optional[ChainKeys] = None) -> int:
         """Single entries of a cache that keeps no chain (``chained=False``):
         ``at = {tokens: id}`` puts ``id`` under the key of the block that
         *ends* at ``tokens`` (a whole number of blocks of ``prompt``) — what
         is kept there is a property of the whole prefix up to that length,
         not of the block.  First writer wins; returns the entries created."""
-        prompt = np.asarray(list(prompt), dtype=np.int64).reshape(-1)
         if not at:
             return 0
-        keys = self._keys_for(prompt, max(at) // self.block_size, salt)
+        chain = self._keys_for(as_tokens(prompt),
+                               max(at) // self.block_size, salt, keys)
         created = 0
         for tokens, ident in sorted(at.items()):
-            key = keys[tokens // self.block_size - 1]
+            key = chain[tokens // self.block_size - 1]
             if key in self._entries:
                 self._entries.move_to_end(key)
                 continue

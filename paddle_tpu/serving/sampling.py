@@ -52,7 +52,7 @@ from ..ops.threshold_search import (largest_key as _largest_key,
                                      order_keys as _order_keys)
 
 __all__ = ["SamplingParams", "sample", "device_sample", "DeviceSampler",
-           "sampler_path"]
+           "sampler_path", "host_prng_key"]
 
 _NEG_INF = np.float32(-1e30)
 
@@ -173,6 +173,16 @@ def _device_masked_logits(logits, temps, top_ks, top_ps):
                      z, _NEG_INF)
 
 
+def host_prng_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)``'s two ``uint32`` words, computed on the
+    host: the seed's low word second and, where 64-bit types are on, its
+    high word first (else 0: the seed has wrapped to 32 bits by then).
+    ``tests/test_serving_admission.py`` holds it to JAX's own bitwise."""
+    seed = int(seed)
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], dtype=np.uint32)
+
+
 def device_sample(logits, temps, top_ks, top_ps, keys, live=None
                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Sample one token per row, entirely on device (traced inside the
@@ -221,7 +231,9 @@ class DeviceSampler:
     last sampled token per slot (``tokens`` — the next decode step's
     input ids, read device-side so no host round-trip feeds the loop).
     Host side, the engine **stages** a slot at admission
-    (:meth:`stage_slot`): parameters are written into the lanes and the
+    (:meth:`lane_rows`, written with the slot's table rows by the engine's
+    one staging program; :meth:`stage_slot` writes the same rows a lane at
+    a time): parameters are written into the lanes and the
     key lane is re-seeded from the request's seed — identically on first
     admission and on preempt-resume, which is what makes seeded replay
     bitwise deterministic (the old per-request ``RandomState`` contract,
@@ -260,28 +272,37 @@ class DeviceSampler:
 
     # -- host-side staging (between steps; value-only, never a shape) ------
 
+    def lanes(self) -> list:
+        """The ``[slots, ...]`` lanes a slot is staged in, in the order of
+        :meth:`lane_rows`: key, temperature, top-k, top-p, and with a
+        grammar table the grammar id and the automaton's state."""
+        out = [self.keys, self.temps, self.top_ks, self.top_ps]
+        if self.grammar is not None:
+            out += [self.grammar_ids, self.grammar_states]
+        return out
+
+    def lane_rows(self, params: SamplingParams, seed: int) -> list:
+        """One slot's row of every lane for ``params`` and ``seed``, made on
+        the host.  The key is bitwise ``jax.random.PRNGKey(seed)``
+        (:func:`host_prng_key`); the grammar lanes are the grammar's id and
+        the automaton's start state, so a replayed request walks the same
+        path."""
+        rows = [host_prng_key(seed), np.float32(params.temperature),
+                np.int32(params.top_k), np.float32(params.top_p)]
+        if self.grammar is not None:
+            rows += [np.int32(self.grammar.gid_of(params.grammar)),
+                     np.int32(0)]
+        return rows
+
     def stage_slot(self, slot: int, params: SamplingParams,
                    seed: int) -> None:
-        """Write one slot's sampling parameters and re-seed its key lane
-        (admission and preempt-resume both land here, so replay streams
-        are reconstructible by construction)."""
-        self.keys._set_data(self.keys._value().at[slot].set(
-            jax.random.PRNGKey(int(seed)).astype(jnp.uint32)))
-        self.temps._set_data(self.temps._value().at[slot].set(
-            jnp.float32(params.temperature)))
-        self.top_ks._set_data(self.top_ks._value().at[slot].set(
-            jnp.int32(params.top_k)))
-        self.top_ps._set_data(self.top_ps._value().at[slot].set(
-            jnp.float32(params.top_p)))
-        if self.grammar is not None:
-            # grammar id + automaton start state: re-staged identically
-            # on preempt-resume/recovery, so a replayed request walks
-            # the same automaton path bitwise
-            gid = self.grammar.gid_of(params.grammar)
-            self.grammar_ids._set_data(
-                self.grammar_ids._value().at[slot].set(jnp.int32(gid)))
-            self.grammar_states._set_data(
-                self.grammar_states._value().at[slot].set(jnp.int32(0)))
+        """Write one slot's sampling parameters and re-seed its key lane, a
+        lane at a time (the speculative draft's sampler; the engine stages
+        its own in one program with the slot's tables, ``serving/staging``).
+        Admission and preempt-resume both stage from the same parameters and
+        seed, so replay streams are reconstructible by construction."""
+        for lane, row in zip(self.lanes(), self.lane_rows(params, seed)):
+            lane._set_data(lane._value().at[slot].set(row))
 
     def reset(self) -> None:
         """Forget all slots (warmup scribbles over slot 0)."""

@@ -38,7 +38,7 @@ from ..ops.pallas import eva_attention_kernel as eva
 from .kv_cache import _as_i32
 from .paging import (AllocatorError, BlockAllocator, PagedKVCache,
                      SCRATCH_BLOCK)
-from .prefix_cache import PrefixCache
+from .prefix_cache import PrefixCache, as_tokens
 
 __all__ = ["WindowedKVCache", "WindowedPrefixCache"]
 
@@ -98,7 +98,7 @@ class WindowedKVCache(PagedKVCache):
 
     def begin_sequence(self, slot: int, shared, prefix_len: int,
                        tail_bucket: int, *, total: int = 0,
-                       reserve: int = 0) -> bool:
+                       reserve: int = 0, write: bool = True) -> bool:
         """Storage of one admission, all or nothing: refs on the hit's
         summary and exact blocks, fresh exact blocks for the tail bucket,
         and a fresh summary block for every window a sequence of ``total``
@@ -144,9 +144,17 @@ class WindowedKVCache(PagedKVCache):
         self._slot_blocks[slot] = owned
         self._slot_windows[slot] = windows + fresh_w
         self._published[slot] = len(windows)
-        self._set_row(slot, owned)
-        self._set_row(slot, self._slot_windows[slot], self.summary_tables)
+        if write:
+            self._set_row(slot, owned)
+            self._set_row(slot, self._slot_windows[slot], self.summary_tables)
         return True
+
+    def tables(self) -> List[Tensor]:
+        return [self.block_tables, self.summary_tables]
+
+    def table_rows(self, slot: int):
+        return super().table_rows(slot) + [
+            self._row(self._slot_windows[slot], self.max_windows)]
 
     def release_slot(self, slot: int) -> None:
         held, self._slot_windows[slot] = self._slot_windows[slot], []
@@ -165,7 +173,8 @@ class WindowedKVCache(PagedKVCache):
     def published(self, slot: int) -> int:
         return self._published[slot]
 
-    def release_windows(self, slot: int, seq_len: int) -> int:
+    def release_windows(self, slot: int, seq_len: int, *,
+                        write: bool = True) -> int:
         """Host half of publishing: the windows ``seq_len`` tokens have
         closed are marked published (their summaries were just written by a
         program) and their exact blocks are unreferenced.  Returns the exact
@@ -179,7 +188,8 @@ class WindowedKVCache(PagedKVCache):
         drop = [b for b in owned[lo:hi] if b != SCRATCH_BLOCK]
         owned[lo:hi] = [SCRATCH_BLOCK] * (hi - lo)
         self._published[slot] = done
-        self._set_row(slot, owned)
+        if write:
+            self._set_row(slot, owned)
         for b in drop:
             self.allocator.unref(b)
         self.exact_blocks_released += len(drop)
@@ -351,16 +361,22 @@ class WindowedPrefixCache:
         return dict(first_block=n_windows * self.cache.window_blocks,
                     max_blocks=self.cache.window_blocks - 1)
 
-    def lookup(self, prompt, count: bool = True, salt: bytes = b""):
-        """``(n_tokens, (summary block ids, exact block ids))``."""
-        n_w, windows = self.windows.lookup(prompt, count=False, salt=salt)
+    def lookup(self, prompt, count: bool = True, salt: bytes = b"",
+               keys=None):
+        """``(n_tokens, (summary block ids, exact block ids))``; ``keys``:
+        the prompt's :class:`~.prefix_cache.ChainKeys` where the caller keeps
+        them (both chains', each under its block size)."""
+        n_w, windows = self.windows.lookup(prompt, count=False, salt=salt,
+                                           keys=keys)
         n_e, exact = self.exact.lookup(prompt, count=False, salt=salt,
+                                       keys=keys,
                                        **self._exact_span(len(windows)))
         if count:
             self.record_lookup(len(prompt), n_w + n_e)
         return n_w + n_e, (windows, exact)
 
     def probe(self, prompt, salt: bytes = b"") -> int:
+        prompt = as_tokens(prompt)
         n_w = self.windows.probe(prompt, salt=salt)
         return n_w + self.exact.probe(
             prompt, salt=salt,
@@ -369,14 +385,15 @@ class WindowedPrefixCache:
     def record_lookup(self, prompt_tokens: int, hit_tokens: int) -> None:
         self.exact.record_lookup(prompt_tokens, hit_tokens)
 
-    def register(self, prompt, owned: WindowHit, salt: bytes = b"") -> int:
+    def register(self, prompt, owned: WindowHit, salt: bytes = b"",
+                 keys=None) -> int:
         """The prompt's whole published windows, and the whole blocks of its
         last, unfinished window while the slot still holds them."""
         windows, exact = owned
-        n = self.windows.register(prompt, windows, salt=salt)
+        n = self.windows.register(prompt, windows, salt=salt, keys=keys)
         first = (len(prompt) // self.cache.window) * self.cache.window_blocks
         return n + self.exact.register(prompt, exact, salt=salt,
-                                       first_block=first)
+                                       first_block=first, keys=keys)
 
     def bump_epoch(self) -> int:
         self.windows.bump_epoch()

@@ -381,8 +381,8 @@ def test_a_cold_long_prompt_goes_in_a_window_at_a_time(f32, tokens):
     peak = []
     sound = eng.cache.extend_tail
 
-    def watched(slot, start, bucket):
-        ok = sound(slot, start, bucket)
+    def watched(slot, start, bucket, **kw):
+        ok = sound(slot, start, bucket, **kw)
         peak.append(eng.cache.allocator.stats()["used"])
         return ok
 

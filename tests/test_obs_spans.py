@@ -163,8 +163,8 @@ def test_flight_ring_takes_a_closed_span_as_its_step_record():
 
 STEP_CHILDREN = ["engine.reap", "engine.admit", "engine.prepare_decode",
                  "engine.decode", "engine.pull", "engine.deliver"]
-ADMIT_CHILDREN = ["engine.prefix_lookup", "engine.prefill",
-                  "engine.first_token"]
+ADMIT_CHILDREN = ["engine.prefix_lookup", "engine.stage", "engine.prefill",
+                  "engine.first_token", "engine.register"]
 
 
 @pytest.fixture(scope="module")
@@ -377,8 +377,10 @@ def test_admit_spans_carry_the_request(engine_run):
         assert at["prompt_tokens"] == int(req.prompt_ids.size)
         assert at["trace"].endswith(f":r{req.request_id}")
         assert [c[NAME] for c in kids[a[SID]]] == ADMIT_CHILDREN
-        assert kids[a[SID]][1][ATTRS] == {"bucket": at["bucket"],
+        assert kids[a[SID]][1][ATTRS] == {"programs": 1, "piece": 0}
+        assert kids[a[SID]][2][ATTRS] == {"bucket": at["bucket"],
                                           "attempts": 1}
+        assert at["key_passes"] == 1 and at["staging_programs"] == 1
     # the later two hit the first one's two whole prefix blocks
     assert [a[ATTRS]["hit_tokens"] for a in admits] == [0, 16, 16]
 
