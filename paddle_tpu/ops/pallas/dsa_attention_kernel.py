@@ -37,12 +37,16 @@ the cut all kept.  The pieces, each with a jnp oracle beside it:
   ``TIE_ROOM`` tokens tied *exactly* at the cut are past what a decode step
   keeps (the lowest positions stay); the prefill form and the oracle keep
   every tie.
-- :func:`sparse_prefill` (``dsa_sparse_prefill``): the dense tail-prefill
-  attention (a tile of tail queries over the slot's whole block row, chunk
-  by chunk, online softmax) with one more condition in its mask: causal AND
-  ``I >= cut``, the scores tile and the cut read beside the queries.  It does
+- :func:`sparse_prefill` (``dsa_sparse_prefill``): the K/V tail-prefill
+  kernel itself (``paged_attention_kernel._prefill_kernel``: a work list of
+  live (query tile, key chunk) items, a KV head's query heads the rows of one
+  matmul, the next item's chunk fetched into a second buffer while this one
+  multiplies, a chunk whose blocks are a run of the pool in one copy a side)
+  with one more condition in its mask: causal AND ``I >= cut``, the item's
+  tile of the scores and its queries' cuts read beside the queries.  It does
   the dense kernel's work whatever is selected; its roofline is counted for
-  that.
+  that.  No second kernel body: what changes the dense tail prefill changes
+  this one.
 
 Scores, softmax statistics and accumulators are float32; operands go to the
 MXU in the dtype they arrive in (``precision=DEFAULT``: Mosaic refuses bf16
@@ -60,6 +64,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..threshold_search import key_values, kth_largest_key, order_keys
 from .mla_attention_kernel import decode_work_list
+from .paged_attention_kernel import _prefill_call, chunk_runs
 
 NEG_INF = -1e30
 
@@ -80,9 +85,13 @@ SELECT_CHUNK_TOKENS = 512
 TIE_ROOM = 128
 #: selected tokens whose copies one loop step of ``dsa_sparse_decode`` starts
 UNROLL = 8
-#: cached tokens one grid step of ``dsa_sparse_prefill`` attends over ...
+#: cached tokens one work item of ``dsa_sparse_prefill`` attends over (whole
+#: blocks: the dense tail prefill's chunk at the cells' pools) ...
 PREFILL_CHUNK_TOKENS = 256
-#: ... for this many tail queries (x query heads = rows)
+#: ... for this many tail queries (x a KV head's query heads = the rows of an
+#: item's matmul: 512 at 8 query heads a KV head, its float32 score tile 2 MB
+#: — twice what ``prefill_plan`` gives the dense form; inside the default
+#: scoped VMEM with the second chunk buffers, ``tests/test_obs_spans.py``)
 PREFILL_Q_TOKENS = 64
 #: tail queries whose ``[rows, T]`` index scores exist at once
 PREFILL_SCORE_ROWS = 512
@@ -166,12 +175,7 @@ def _index_kernel(tbl_ref, last_ref, grp_ref, chunk_ref, run_ref, q_ref,
 def _whole_runs(tables, grp, chunk, cb: int):
     """``[items]`` int32: 1 where work item ``(grp, chunk)``'s ``cb`` table
     entries are consecutive block ids (the chunk is one run of the pool)."""
-    G, mb = tables.shape
-    pad = -mb % cb
-    t = jnp.pad(tables, ((0, 0), (0, pad)), constant_values=-1)
-    t = t.reshape(G, (mb + pad) // cb, cb)
-    run = jnp.all(t[..., 1:] == t[..., :-1] + 1, axis=-1)
-    return run[grp, chunk].astype(jnp.int32)
+    return chunk_runs(tables, cb)[grp, chunk].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "interpret"))
@@ -460,94 +464,25 @@ def masked_decode_reference(q, k_pool, v_pool, tables, selected, active, *,
     return jnp.where(live[:, None, None], out.reshape(B, H, D), 0)
 
 
-# -- tail prefill: the dense form under causal AND selected ----------------------
+# -- tail prefill: the dense kernel under causal AND selected --------------------
 
-def _sparse_prefill_kernel(row_ref, start_ref, len_ref, q_ref, sc_ref,
-                           cut_ref, k_hbm, v_hbm, o_ref, k_ref, v_ref, sem,
-                           acc_ref, m_ref, l_ref, *, scale, cb, bs, mb, rep):
-    t, c = pl.program_id(0), pl.program_id(1)
-    nc = pl.num_programs(1)
-    ct = cb * bs
-    hkv, rows, D = q_ref.shape                 # rows: token-major, rep-minor
-    tq = rows // rep
-
-    @pl.when(c == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    q0 = start_ref[0] + t * tq                 # the tile's first position
-    # live: the chunk starts at or before the tile's last query, and the
-    # tile holds a real token of the prompt
-    live = jnp.logical_and(c * ct <= q0 + tq - 1, q0 < len_ref[0])
-
-    @pl.when(live)
-    def _compute():
-        def copies(j):
-            blk = row_ref[jnp.minimum(c * cb + j, mb - 1)]
-            dst = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            return [pltpu.make_async_copy(pool.at[blk], buf.at[dst],
-                                          sem.at[side])
-                    for side, (pool, buf) in enumerate(((k_hbm, k_ref),
-                                                        (v_hbm, v_ref)))]
-
-        def start(j, carry):
-            for cp in copies(j):
-                cp.start()
-            return carry
-
-        def wait(_j, carry):
-            for side, (pool, buf) in enumerate(((k_hbm, k_ref),
-                                                (v_hbm, v_ref))):
-                pltpu.make_async_copy(pool.at[0], buf.at[pl.ds(0, bs)],
-                                      sem.at[side]).wait()
-            return carry
-
-        n_blocks = jnp.minimum((q0 + tq - 1) // bs - c * cb + 1, cb)
-        jax.lax.fori_loop(0, n_blocks, start, 0)
-        jax.lax.fori_loop(0, n_blocks, wait, 0)
-        # kv heads lead, so each contraction is a head-batched matmul
-        k = jnp.swapaxes(k_ref[...].astype(jnp.float32), 0, 1
-                         ).astype(k_ref.dtype)              # [Hkv, ct, D]
-        v = jnp.swapaxes(v_ref[...].astype(jnp.float32), 0, 1)
-        kpos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (tq, ct), 1)
-        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, ct), 0)
-        # the selection as a float32 tile, so that what is repeated over a
-        # query's heads is a sublane-aligned array and no vector of bits
-        sel = jnp.where((kpos <= qpos) & (sc_ref[...] >= cut_ref[...]),
-                        1.0, 0.0)                            # [tq, ct]
-        keep = jnp.broadcast_to(sel[:, None, :], (tq, rep, ct)
-                                ).reshape(rows, ct) > 0.5
-        # a weight of zero does not hide a NaN: what was not copied goes
-        vpos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (1, ct, 1), 1)
-        v = jnp.where(vpos <= q0 + tq - 1, v, 0.0).astype(v_ref.dtype)
-        s = jnp.einsum("gqd,gkd->gqk", q_ref[...], k, precision=_DEFAULT,
-                       preferred_element_type=jnp.float32) * scale
-        s = jnp.where(keep[None], s, NEG_INF)               # [Hkv, rows, ct]
-        m_prev = m_ref[:, :, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        p = jnp.where(keep[None], jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
-        pv = jnp.einsum("gqk,gkd->gqd", p.astype(v.dtype), v,
-                        precision=_DEFAULT,
-                        preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(c == nc - 1)
-    def _done():
-        l = l_ref[:, :, 0:1]
-        o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
-                      ).astype(o_ref.dtype)
+def sparse_prefill_plan(S: int, block_size: int, max_blocks: int):
+    """``(tile, chunk_tokens)`` of the tail-prefill kernel under a selection,
+    for ``S`` tail queries: from the shapes alone (the engine's host counts
+    its ``prefill_items_*`` with it; a bucket and the ``PREFILL_SCORE_ROWS``
+    queries of it that one call takes have the same tile)."""
+    return (_pow2_tile(S, PREFILL_Q_TOKENS),
+            _whole_blocks(PREFILL_CHUNK_TOKENS, block_size, max_blocks)
+            * block_size)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def sparse_prefill(q, k_pool, v_pool, block_row, start, length, scores, cut,
                    *, scale: float, interpret=False):
-    """Tail-prefill attention off the block pool under causal AND selected.
+    """Tail-prefill attention off the block pool under causal AND selected:
+    the K/V tail-prefill kernel (``paged_attention_kernel._prefill_call``: a
+    work list of live (query tile, key chunk) items, the next item's chunk
+    fetched while this one multiplies, a run of the pool in one copy) with
+    the selection as one more condition of its mask.
 
     Args:
         q:         ``[S, H, D]`` tail queries at absolute positions
@@ -557,66 +492,22 @@ def sparse_prefill(q, k_pool, v_pool, block_row, start, length, scores, cut,
         block_row: ``[max_blocks]`` int32, the slot's row of the table.
         start:     int32 scalar: the first query's position.
         length:    int32 scalar: the prompt's real length; tiles wholly at
-                   or past it are not computed.
+                   or past it are not visited.
         scores:    ``[S, T]`` float32 index scores of every tail query
                    (places past a query's position may hold anything).
         cut:       ``[S]`` float32: a query keeps ``scores >= cut``.
 
     Returns:
-        ``[S, H, D]`` (zero in skipped tiles).
+        ``[S, H, D]``; zero in the rows at or past ``length``.
     """
-    S, H, D = q.shape
-    bs, hkv = k_pool.shape[1:3]
-    rep = H // hkv
-    mb = block_row.shape[0]
-    cb = _whole_blocks(PREFILL_CHUNK_TOKENS, bs, mb)
-    ct = cb * bs
-    n_chunks = -(-mb // cb)
-    tq = _pow2_tile(S, PREFILL_Q_TOKENS)
-    rows = tq * rep
-    pad = n_chunks * ct - scores.shape[1]
-    if pad:
-        scores = jnp.pad(scores, ((0, 0), (0, pad)))
-    # kv head g serves query heads g*rep .. g*rep+rep-1: q_g[g, s*rep + r]
-    q_g = q.reshape(S, hkv, rep, D).transpose(1, 0, 2, 3
-                                              ).reshape(hkv, S * rep, D)
-    kernel = functools.partial(_sparse_prefill_kernel, scale=scale,
-                               cb=cb, bs=bs, mb=mb, rep=rep)
-    qo_spec = pl.BlockSpec((hkv, rows, D), lambda t, c, r, st, ln: (0, t, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S // tq, n_chunks),
-        in_specs=[
-            qo_spec,
-            pl.BlockSpec((tq, ct), lambda t, c, r, st, ln: (t, c)),
-            pl.BlockSpec((tq, 1), lambda t, c, r, st, ln: (t, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=qo_spec,
-        scratch_shapes=[
-            pltpu.VMEM((ct, hkv, D), k_pool.dtype),
-            pltpu.VMEM((ct, hkv, D), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((hkv, rows, D), jnp.float32),
-            pltpu.VMEM((hkv, rows, 128), jnp.float32),
-            pltpu.VMEM((hkv, rows, 128), jnp.float32),
-        ],
-    )
-    o_g = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, S * rep, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-        name="dsa_sparse_prefill",
-    )(block_row.astype(jnp.int32),
-      jnp.asarray(start, jnp.int32).reshape(1),
-      jnp.asarray(length, jnp.int32).reshape(1), q_g,
-      scores.astype(jnp.float32), cut.astype(jnp.float32)[:, None],
-      k_pool, v_pool)
-    return o_g.reshape(hkv, S, rep, D).transpose(1, 0, 2, 3).reshape(S, H, D)
+    ts, ct = sparse_prefill_plan(q.shape[0], k_pool.shape[1],
+                                 block_row.shape[0])
+    return _prefill_call(
+        q[None], k_pool, v_pool, block_row,
+        jnp.asarray(start, jnp.int32).reshape(()),
+        jnp.asarray(length, jnp.int32).reshape(()), tile=ts, chunk_tokens=ct,
+        window=0, interpret=interpret, scores=scores, cut=cut[:, None],
+        scale=scale)[0]
 
 
 def masked_prefill_reference(q, k_pool, v_pool, block_row, selected, *,

@@ -51,27 +51,49 @@ slot's K/V in HBM:
   last *real* row; a tile of padding has none, is never visited, and its
   output rows are zeros (the wrapper's, with the pad rows of the last real
   tile).  Tile and chunk come from ``prefill_plan``, a function of the shapes
-  alone: the chunk is the decode kernel's (256 tokens at both cells'
-  pools), the tile the largest power-of-two divisor of the bucket whose
-  float32 score tile ``[Hkv, rep * tile, chunk]`` is at most 1 MiB (32 rows
-  at 4 KV heads of 8 query heads, 64 at 16 heads of their own), which keeps
-  the kernel inside 16 MiB of scoped VMEM up to 32 KV heads of 128.  The
-  pools stay in HBM; an item copies its chunk's blocks by table value, one
-  DMA a block and side, all started then all awaited in two ``fori_loop``s —
-  none past the tile's last real row, none wholly behind its first row's
-  window (a released table entry is never followed).  Queries reach the
-  kernel as ``[Hkv, tiles * rep * tile, D]`` in the pool's dtype, so a KV
-  head's ``rep * tile`` query rows are the rows of ONE matmul against the
-  chunk's keys and of one against its values (``precision=DEFAULT``, float32
-  statistics and accumulators); ``rep`` 1 is the same contraction with
-  fewer rows.  The absolute-position causal mask ``kpos <= qpos`` (and
-  ``kpos > qpos - window``) is the fused replacement for the gather +
-  two-phase mask of ``block_prefill_attention``; values no real row of the
-  tile may read are dropped before the product.  The grid is static
-  (``prefill_places``: tiles x the row's chunks, or x a window's), the live
-  count rides in scalar prefetch, and a place past it keeps the last item's
-  tile.  The entry is jitted on its static arguments, so a model's layers
-  trace and lower it once a (shape, window).
+  alone: the chunk is the decode kernel's (256 tokens at the cells' pools),
+  the tile the largest power-of-two divisor of the bucket whose float32 score
+  tile ``[Hkv, rep * tile, chunk]`` is at most 1 MiB (32 rows at 4 KV heads
+  of 8 query heads, 64 at 16 heads of their own), which keeps the kernel
+  inside 16 MiB of scoped VMEM up to 32 KV heads of 128.  **The copies are
+  off an item's critical path**: the pools stay in HBM, the kernel keeps TWO
+  chunk buffers a side, and the grid has one step more than the list has
+  items: step ``s`` starts the copies of item ``s`` into buffer ``s % 2`` and
+  then awaits and multiplies item ``s - 1`` in the other, whose copies the
+  step before started (the first step only fetches, the last only
+  multiplies).  One site starts and one awaits, by one rule: an item's tile,
+  chunk and live blocks come from its place of the list through
+  ``prefill_item_blocks``, so what is awaited is what was started, and no
+  copy is outstanding when the list ends.  **A run of the pool comes in one
+  copy a side**: ``chunk_runs`` flags, a chunk of the slot's block row,
+  whether its table entries are consecutive block ids (a document prefilled
+  into a fresh pool lies so), in scalar prefetch; a flagged chunk whose every
+  block is live is ONE descriptor a side, any other — a chunk of scattered
+  blocks, or one the tile's last real row or its first row's window cuts —
+  one a live block by table value, none past the tile's last real row, none
+  wholly behind its first row's
+  window (a released or stale table entry is never followed).  The decision
+  reads the table and the lengths, nothing else.  Queries reach the kernel as
+  ``[Hkv, tiles * rep * tile, D]`` in the pool's dtype, so a KV head's ``rep
+  * tile`` query rows are the rows of ONE matmul against the chunk's keys and
+  of one against its values (``precision=DEFAULT``, float32 statistics and
+  accumulators); ``rep`` 1 is the same contraction with fewer rows.  The
+  absolute-position causal mask ``kpos <= qpos`` (and ``kpos > qpos -
+  window``) is the fused replacement for the gather + two-phase mask of
+  ``block_prefill_attention``; values no real row of the tile may read are
+  dropped before the product, whatever a copy brought.  Given ``scores`` and
+  ``cut`` (``_prefill_call``; ``dsa_attention_kernel.sparse_prefill`` gives
+  them) the mask has one more condition, ``scores >= cut``, the item's ``[tile,
+  chunk]`` tile of the scores read beside the queries and repeated over a KV
+  head's query heads: the indexed model's tail prefill is this kernel, under
+  the name ``dsa_sparse_prefill``.  The grid's bound is the list's live count
+  and one (an idle place past it cost 0.05 us, and a 16-token tail's 121
+  items in a 256-row bucket had 903 of them behind: 6 % of the call); the
+  list's arrays are static (``prefill_places``: tiles x the row's chunks, or
+  x a window's).  No reader of this kernel tells it by its operands' order
+  (``swa_paged_prefill.PATTERNS`` is its name).  The entry is jitted on its
+  static arguments, so a model's layers trace and lower it once a (shape,
+  window).
 
 GQA stays inside the kernels with no repeat: the wrappers lay queries
 out so kv head ``g`` serves query heads ``g * rep .. g * rep + rep - 1``
@@ -464,9 +486,10 @@ def prefill_plan(S: int, kv_heads: int, rep: int, lanes: int, itemsize: int,
 
 def prefill_places(S: int, tile: int, chunk_tokens: int, max_blocks: int,
                    block_size: int, window: int) -> int:
-    """The static size of the prefill kernel's grid: a query tile's chunks
-    are the slot's whole row at most, and with a window those from its first
-    row's oldest key to its last row."""
+    """The static size of the prefill kernel's work list (its grid's bound
+    is the live count and one): a query tile's chunks are the slot's whole
+    row at most, and with a window those from its first row's oldest key to
+    its last row."""
     chunks = -(-max_blocks * block_size // chunk_tokens)
     if window:
         chunks = min(chunks, (window + tile - 2) // chunk_tokens + 2)
@@ -488,82 +511,175 @@ def prefill_tile_chunks(start, length, *, S: int, tile: int,
 
 
 def prefill_work_list(start, length, *, S: int, tile: int, chunk_tokens: int,
-                      window: int, places: int):
+                      window: int, places: int, xp=jnp):
     """``(tile, chunk, n)``: the (query tile, key chunk) pairs of
     :func:`prefill_tile_chunks`, tile-major and chunks ascending, in the
-    first ``n`` of ``places`` places."""
+    first ``n`` of ``places`` places.  With ``xp=numpy`` the host walks the
+    kernel's own list."""
     first, per = prefill_tile_chunks(start, length, S=S, tile=tile,
-                                     chunk_tokens=chunk_tokens, window=window)
-    ends = jnp.cumsum(per)
+                                     chunk_tokens=chunk_tokens, window=window,
+                                     xp=xp)
+    ends = xp.cumsum(per)
     n = ends[-1]
-    idx = jnp.arange(places, dtype=jnp.int32)
-    t = jnp.minimum(jnp.searchsorted(ends, idx, side="right"), S // tile - 1)
+    idx = xp.arange(places, dtype=xp.int32)
+    t = xp.minimum(xp.searchsorted(ends, idx, side="right"), S // tile - 1)
     chunk = idx - (ends - per)[t] + first[t]
-    # a place past the list keeps the last item's tile: its index maps move
-    # no query tile in and, above all, no output tile out
-    t = jnp.where(idx < n, t, t[jnp.maximum(n - 1, 0)])
-    return (t.astype(jnp.int32), jnp.where(idx < n, chunk, 0).astype(
-        jnp.int32), n.astype(jnp.int32))
+    # the grid's bound is ``n + 1``: no step reads a place past the list
+    # (they keep the last item's tile)
+    t = xp.where(idx < n, t, t[xp.maximum(n - 1, 0)])
+    return (t.astype(xp.int32), xp.where(idx < n, chunk, 0).astype(
+        xp.int32), n.astype(xp.int32))
 
 
-def _prefill_kernel(row_ref, start_ref, len_ref, tile_ref, chunk_ref, n_ref,
-                    q_ref, k_hbm, v_hbm, o_ref, k_ref, v_ref, sem, acc_ref,
-                    m_ref, l_ref, *, scale, bs, mb, ts, window):
-    i = pl.program_id(0)
-    ct = k_ref.shape[0]
-    cb = ct // bs
+def chunk_runs(tables, chunk_blocks: int, xp=jnp):
+    """``[..., chunks]`` bool of block tables ``[..., max_blocks]``: where a
+    chunk's ``chunk_blocks`` entries are consecutive block ids, so that the
+    chunk is one run of the pool and comes in one copy (a document prefilled
+    into a fresh pool lies so).  A last chunk the row does not fill is none.
+    With ``xp=numpy`` the host counts by the same rule."""
+    mb = tables.shape[-1]
+    pad = -mb % chunk_blocks
+    t = xp.pad(tables, [(0, 0)] * (tables.ndim - 1) + [(0, pad)],
+               constant_values=-1)
+    t = t.reshape(tables.shape[:-1] + ((mb + pad) // chunk_blocks,
+                                       chunk_blocks))
+    return xp.all(t[..., 1:] == t[..., :-1] + 1, axis=-1)
+
+
+def prefill_item_blocks(t, c, start, length, *, tile: int, block_size: int,
+                        chunk_blocks: int, window: int, xp=jnp):
+    """``(q0, hi, lo, head, live)`` of the work item (query tile ``t``, key
+    chunk ``c``): the tile's first position, the keys ``lo .. hi`` its real
+    rows may read (up to its last real row, from its first row's oldest key)
+    and the chunk's blocks ``head .. live - 1`` that hold one of them — the
+    blocks an item copies.  The kernel asks it of the item it multiplies and
+    of the one it fetches for; with ``xp=numpy`` the host counts by it."""
+    q0 = start + t * tile
+    hi = xp.minimum(q0 + tile, length) - 1
+    lo = xp.maximum(q0 - (window - 1), 0) if window else xp.zeros_like(q0)
+    live = xp.minimum(hi // block_size - c * chunk_blocks + 1, chunk_blocks)
+    head = xp.maximum(lo // block_size - c * chunk_blocks, 0)
+    return q0, hi, lo, head, live
+
+
+def prefill_one_copy(run, head, live, chunk_blocks: int):
+    """Whether an item's chunk comes in ONE copy a side: its blocks are a run
+    of the pool (``run``, :func:`chunk_runs`) and every one of them is live
+    (:func:`prefill_item_blocks`), so that neither the tile's last real row
+    nor its window cuts it.  The kernel's rule, and the host's count."""
+    return run & (head == 0) & (live == chunk_blocks)
+
+
+def prefill_item_counts(block_row, start, length, *, S: int, tile: int,
+                        chunk_tokens: int, block_size: int, window: int):
+    """``(items, run_items)`` of the tail ``[start, length)``: the work items
+    the kernel walks, and those of them whose chunk comes in ONE copy a side,
+    from ``block_row`` (numpy, the slot's table row as the host has it): the
+    kernel's own list (:func:`prefill_work_list`) and rule
+    (:func:`prefill_one_copy`), on the host."""
+    import numpy as np
+
+    cb = chunk_tokens // block_size
+    t, c, n = prefill_work_list(
+        np.int32(start), np.int32(length), S=S, tile=tile,
+        chunk_tokens=chunk_tokens, window=window, xp=np,
+        places=prefill_places(S, tile, chunk_tokens, len(block_row),
+                              block_size, window))
+    t, c = t[:n], c[:n]
+    _, _, _, head, live = prefill_item_blocks(
+        t, c, start, length, tile=tile, block_size=block_size,
+        chunk_blocks=cb, window=window, xp=np)
+    runs = chunk_runs(np.asarray(block_row), cb, xp=np)
+    return int(n), int(np.sum(prefill_one_copy(runs[c], head, live, cb)))
+
+
+def _prefill_kernel(row_ref, start_ref, len_ref, tile_ref, chunk_ref, run_ref,
+                    q_ref, *refs, scale, bs, mb, ts, window, selected):
+    if selected:                                 # causal AND scores >= cut
+        sc_ref, cut_ref, *refs = refs
+    k_hbm, v_hbm, o_ref, k_ref, v_ref, sem, acc_ref, m_ref, l_ref = refs
+    step = pl.program_id(0)
+    n = pl.num_programs(0) - 1                   # a step more than items
+    cb = k_ref.shape[1]
+    ct = cb * bs
     rows = q_ref.shape[1]                        # rep * ts: row r * ts + j
 
-    @pl.when(i < n_ref[0])                       # places past the list: idle
-    def _item():
-        t, c = tile_ref[i], chunk_ref[i]
-        q0 = start_ref[0] + t * ts               # tile's first abs position
-        # the keys the tile's real rows may read: up to its last real row,
-        # from its first row's oldest key
-        hi = jnp.minimum(q0 + ts, len_ref[0]) - 1
-        lo = jnp.maximum(q0 - (window - 1), 0) if window else 0
+    def item(at):
+        t, c = tile_ref[at], chunk_ref[at]
+        return (c,) + prefill_item_blocks(
+            t, c, start_ref[0], len_ref[0], tile=ts, block_size=bs,
+            chunk_blocks=cb, window=window)
 
-        @pl.when(c == lo // ct)                  # the tile's first chunk
+    def copies(it, buf, go):
+        """Start (``go``) or await the copies of the chunk of ``it`` (an
+        :func:`item`) into buffer ``buf``, by the one rule both sides of a
+        copy read: the whole chunk in ONE copy a side where its blocks are a
+        run of the pool and all live, else one a live block, by table value —
+        none past the tile's last real row, none wholly behind its first
+        row's window (a released entry is never followed; what a buffer holds
+        there is dropped below).  An await needs a copy's size and semaphore,
+        not its source: it reads no table."""
+        c, _q0, _hi, _lo, head, live = it
+        sides = tuple(enumerate(((k_hbm, k_ref), (v_hbm, v_ref))))
+        whole = prefill_one_copy(run_ref[c] == 1, head, live, cb)
+        if k_hbm.shape[0] < cb:
+            # a pool smaller than a chunk (a window group sized for one short
+            # sequence) holds no run of one, and no slice of one can be taken
+            whole = False
+
+        @pl.when(whole)
+        def _one_copy():
+            first = row_ref[c * cb] if go else 0
+            for side, (pool, dst) in sides:
+                cp = pltpu.make_async_copy(pool.at[pl.ds(first, cb)],
+                                           dst.at[buf], sem.at[buf, side, 0])
+                (cp.start if go else cp.wait)()
+
+        @pl.when(jnp.logical_not(whole))
+        def _a_copy_a_block():
+            def block(j, carry):
+                blk = row_ref[jnp.minimum(c * cb + j, mb - 1)] if go else 0
+                for side, (pool, dst) in sides:
+                    cp = pltpu.make_async_copy(pool.at[blk], dst.at[buf, j],
+                                               sem.at[buf, side, j])
+                    (cp.start if go else cp.wait)()
+                return carry
+
+            jax.lax.fori_loop(head, live, block, 0)
+
+    # one step more than the list has items: step ``s`` starts the copies of
+    # item ``s`` into buffer ``s % 2`` (the last step has none to start), then
+    # awaits and multiplies item ``s - 1`` in the other — whose copies the
+    # step before started while the item before multiplied (the first step
+    # only fetches).  ONE site starts and one awaits, by the same rule, and no
+    # copy is outstanding when the list ends.
+    @pl.when(step < n)
+    def _fetch():
+        copies(item(step), step % 2, True)
+
+    @pl.when(step > 0)
+    def _item():
+        i = step - 1
+        cur, buf = item(i), i % 2
+        copies(cur, buf, False)
+        c, q0, hi, lo, _head, _live = cur
+
+        @pl.when(c == lo // ct)                      # the tile's first chunk
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-        # one copy a live block of the chunk and side, by table value, all
-        # started then all awaited (the decode kernel's pattern): no block
-        # past the tile's last real row, none wholly behind its first row's
-        # window (what the buffers hold there is dropped below)
-        def block_copies(j):
-            blk = row_ref[jnp.minimum(c * cb + j, mb - 1)]
-            row = pl.ds(pl.multiple_of(j * bs, bs), bs)
-            return [pltpu.make_async_copy(pool.at[blk], buf.at[row],
-                                          sem.at[side, j])
-                    for side, (pool, buf) in enumerate(((k_hbm, k_ref),
-                                                        (v_hbm, v_ref)))]
-
-        def start(j, carry):
-            for cp in block_copies(j):
-                cp.start()
-            return carry
-
-        def wait(j, carry):
-            for cp in block_copies(j):
-                cp.wait()
-            return carry
-
-        live = jnp.minimum(hi // bs - c * cb + 1, cb)
-        head = jnp.maximum(lo // bs - c * cb, 0)
-        jax.lax.fori_loop(head, live, start, 0)
-        jax.lax.fori_loop(head, live, wait, 0)
-
-        op = k_ref.dtype                         # the MXU's operands
+        op = k_ref.dtype                             # the MXU's operands
         # kv heads lead: a KV head's rep * ts query rows are the rows of ONE
         # matmul against the chunk's keys, and of one against its values
-        k = jnp.swapaxes(k_ref[...].astype(jnp.float32), 0, 1)  # [Hkv,ct,D]
-        v = jnp.swapaxes(v_ref[...].astype(jnp.float32), 0, 1)
+        k = jnp.swapaxes(k_ref[buf].reshape((ct,) + k_ref.shape[3:]
+                                            ).astype(jnp.float32), 0, 1)
+        v = jnp.swapaxes(v_ref[buf].reshape((ct,) + v_ref.shape[3:]
+                                            ).astype(jnp.float32), 0, 1)
         pos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (1, ct, 1), 1)
-        # a weight of zero does not hide a NaN: values no real row of the
-        # tile may read (past the prompt, or in a block not copied) go
+        # a weight of zero does not hide a NaN: values no real row of the tile
+        # may read (past the prompt, or in a block not copied) go
         v = jnp.where((pos <= hi) & (pos >= lo), v, 0.0)
         s = jnp.einsum("grd,gkd->grk", q_ref[...], k.astype(op),
                        precision=jax.lax.Precision.DEFAULT,
@@ -573,26 +689,35 @@ def _prefill_kernel(row_ref, start_ref, len_ref, tile_ref, chunk_ref, n_ref,
         # a pad row of the tile reads what the last real row reads
         qpos = jnp.minimum(q0 + j, hi)
         kpos = c * ct + jax.lax.broadcasted_iota(jnp.int32, (rows, ct), 1)
-        mask = kpos <= qpos                      # abs-position causal mask
+        mask = kpos <= qpos                          # abs-position causal mask
         if window:
             mask &= kpos > qpos - window
-        s = jnp.where(mask[None], s, NEG_INF)    # [Hkv, rows, ct]
-        m_prev = m_ref[:, :, 0:1]
+        if selected:
+            # the selection of the tile's ts queries, the same for a query's
+            # rep heads: rep copies of one sublane-aligned float32 tile (no
+            # vector of bits is repeated)
+            sel = jnp.where(sc_ref[...] >= cut_ref[...], 1.0, 0.0)
+            mask &= jnp.concatenate([sel] * (rows // ts), axis=0) > 0.5
+        s = jnp.where(mask[None], s, NEG_INF)        # [Hkv, rows, ct]
+        m_prev = m_ref[...]                          # [Hkv, rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         # a row that has met no key of its own yet keeps nothing
         p = jnp.where(mask[None], jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_ref[:, :, 0:1] * corr + jnp.sum(p, axis=2, keepdims=True)
+        l_new = l_ref[...] * corr + jnp.sum(p, axis=2, keepdims=True)
         acc_ref[...] = acc_ref[...] * corr + jnp.einsum(
             "grk,gkd->grd", p.astype(op), v.astype(op),
             precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[...] = m_new
+        l_ref[...] = l_new
 
-        @pl.when(c == hi // ct)                  # the tile's last chunk
-        def _finalize():                         # l > 0: a row reads itself
-            o_ref[...] = (acc_ref[...] / l_ref[:, :, 0:1]).astype(o_ref.dtype)
+        @pl.when(c == hi // ct)                      # the tile's last chunk
+        def _finalize():
+            l = l_ref[...]                       # > 0: a row reads itself
+            if selected:                         # but a pad row's selection
+                l = jnp.where(l == 0.0, 1.0, l)  # may hold no key at all
+            o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
@@ -644,42 +769,72 @@ def paged_prefill_attention_kernel(q, k_pool, v_pool, block_row, start,
 # jitted like the decode kernel's entry: a model's layers trace and lower the
 # kernel once a (shape, window), not once each
 @functools.partial(jax.jit, static_argnames=(
-    "tile", "chunk_tokens", "window", "interpret"))
+    "tile", "chunk_tokens", "window", "interpret", "scale"))
 def _prefill_call(q, k_pool, v_pool, block_row, start, length, *, tile,
-                  chunk_tokens, window, interpret):
+                  chunk_tokens, window, interpret, scores=None, cut=None,
+                  scale=None):
+    """The tail-prefill kernel on ``tile`` query rows and ``chunk_tokens``
+    keys an item.  ``scores [S, T]`` and ``cut [S, 1]`` (float32) put one
+    more condition in the mask: query ``s`` reads key ``t`` only where
+    ``scores[s, t] >= cut[s]`` (the indexed model's selection; the kernel's
+    name is then ``dsa_sparse_prefill``); ``scale`` is the softmax scale
+    where it is not the queries' width ``** -0.5``."""
     _, S, H, head_dim = q.shape
     bs, Hkv, D = k_pool.shape[1:]
     rep = H // Hkv
     mb = block_row.shape[0]
     ts, ct = tile, chunk_tokens
     tiles = S // ts
-    scale = 1.0 / (head_dim ** 0.5)
+    selected = scores is not None
     places = prefill_places(S, ts, ct, mb, bs, window)
     t, chunk, n = prefill_work_list(start, length, S=S, tile=ts,
                                     chunk_tokens=ct, window=window,
                                     places=places)
-    kernel = functools.partial(_prefill_kernel, scale=scale, bs=bs, mb=mb,
-                               ts=ts, window=window)
+    block_row = block_row.astype(jnp.int32)
+    kernel = functools.partial(
+        _prefill_kernel, bs=bs, mb=mb, ts=ts, window=window,
+        selected=selected,
+        scale=1.0 / (head_dim ** 0.5) if scale is None else scale)
     # query head h = g * rep + r, tile t, row j  ->  q_g[g, t, r * ts + j]:
     # a tile's block is the rows of one matmul a KV head, no in-kernel repeat
     q_g = _to_lanes(q[0], D).astype(k_pool.dtype).reshape(
         tiles, ts, Hkv, rep, D).transpose(2, 0, 3, 1, 4).reshape(
         Hkv, tiles * rep * ts, D)
+    # (a step multiplies the item before its own place; the first, none)
     qo_spec = pl.BlockSpec(
-        (Hkv, rep * ts, D), lambda i, row, st, ln, tl, ch, n: (0, tl[i], 0))
+        (Hkv, rep * ts, D),
+        lambda s, row, st, ln, tl, ch, ru: (0, tl[jnp.maximum(s - 1, 0)], 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    selection = []
+    if selected:
+        # the item's tile of the scores and its queries' cuts, beside them
+        pad = -scores.shape[1] % ct
+        selection = [jnp.pad(scores.astype(jnp.float32), ((0, 0), (0, pad))),
+                     cut.astype(jnp.float32)]
+        sel_specs = [
+            pl.BlockSpec((ts, ct), lambda s, row, st, ln, tl, ch, ru:
+                         (tl[jnp.maximum(s - 1, 0)],
+                          ch[jnp.maximum(s - 1, 0)])),
+            pl.BlockSpec((ts, 1), lambda s, row, st, ln, tl, ch, ru:
+                         (tl[jnp.maximum(s - 1, 0)], 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
-        grid=(places,),                          # static: see the docstring
-        in_specs=[qo_spec, pool_spec, pool_spec],
+        grid=(n + 1,),                           # an item a step, and one
+        in_specs=[qo_spec] + (sel_specs if selected else [])
+        + [pool_spec, pool_spec],
         out_specs=qo_spec,
         scratch_shapes=[
-            pltpu.VMEM((ct, Hkv, D), k_pool.dtype),
-            pltpu.VMEM((ct, Hkv, D), v_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, ct // bs)),
+            # two buffers a side: an item multiplies one while the next
+            # item's chunk arrives in the other
+            pltpu.VMEM((2, ct // bs, bs, Hkv, D), k_pool.dtype),
+            pltpu.VMEM((2, ct // bs, bs, Hkv, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, ct // bs)),
             pltpu.VMEM((Hkv, rep * ts, D), jnp.float32),
-            pltpu.VMEM((Hkv, rep * ts, 128), jnp.float32),
-            pltpu.VMEM((Hkv, rep * ts, 128), jnp.float32),
+            # the softmax's running maximum and sum, one number a row: read
+            # and written whole every item (128 lanes of it cost an item of
+            # 512 rows 0.4 us on the chip)
+            pltpu.VMEM((Hkv, rep * ts, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rep * ts, 1), jnp.float32),
         ],
     )
     o_g = pl.pallas_call(
@@ -689,9 +844,10 @@ def _prefill_call(q, k_pool, v_pool, block_row, start, length, *, tile,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_prefill_attention",
-    )(block_row.astype(jnp.int32), start.reshape(1), length.reshape(1), t,
-      chunk, n.reshape(1), q_g, k_pool, v_pool)
+        name="dsa_sparse_prefill" if selected else "paged_prefill_attention",
+    )(block_row, start.reshape(1), length.reshape(1), t, chunk,
+      chunk_runs(block_row, ct // bs).astype(jnp.int32), q_g, *selection,
+      k_pool, v_pool)
     out = o_g.reshape(Hkv, tiles, rep, ts, D).transpose(1, 3, 0, 2, 4).reshape(
         S, H, D)[..., :head_dim]
     # the tiles never visited, and the pad rows of the last one that was

@@ -736,7 +736,8 @@ class Engine:
         #: tokens attended to and tokens cached, summed over running slots
         #: and averaged over the layers
         self._sparse = {"steps": 0, "selected": 0, "context": 0,
-                        "prefills": 0, "prefill_context": 0}
+                        "prefills": 0, "prefill_context": 0,
+                        "prefill_items_full": 0, "prefill_items_run": 0}
         #: decode steps by the way their program went through the
         #: sampler (``sampling.sampler_path``)
         self._sampler_steps = {"steps_greedy": 0, "steps_sampled": 0}
@@ -756,8 +757,8 @@ class Engine:
         self._swa = {"steps": 0, "full_rows": 0, "window_rows": 0,
                      "context": 0, "blocks_released_decode": 0,
                      "blocks_released_prefill": 0, "prefill_items_full": 0,
-                     "prefill_items_window": 0, "prefill_tile_rows": 0,
-                     "prefill_real_rows": 0}
+                     "prefill_items_window": 0, "prefill_items_run": 0,
+                     "prefill_tile_rows": 0, "prefill_real_rows": 0}
         #: a group that keeps state: slots whose state the decode steps
         #: rewrote, and prefills by the state they started from
         self._state = {"steps": 0, "slots": 0, "prefills": 0,
@@ -1707,12 +1708,14 @@ class Engine:
                     sp.attrs["dsa_context"] = scored
                     self._sparse["prefills"] += scored > 0
                     self._sparse["prefill_context"] += scored
+                    self._note_indexed_prefill(sp, req.slot, start, end,
+                                               scored > 0)
                 if self.cache_spec.kind == "windowed":
                     self._note_prefill_windows(sp, start, end)
                 if self.cache_spec.kind == "latent":
                     self._note_prefill_pairs(sp, start, end)
                 if self.cache_spec.layer_groups:
-                    self._note_group_prefill(sp, start, end)
+                    self._note_group_prefill(sp, req.slot, start, end)
                     if self.cache.states:
                         self._note_state_prefill(sp, req)
                 return self._step_call("serving.prefill",
@@ -2224,7 +2227,8 @@ class Engine:
                     state_snapshots=first.num_blocks
                     - first.allocator.reserved)
 
-    def _note_group_prefill(self, sp, start: int, L: int) -> None:
+    def _note_group_prefill(self, sp, slot: int, start: int,
+                            L: int) -> None:
         """What the prefill of ``[start, L)`` must read in one layer of each
         kind: ``rows``, the keys its real queries attend to summed over the
         queries, and ``keys``, the distinct ones behind them."""
@@ -2237,18 +2241,36 @@ class Engine:
         # kind of layer, and the rows it multiplies against the rows asked
         # for, from the kernel's own plan and list (nothing under
         # ``kernel="reference"``, which has neither)
-        work = {"prefill_real_rows": L - start}
+        work = {"prefill_real_rows": L - start, "prefill_items_run": 0}
         for pool in self.cache.pools:
-            got = pool.prefill_work(sp.attrs["bucket"], start, L,
+            got = pool.prefill_work(slot, sp.attrs["bucket"], start, L,
                                     self.config.num_attention_heads)
             if got is None:
                 return
             work["prefill_items_window" if pool.kv_window
                  else "prefill_items_full"] = got[0]
             work.setdefault("prefill_tile_rows", got[1])
+            work["prefill_items_run"] += got[2]
         sp.set(**work)
         for k, v in work.items():
             self._swa[k] += v
+
+    def _note_indexed_prefill(self, sp, slot: int, start: int, L: int,
+                              indexed: bool) -> None:
+        """A layer's work items of the tail-prefill kernel in an indexed
+        pool, under the selection (``indexed``) or dense, and those of them
+        whose chunk is one run of the pool: under the names a cache stated by
+        layer gives them (no layer here keeps a window: no
+        ``prefill_items_window``)."""
+        got = self.cache.prefill_work(slot, sp.attrs["bucket"], start, L,
+                                      self.config.num_attention_heads,
+                                      indexed=indexed)
+        if got is None:                  # ``kernel="reference"``: no list
+            return
+        work = {"prefill_items_full": got[0], "prefill_items_run": got[2]}
+        sp.set(**work)
+        for k, v in work.items():
+            self._sparse[k] += v
 
     def _note_state_prefill(self, sp, req: Request) -> None:
         """What the program about to run does to the groups that keep state,

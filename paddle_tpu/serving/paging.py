@@ -976,27 +976,38 @@ class PagedKVCache:
         return lambda seq_len: seq_len // ct - max(0, seq_len - w + 1) // ct \
             + 1
 
-    def prefill_work(self, bucket: int, start: int, length: int,
-                     query_heads: int) -> Optional[Tuple[int, int]]:
-        """``(items, tile_rows)``: what one layer of this pool costs the
-        Pallas tail-prefill kernel for the tail ``[start, length)`` in a
-        ``bucket``-row program — the (query tile, key chunk) work items it
-        walks and the query rows it multiplies (whole tiles) — by the
-        kernel's own plan and list, on the host; None under
-        ``kernel="reference"``, which has no work list."""
+    def prefill_work(self, slot: int, bucket: int, start: int, length: int,
+                     query_heads: int, *, indexed: bool = False
+                     ) -> Optional[Tuple[int, int, int]]:
+        """``(items, tile_rows, run_items)``: what one layer of this pool
+        costs the Pallas tail-prefill kernel for ``slot``'s tail ``[start,
+        length)`` in a ``bucket``-row program — the (query tile, key chunk)
+        work items it walks, the query rows it multiplies (whole tiles), and
+        the items whose chunk comes in one copy a side (a run of the pool:
+        from the block ids the allocator holds for the slot, no pull from the
+        device) — by the kernel's own plan, list and rule, on the host.
+        ``indexed``: the tail takes the kernel under the indexer's selection
+        (a prompt past ``topk``; its tile is the same for the bucket as for
+        the ``PREFILL_SCORE_ROWS`` queries of a call).
+        None under ``kernel="reference"``, which has no work list."""
         if self.kernel != "pallas":
             return None
+        from ..ops.pallas import dsa_attention_kernel as dsa
         from ..ops.pallas import paged_attention_kernel as pk
 
         arr = self.sides[0][0]._value()
         _, bs, heads, lanes = arr.sharding.shard_shape(arr.shape)
-        ts, ct = pk.prefill_plan(bucket, heads, query_heads // arr.shape[2],
-                                 lanes, arr.dtype.itemsize, bs,
-                                 self.max_blocks_per_slot)
-        _, count = pk.prefill_tile_chunks(
-            np.int32(start), np.int32(length), S=bucket, tile=ts,
-            chunk_tokens=ct, window=self.kv_window, xp=np)
-        return int(count.sum()), -(-(length - start) // ts) * ts
+        mb = self.max_blocks_per_slot
+        if indexed:
+            ts, ct = dsa.sparse_prefill_plan(bucket, bs, mb)
+        else:
+            ts, ct = pk.prefill_plan(bucket, heads,
+                                     query_heads // arr.shape[2], lanes,
+                                     arr.dtype.itemsize, bs, mb)
+        items, runs = pk.prefill_item_counts(
+            self.table_rows(slot)[0], start, length, S=bucket, tile=ts,
+            chunk_tokens=ct, block_size=bs, window=self.kv_window)
+        return items, -(-(length - start) // ts) * ts, runs
 
     def layer_nbytes(self) -> int:
         """Bytes of one layer's buffer of the first side (K and V are

@@ -318,6 +318,57 @@ def test_sparse_decode_and_prefill_kernels_equal_their_oracles(kernel_dtype):
         np.testing.assert_allclose(a[:real], b[:real], atol=tol)
 
 
+@pytest.mark.parametrize("table", ["shuffled", "runs", "mixed"])
+@pytest.mark.parametrize("start,real", [
+    (512, 100),      # two tiles of 64 queries, the last part padding
+    (0, 128),        # cold: a tile's list ends in the chunk it lies in
+    (256, 7),        # one real tile of two: the other is never visited
+])
+def test_the_tail_kernel_under_a_selection_keeps_every_tie(table, start,
+                                                            real):
+    """``sparse_prefill`` (the K/V tail-prefill kernel with the selection in
+    its mask) against the masked oracle, on chunks of 256 keys that come in
+    one copy (``runs``), block by block (``shuffled``) or both (``mixed``):
+    scores drawn from five values, so that many keys tie at a query's cut and
+    all are kept; the rows past the real length — whose scores, in a group no
+    query of which is real, are whatever the buffer held: NaN here — come out
+    zero."""
+    rng = np.random.default_rng(start + real)
+    NB, bs, hkv, rep, D, mb, S, topk = 130, 8, 2, 2, 128, 96, 128, 48
+    kp, vp = (jnp.asarray(rng.normal(size=(NB, bs, hkv, D)), jnp.float32)
+              for _ in range(2))
+    row = np.arange(1, mb + 1)
+    if table != "runs":
+        for c in range(0 if table == "shuffled" else 1, mb // 32, 2):
+            row[c * 32:(c + 1) * 32] = rng.permutation(row[c * 32:(c + 1) * 32])
+    if table == "shuffled":
+        row = rng.permutation(row)
+    assert np.asarray(dsa._whole_runs(
+        jnp.asarray(row[None]), jnp.zeros(3, jnp.int32), jnp.arange(3), 32)
+        ).tolist() == {"runs": [1, 1, 1], "mixed": [1, 0, 1],
+                       "shuffled": [0, 0, 0]}[table]
+    T = mb * bs
+    q = jnp.asarray(rng.normal(size=(S, hkv * rep, D)), jnp.float32)
+    scores = rng.integers(0, 5, (S, T)).astype(np.float32)
+    qpos = start + np.arange(S)
+    visible = np.arange(T)[None, :] <= qpos[:, None]
+    cut = np.asarray(dsa.selection_cut_reference(
+        jnp.asarray(scores), jnp.asarray(visible), topk))
+    selected = visible & (scores >= cut[:, None])
+    kept = selected[:real].sum(1)
+    assert (kept >= np.minimum(qpos[:real] + 1, topk)).all()
+    assert (kept > topk).any()                # ties at the cut, all kept
+    want = np.asarray(dsa.masked_prefill_reference(
+        q, kp, vp, jnp.asarray(row, jnp.int32), jnp.asarray(selected),
+        scale=0.25))
+    scores[-(-real // 32) * 32:] = np.nan     # groups the indexer never wrote
+    got = np.asarray(dsa.sparse_prefill(
+        q, kp, vp, jnp.asarray(row, jnp.int32), start, start + real,
+        jnp.asarray(scores), jnp.asarray(cut), scale=0.25, interpret=True))
+    np.testing.assert_allclose(got[:real], want[:real], atol=1e-5)
+    assert not got[real:].any()               # zeros: not NaN, not stale
+
+
 def test_a_long_tail_is_scored_a_tile_of_queries_at_a_time(monkeypatch):
     """A bucket longer than ``PREFILL_SCORE_ROWS`` goes through the indexed
     prefill in tiles (``lax.map``): no ``[S, T]`` score array exists."""
@@ -387,6 +438,21 @@ def test_engine_serves_across_topk_with_a_prefix_hit_and_counts(f32, tokens,
     fills = [r[4]["dsa_context"] for r in _spans.snapshot(t0)
              if r[0] == "engine.prefill" and "dsa_context" in r[4]]
     assert {0, 100, 73} <= set(fills)
+    # the tail-prefill kernel's work items a layer, under the selection (a
+    # prompt past ``topk``) or dense, and those whose chunk is one run of the
+    # pool: on every prefill's span under ``kernel="pallas"`` and summed in
+    # the stats; the reference path has no list
+    work = [r[4] for r in _spans.snapshot(t0) if r[0] == "engine.prefill"
+            and "prefill_items_full" in r[4]]
+    names = ("prefill_items_full", "prefill_items_run")
+    if kernel == "pallas":
+        assert len(work) == len(fills) == 3
+        for a in work:
+            assert "prefill_items_window" not in a   # no layer keeps a window
+            assert 0 <= a["prefill_items_run"] <= a["prefill_items_full"] >= 1
+    else:
+        assert not work
+    assert [sp[k] for k in names] == [sum(a[k] for a in work) for k in names]
     assert eng.health()["kv_block_invariants"] == "ok"
 
 

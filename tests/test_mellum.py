@@ -483,24 +483,43 @@ def test_a_tail_behind_a_hit_reports_the_prefill_kernels_work_items(f32,
     t0 = time.perf_counter()
     h = eng.add_request(np.concatenate([doc, tokens[64:73]]),
                         max_new_tokens=2)
+    eng.step()                                # the admission and its prefill
+    rows = [np.asarray(ids) for ids in eng.cache.owned_blocks(h.slot)]
     eng.run()
     assert h.finished and not h.error
     (a,) = [r[4] for r in _spans.snapshot(t0) if r[0] == "engine.prefill"]
     assert (a["bucket"], a["swa_full_keys"]) == (16, 73)
     c = model.config
-    want = {"prefill_real_rows": 9}
-    for pool, key in zip(eng.cache.pools, ("prefill_items_full",
-                                           "prefill_items_window")):
+    want = {"prefill_real_rows": 9, "prefill_items_run": 0}
+    for pool, key, ids in zip(eng.cache.pools, ("prefill_items_full",
+                                                "prefill_items_window"),
+                              rows):
         _, bs, hkv, lanes = pool.sides[0][0].shape
         mb = pool.max_blocks_per_slot
         ts, ct = pk.prefill_plan(16, hkv, c.num_attention_heads // hkv,
                                  lanes, 4, bs, mb)
-        _, _, n = pk.prefill_work_list(
+        tile, chunk, n = pk.prefill_work_list(
             jnp.int32(64), jnp.int32(73), S=16, tile=ts, chunk_tokens=ct,
             window=pool.kv_window, places=pk.prefill_places(
                 16, ts, ct, mb, bs, pool.kv_window))
         want[key] = int(n)
         want.setdefault("prefill_tile_rows", -(-9 // ts) * ts)
+        # the items whose chunk the kernel takes in one copy: every block of
+        # the chunk holds a key the tile reads (none behind the first row's
+        # window, none past the last real row) and their ids are consecutive
+        cb = ct // bs
+        for t, ch in zip(np.asarray(tile)[:int(n)], np.asarray(chunk)):
+            lo = max(0, 64 + t * ts - pool.kv_window + 1) \
+                if pool.kv_window else 0
+            hi = min(64 + (t + 1) * ts, 73) - 1
+            blocks = ids[ch * cb:(ch + 1) * cb]
+            want["prefill_items_run"] += int(
+                len(blocks) == cb and lo // bs <= ch * cb
+                and hi // bs >= (ch + 1) * cb - 1
+                and (np.diff(blocks) == 1).all())
+    # (a row of 128 positions is one chunk, which the tail's end cuts: none
+    # here; ``tests/test_serving_admission.py`` counts at the cells' shapes)
+    assert want["prefill_items_run"] == 0
     assert want["prefill_items_full"] >= want["prefill_items_window"] >= 1
     assert want["prefill_tile_rows"] >= 9
     assert {k: a[k] for k in want} == want

@@ -1016,34 +1016,53 @@ def test_windowed_pool_programs_move_no_layer_buffer_on_the_chip(
         assert scope in hlo, scope
 
 
-@pytest.mark.parametrize("hkv,rep,hd,bucket,mb,window", [
-    (4, 8, 128, 256, 2048, 0),        # Mellum2's full layers: the ide tail
-    (4, 8, 128, 2048, 2048, 1024),    # its window layers: a resident piece
-    (4, 8, 128, 2048, 2048, 0),
-    (16, 1, 64, 32, 64, 0),           # GPT-2 345M: 64 wide in 128 lanes
-    (16, 1, 64, 1024, 64, 0),
-    (32, 1, 128, 256, 2048, 0),       # 32 KV heads of 128: ROADMAP R8's width
-    (32, 1, 128, 2048, 2048, 0),
+@pytest.mark.parametrize("hkv,rep,hd,bucket,mb,window,indexed", [
+    (4, 8, 128, 256, 2048, 0, False),     # Mellum2's full layers: the ide tail
+    (4, 8, 128, 2048, 2048, 1024, False),  # its window layers: a resident piece
+    (4, 8, 128, 2048, 2048, 0, False),
+    (16, 1, 64, 32, 64, 0, False),        # GPT-2 345M: 64 wide in 128 lanes
+    (16, 1, 64, 1024, 64, 0, False),
+    (32, 1, 128, 256, 2048, 0, False),    # 32 KV heads of 128: ROADMAP R8's
+    (32, 1, 128, 2048, 2048, 0, False),
+    (8, 4, 64, 256, 1024, 0, False),      # LFM2-24B-A2B: the agent's tail
+    (8, 4, 64, 2048, 1024, 0, False),
+    (4, 8, 128, 512, 2048, 0, True),      # Keye-VL-2.0: the long-document
+                                          # question's 512 queries at once
 ])
 def test_tail_prefill_kernel_fits_scoped_vmem_at_the_cells_widths(
-        one_chip, hkv, rep, hd, bucket, mb, window):
+        one_chip, hkv, rep, hd, bucket, mb, window, indexed):
     """The tail-prefill kernel alone, its tile and chunk from
     :func:`prefill_plan`, compiles for the described v5e inside the default
-    16 MiB of scoped VMEM (it asks for no more) at the widths the cells run
-    it at — and at 32 KV heads of 128, where the kernel it replaced wanted
-    49.9 MB (its 16-key score tiles padded to 128 lanes)."""
+    16 MiB of scoped VMEM (it asks for no more) with its two chunk buffers a
+    side at the widths the cells run it at — and at 32 KV heads of 128, where
+    the kernel it replaced wanted 49.9 MB (its 16-key score tiles padded to
+    128 lanes).  ``indexed``: the same kernel under the indexed model's
+    selection (a 64-token tile of the scores beside the queries), as
+    ``sparse_prefill`` calls it for the 512 queries scored at once."""
     import jax
     import jax.numpy as jnp
 
+    from paddle_tpu.ops.pallas import dsa_attention_kernel as dsa
     from paddle_tpu.ops.pallas import paged_attention_kernel as pk
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    pool = sds((mb + 1, 16, hkv, 128), jnp.bfloat16)
+    if indexed:
+        S = min(bucket, dsa.PREFILL_SCORE_ROWS)
+        assert dsa.sparse_prefill_plan(S, 16, mb) == (64, 256)
+        (line,) = kernel_lines(
+            lambda q, k, v, row, st, ln, sc, cut: dsa.sparse_prefill(
+                q, k, v, row, st, ln, sc, cut, scale=hd ** -0.5),
+            sds((S, hkv * rep, hd), jnp.bfloat16), pool, pool,
+            sds((mb,), jnp.int32), sds((), jnp.int32), sds((), jnp.int32),
+            sds((S, mb * 16), jnp.float32), sds((S,), jnp.float32))
+        assert line.startswith("%dsa_sparse_prefill.")
+        return
     ts, ct = pk.prefill_plan(bucket, hkv, rep, 128, 2, 16, mb)
     assert hkv * rep * ts * ct * 4 <= pk.PREFILL_SCORE_BYTES
     assert ct % 16 == 0 and bucket % ts == 0 and ts >= 16
-    pool = sds((mb + 1, 16, hkv, 128), jnp.bfloat16)
     (line,) = kernel_lines(
         lambda q, k, v, row, st, ln: pk.paged_prefill_attention_kernel(
             q, k, v, row, st, ln, window=window),

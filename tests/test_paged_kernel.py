@@ -277,19 +277,24 @@ class TestPrefillKernelParity:
         np.testing.assert_array_equal(np.asarray(out[0, 0]),
                                       np.asarray(out2[0, 0]))
 
+    @pytest.mark.parametrize("run", [False, True])
     @pytest.mark.parametrize("hkv,h", [(4, 4), (1, 8)])   # rep 1 and rep 8
     @pytest.mark.parametrize("start,real", [(0, 5), (256, 16), (240, 40),
                                             (16, None)])
     def test_real_length_pads_are_zero_and_poison_is_unseen(self, hkv, h,
-                                                            start, real):
+                                                            start, real, run):
         """The prompt's real length in a 64-row bucket off the lane-padded
         pool, behind a prefix that ends on (256) and off (240) the 256-token
         chunk: the real rows are the reference's, the pad rows exactly zero,
-        and a NaN in every position past the real length moves nothing."""
+        and a NaN in every position past the real length moves nothing —
+        through a shuffled table, and where the slot's blocks lie one after
+        another in the pool (``run``: a chunk wholly at or before the tile's
+        last real row comes in one copy a side)."""
         rs = np.random.RandomState(5)
         NB, BS, D, MB, S = 41, 8, 16, 40, 64
         kp, vp = _rand_pool(rs, NB, BS, hkv, D)
-        row = jnp.asarray(rs.permutation(np.arange(1, NB))[:MB], jnp.int32)
+        row = jnp.asarray(np.arange(1, NB) if run else
+                          rs.permutation(np.arange(1, NB))[:MB], jnp.int32)
         q = jnp.asarray(rs.randn(1, S, h, D), jnp.float32)
         n = S if real is None else real
         ref = _ref_prefill(q, kp, vp, row, start)

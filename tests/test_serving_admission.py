@@ -460,3 +460,62 @@ def test_a_requests_keys_are_the_parents_and_survive_its_lookups(served):
     assert eng.prefix_cache.lookup(prompt, count=False,
                                    keys=req._keys)[0] == 0
     assert req._keys.passes == 2
+
+
+# -- the host's count of the tail-prefill kernel's one-copy chunks ---------------
+
+#: cell -> (KV heads, query heads, window, the bucket, the tail's real tokens,
+#: the tail takes the kernel under the indexer's selection): the
+#: long-document question behind its resident document, and the ide tail on a
+#: full and on a window layer's pool — pools of the cells' block size and
+#: row length, one layer, no model
+CELL_SHAPES = {
+    "longdoc": (4, 32, 0, 512, 200, True),
+    "ide-full": (4, 32, 0, 256, 16, False),
+    "ide-window": (4, 32, 1024, 256, 16, False),
+}
+
+
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_a_resident_documents_chunks_are_runs_and_a_churned_pools_are_not(
+        cell):
+    """``prefill_work`` at the cells' shapes (block 16, rows of 32,768
+    positions, a 30,720-token document): what the allocator hands out one
+    after another is a run a 256-key chunk, so every item of the tail but
+    those its own end or its window cuts counts in ``prefill_items_run``; the
+    same admission into blocks a churned free list hands out counts the same
+    items and no run."""
+    from paddle_tpu.serving.paging import PagedKVCache
+
+    hkv, heads, window, bucket, real, indexed = CELL_SHAPES[cell]
+    doc, bs = 30720, 16
+    pool = PagedKVCache(2, 1, 32768, hkv, 128, "bfloat16", block_size=bs,
+                        num_blocks=2 * (doc + bucket) // bs + 1,
+                        kernel="pallas", window=window)
+
+    def admit(slot):
+        assert pool.begin_sequence(slot, [], 0, doc + bucket, write=False)
+        if window:                       # as the pieces before the tail did
+            pool.release_behind(slot, doc, write=False)
+        return pool.prefill_work(slot, bucket, doc, doc + real, heads,
+                                 indexed=indexed)
+
+    ts = 64 if indexed else 32
+    tiles = -(-real // ts)
+    items, rows, runs = admit(0)
+    assert rows == tiles * ts
+    if window:
+        # a tile reads from its first row's oldest key, 29,697: chunks 116
+        # (whose first block holds it: nothing of the chunk is released) to
+        # 120, which the tail's own end cuts
+        assert (items, runs) == (5, 4)
+    else:
+        # every tile reads the document's 120 chunks and the tail's own
+        assert (items, runs) == (tiles * 121, tiles * 120)
+    # the free list after a churn: the blocks of a released slot, shuffled
+    pool.release_slot(0)
+    free = list(pool.allocator._free)
+    np.random.default_rng(0).shuffle(free)
+    pool.allocator._free.clear()
+    pool.allocator._free.extend(free)
+    assert admit(1) == (items, rows, 0)
