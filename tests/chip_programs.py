@@ -1,8 +1,9 @@
 """The chip that is described here, and programs compiled for it: what the
 tests that read a compiled program's text share (``test_obs_spans.py``: the
 kernels' names, the pool's form; ``test_obs_scopes.py``: whom an instruction
-belongs to).  No chip is attached: the TPU's compiler is installed and
-compiles for a v5e that is described."""
+belongs to).  The deployments themselves are ``families.py``'s.  No chip is
+attached: the TPU's compiler is installed and compiles for a v5e that is
+described."""
 import re
 
 import numpy as np
@@ -61,37 +62,57 @@ def load_patterns(kernel):
     return mod
 
 
-def engine_program(one_chip, program, layers=1):
-    """``(engine, compiled)``: GPT-2 345M's widths (16 heads x 64, vocab
-    50304, bf16; ``layers`` layers, 32 slots, block 16, a 513-block pool),
-    the paged engine's decode or bucket-32 prefill program built as
-    ``to_static`` builds it and compiled for the described v5e, the kernels
-    as the chip runs them."""
+@pytest.fixture(scope="module")
+def pool_programs(one_chip):
+    """``pool_programs(kind, program, **config) -> (engine, compiled)``: the
+    paged engine of the family that states that kind of cache, at the widths
+    of its ``chip`` entry in ``families.py`` (``config`` over them), and one
+    of its programs (``decode``, ``prefill`` or ``prefill-<bucket>``,
+    ``publish``) built as ``to_static`` builds it and compiled for the
+    described v5e, the kernels as the chip runs them.  An engine is built
+    once a module and a program compiled once."""
+    from families import BY_KIND
     from paddle_tpu.core.autograd import no_grad
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.models import (deepseek_v3, held_experts, keye_vl2, lfm2)
     from paddle_tpu.serving import Engine
 
-    paddle.seed(0)
-    model = GPTForCausalLM(GPTConfig(
-        vocab_size=50304, hidden_size=1024, num_hidden_layers=layers,
-        num_attention_heads=16, max_position_embeddings=1024,
-        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
-    model.to(dtype="bfloat16")
-    eng = Engine(model, num_slots=32, max_seq=1024, min_bucket=32,
-                 block_size=16, num_kv_blocks=513,
-                 kernel="pallas")
-    eng.cache._interpret = False          # the kernels as the chip runs them
-    eng._build_steps()
-    if program == "decode":
-        fn, args = eng._decode_fn, [np.zeros((32,), np.int32)]
-    else:
-        fn, args = eng._prefill_fn, [np.zeros((1, 32), np.int64), np.int32(0),
-                                     np.int32(1), np.int32(0)]
-        assert eng.cache.begin_sequence(0, [], 0, 32)
-    with no_grad():
-        compiled = compile_for(one_chip, fn._fn,
-                               [paddle.to_tensor(a) for a in args])
-    return eng, compiled
+    engines, compiled = {}, {}
+
+    def get(kind, program, **config):
+        family, built = BY_KIND[kind], (kind, *sorted(config.items()))
+        if built not in engines:
+            eng = engines[built] = Engine(
+                family.chip_model(**config), block_size=16, kernel="pallas",
+                **family.chip["engine"])
+            for pool in getattr(eng.cache, "pools", [eng.cache]):
+                pool._interpret = False   # the kernels as the chip runs them
+            eng._build_steps()
+        eng = engines[built]
+        if (built, program) in compiled:
+            return eng, compiled[built, program]
+        bucket = family.chip["programs"][program]
+        if program == "decode":
+            fn, args = eng._decode_fn, [np.zeros((eng.num_slots,), np.int32)]
+        elif program == "publish":
+            fn, args = eng._publish_fn, [np.int32(0), np.int32(0)]
+        else:
+            fn, args = eng._prefill_fn, [np.zeros((1, bucket), np.int64),
+                                         np.int32(0), np.int32(1), np.int32(0)]
+            assert eng.cache.begin_sequence(0, [], 0, bucket)
+        try:
+            with pytest.MonkeyPatch.context() as mp, no_grad():
+                for module in (deepseek_v3, held_experts, keye_vl2, lfm2):
+                    mp.setattr(module, "_interpret", lambda: False)
+                compiled[built, program] = compile_for(
+                    one_chip, fn._fn, [paddle.to_tensor(a) for a in args])
+        finally:
+            if bucket:
+                eng.cache.release_slot(0)
+        return eng, compiled[built, program]
+
+    yield get
+    engines.clear()
+    compiled.clear()
 
 
 def compile_for(one_chip, fn, args):

@@ -36,6 +36,7 @@ os.environ.setdefault("JAX_DEFAULT_MATMUL_PRECISION", "highest")
 os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -53,8 +54,6 @@ def pytest_addoption(parser):
 
 
 def pytest_collection_modifyitems(config, items):
-    import pytest
-
     if config.getoption("--runslow") or \
             os.environ.get("PADDLE_TPU_RUN_SLOW") == "1":
         return
@@ -65,57 +64,36 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
-# -- per-file wall-time report (tools/collect_gate.py budget gate) --------
-# The tier-1 suite sits close to its CI timeout; one test file quietly
-# growing 2x can push the whole suite over.  With
-# PADDLE_TPU_TIER1_TIMING_REPORT=<path> set, each pytest invocation sums
-# setup+call+teardown durations per test FILE and appends a JSON report
-# that `tools/collect_gate.py --timing-report <path>` checks against the
-# recorded budgets in tools/tier1_budgets.json.
+# -- a file's tests stay on one worker ------------------------------------
+# The suite's expensive things are made once a file: a ``scope="module"``
+# fixture (four engine warm-ups in ``fleet_chaos``), and the programs a
+# process compiles for the file's model.  xdist's ``--dist load`` hands a
+# file's tests out one by one, so every worker that drew one built the
+# fixture and compiled the programs again (PR 43's finding: 7,334 summed
+# seconds where one worker a file needs under 4,500).  How the suite is
+# spread is therefore the suite's decision, made here whatever ``--dist`` the
+# command names: a file goes to one worker, the longest file first, by the
+# seconds ``tests/file_seconds.json`` records (``tools/junit_seconds.py``
+# writes it from a run's junit file; a file it does not know goes first).
 
-_file_times: dict = {}
-
-
-def pytest_runtest_logreport(report):
-    if not os.environ.get("PADDLE_TPU_TIER1_TIMING_REPORT"):
-        return
-    path = report.nodeid.split("::", 1)[0]
-    _file_times[path] = _file_times.get(path, 0.0) + report.duration
-
-
-def pytest_sessionfinish(session, exitstatus):
-    out = os.environ.get("PADDLE_TPU_TIER1_TIMING_REPORT")
-    if not out or not _file_times:
-        return
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
     import json
 
-    # merge-on-write so a chunked suite (several pytest invocations
-    # sharing one report path) accumulates into a single report.  Per
-    # file the merge takes the MAX across invocations, not the sum: a
-    # re-run against a stale report must not double every file's time
-    # and falsely trip the budget gate (chunked invocations cover
-    # disjoint files, so max == the one real measurement there).  A
-    # report older than _REPORT_STALE_S is a previous run's leftover
-    # (cached CI workspace, forgotten env var) — replaced, not merged,
-    # so yesterday's slow numbers cannot mask today's fix.
-    _REPORT_STALE_S = 2 * 3600
-    merged = {}
-    if os.path.exists(out):
-        try:
-            import time as _time
+    from xdist.scheduler import LoadFileScheduling
 
-            if _time.time() - os.path.getmtime(out) < _REPORT_STALE_S:
-                with open(out) as f:
-                    merged = json.load(f).get("file_seconds", {})
-        except (OSError, ValueError):
-            merged = {}
-    for path, secs in _file_times.items():
-        merged[path] = max(merged.get(path, 0.0), secs)
-    with open(out, "w") as f:
-        json.dump({"file_seconds":
-                   {k: round(v, 2) for k, v in sorted(merged.items())}},
-                  f, indent=1, sort_keys=True)
-    _file_times.clear()
+    with open(os.path.join(os.path.dirname(__file__),
+                           "file_seconds.json")) as f:
+        seconds = json.load(f)
+
+    class LongestFileFirst(LoadFileScheduling):
+        def _assign_work_unit(self, node):
+            longest = max(self.workqueue,
+                          key=lambda path: seconds.get(path, float("inf")))
+            self.workqueue.move_to_end(longest, last=False)
+            super()._assign_work_unit(node)
+
+    return LongestFileFirst(config, log)
 
 
 # -- the global mesh ends with the module that set it ---------------------
@@ -127,9 +105,6 @@ def pytest_sessionfinish(session, exitstatus):
 # leaked ``hybrid_mesh(dp=2, mp=4)``): its unsharded engines trace the model's
 # ``mark_sharding`` under the leaked 8-device mesh while the file's own model
 # lies on 2 devices.
-
-import pytest  # noqa: E402
-
 
 @pytest.fixture(autouse=True, scope="module")
 def _global_mesh_ends_with_its_module():

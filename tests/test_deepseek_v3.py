@@ -4,87 +4,29 @@ paged pool, sigmoid-routed held experts with a shared one) at a tiny preset
 which 4 held, 1 + 2 layers, vocabulary 512), against the benchmark's plain
 reference (``benchmarks/references/deepseek_v3.py``: float32, non-absorbed, a
 loop over the held experts; it imports nothing of the program)."""
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
-import jax
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import paddle_tpu as paddle
+from paddle_tpu import inference
+from paddle_tpu.serving.kv_cache import CacheSpec
+from paddle_tpu.serving.paging import PagedKVCache
 
-import paddle_tpu as paddle                                   # noqa: E402
-from paddle_tpu import inference                              # noqa: E402
-from paddle_tpu.models import deepseek_v3 as dm               # noqa: E402
-from paddle_tpu.serving.kv_cache import CacheSpec, cache_spec_of  # noqa: E402
-from paddle_tpu.serving.paging import (                       # noqa: E402
-    PagedCacheContext, PagedKVCache)
+from families import (  # noqa: F401 — the fixtures, and the common cases
+    BLOCK, FAMILIES, compiled_steps, f32, family, tokens, want,
+    test_full_forward_equals_the_reference,
+    test_the_cache_refuses_what_it_has_no_form_for,
+    test_the_model_states_its_cache_and_keeps_its_dtype)
 
-from benchmarks.adapters import _load                         # noqa: E402
 from benchmarks.harness import weights                        # noqa: E402
-from benchmarks.harness.manifest import load_module           # noqa: E402
 
-REF = load_module("references", "deepseek_v3")
-ADAPTER = load_module("adapters", "deepseek_v3")
-SEED = 2 ** 31 + 26
-BLOCK = 8
-
-
-def tiny_config(**kw) -> dict:
-    with open(os.path.join(ROOT, "tests", "benchmark_tests",
-                           "tiny_deepseek_v3.json")) as f:
-        return dict(json.load(f), **kw)
-
-
-def seeded(dtype: str, **kw):
-    """``(model, tree, d)``: the program's model holding the benchmark's
-    seeded weights in ``dtype``; ``tree`` is what the reference reads."""
-    cfg = tiny_config(torch_dtype=dtype, **kw)
-    d = REF.dims(cfg)
-    tree = weights.make(REF.weight_shapes(cfg), SEED, jnp.dtype(dtype))
-    paddle.seed(0)
-    model = ADAPTER.build_model(cfg)
-    model.eval()
-    _load.load(model, ADAPTER, tree, d)
-    return model, tree, d
-
-
-def reference_logits(tree, d, tokens, control=False):
-    h = REF.hidden(tree, jnp.asarray(tokens), d, control=control)
-    return np.asarray(REF.logits_rows({k: tree[k] for k in REF.HEAD_KEYS},
-                                      h, d, control=control))
-
-
-@pytest.fixture(scope="module")
-def f32():
-    return seeded("float32")
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(7).integers(0, 512, (45,), dtype=np.int32)
+FAMILY = FAMILIES["deepseek_v3"]
+REF, dm, SEED = FAMILY.ref, FAMILY.models, FAMILY.seed
+seeded, reference_logits = FAMILY.seeded, FAMILY.reference_logits
 
 
 # -- (a) float32 against the reference ---------------------------------------
-
-def test_full_forward_equals_the_reference(f32, tokens):
-    model, tree, d = f32
-    got = np.asarray(model(paddle.to_tensor(tokens[None]))._value())[0]
-    want = reference_logits(tree, d, tokens)
-    assert got.dtype == np.float32
-    np.testing.assert_allclose(got, want, atol=1e-4, rtol=0)
-
-
-def test_the_model_states_a_latent_cache_and_parameters_keep_their_dtype():
-    model = dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny(dtype="bfloat16"))
-    spec = cache_spec_of(model)
-    assert spec == CacheSpec.latent(3, 32 + 8) and spec.sides == ((1, 40),)
-    assert {str(p.dtype) for p in model.parameters()} == {"bfloat16"}
-
 
 @pytest.mark.parametrize("kernel", ["reference", "pallas"])
 def test_prefix_tail_prefill_and_decode_through_the_latent_pool(
@@ -102,15 +44,7 @@ def test_prefix_tail_prefill_and_decode_through_the_latent_pool(
     assert [tuple(b.shape) for b in cache.buffers()] == \
         [(25, BLOCK, 1, 128)] * d["layers"]        # one buffer a layer
 
-    def prefill(slot, ids, start, length):
-        ctx = PagedCacheContext(
-            cache, "prefill", slot=paddle.to_tensor(np.int32(slot)),
-            length=paddle.to_tensor(np.int32(length)),
-            start=paddle.to_tensor(np.int32(start)))
-        out = model(paddle.to_tensor(ids[None]), cache_ctx=ctx)
-        cache.set_length(slot, length)
-        assert ctx.narrowed and tuple(out.shape) == (1, 1, d["vocab"])
-        return np.asarray(out._value())[0, 0]
+    prefill, decode = compiled_steps(model, cache, counts="expert_counts")
 
     assert cache.begin_sequence(0, [], 0, 16)
     np.testing.assert_allclose(prefill(0, tokens[:16], 0, 16), want[15],
@@ -121,19 +55,15 @@ def test_prefix_tail_prefill_and_decode_through_the_latent_pool(
     tail[:21] = tokens[16:37]
     np.testing.assert_allclose(prefill(2, tail, 16, 37), want[36],
                                atol=1e-4, rtol=0)
-    active = paddle.to_tensor(np.asarray([0, 0, 1], np.int32))
+    active = np.asarray([0, 0, 1], np.int32)
     for pos in range(37, 43):
         assert cache.ensure_capacity(2, pos)
-        ctx = PagedCacheContext(cache, "decode", active=active)
         step = np.zeros((3, 1), np.int32)
         step[2, 0] = tokens[pos]
-        out = model(paddle.to_tensor(step), cache_ctx=ctx)
-        cache.advance(active)
-        np.testing.assert_allclose(np.asarray(out._value())[2, 0], want[pos],
-                                   atol=1e-4, rtol=0)
-        held, touched = (sum(int(c) for c in col)
-                         for col in zip(*ctx.expert_counts))
-        assert len(ctx.expert_counts) == 2 and 0 <= touched <= held <= 8
+        out, counts = decode(step, active)
+        np.testing.assert_allclose(out[2, 0], want[pos], atol=1e-4, rtol=0)
+        held, touched = (sum(col) for col in zip(*counts))
+        assert len(counts) == 2 and 0 <= touched <= held <= 8
     assert cache.check_invariants() == []
 
 
@@ -175,7 +105,10 @@ def test_bf16_engine_serves_within_a_tolerance_the_fp8_control_exceeds():
         want = reference_logits(tree, d, full)[rows]
         best = want.max(axis=-1)
         worst = max(worst, float((best - want[np.arange(10), out]).max()))
-        low = reference_logits(tree, d, full, control=True)[rows]
+        low = np.asarray(REF.logits_rows(
+            {k: tree[k] for k in REF.HEAD_KEYS},
+            REF.hidden(tree, jnp.asarray(full), d, control=True), d,
+            control=True))[rows]
         control = max(control, float(
             (best - want[np.arange(10), low.argmax(-1)]).max()))
     assert worst <= TOL < control, (worst, control)
@@ -197,7 +130,7 @@ def test_cold_warm_and_long_tail_admissions_serve_the_cache_free_tokens():
     eng = inference.create_engine(model, block_size=BLOCK, min_bucket=16,
                                   max_seq=128, num_slots=4)
     assert eng.kernel == "pallas"
-    eng.warmup()
+    eng.warmup(buckets=[16, 64])
     rng = np.random.default_rng(9)
     doc = rng.integers(0, 512, (48,), dtype=np.int32)
     prompts = [np.concatenate([doc, rng.integers(0, 512, (n,), np.int32)])
@@ -235,7 +168,7 @@ def test_the_four_shares_and_the_shared_expert_once_are_the_whole_layer():
     """Each of four chips holds 4 of the 16 experts.  What the four compute
     for their own experts, plus the shared expert counted once, is what the
     uncut reference gives for the whole layer."""
-    cfg = tiny_config(n_routed_experts=16, held_experts=[0, 16])
+    cfg = FAMILY.tiny_config(n_routed_experts=16, held_experts=[0, 16])
     d = REF.dims(cfg)
     names = REF.layer_names(1, d)
     got = weights.make(REF.weight_shapes(cfg), SEED, jnp.float32, only=names)
@@ -299,38 +232,13 @@ def test_a_batch_routed_entirely_to_the_same_experts_loses_nothing():
     assert not np.asarray(y[33:]).any()
 
 
-# -- (g) what the latent pool refuses -----------------------------------------
-
-def _refusals():
-    from paddle_tpu.serving.sharding import serving_mesh
-    from paddle_tpu.serving.spec_decode import SpecConfig
-
-    draft = dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
-    return {
-        "mesh": (dict(mesh=serving_mesh(2)), "no kv_heads axis to shard"),
-        "speculation": (dict(speculation=SpecConfig(draft_model=draft, k=2)),
-                        "no latent form"),
-    }
-
-
-@pytest.mark.parametrize("what", ["mesh", "speculation"])
-def test_the_latent_pool_refuses_what_it_has_no_form_for(what):
-    from paddle_tpu.serving import Engine
-
-    kwargs, says = _refusals()[what]
-    model = dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
-    with pytest.raises(ValueError, match=says):
-        Engine(model, num_slots=2, max_seq=64, block_size=BLOCK,
-               min_bucket=16, **kwargs)
-
-
-@pytest.mark.parametrize("family", ["gpt", "llama"])
-def test_kv_models_state_their_cache_and_get_the_buffers_they_had(family):
+@pytest.mark.parametrize("kv", ["gpt", "llama"])
+def test_kv_models_state_their_cache_and_get_the_buffers_they_had(kv):
     from paddle_tpu.models import (GPTForCausalLM, LlamaForCausalLM,
                                    gpt_tiny, llama_tiny)
     from paddle_tpu.serving import Engine
 
-    model = GPTForCausalLM(gpt_tiny()) if family == "gpt" \
+    model = GPTForCausalLM(gpt_tiny()) if kv == "gpt" \
         else LlamaForCausalLM(llama_tiny())
     cfg = model.config
     kv = getattr(cfg, "n_kv_heads", None) or cfg.num_attention_heads

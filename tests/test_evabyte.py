@@ -5,94 +5,30 @@ windows of 32 positions in chunks of 4, 2 layers, 3 predictors, vocabulary
 64), against the benchmark's plain reference
 (``benchmarks/references/evabyte.py``: float32, a window's part at a time; it
 imports nothing of the program)."""
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-import paddle_tpu as paddle                                   # noqa: E402
-from paddle_tpu import inference                              # noqa: E402
-from paddle_tpu.models import evabyte as em                   # noqa: E402
-from paddle_tpu.obs import spans as _spans                    # noqa: E402
-from paddle_tpu.ops.pallas import eva_attention_kernel as eva  # noqa: E402
-from paddle_tpu.serving.kv_cache import CacheSpec, cache_spec_of  # noqa: E402
-from paddle_tpu.serving.paging import PagedCacheContext       # noqa: E402
-from paddle_tpu.serving.window_cache import (                 # noqa: E402
+import paddle_tpu as paddle
+from paddle_tpu import inference
+from paddle_tpu.obs import spans as _spans
+from paddle_tpu.ops.pallas import eva_attention_kernel as eva
+from paddle_tpu.serving.window_cache import (
     WindowedKVCache, WindowedPrefixCache)
 
-from benchmarks.adapters import _load                         # noqa: E402
-from benchmarks.harness import weights                        # noqa: E402
-from benchmarks.harness.manifest import load_module           # noqa: E402
+from families import (  # noqa: F401 — the fixtures, and the common cases
+    BLOCK, FAMILIES, compiled_steps, f32, family, pytest_generate_tests,
+    tokens, want,
+    test_admission_waits_for_blocks,
+    test_bf16_engine_serves_within_a_tolerance,
+    test_the_cache_refuses_what_it_has_no_form_for,
+    test_the_model_states_its_cache_and_keeps_its_dtype)
 
-REF = load_module("references", "evabyte")
-ADAPTER = load_module("adapters", "evabyte")
-SEED = 2 ** 31 + 34
-BLOCK, W, C = 8, 32, 4
+FAMILY = FAMILIES["evabyte"]
+REF = FAMILY.ref
+engine, greedy_matches = FAMILY.engine, FAMILY.greedy_matches
+W, C = 32, 4
 ROWS = W // C
-
-
-def seeded(dtype: str = "float32", **kw):
-    """``(model, tree, d)``: the program's model holding the benchmark's
-    seeded weights in ``dtype``; ``tree`` is what the reference reads."""
-    with open(os.path.join(ROOT, "tests", "benchmark_tests",
-                           "tiny_evabyte.json")) as f:
-        cfg = dict(json.load(f), torch_dtype=dtype, **kw)
-    d = REF.dims(cfg)
-    tree = weights.make(REF.weight_shapes(cfg), SEED, jnp.dtype(dtype))
-    paddle.seed(0)
-    model = ADAPTER.build_model(cfg)
-    model.eval()
-    _load.load(model, ADAPTER, tree, d)
-    return model, tree, d
-
-
-def reference_logits(tree, d, tokens):
-    h = REF.hidden(tree, jnp.asarray(tokens), d)
-    return np.asarray(REF.logits_rows({k: tree[k] for k in REF.HEAD_KEYS},
-                                      h, d))
-
-
-@pytest.fixture(scope="module")
-def f32():
-    return seeded()
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(7).integers(0, 64, (120,), dtype=np.int32)
-
-
-@pytest.fixture(scope="module")
-def want(f32, tokens):
-    _model, tree, d = f32
-    return reference_logits(tree, d, tokens)
-
-
-def engine(model, kernel="pallas", buckets=(8, 16, 32), **kw):
-    kw = dict(dict(num_slots=3, max_seq=128, min_bucket=8, block_size=BLOCK,
-                   kernel=kernel), **kw)
-    eng = inference.create_engine(model, **kw)
-    eng.warmup(buckets=list(buckets))
-    return eng
-
-
-def greedy_matches(tree, d, prompt, out):
-    """The served tokens are the reference's first choice wherever its best
-    two logits are apart."""
-    seq = np.concatenate([prompt, np.asarray(out)])
-    lg = reference_logits(tree, d, seq)[len(prompt) - 1:-1]
-    top2 = np.sort(lg, axis=-1)[:, -2:]
-    sure = top2[:, 1] - top2[:, 0] > 1e-4
-    assert sure.sum() >= len(out) // 2
-    np.testing.assert_array_equal(np.asarray(out)[sure],
-                                  lg.argmax(-1)[sure])
 
 
 # -- (a) the full forward against the reference, every predictor --------------
@@ -120,13 +56,8 @@ def test_full_forward_equals_the_reference_over_three_windows(f32, tokens,
     assert np.abs(other - want).max() > 1e-4
 
 
-def test_the_model_states_two_groups_and_parameters_keep_their_dtype():
-    model = em.EvaByteForCausalLM(em.evabyte_tiny(dtype="bfloat16"))
-    spec = cache_spec_of(model)
-    assert spec == CacheSpec.windowed(2, 4, 16, W, C)
-    assert spec.kind == "windowed" and spec.sides == ((4, 16), (4, 16))
-    assert {str(p.dtype) for p in model.parameters()} == {"bfloat16"}
-    assert len(model.summary_params()) == 2
+def test_every_layer_has_its_summary_parameters():
+    assert len(FAMILY.tiny_model().summary_params()) == 2
 
 
 # -- (b) prefill in pieces, decode across two boundaries: logits --------------
@@ -151,29 +82,13 @@ def test_prefill_in_pieces_then_decode_across_two_window_ends(f32, tokens,
     assert [tuple(b.shape) for b in cache.summary_buffers()] == \
         [(9, ROWS, 4, 128)] * 2 * d["layers"]
 
+    prefill_step, decode_step = compiled_steps(model, cache,
+                                               counts="row_counts")
+
     def prefill(slot, ids, start, length):
-        ctx = PagedCacheContext(
-            cache, "prefill", slot=paddle.to_tensor(np.int32(slot)),
-            length=paddle.to_tensor(np.int32(length)),
-            start=paddle.to_tensor(np.int32(start)))
-        out = model(paddle.to_tensor(ids[None]), cache_ctx=ctx)
-        cache.set_length(slot, length)
+        row = prefill_step(slot, ids, start, length)
         cache.release_windows(slot, length)
-        return np.asarray(out._value())[0, 0]
-
-    from paddle_tpu import jit as jit_mod
-    from paddle_tpu.core.autograd import no_grad
-    from paddle_tpu.core.tensor import Tensor
-
-    def decode_step(step, act):
-        ctx = PagedCacheContext(cache, "decode", active=act)
-        out = model(step, cache_ctx=ctx)
-        cache.advance(act)
-        assert len(ctx.row_counts) == d["layers"]
-        return out, Tensor._wrap(jnp.stack(
-            [jnp.stack(c) for c in ctx.row_counts]))
-
-    step_fn = jit_mod.to_static(decode_step)     # one program, as the engine
+        return row
 
     def decode(pos_of):
         active = np.zeros(3, np.int32)
@@ -186,14 +101,11 @@ def test_prefill_in_pieces_then_decode_across_two_window_ends(f32, tokens,
                 assert cache.release_windows(s, pos) == W // BLOCK
             assert cache.ensure_capacity(s, pos)
             active[s], step[s, 0] = 1, tokens[pos]
-        with no_grad():
-            out, counts = step_fn(paddle.to_tensor(step),
-                                  paddle.to_tensor(active))
-        out = np.asarray(out._value())
+        out, counts = decode_step(step, active)
         for s, pos in pos_of.items():
             np.testing.assert_allclose(out[s, 0], want[pos], atol=1e-4,
                                        rtol=0)
-        return [tuple(int(x) for x in c) for c in np.asarray(counts._value())]
+        return counts
 
     assert cache.begin_sequence(0, None, 0, 24, total=128)
     assert len(cache._slot_windows[0]) == 4         # a life's summary blocks
@@ -345,22 +257,6 @@ def test_a_roll_over_frees_a_windows_blocks_and_resume_reproduces(f32,
     st = eng.stats()
     assert st["paging"]["prefix"]["hit_tokens"] >= 32     # resumed by a hit
     assert st["eva"]["windows_published_decode"] >= 2
-    assert eng.health()["kv_block_invariants"] == "ok"
-
-
-def test_admission_waits_for_blocks_of_either_group(f32, tokens):
-    """With summary blocks for one sequence's life only, the second request
-    is deferred, not failed, and is served when the first retires."""
-    model, _tree, _d = f32
-    eng = engine(model, buckets=(8, 32), num_slots=2,
-                 num_summary_blocks=4)                       # 3 usable
-    a = eng.add_request(tokens[:70], max_new_tokens=20)      # 2 windows
-    b = eng.add_request(tokens[10:80], max_new_tokens=20)
-    eng.step()
-    assert len(eng.running) == 1 and len(eng.queue) == 1
-    eng.run()
-    assert a.finished and b.finished and not a.error and not b.error
-    assert eng.stats()["failures"]["failed"] == 0
     assert eng.health()["kv_block_invariants"] == "ok"
 
 
@@ -516,45 +412,10 @@ def test_the_engine_counts_rows_and_windows_on_its_spans(f32, tokens):
     #                            two warmed buckets, decode, publish: no more
 
 
-def test_bf16_engine_serves_within_a_tolerance():
-    """bf16 weights and pools through ``create_engine``: every greedy token's
-    logit lies within a tolerance of the reference's best."""
-    model, tree, d = seeded("bfloat16")
-    eng = engine(model, buckets=(16, 32))
-    prompt = np.random.default_rng(5).integers(0, 64, (75,), dtype=np.int32)
-    h = eng.add_request(prompt, max_new_tokens=30)
-    eng.run()
-    out = np.asarray(h.output_ids)
-    tree32 = {k: v.astype(jnp.float32) for k, v in tree.items()}
-    lg = reference_logits(tree32, d, np.concatenate([prompt, out])
-                          )[len(prompt) - 1:-1]
-    gap = lg.max(-1) - np.take_along_axis(lg, out[:, None], -1)[:, 0]
-    assert gap.max() < 0.02, gap.max()
-    assert {str(b.dtype) for b in eng.cache.summary_buffers()} == {"bfloat16"}
+def test_the_summary_groups_size_is_refused_where_it_means_nothing():
+    from paddle_tpu.models import GPTForCausalLM, gpt_tiny
 
-
-def _refusals():
-    from paddle_tpu.serving.sharding import serving_mesh
-    from paddle_tpu.serving.spec_decode import SpecConfig
-
-    draft = em.EvaByteForCausalLM(em.evabyte_tiny())
-    return [("mesh", dict(mesh=serving_mesh(2)), "serving mesh"),
-            ("speculation",
-             dict(speculation=SpecConfig(draft_model=draft, k=2)),
-             "speculation=")]
-
-
-@pytest.mark.parametrize("what", ["mesh", "speculation"])
-def test_the_windowed_pool_refuses_what_it_has_no_form_for(what):
     paddle.seed(0)
-    model = em.EvaByteForCausalLM(em.evabyte_tiny())
-    kw, msg = next((kw, msg) for name, kw, msg in _refusals()
-                   if name == what)
-    with pytest.raises(ValueError, match=msg):
-        inference.create_engine(model, num_slots=2, max_seq=64,
-                                min_bucket=8, block_size=BLOCK, **kw)
     with pytest.raises(ValueError, match="num_summary_blocks"):
-        from paddle_tpu.models import GPTForCausalLM, gpt_tiny
-
         inference.create_engine(GPTForCausalLM(gpt_tiny()), num_slots=2,
                                 num_summary_blocks=4)

@@ -47,7 +47,6 @@ class TestPersistentCacheIsolation:
         exported (as a chip driver's environment may have it) still runs
         the suite cache-less, and writes nothing there."""
         env = dict(os.environ)
-        env.pop("PADDLE_TPU_TIER1_TIMING_REPORT", None)
         env["JAX_PLATFORMS"] = "cpu"
         env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cc")
         r = subprocess.run(

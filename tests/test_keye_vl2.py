@@ -5,72 +5,29 @@ with ``topk`` 24, 16 experts top-4 of which 4 held, 3 layers, vocabulary
 512), against the benchmark's plain reference
 (``benchmarks/references/keye_vl2.py``: float32, a selection mask per query,
 a loop over the held experts; it imports nothing of the program)."""
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import paddle_tpu as paddle
+from paddle_tpu import inference
+from paddle_tpu.obs import spans as _spans
+from paddle_tpu.ops.pallas import dsa_attention_kernel as dsa
+from paddle_tpu.serving.paging import PagedKVCache
 
-import paddle_tpu as paddle                                   # noqa: E402
-from paddle_tpu import inference                              # noqa: E402
-from paddle_tpu.models import keye_vl2 as km                  # noqa: E402
-from paddle_tpu.obs import spans as _spans                    # noqa: E402
-from paddle_tpu.ops.pallas import dsa_attention_kernel as dsa  # noqa: E402
-from paddle_tpu.serving.kv_cache import CacheSpec, cache_spec_of  # noqa: E402
-from paddle_tpu.serving.paging import (                       # noqa: E402
-    PagedCacheContext, PagedKVCache)
+from families import (  # noqa: F401 — the fixtures, and the common cases
+    BLOCK, FAMILIES, compiled_steps, f32, family, tokens,
+    test_the_cache_refuses_what_it_has_no_form_for,
+    test_the_model_states_its_cache_and_keeps_its_dtype)
 
-from benchmarks.adapters import _load                         # noqa: E402
 from benchmarks.harness import weights                        # noqa: E402
-from benchmarks.harness.manifest import load_module           # noqa: E402
 
-REF = load_module("references", "keye_vl2")
-ADAPTER = load_module("adapters", "keye_vl2")
-SEED = 2 ** 31 + 30
-BLOCK = 8
+FAMILY = FAMILIES["keye_vl2"]
+REF, km, SEED = FAMILY.ref, FAMILY.models, FAMILY.seed
+seeded, reference_logits = FAMILY.seeded, FAMILY.reference_logits
+tiny_config = FAMILY.tiny_config
 TOPK = 24
-
-
-def tiny_config(**kw) -> dict:
-    with open(os.path.join(ROOT, "tests", "benchmark_tests",
-                           "tiny_keye_vl2.json")) as f:
-        return dict(json.load(f), **kw)
-
-
-def seeded(dtype: str, **kw):
-    """``(model, tree, d)``: the program's model holding the benchmark's
-    seeded weights in ``dtype``; ``tree`` is what the reference reads."""
-    cfg = tiny_config(torch_dtype=dtype, **kw)
-    d = REF.dims(cfg)
-    tree = weights.make(REF.weight_shapes(cfg), SEED, jnp.dtype(dtype))
-    paddle.seed(0)
-    model = ADAPTER.build_model(cfg)
-    model.eval()
-    _load.load(model, ADAPTER, tree, d)
-    return model, tree, d
-
-
-def reference_logits(tree, d, tokens, **kw):
-    h = REF.hidden(tree, jnp.asarray(tokens), d, **kw)
-    return np.asarray(REF.logits_rows({k: tree[k] for k in REF.HEAD_KEYS},
-                                      h, d))
-
-
-@pytest.fixture(scope="module")
-def f32():
-    return seeded("float32")
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(7).integers(0, 512, (72,), dtype=np.int32)
 
 
 # -- (a) float32 against the reference ---------------------------------------
@@ -113,14 +70,6 @@ def test_unequal_position_streams_reach_the_rotary(f32):
     assert np.abs(equal - got).max() > 1e-3
 
 
-def test_the_model_states_three_sides_and_parameters_keep_their_dtype():
-    model = km.KeyeVL2ForCausalLM(km.keye_vl2_tiny(dtype="bfloat16"))
-    spec = cache_spec_of(model)
-    assert spec == CacheSpec.indexed(3, 2, 16, 8, TOPK)
-    assert spec.sides == ((2, 16), (2, 16), (1, 8)) and spec.topk == TOPK
-    assert {str(p.dtype) for p in model.parameters()} == {"bfloat16"}
-
-
 @pytest.mark.parametrize("kernel", ["reference", "pallas"])
 def test_prefix_tail_prefill_and_decode_through_the_indexed_pool(
         f32, tokens, kernel):
@@ -141,15 +90,8 @@ def test_prefix_tail_prefill_and_decode_through_the_indexed_pool(
         [(25, BLOCK, 2, 128)] * 2 * d["layers"] \
         + [(25, BLOCK, 1, 128)] * d["layers"]     # a buffer a layer a side
 
-    def prefill(slot, ids, start, length):
-        ctx = PagedCacheContext(
-            cache, "prefill", slot=paddle.to_tensor(np.int32(slot)),
-            length=paddle.to_tensor(np.int32(length)),
-            start=paddle.to_tensor(np.int32(start)))
-        out = model(paddle.to_tensor(ids[None]), cache_ctx=ctx)
-        cache.set_length(slot, length)
-        assert ctx.narrowed and tuple(out.shape) == (1, 1, d["vocab"])
-        return np.asarray(out._value())[0, 0]
+    prefill, decode_step = compiled_steps(model, cache,
+                                          counts="selection_counts")
 
     def decode(slots, pos_of):
         active = np.zeros(3, np.int32)
@@ -157,16 +99,12 @@ def test_prefix_tail_prefill_and_decode_through_the_indexed_pool(
         for s in slots:
             assert cache.ensure_capacity(s, pos_of[s])
             active[s], step[s, 0] = 1, tokens[pos_of[s]]
-        act = paddle.to_tensor(active)
-        ctx = PagedCacheContext(cache, "decode", active=act)
-        out = np.asarray(model(paddle.to_tensor(step),
-                               cache_ctx=ctx)._value())
-        cache.advance(act)
+        out, counts = decode_step(step, active)
         for s in slots:
             np.testing.assert_allclose(out[s, 0], want[pos_of[s]],
                                        atol=1e-4, rtol=0)
-        assert len(ctx.selection_counts) == d["layers"]
-        return [(int(a), int(b)) for a, b in ctx.selection_counts]
+        assert len(counts) == d["layers"]
+        return counts
 
     assert cache.begin_sequence(0, [], 0, 16)
     np.testing.assert_allclose(prefill(0, tokens[:16], 0, 16), want[15],
@@ -403,7 +341,7 @@ def test_engine_serves_across_topk_with_a_prefix_hit_and_counts(f32, tokens,
     eng = inference.create_engine(model, num_slots=3, max_seq=128,
                                   min_bucket=16, block_size=BLOCK,
                                   kernel=kernel)
-    eng.warmup()
+    eng.warmup(buckets=[16, 128])            # the two the three prompts take
     import time
     t0 = time.perf_counter()
     rng = np.random.default_rng(11)
@@ -419,13 +357,7 @@ def test_engine_serves_across_topk_with_a_prefix_hit_and_counts(f32, tokens,
     assert st["failures"]["failed"] == 0
     assert st["paging"]["prefix"]["hit_tokens"] == 64
     for h, prompt in zip(handles, (cold, grow, hit)):
-        out = np.asarray(h.output_ids)
-        seq = np.concatenate([prompt, out])
-        lg = reference_logits(tree, d, seq)[len(prompt) - 1:-1]
-        top2 = np.sort(lg, axis=-1)[:, -2:]
-        sure = top2[:, 1] - top2[:, 0] > 1e-3
-        assert sure.sum() >= len(out) // 2
-        np.testing.assert_array_equal(out[sure], lg.argmax(-1)[sure])
+        FAMILY.greedy_matches(tree, d, prompt, h.output_ids, margin=1e-3)
     sp = st["sparse"]
     assert sp["steps"] > 0 and 0 < sp["selected"] < sp["context"]
     assert sp["prefills"] == 2 and sp["prefill_context"] == 100 + 73
@@ -464,20 +396,13 @@ def test_bf16_engine_serves_within_a_tolerance_the_wrong_selection_exceeds():
     eng = inference.create_engine(model, num_slots=2, max_seq=128,
                                   min_bucket=16, block_size=BLOCK,
                                   kernel="pallas")
-    eng.warmup()
+    eng.warmup(buckets=[128])
     rng = np.random.default_rng(5)
     prompt = rng.integers(0, 512, (70,), dtype=np.int32)
     h = eng.add_request(prompt, max_new_tokens=24)
     eng.run()
-    out = np.asarray(h.output_ids)
-    seq = np.concatenate([prompt, out])
-
-    def gap(**kw):
-        lg = reference_logits(tree, d, seq, **kw)[len(prompt) - 1:-1]
-        return float((lg.max(-1) - np.take_along_axis(
-            lg, out[:, None], -1)[:, 0]).max())
-
-    sound, wrong = gap(), gap(select=REF.select_first)
+    sound, wrong = (FAMILY.served_gap(tree, d, prompt, h.output_ids, **kw)
+                    for kw in ({}, dict(select=REF.select_first)))
     assert sound < 0.08 < wrong, (sound, wrong)  # read 0.030 and 0.270
 
 
@@ -514,27 +439,3 @@ def test_the_four_shares_are_the_whole_layer(f32):
     np.testing.assert_allclose(sum(parts_ref), want, atol=1e-5)
     np.testing.assert_allclose(sum(parts_prog), want, atol=1e-5)
     assert lw["moe.router"].shape == (d["hidden"], 16)
-
-
-# -- (e) what the indexed pool has no form for ----------------------------------
-
-def _refusals():
-    from paddle_tpu.serving.sharding import serving_mesh
-    from paddle_tpu.serving.spec_decode import SpecConfig
-
-    draft = km.KeyeVL2ForCausalLM(km.keye_vl2_tiny())
-    return [("mesh", dict(mesh=serving_mesh(2)), "serving mesh"),
-            ("speculation",
-             dict(speculation=SpecConfig(draft_model=draft, k=2)),
-             "speculation=")]
-
-
-@pytest.mark.parametrize("what", ["mesh", "speculation"])
-def test_the_indexed_pool_refuses_what_it_has_no_form_for(what):
-    paddle.seed(0)
-    model = km.KeyeVL2ForCausalLM(km.keye_vl2_tiny())
-    kw, msg = next((kw, msg) for name, kw, msg in _refusals()
-                   if name == what)
-    with pytest.raises(ValueError, match=msg):
-        inference.create_engine(model, num_slots=2, max_seq=64,
-                                min_bucket=16, block_size=BLOCK, **kw)

@@ -5,95 +5,33 @@ slot a layer that every token rewrites, kept by snapshot so that a prefix hit
 can restore it — and the share of an expert-parallel deployment.  Everything is
 held against ``benchmarks/references/lfm2_moe.py`` (plain jnp, float32,
 imports nothing of the program)."""
-import json
-import os
-import sys
 import time
 
 import numpy as np
 import pytest
 import jax.numpy as jnp
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-import paddle_tpu as paddle                                   # noqa: E402
-from paddle_tpu import inference                              # noqa: E402
-from paddle_tpu.models import lfm2 as lm                      # noqa: E402
-from paddle_tpu.obs import spans as _spans                    # noqa: E402
-from paddle_tpu.serving import group_cache                    # noqa: E402
-from paddle_tpu.serving.group_cache import (                  # noqa: E402
+import paddle_tpu as paddle
+from paddle_tpu import inference
+from paddle_tpu.obs import spans as _spans
+from paddle_tpu.serving.group_cache import (
     ZERO_ROW, GroupedKVCache, GroupedPrefixCache, StatePool)
-from paddle_tpu.serving.kv_cache import CacheGroup, CacheSpec  # noqa: E402
-from paddle_tpu.serving.paging import PagedCacheContext       # noqa: E402
+from paddle_tpu.serving.kv_cache import CacheGroup, CacheSpec
 
-from benchmarks.adapters import _load                         # noqa: E402
+from families import (  # noqa: F401 — the fixtures, and the common cases
+    BLOCK, FAMILIES, STRIDE, compiled_steps, f32, family, tokens, want,
+    with_stride,
+    test_full_forward_equals_the_reference,
+    test_the_cache_refuses_what_it_has_no_form_for,
+    test_the_model_states_its_cache_and_keeps_its_dtype,
+    test_the_shares_layer_outputs_add_up_to_the_uncut_layer)
+
 from benchmarks.harness import weights                        # noqa: E402
-from benchmarks.harness.manifest import load_module           # noqa: E402
 
-REF = load_module("references", "lfm2_moe")
-ADAPTER = load_module("adapters", "lfm2_moe")
-SEED = 2 ** 31 + 40
-BLOCK, STRIDE = 8, 16     # the tests' block and snapshot stride
-
-
-def with_stride(build, *args, **kw):
-    """``build(*args, **kw)`` with the snapshot stride at the tests' 16, so
-    that a prompt of a few blocks passes several (a pool reads the constant
-    when it is built)."""
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setattr(group_cache, "SNAPSHOT_STRIDE", STRIDE)
-        return build(*args, **kw)
-
-
-def tiny_config(**kw) -> dict:
-    with open(os.path.join(ROOT, "tests", "benchmark_tests",
-                           "tiny_lfm2_moe.json")) as f:
-        return dict(json.load(f), **kw)
-
-
-def seeded(dtype: str = "float32", **kw):
-    """``(model, tree, d)``: the program's model holding the benchmark's
-    seeded weights in ``dtype``; ``tree`` is what the reference reads."""
-    cfg = tiny_config(torch_dtype=dtype, **kw)
-    d = REF.dims(cfg)
-    tree = weights.make(REF.weight_shapes(cfg), SEED, jnp.dtype(dtype))
-    paddle.seed(0)
-    model = ADAPTER.build_model(cfg)
-    model.eval()
-    _load.load(model, ADAPTER, tree, d)
-    return model, tree, d
-
-
-def reference_logits(tree, d, tokens):
-    h = REF.hidden(tree, jnp.asarray(tokens), d)
-    return np.asarray(REF.logits_rows({k: tree[k] for k in REF.HEAD_KEYS},
-                                      h, d))
-
-
-@pytest.fixture(scope="module")
-def f32():
-    return seeded()
-
-
-@pytest.fixture(scope="module")
-def tokens():
-    return np.random.default_rng(7).integers(0, 500, (160,), dtype=np.int32)
-
-
-@pytest.fixture(scope="module")
-def want(f32, tokens):
-    _model, tree, d = f32
-    return reference_logits(tree, d, tokens[:128])
-
-
-def engine(model, kernel="reference", buckets=(16, 32, 64), **kw):
-    kw = dict(dict(num_slots=3, max_seq=128, min_bucket=8, block_size=BLOCK,
-                   kernel=kernel), **kw)
-    eng = with_stride(inference.create_engine, model, **kw)
-    eng.warmup(buckets=list(buckets))
-    return eng
+FAMILY = FAMILIES["lfm2_moe"]
+REF, lm = FAMILY.ref, FAMILY.models
+seeded, reference_logits = FAMILY.seeded, FAMILY.reference_logits
+engine, greedy_matches = FAMILY.engine, FAMILY.greedy_matches
 
 
 @pytest.fixture(scope="module")
@@ -110,18 +48,6 @@ def cold(small):
     return engine(small[0], buckets=(128,), enable_prefix_cache=False)
 
 
-def greedy_matches(tree, d, prompt, out):
-    """The served tokens are the reference's first choice wherever its best
-    two logits are apart."""
-    seq = np.concatenate([prompt, np.asarray(out)])
-    lg = reference_logits(tree, d, seq)[len(prompt) - 1:-1]
-    top2 = np.sort(lg, axis=-1)[:, -2:]
-    sure = top2[:, 1] - top2[:, 0] > 1e-4
-    assert sure.sum() >= len(out) // 2
-    np.testing.assert_array_equal(np.asarray(out)[sure],
-                                  lg.argmax(-1)[sure])
-
-
 def grouped(model, kernel="reference", num_blocks=(40, 12), slots=3):
     return with_stride(
         GroupedKVCache, model.cache_spec().groups, num_slots=slots,
@@ -129,25 +55,7 @@ def grouped(model, kernel="reference", num_blocks=(40, 12), slots=3):
         num_blocks=list(num_blocks), kernel=kernel, max_tail=64)
 
 
-def prefill(model, cache, slot, ids, start, length):
-    """One tail prefill through the cache, eagerly: the logits of the row
-    the engine samples from."""
-    ctx = PagedCacheContext(
-        cache, "prefill", slot=paddle.to_tensor(np.int32(slot)),
-        length=paddle.to_tensor(np.int32(length)),
-        start=paddle.to_tensor(np.int32(start)))
-    out = model(paddle.to_tensor(np.asarray(ids)[None]), cache_ctx=ctx)
-    cache.set_length(slot, length)
-    return np.asarray(out._value())[0, 0]
-
-
 # -- (a) the model, its statement, the reference's convolution -----------------
-
-def test_full_forward_equals_the_reference(f32, tokens, want):
-    model, _tree, _d = f32
-    got = np.asarray(model(paddle.to_tensor(tokens[None, :128]))._value())[0]
-    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
-
 
 def test_the_references_convolution_is_the_three_term_sum():
     """``short_conv`` against the definition written out a position at a
@@ -168,15 +76,10 @@ def test_the_references_convolution_is_the_three_term_sum():
     np.testing.assert_allclose(tail, got[6:], atol=1e-6)
 
 
-def test_the_model_states_an_attention_group_and_a_state_group():
-    model = lm.Lfm2ForCausalLM(lm.lfm2_tiny(dtype="bfloat16"))
+def test_the_engine_builds_a_pool_a_state_array_and_a_snapshot_pool():
+    model = FAMILY.tiny_model(dtype="bfloat16")
     spec = model.cache_spec()
     assert spec.kind == "kv" and spec.num_layers == 4 and spec.tail_limit == 0
-    attn, conv = spec.groups
-    assert (attn.layers, attn.sides, attn.window, attn.state) == \
-        ((2,), ((2, 16), (2, 16)), 0, False)
-    assert (conv.layers, conv.sides, conv.window, conv.state) == \
-        ((0, 1, 3), ((2, 64),), 0, True)
     eng = inference.create_engine(model, num_slots=2, max_seq=64,
                                   min_bucket=8, block_size=BLOCK,
                                   num_kv_blocks=12, num_state_snapshots=7)
@@ -364,30 +267,20 @@ def test_prefill_then_decode_through_the_cache(f32, tokens, want, kernel):
     assert cache.nbytes() == 40 * 2 * BLOCK * 2 * 128 * 4 \
         + 3 * 2 * (3 + 12) * 64 * 4
 
-    from paddle_tpu import jit as jit_mod
-    from paddle_tpu.core.autograd import no_grad
-
-    def decode_step(step, act):
-        ctx = PagedCacheContext(cache, "decode", active=act)
-        out = model(step, cache_ctx=ctx)
-        cache.advance(act)
-        return out
-
-    step_fn = jit_mod.to_static(decode_step)     # one program, as the engine
+    prefill, decode = compiled_steps(model, cache)
 
     assert cache.begin_sequence(0, None, 0, 40, total=128, end=40)
     assert cache.planned == [(ZERO_ROW, 2)]
-    np.testing.assert_allclose(prefill(model, cache, 0, tokens[:40], 0, 40),
-                               want[39], atol=2e-5, rtol=0)
+    np.testing.assert_allclose(prefill(0, tokens[:40], 0, 40), want[39],
+                               atol=2e-5, rtol=0)
     blocks, wrote = cache.owned_blocks(0)
     assert wrote.keys() == {16, 32}
     hit = (list(blocks[:4]), wrote[32])
     assert cache.begin_sequence(2, hit, 32, 24, total=128, end=56)
     assert cache.planned == [(wrote[32], 1)]          # 48 (the stride's, last)
     assert kv.allocator.refcount(blocks[0]) == 2      # shared, not copied
-    np.testing.assert_allclose(
-        prefill(model, cache, 2, tokens[32:56], 32, 56), want[55],
-        atol=2e-5, rtol=0)
+    np.testing.assert_allclose(prefill(2, tokens[32:56], 32, 56), want[55],
+                               atol=2e-5, rtol=0)
     pos_of = {0: 40, 2: 56}
     idle = np.asarray(state.state.numpy())[:, :, 1].copy()
     while pos_of[2] < 100:
@@ -396,9 +289,7 @@ def test_prefill_then_decode_through_the_cache(f32, tokens, want, kernel):
         for s, pos in pos_of.items():
             assert cache.ensure_capacity(s, pos)
             active[s], step[s, 0] = 1, tokens[pos]
-        with no_grad():
-            out = np.asarray(step_fn(paddle.to_tensor(step),
-                                     paddle.to_tensor(active))._value())
+        out, _counts = decode(step, active)
         for s, pos in pos_of.items():
             np.testing.assert_allclose(out[s, 0], want[pos], atol=2e-5,
                                        rtol=0)
@@ -423,20 +314,21 @@ def test_a_tail_from_a_snapshot_is_bit_equal_to_the_cold_path_where_every_layer_
     cold = grouped(model)
     assert cold.begin_sequence(0, None, 0, 64, end=40)
     ids[:40] = tokens[:40]
-    whole = prefill(model, cold, 0, ids, 0, 40)
+    whole = compiled_steps(model, cold)[0](0, ids, 0, 40)
     np.testing.assert_allclose(
         whole, reference_logits(tree, d, tokens[:40])[39], atol=2e-5, rtol=0)
     warm = grouped(model)
     assert warm.begin_sequence(0, None, 0, 64, end=32)
     ids[:] = 0
     ids[:32] = tokens[:32]
-    prefill(model, warm, 0, ids, 0, 32)
+    prefill, _decode = compiled_steps(model, warm)
+    prefill(0, ids, 0, 32)
     blocks, wrote = warm.owned_blocks(0)
     assert warm.begin_sequence(1, (list(blocks[:4]), wrote[32]), 32, 64,
                                end=40)
     ids[:] = 0
     ids[:8] = tokens[32:40]
-    tail = prefill(model, warm, 1, ids, 32, 40)
+    tail = prefill(1, ids, 32, 40)
     np.testing.assert_array_equal(tail, whole)
     np.testing.assert_array_equal(
         np.asarray(warm.states[0].state.numpy())[:, :, 1],
@@ -458,7 +350,8 @@ def test_a_nan_in_the_pad_rows_reaches_no_state_and_no_logit(tokens):
     assert cache.begin_sequence(0, None, 0, 32, end=21)
     ids = np.full(32, 511, np.int32)
     ids[:21] = tokens[:21]
-    got = prefill(model, cache, 0, ids, 0, 21)
+    prefill, decode = compiled_steps(model, cache)
+    got = prefill(0, ids, 0, 21)
     assert np.isnan(got[511])
     np.testing.assert_allclose(got[:511], want[20, :511], atol=2e-5, rtol=0)
     state = cache.states[0]
@@ -469,11 +362,7 @@ def test_a_nan_in_the_pad_rows_reaches_no_state_and_no_logit(tokens):
         assert cache.ensure_capacity(0, pos)
         active, step = np.zeros(3, np.int32), np.full((3, 1), 511, np.int32)
         active[0], step[0, 0] = 1, tokens[pos]
-        ctx = PagedCacheContext(cache, "decode",
-                                active=paddle.to_tensor(active))
-        out = np.asarray(model(paddle.to_tensor(step),
-                               cache_ctx=ctx)._value())
-        cache.advance(paddle.to_tensor(active))
+        out, _counts = decode(step, active)
         np.testing.assert_allclose(out[0, 0, :511], want[pos, :511],
                                    atol=2e-5, rtol=0)
     # the idle slots decoded the NaN token: it reached no state of theirs
@@ -717,38 +606,9 @@ def test_bf16_engine_serves_within_a_tolerance():
     for p in (prompt, np.concatenate([prompt[:24], prompt[:9]])):
         h = eng.add_request(p, max_new_tokens=30)
         eng.run()
-        seq = np.concatenate([p, np.asarray(h.output_ids)])
-        lg = reference_logits(tree, d, seq)[len(p) - 1:-1]
-        gap = lg.max(-1) - np.take_along_axis(
-            lg, np.asarray(h.output_ids)[:, None], axis=-1)[:, 0]
-        assert gap.max() < 0.05
+        assert FAMILY.served_gap(tree, d, p, h.output_ids) < 0.05
     assert eng.stats()["state"]["prefills_restored"] == 1
     assert eng.health()["kv_block_invariants"] == "ok"
-
-
-def _refusals():
-    from paddle_tpu.serving.sharding import serving_mesh
-    from paddle_tpu.serving.spec_decode import SpecConfig
-
-    draft = lm.Lfm2ForCausalLM(lm.lfm2_tiny())
-    return {"mesh": (dict(mesh=serving_mesh(2)),
-                     r"a serving mesh of more than one device \(the state "
-                     r"and its snapshot pool are not sharded\)"),
-            "speculation": (
-                dict(speculation=SpecConfig(draft_model=draft, k=2)),
-                r"speculation= \(the verify window has no state form\)")}
-
-
-@pytest.mark.parametrize("what", ["mesh", "speculation"])
-def test_a_state_group_refuses_what_it_has_no_form_for(what):
-    paddle.seed(0)
-    model = lm.Lfm2ForCausalLM(lm.lfm2_tiny())
-    kw, msg = _refusals()[what]
-    with pytest.raises(ValueError, match="Lfm2ForCausalLM keeps a state of "
-                       "fixed size a slot in some layers, snapshots of it "
-                       "for the prefix cache and cannot serve with " + msg):
-        inference.create_engine(model, num_slots=2, max_seq=64,
-                                min_bucket=8, block_size=BLOCK, **kw)
 
 
 def test_the_snapshot_pools_size_is_refused_where_it_means_nothing():
@@ -770,42 +630,15 @@ def test_a_block_that_does_not_divide_the_stride_is_refused():
                                 block_size=24)
 
 
-# -- (e) the share: four chips' layers add up to the uncut layer ---------------
+# -- (e) the share's router ----------------------------------------------------
 
-def test_the_shares_layer_outputs_add_up_to_the_uncut_layer():
-    """16 experts, 4 shares of 4: each share scores all 16 (sigmoid, the
-    selection bias in the choice only), normalises over the 4 chosen and
-    computes its own; the four outputs sum to the reference's layer with every
-    expert held (the router and the bias, which every chip holds alike, are
-    counted once: they add no term of their own)."""
-    cfg = tiny_config(num_experts=16, held_experts=[0, 16])
+def test_the_selection_bias_moves_the_choice_and_not_the_weights():
+    cfg = FAMILY.tiny_config(num_experts=16, held_experts=[0, 16])
     d = REF.dims(cfg)
-    tree = weights.make(REF.weight_shapes(cfg), SEED, jnp.float32)
-    lw = REF.layer_weights(tree, 1, d)
+    lw = REF.layer_weights(
+        weights.make(REF.weight_shapes(cfg), FAMILY.seed, jnp.float32), 1, d)
     x = jnp.asarray(np.random.default_rng(5).normal(size=(40, 64)),
                     jnp.float32)
-    whole = np.asarray(REF.experts(x, lw, d, False))
-    total = np.zeros_like(whole)
-    parts = []
-    for share in range(4):
-        held = (4 * share, 4 * share + 4)
-        paddle.seed(0)
-        layer = lm.Lfm2MoE(ADAPTER.program_config(
-            dict(cfg, held_experts=list(held))))
-        layer.gate._set_data(lw["moe.router"])
-        layer.expert_bias._set_data(lw["moe.bias"])
-        layer.experts_gate_up._set_data(jnp.concatenate(
-            [lw["moe.w_gate"], lw["moe.w_up"]], axis=2)[held[0]:held[1]])
-        layer.experts_down._set_data(lw["moe.w_down"][held[0]:held[1]])
-        y = np.asarray(layer(x[None])[0])
-        np.testing.assert_allclose(
-            y, np.asarray(REF.experts(x, lw, d, False, held=held)),
-            atol=2e-5)
-        parts.append(y)
-        total += y
-    np.testing.assert_allclose(total, whole, atol=5e-5)
-    assert all(np.abs(p).max() > 1e-4 for p in parts)    # each share adds
-    # the bias moves the choice and not the weights
     chosen, w = REF.route(x, lw["moe.router"], lw["moe.bias"], d)
     plain, _ = REF.route(x, lw["moe.router"], 0 * lw["moe.bias"], d)
     assert (np.sort(np.asarray(chosen), 1)

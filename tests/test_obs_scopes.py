@@ -14,7 +14,7 @@ from paddle_tpu import obs
 from paddle_tpu.jit import trace as jit_trace
 from paddle_tpu.obs import hlo_cost, spans
 
-from chip_programs import custom_call_lines, engine_program, one_chip  # noqa: F401,E501
+from chip_programs import custom_call_lines, one_chip, pool_programs  # noqa: F401,E501
 
 NAME, ATTRS = 0, 4
 
@@ -347,7 +347,7 @@ CONTROL = ("while", "conditional", "call")
     ("decode", "paged_decode_attention"),
     ("prefill", "paged_prefill_attention")])
 def test_engine_programs_on_the_chip_have_owners_and_their_kernels_names(
-        one_chip, program, kernel):
+        pool_programs, program, kernel):
     """The decode and bucket-32 prefill programs at GPT-2 345M's widths, two
     layers, compiled for the described v5e: of the instructions that run as
     device events (those of the entry, of loop bodies and of branches, not a
@@ -357,7 +357,7 @@ def test_engine_programs_on_the_chip_have_owners_and_their_kernels_names(
     XLA:TPU names a Pallas custom call after the LAST part of its op_name,
     the ``pallas_call``'s ``name=``, which is what the accepted roofline
     readers match."""
-    _eng, compiled = engine_program(one_chip, program, layers=2)
+    _eng, compiled = pool_programs("paged", program, num_hidden_layers=2)
     hlo = compiled.as_text()
     rows = hlo_cost.instructions(hlo)
     got = hlo_cost.scope_map(hlo)["instructions"]

@@ -9,72 +9,23 @@ import numpy as np
 import pytest
 import jax
 
-from paddle_tpu import inference
 from paddle_tpu.obs import spans as _spans
-from paddle_tpu.serving import group_cache
 from paddle_tpu.serving.paging import BlockAllocator
 from paddle_tpu.serving.prefix_cache import ChainKeys, PrefixCache
 from paddle_tpu.serving.sampling import SamplingParams, host_prng_key
 
-BLOCK = 8
+from families import BLOCK, BY_KIND
 
-
-def _gpt():
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-    return GPTForCausalLM(GPTConfig(
-        vocab_size=128, hidden_size=64, num_hidden_layers=2,
-        num_attention_heads=4, max_position_embeddings=128,
-        hidden_dropout_prob=0.0, attention_probs_dropout_prob=0.0))
-
-
-def _latent():
-    from paddle_tpu.models import deepseek_v3 as dm
-    return dm.DeepseekV3ForCausalLM(dm.deepseek_v3_tiny())
-
-
-def _indexed():
-    from paddle_tpu.models import keye_vl2 as km
-    return km.KeyeVL2ForCausalLM(km.keye_vl2_tiny())
-
-
-def _windowed():
-    from paddle_tpu.models import evabyte as em
-    return em.EvaByteForCausalLM(em.evabyte_tiny())
-
-
-def _grouped_window():
-    from paddle_tpu.models import mellum as mm
-    return mm.MellumForCausalLM(mm.mellum_tiny())
-
-
-def _grouped_state():
-    from paddle_tpu.models import lfm2 as lm
-    return lm.Lfm2ForCausalLM(lm.lfm2_tiny())
-
-
-#: kind -> (model, a short prompt's length, a long one's and the pieces the
-#: long one is prefilled in: several where a group keeps a window)
-KINDS = {
-    "paged": (_gpt, 21, 100, 1),
-    "latent": (_latent, 21, 100, 1),
-    "indexed": (_indexed, 21, 100, 1),
-    "windowed": (_windowed, 21, 70, 3),          # a window of 32: 32, 64, 70
-    "grouped_window": (_grouped_window, 21, 100, 3),   # pieces of 48
-    "grouped_state": (_grouped_state, 21, 100, 1),
-}
+#: the kinds of cache served here, each by the family that states it
+KINDS = {kind: f for kind, f in BY_KIND.items() if f.prompts}
 
 
 @pytest.fixture(scope="module", params=list(KINDS))
 def served(request):
     """``(kind, its warmed engine)``, one a kind for the whole file."""
-    with pytest.MonkeyPatch.context() as mp:
-        # (a snapshot every 16 positions, so that a tiny prompt plans some)
-        mp.setattr(group_cache, "SNAPSHOT_STRIDE", 16)
-        eng = inference.create_engine(
-            KINDS[request.param][0](), num_slots=3, max_seq=128, min_bucket=8,
-            block_size=BLOCK, kernel="reference")
-    eng.warmup()
-    return request.param, eng
+    family = KINDS[request.param]
+    return request.param, family.engine(family.tiny_model(),
+                                        kernel="reference", buckets=())
 
 
 def prompt_of(n, seed=0):
@@ -190,7 +141,7 @@ def device_matches_host(eng):
 @pytest.mark.parametrize("prompt", ["short", "long"])
 def test_an_admission_stages_its_slot_by_the_rule(served, sampled, prompt):
     kind, eng = served
-    _model, short, long, n_pieces = KINDS[kind]
+    short, long, n_pieces = KINDS[kind].prompts
     L, n = (short, 1) if prompt == "short" else (long, n_pieces)
     params = SamplingParams(temperature=0.8, top_k=7, top_p=0.9, seed=1234) \
         if sampled else SamplingParams()

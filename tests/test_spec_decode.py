@@ -22,7 +22,7 @@ The correctness bar mirrors the rest of the serving stack:
   uninterrupted run, exactly once, with flat compile counters.
 
 NOTHING here may be marked slow — tools/collect_gate.py enforces this
-module rides in tier-1 (tier1_budgets.json caps its wall time).
+module rides in tier-1.
 """
 import os
 import tempfile

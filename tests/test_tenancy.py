@@ -24,7 +24,7 @@ The correctness bar follows the serving stack's house rules:
   adapters/grammars is bitwise the single-chip tenancy engine.
 
 NOTHING here may be marked slow — tools/collect_gate.py enforces this
-module rides in tier-1 (tier1_budgets.json caps its wall time).
+module rides in tier-1.
 """
 import os
 import tempfile

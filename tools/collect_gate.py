@@ -9,18 +9,7 @@ tests is worse than a red one.  This gate runs ``pytest --collect-only``
 and exits nonzero on ANY collection error, so an import break can never
 again zero out the suite unnoticed.
 
-A second failure class this gate covers (ISSUE 6): the tier-1 suite
-runs close to its CI timeout (cold-compile since the persistent XLA
-cache went opt-in — tests/test_isolation.py), so ONE file quietly growing 2x
-pushes the whole suite over and zeroes it out just as surely as an
-import break.  ``tools/tier1_budgets.json`` records a wall-time budget
-for the slowest tier-1 files; a run that sets
-``PADDLE_TPU_TIER1_TIMING_REPORT=<path>`` gets a per-file duration
-report from tests/conftest.py, and ``--timing-report <path>`` here
-fails the gate when any budgeted file exceeds its recorded budget by
-more than 25%.
-
-A third failure class (ISSUE 7): the serving stack's zero-recompile and
+A second failure class (ISSUE 7): the serving stack's zero-recompile and
 no-host-round-trip invariants are now *statically* checkable.
 ``--lint`` runs ``python -m tools.tpulint paddle_tpu/`` (the
 recompile-hazard/host-sync AST lint — every suppression must carry a
@@ -32,17 +21,14 @@ key fails the gate instead of surfacing as a steady-state cache miss).
 Usage::
 
     python tools/collect_gate.py [pytest-target ...]   # default: tests/
-    python tools/collect_gate.py --timing-report /tmp/t1_times.json
     python tools/collect_gate.py --lint
 
 Exit codes: 0 = everything collects; 1 = collection errors (listed on
-stderr), a busted wall-time budget, an active lint finding, or shape-
-manifest drift; pytest's own exit code for other failures (usage error
-etc.).
+stderr), an active lint finding, or shape-manifest drift; pytest's own
+exit code for other failures (usage error etc.).
 """
 from __future__ import annotations
 
-import json
 import os
 import re
 import subprocess
@@ -50,24 +36,12 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-BUDGET_MANIFEST = os.path.join(REPO, "tools", "tier1_budgets.json")
-
 
 def main(argv=None) -> int:
     args = list(argv if argv is not None else sys.argv[1:])
     run_lint = "--lint" in args
     if run_lint:
         args.remove("--lint")
-    report_path = None
-    if "--timing-report" in args:
-        i = args.index("--timing-report")
-        try:
-            report_path = args[i + 1]
-        except IndexError:
-            print("collect_gate: --timing-report needs a path",
-                  file=sys.stderr)
-            return 2
-        del args[i:i + 2]
     targets = args or ["tests/"]
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
@@ -101,10 +75,6 @@ def main(argv=None) -> int:
     rc = paging_gate(env, collected_output=out)
     if rc:
         return rc
-    if report_path is not None:
-        rc = budget_gate(report_path)
-        if rc:
-            return rc
     if run_lint:
         rc = lint_gate(env)
         if rc:
@@ -222,58 +192,6 @@ def paging_gate(env=None, collected_output=None) -> int:
     print("collect_gate: tier-1-critical OK — " + ", ".join(
         f"{n} tests in {t}" for t, n in counts.items()) +
         "; none marked slow")
-    return 0
-
-
-def budget_gate(report_path: str,
-                manifest_path: str = BUDGET_MANIFEST) -> int:
-    """Tier-1 wall-time budgets: every file recorded in
-    ``tools/tier1_budgets.json`` must stay within ``tolerance`` (default
-    +25%) of its budgeted seconds in the run's per-file timing report
-    (written by tests/conftest.py under
-    ``PADDLE_TPU_TIER1_TIMING_REPORT``).
-
-    A budgeted file MISSING from the report also fails: the manifest
-    names the files that dominate the suite's runtime, and a rename or
-    deletion that silently drops one from measurement would let its
-    successor grow unwatched — re-record the manifest instead."""
-    try:
-        with open(manifest_path) as f:
-            manifest = json.load(f)
-        tolerance = float(manifest.get("tolerance", 0.25))
-        budgets = manifest["budgets"]
-    except (OSError, ValueError, KeyError) as e:
-        print(f"collect_gate: FAIL — cannot read budget manifest "
-              f"{manifest_path}: {type(e).__name__}: {e}", file=sys.stderr)
-        return 1
-    try:
-        with open(report_path) as f:
-            measured = json.load(f)["file_seconds"]
-    except (OSError, ValueError, KeyError) as e:
-        print(f"collect_gate: FAIL — cannot read timing report "
-              f"{report_path}: {e}", file=sys.stderr)
-        return 1
-    over = []
-    for path, budget in sorted(budgets.items()):
-        got = measured.get(path)
-        if got is None:
-            over.append(f"  {path}: budgeted {budget}s but absent from "
-                        "the timing report (renamed/deleted? re-record "
-                        "tools/tier1_budgets.json)")
-        elif got > budget * (1.0 + tolerance):
-            over.append(f"  {path}: {got:.1f}s > budget {budget}s "
-                        f"+{tolerance:.0%} (= {budget * (1 + tolerance):.1f}s)")
-    if over:
-        print(f"collect_gate: FAIL — {len(over)} tier-1 wall-time budget "
-              f"violation(s) (the cold-compile suite runs close to its "
-              f"CI timeout; "
-              f"trim the test or re-record the budget deliberately):",
-              file=sys.stderr)
-        for line in over:
-            print(line, file=sys.stderr)
-        return 1
-    print(f"collect_gate: budgets OK — {len(budgets)} tier-1 files within "
-          f"+{tolerance:.0%} of their recorded wall-time budgets")
     return 0
 
 
