@@ -30,3 +30,6 @@ from .mellum import (
 from .lfm2 import (
     Lfm2Config, Lfm2ForCausalLM, lfm2_tiny,
 )
+from .kimi_linear import (
+    KimiLinearConfig, KimiLinearForCausalLM, kimi_linear_tiny,
+)

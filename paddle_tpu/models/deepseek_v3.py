@@ -73,7 +73,8 @@ class DeepseekV3Config:
     hidden_size: int = 2048
     num_hidden_layers: int = 40
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    #: None: one query projection, no low-rank pair and no norm between
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -90,6 +91,9 @@ class DeepseekV3Config:
     max_position_embeddings: int = 131072
     rms_norm_eps: float = 1e-6
     rope_theta: float = 32e6
+    #: no rotation at all: the ``qk_rope_head_dim`` columns of a query and
+    #: the shared key part beside the latent are used as projected
+    mla_use_nope: bool = False
     initializer_range: float = 0.02
     dtype: str = "float32"
 
@@ -148,6 +152,13 @@ def causal_latent_attention(q_lat, lat, *, scale: float, dv: int):
 
 
 class DeepseekV3Attention(Layer):
+    """Latent attention of any configuration that names the widths the way
+    :class:`DeepseekV3Config` does (``kimi_linear.py``'s does).  Two cases
+    beside the published DeepSeek-V3 form: ``q_lora_rank=None`` has one
+    ``q_proj`` in place of ``q_a_proj``/``q_a_layernorm``/``q_b_proj``, and
+    ``mla_use_nope`` rotates nothing — the ``rope``-wide columns are one
+    more key part that every head shares."""
+
     def __init__(self, c: DeepseekV3Config):
         super().__init__()
         self.c = c
@@ -162,9 +173,12 @@ class DeepseekV3Attention(Layer):
             return self.create_parameter([n], dtype=c.dtype,
                                          default_initializer=I.Constant(1.0))
 
-        self.q_a_proj = mat(h, c.q_lora_rank)
-        self.q_a_layernorm = gain(c.q_lora_rank)
-        self.q_b_proj = mat(c.q_lora_rank, H * c.qk_head_dim)
+        if c.q_lora_rank is None:
+            self.q_proj = mat(h, H * c.qk_head_dim)
+        else:
+            self.q_a_proj = mat(h, c.q_lora_rank)
+            self.q_a_layernorm = gain(c.q_lora_rank)
+            self.q_b_proj = mat(c.q_lora_rank, H * c.qk_head_dim)
         self.kv_a_proj_with_mqa = mat(h, c.latent_dim)
         self.kv_a_layernorm = gain(c.kv_lora_rank)
         # the published kv_b_proj [rank, H * (nope + v)], per head and in
@@ -189,16 +203,22 @@ class DeepseekV3Attention(Layer):
         else:
             raise ValueError(f"latent attention has no {cache_ctx.mode!r} "
                              f"form")
-        c_q = _rms(jnp.dot(x, self.q_a_proj._value()),
-                   self.q_a_layernorm._value(), c.rms_norm_eps)
-        q = jnp.dot(c_q, self.q_b_proj._value()).reshape(
-            B, S, H, c.qk_head_dim)
+        if c.q_lora_rank is None:
+            q = jnp.dot(x, self.q_proj._value())
+        else:
+            c_q = _rms(jnp.dot(x, self.q_a_proj._value()),
+                       self.q_a_layernorm._value(), c.rms_norm_eps)
+            q = jnp.dot(c_q, self.q_b_proj._value())
+        q = q.reshape(B, S, H, c.qk_head_dim)
         q_nope = q[..., :c.qk_nope_head_dim]
-        q_rope = _rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
+        q_rope = q[..., c.qk_nope_head_dim:]
         ckr = jnp.dot(x, self.kv_a_proj_with_mqa._value())
         c_kv = _rms(ckr[..., :rank], self.kv_a_layernorm._value(),
                     c.rms_norm_eps)
-        k_rope = _rope(ckr[..., rank:], pos, c.rope_theta)
+        k_rope = ckr[..., rank:]
+        if not c.mla_use_nope:
+            q_rope = _rope(q_rope, pos, c.rope_theta)
+            k_rope = _rope(k_rope, pos, c.rope_theta)
         lat = jnp.concatenate([c_kv, k_rope], axis=-1)        # [B, S, W]
         scale = c.qk_head_dim ** -0.5
         if cache_ctx is not None and cache_ctx.mode == "prefill":

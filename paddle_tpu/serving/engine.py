@@ -762,7 +762,9 @@ class Engine:
         #: a group that keeps state: slots whose state the decode steps
         #: rewrote, and prefills by the state they started from
         self._state = {"steps": 0, "slots": 0, "prefills": 0,
-                       "prefills_restored": 0, "hit_tokens_given_up": 0}
+                       "prefills_restored": 0, "hit_tokens_given_up": 0,
+                       "state_bytes_restored": 0,
+                       "state_bytes_snapshotted": 0, "step_state_bytes": 0}
         #: the host's path of the admissions: walks over a prompt for its
         #: chain keys and staging programs issued (``serving/staging``)
         self._admission = {"admissions": 0, "key_passes": 0,
@@ -1717,7 +1719,7 @@ class Engine:
                 if self.cache_spec.layer_groups:
                     self._note_group_prefill(sp, req.slot, start, end)
                     if self.cache.states:
-                        self._note_state_prefill(sp, req)
+                        self._note_state_prefill(sp, req, end - start)
                 return self._step_call("serving.prefill",
                                        self._prefill_fn, *args, span=sp)
         except Exception as e:           # noqa: BLE001 — isolation boundary
@@ -2217,12 +2219,17 @@ class Engine:
         if self.cache.states:
             # the step rewrites the state of every running slot, in each of
             # the state groups' layers, and of no other
+            first = self.cache.states[0]
+            # what its recurrent side's kernels must read and write: the
+            # running slots' state, once in and once out, a layer
+            moved = len(self.running) * first.num_layers * 2 \
+                * first.recurrent_nbytes()
             self._state["steps"] += 1
             self._state["slots"] += len(self.running)
+            self._state["step_state_bytes"] += moved
             if self._step_span is not None:
-                first = self.cache.states[0]
                 self._step_span.set(
-                    state_slots=len(self.running),
+                    state_slots=len(self.running), state_bytes=moved,
                     state_snapshots_used=first.rows_in_use(),
                     state_snapshots=first.num_blocks
                     - first.allocator.reserved)
@@ -2237,6 +2244,8 @@ class Engine:
         sp.set(swa_full_rows=int(np.sum(i + 1)), swa_full_keys=L,
                swa_window_rows=int(np.sum(np.minimum(i + 1, W))),
                swa_window_keys=L - max(0, start - W + 1))
+        if self.cache_spec.kind == "latent":
+            return          # the latent kernels' work is ``_note_prefill_pairs``
         # how the tail-prefill kernel gets there: a layer's work items by
         # kind of layer, and the rows it multiplies against the rows asked
         # for, from the kernel's own plan and list (nothing under
@@ -2272,19 +2281,29 @@ class Engine:
         for k, v in work.items():
             self._sparse[k] += v
 
-    def _note_state_prefill(self, sp, req: Request) -> None:
+    def _note_state_prefill(self, sp, req: Request, tail: int) -> None:
         """What the program about to run does to the groups that keep state,
         from the plan its admission wrote: the snapshot row it starts from
         (0: the zeros, a cold prompt), the snapshots it writes, and the
         tokens this admission's hit gave up for want of a snapshot or of a
         window's blocks."""
         row, written = self.cache.planned[0]
+        # what the restore and the snapshots move: a row weighs a slot's state
+        weight = self.cache.states[0].slot_nbytes()
+        moved = dict(state_bytes_restored=weight * (row > 0),
+                     state_bytes_snapshotted=weight * written)
         sp.set(state_row=row, state_snapshots_written=written,
-               state_hit_given_up=req._hit_given_up)
+               state_hit_given_up=req._hit_given_up, **moved)
+        chunk = self.cache.states[0].chunk
+        if chunk:
+            # the recurrence's scan over the tail's real tokens, in chunks
+            sp.set(kda_tail_tokens=tail, kda_chunks=-(-tail // chunk))
         st = self._state
         st["prefills"] += 1
         st["prefills_restored"] += row > 0
         st["hit_tokens_given_up"] += req._hit_given_up
+        for k, v in moved.items():
+            st[k] += v
 
     def _note_prefill_pairs(self, sp, start: int, L: int) -> None:
         """The (query, key) pairs of the tail ``[start, L)``'s real tokens,

@@ -36,7 +36,14 @@ several of them:
   where the groups above have their blocks *and* every state group has a
   snapshot, shortened otherwise.  The tail prefill reads its first state from
   the pool inside its own program (row 0: zeros, a cold prompt) and writes
-  the slot's state from the prompt's real end.
+  the slot's state from the prompt's real end.  Its sides are of two kinds
+  (``CacheGroup``): a *shift* side the pool itself shifts, and a
+  *recurrent* side — a float32 matrix a head — that the model's recurrence
+  maps forward: the pool hands a prefill its starting state and the ends to
+  return states at, takes back the state at the tail's real end and at
+  those ends, and gives a decode step the layer's buffer to rewrite in
+  place for the running slots.  One snapshot row, one plan and one
+  reference count cover all of a group's sides.
 """
 from __future__ import annotations
 
@@ -62,59 +69,99 @@ GroupHit = Tuple[object, ...]
 #: the snapshot row that is zeros and never written: a cold prompt's state
 ZERO_ROW = 0
 #: a tail prefill leaves a snapshot at every absolute position that is a
-#: multiple of this (and at its prompt's last whole block)
+#: multiple of its group's stride (and at its prompt's last whole block);
+#: this is the stride of a group that states none (``CacheGroup.stride`` 0)
 SNAPSHOT_STRIDE = 256
+#: the bytes a snapshot pool takes at most when its rows are not given
+SNAPSHOT_POOL_BYTES = 1 << 30
 #: named scope of a state group's writes (the slot's state, the snapshots)
 STATE_WRITE_SCOPE = "state.write"
 
 
 class StatePool:
-    """A state group's storage: ``state [layers, rows, slots, width]``, the
-    buffer each slot keeps a layer — the last ``rows`` columns of a
-    ``width``-wide product — and ``snapshots [layers, rows, snapshot rows,
-    width]``, copies of it that tail prefills left at known lengths, with an
-    allocator of snapshot rows (row 0 is zeros and never written).  Slots and
-    snapshot rows lie on the tiled dims, so a decode step's shift is whole
-    tiles.
+    """A state group's storage, a side of each kind at most.
 
-    A prefill program is told what to do by the slot's **plan**, a row of
-    int32 the host writes at admission (device state like a block table):
-    the snapshot row it starts from (:data:`ZERO_ROW`: a cold prompt), then
-    the rows to write and the tail-relative ends to write them at (a row past
+    The **shift** side: ``state [layers, rows, slots, width]``, the buffer
+    each slot keeps a layer — the last ``rows`` columns of a ``width``-wide
+    product — and ``snapshots [layers, rows, snapshot rows, width]``, copies
+    of it that tail prefills left at known lengths.  Slots and snapshot rows
+    lie on the tiled dims, so a decode step's shift is whole tiles.
+
+    The **recurrent** side ``(heads, d_k, d_v)``, float32: ``recurrent[layer]
+    [slots, heads, d_k, d_v]`` and ``recurrent_snapshots[layer] [snapshot
+    rows, heads, d_k, d_v]``, a buffer a layer so that a decode kernel can
+    alias the one it rewrites.  The pool never computes it: a prefill is
+    given its starting state and returns the states to keep, a decode step
+    is given the layer's buffer.
+
+    One allocator of snapshot rows (row 0 is zeros and never written) and
+    one **plan** a slot cover both: a row of int32 the host writes at
+    admission (device state like a block table): the snapshot row the
+    prefill program starts from (:data:`ZERO_ROW`: a cold prompt), then the
+    rows to write and the tail-relative ends to write them at (a row past
     the pool: nothing is written)."""
 
-    def __init__(self, num_slots: int, num_layers: int,
-                 side: Tuple[int, int], dtype, *, block_size: int,
-                 max_tail: int, num_snapshots: Optional[int] = None):
+    def __init__(self, num_slots: int, num_layers: int, sides, dtype, *,
+                 block_size: int, max_tail: int,
+                 num_snapshots: Optional[int] = None, stride: int = 0,
+                 chunk: int = 0):
         self.num_slots, self.num_layers = int(num_slots), int(num_layers)
-        self.rows, self.width = int(side[0]), int(side[1])
-        self.block_size, self.stride = int(block_size), SNAPSHOT_STRIDE
+        sides = (tuple(sides),) if isinstance(sides[0], int) else tuple(sides)
+        shift = [s for s in sides if len(s) == 2]
+        recurrent = [s for s in sides if len(s) == 3]
+        if len(shift) > 1 or len(recurrent) > 1 \
+                or len(shift) + len(recurrent) != len(sides):
+            raise ValueError(f"a state pool keeps a shift side (rows, width) "
+                             f"and a recurrent side (heads, d_k, d_v) at "
+                             f"most, got {sides}")
+        self.rows, self.width = (int(n) for n in (shift or [(0, 0)])[0])
+        #: ``(heads, d_k, d_v)`` of the recurrent side, or None
+        self.recurrent_shape = tuple(int(n) for n in recurrent[0]) \
+            if recurrent else None
+        self.block_size = int(block_size)
+        self.stride = int(stride) or SNAPSHOT_STRIDE
         if self.stride % self.block_size:
             raise ValueError(f"block_size {block_size} must divide the "
                              f"snapshot stride {self.stride}")
+        #: rows the recurrent side's scan takes at a time (0: none)
+        self.chunk = int(chunk)
         #: snapshots one prefill program writes at most: the stride's, and
         #: the one at the prompt's last whole block
         self.max_snaps = int(max_tail) // self.stride + 1
+        self.dtype = dtype_mod.convert_dtype(dtype)
         if num_snapshots is None:
-            num_snapshots = self.num_slots * (self.max_snaps + 1) + 1
+            # every slot's prefill and what it started from, as far as the
+            # bytes allow: a row weighs what a slot's state weighs
+            num_snapshots = max(2, min(
+                self.num_slots * (self.max_snaps + 1) + 1,
+                SNAPSHOT_POOL_BYTES // self.slot_nbytes()))
         self.num_blocks = int(num_snapshots)
         if self.num_blocks < 2:
             raise ValueError("a snapshot pool holds the zero row and one more "
                              f"at least, got {num_snapshots}")
-        self.dtype = dtype_mod.convert_dtype(dtype)
         self.allocator = BlockAllocator(self.num_blocks, reserved=1)
-        self.state = Tensor._wrap(jnp.zeros(
-            (self.num_layers, self.rows, self.num_slots, self.width),
-            self.dtype))
-        self.snapshots = Tensor._wrap(jnp.zeros(
-            (self.num_layers, self.rows, self.num_blocks, self.width),
-            self.dtype))
+        self.state = self.snapshots = None
+        if shift:
+            self.state = Tensor._wrap(jnp.zeros(
+                (self.num_layers, self.rows, self.num_slots, self.width),
+                self.dtype))
+            self.snapshots = Tensor._wrap(jnp.zeros(
+                (self.num_layers, self.rows, self.num_blocks, self.width),
+                self.dtype))
+        self.recurrent: List[Tensor] = []
+        self.recurrent_snapshots: List[Tensor] = []
+        if recurrent:
+            for _ in range(self.num_layers):
+                self.recurrent.append(Tensor._wrap(jnp.zeros(
+                    (self.num_slots, *self.recurrent_shape), jnp.float32)))
+                self.recurrent_snapshots.append(Tensor._wrap(jnp.zeros(
+                    (self.num_blocks, *self.recurrent_shape), jnp.float32)))
         #: the plan rows as the host wrote them last (the device's copy is
         #: ``plan``)
         self._plans = np.tile(self._plan_row(ZERO_ROW, {}, 0),
                               (self.num_slots, 1))
         self.plan = Tensor._wrap(jnp.asarray(self._plans))
-        for t in (self.state, self.snapshots, self.plan):
+        for t in (*self.buffers(), self.plan):
             t.persistable = True
         #: snapshot rows each slot holds a reference on: the one it started
         #: from and the ones its prefill wrote
@@ -130,10 +177,22 @@ class StatePool:
     # -- host-side slot lifecycle ---------------------------------------------
 
     def buffers(self) -> List[Tensor]:
-        return [self.state, self.snapshots]
+        shift = [self.state, self.snapshots] if self.state is not None else []
+        return shift + self.recurrent + self.recurrent_snapshots
 
     def nbytes(self) -> int:
         return sum(int(b._value().nbytes) for b in self.buffers())
+
+    def recurrent_nbytes(self) -> int:
+        """Bytes of one slot's recurrent state in one layer."""
+        return 4 * int(np.prod(self.recurrent_shape or (0,)))
+
+    def slot_nbytes(self) -> int:
+        """Bytes a slot's state weighs over the group's layers, which is what
+        a snapshot row weighs."""
+        return self.num_layers * (
+            self.rows * self.width * np.dtype(self.dtype).itemsize
+            + self.recurrent_nbytes())
 
     def rows_in_use(self) -> int:
         """Snapshot rows that hold a snapshot: a slot's or the cache's."""
@@ -144,9 +203,13 @@ class StatePool:
         """The lengths in ``(start, end]`` a tail prefill of ``[start, end)``
         leaves a snapshot at, the most wanted first: the prompt's last whole
         block (where a replay of this very prompt hits), then every
-        ``stride``-th absolute position, the farthest first."""
+        ``stride``-th absolute position, the farthest first.  Where a row is
+        a recurrent state (megabytes) and the prompt ends on a stride, the
+        replay's is left out: a prompt that goes on hits the strided one a
+        block later, and a row is worth more than a replay's last stride."""
         last = (end - 1) // self.block_size * self.block_size
-        ends = [last] if last > start else []
+        ends = [last] if last > start and not (
+            self.recurrent_shape and end % self.stride == 0) else []
         return ends + [e for e in range(end // self.stride * self.stride,
                                         start, -self.stride) if e != last]
 
@@ -218,11 +281,55 @@ class StatePool:
     def stats(self) -> dict:
         return {"snapshot_rows": self.num_blocks - self.allocator.reserved,
                 "snapshot_rows_in_use": self.rows_in_use(),
+                "stride": self.stride,
+                "slot_bytes": self.slot_nbytes(),
+                "state_bytes": self.num_slots * self.slot_nbytes(),
+                "snapshot_pool_bytes": self.num_blocks * self.slot_nbytes(),
                 "snapshots_written": self.snapshots_written,
                 "snapshots_skipped": self.snapshots_skipped,
                 "restored": self.restored, "cold": self.cold}
 
     # -- traced state ops -----------------------------------------------------
+
+    def _planned(self, slot, S: int):
+        """``(slot, row started from, rows to write [K], ends [K])`` of the
+        ``S``-row program of ``slot``, from the device's plan."""
+        K = min(self.max_snaps, S // self.stride + 1)
+        s = _as_i32(slot).reshape(())
+        plan = jax.lax.dynamic_index_in_dim(self.plan._value(), s, 0, False)
+        return s, plan[0], plan[1:1 + K], \
+            plan[1 + self.max_snaps:1 + self.max_snaps + K]
+
+    def recurrent_start(self, layer_idx: int, slot, S: int):
+        """What the recurrence of an ``S``-row tail prefill starts from and
+        must hand back: ``(state [heads, d_k, d_v]`` of the snapshot row the
+        slot's plan names, ``ends [K])``, the tail-relative row counts to
+        return the state after (0: none)."""
+        _, first, _, ends = self._planned(slot, S)
+        pool = self.recurrent_snapshots[layer_idx]._value()
+        return jax.lax.dynamic_index_in_dim(pool, first, 0, False), ends
+
+    def recurrent_finish(self, layer_idx: int, slot, S: int, last, kept):
+        """Takes back ``last [heads, d_k, d_v]``, the state at the tail's
+        real end — the slot's from now on — and ``kept [K, ...]``, the states
+        at :meth:`recurrent_start`'s ends, into the planned rows."""
+        s, _, snaps, _ = self._planned(slot, S)
+        st, pool = self.recurrent[layer_idx], \
+            self.recurrent_snapshots[layer_idx]
+        with jax.named_scope(STATE_WRITE_SCOPE):
+            st._set_data(jax.lax.dynamic_update_index_in_dim(
+                st._value(), last.astype(jnp.float32), s, 0))
+            pool._set_data(pool._value().at[snaps].set(
+                kept.astype(jnp.float32), mode="drop"))
+
+    def recurrent_step(self, layer_idx: int, step, active):
+        """A decode step: ``step(state [slots, heads, d_k, d_v], active)
+        -> (out, state)`` rewrites the running slots' state in the layer's
+        buffer (aliased: the others' bytes stay) and returns ``out``."""
+        buf = self.recurrent[layer_idx]
+        out, new = step(buf._value(), _as_i32(active))
+        buf._set_data(new)
+        return out
 
     def prefill_update(self, layer_idx: int, slot, z, start, length):
         """A tail prefill's columns ``z [1, S, width]`` at absolute positions
@@ -232,13 +339,8 @@ class StatePool:
         1`` views of ``ext [1, rows + S, width]``, tap ``k`` of position ``t``
         column ``t - (rows - k)``."""
         S = z.shape[1]
-        K = min(self.max_snaps, S // self.stride + 1)
-        s = _as_i32(slot).reshape(())
+        s, first, snaps, ends = self._planned(slot, S)
         n = _as_i32(length).reshape(()) - _as_i32(start).reshape(())
-        plan = jax.lax.dynamic_index_in_dim(self.plan._value(), s, 0, False)
-        first = plan[0]
-        snaps, ends = plan[1:1 + K], \
-            plan[1 + self.max_snaps:1 + self.max_snaps + K]
         st, pool = self.state._value(), self.snapshots._value()
         ext = jnp.concatenate([pool[layer_idx, :, first][None],
                                z.astype(self.dtype)], axis=1)
@@ -293,9 +395,10 @@ class GroupedKVCache:
         for g, n in zip(self.groups, sizes):
             if g.state:
                 self.states.append(StatePool(
-                    num_slots, len(g.layers), g.sides[0], dtype,
+                    num_slots, len(g.layers), g.sides, dtype,
                     block_size=block_size, num_snapshots=n,
-                    max_tail=max_tail or max_seq))
+                    max_tail=max_tail or max_seq, stride=g.stride,
+                    chunk=g.chunk))
                 by_group.append(self.states[-1])
                 continue
             if g.window and n is None:
@@ -355,6 +458,9 @@ class GroupedKVCache:
                  "alloc_failures": p.allocator.alloc_failures}
                 for g, p in zip(kept, self.pools)] + [
             {"layers": p.num_layers, "state": [p.rows, p.width],
+             "recurrent": list(p.recurrent_shape or ()),
+             "state_bytes": p.num_slots * p.slot_nbytes(),
+             "snapshot_pool_bytes": p.num_blocks * p.slot_nbytes(),
              "blocks": p.num_blocks - p.allocator.reserved,
              "used": p.allocator.used_blocks,
              "cached_idle": p.allocator.idle_cached_blocks,
@@ -477,6 +583,34 @@ class GroupedKVCache:
     def decode_attention(self, layer_idx: int, q, k, v, active):
         pool, i = self._where[layer_idx]
         return pool.decode_attention(i, q, k, v, active)
+
+    def latent_prefill_write(self, layer_idx: int, slot, lat, start) -> None:
+        pool, i = self._where[layer_idx]
+        pool.latent_prefill_write(i, slot, lat, start)
+
+    def latent_prefill_attention(self, layer_idx: int, slot, q, lat, w_uk,
+                                 w_uv, start, length, *, scale: float):
+        pool, i = self._where[layer_idx]
+        return pool.latent_prefill_attention(i, slot, q, lat, w_uk, w_uv,
+                                             start, length, scale=scale)
+
+    def latent_decode_attention(self, layer_idx: int, q_lat, lat, active, *,
+                                scale: float, dv: int):
+        pool, i = self._where[layer_idx]
+        return pool.latent_decode_attention(i, q_lat, lat, active,
+                                            scale=scale, dv=dv)
+
+    def recurrent_start(self, layer_idx: int, slot, S: int):
+        pool, i = self._where[layer_idx]
+        return pool.recurrent_start(i, slot, S)
+
+    def recurrent_finish(self, layer_idx: int, slot, S: int, last, kept):
+        pool, i = self._where[layer_idx]
+        pool.recurrent_finish(i, slot, S, last, kept)
+
+    def recurrent_step(self, layer_idx: int, step, active):
+        pool, i = self._where[layer_idx]
+        return pool.recurrent_step(i, step, active)
 
     def state_prefill(self, layer_idx: int, slot, z, start, length):
         pool, i = self._where[layer_idx]

@@ -62,16 +62,27 @@ class CacheGroup:
     position.
 
     ``state=True`` is the third retention: nothing a token.  ``sides`` then
-    reads as the one buffer of fixed size a *slot* keeps a layer — ``(rows,
-    width)``: the last ``rows`` columns of a ``width``-wide product, which
-    every token shifts by one — with no table and no blocks.  What a prefix
-    hit can reuse of such a group is a **snapshot** of it that somebody kept
-    at exactly the hit's end (``group_cache.StatePool``)."""
+    reads as the buffers of fixed size a *slot* keeps a layer, with no table
+    and no blocks, and each side's arity says what kind it is: ``(rows,
+    width)`` is a **shift** side — the last ``rows`` columns of a
+    ``width``-wide product, which every token shifts by one, in the cache's
+    dtype — and ``(heads, d_k, d_v)`` a **recurrent** side — a float32 matrix
+    a head that the model's own recurrence maps forward with every token;
+    the pool stores, snapshots and restores it and never computes it.  A
+    group keeps one side of a kind at most.  What a prefix hit can reuse of
+    such a group is a **snapshot** of all its sides that somebody kept at
+    exactly the hit's end (``group_cache.StatePool``); ``stride`` is the
+    distance between the snapshots a tail prefill leaves (the group's own:
+    a snapshot costs what the state weighs; 0: the pool's default) and
+    ``chunk`` the rows the model's scan of a recurrent side takes at a time
+    (0: no scan), which the engine's spans count a tail in."""
 
     layers: Tuple[int, ...]
-    sides: Tuple[Tuple[int, int], ...]
+    sides: Tuple[Tuple[int, ...], ...]
     window: int = 0
     state: bool = False
+    stride: int = 0
+    chunk: int = 0
 
 
 @dataclass(frozen=True)
@@ -144,8 +155,10 @@ class CacheSpec:
         between them name every layer ``0..n-1`` once.  The attention calls
         are ``kind``'s; which keys a call reads is its layer's group's."""
         groups = tuple(CacheGroup(tuple(int(i) for i in g.layers),
-                                  tuple((int(h), int(w)) for h, w in g.sides),
-                                  int(g.window), bool(g.state))
+                                  tuple(tuple(int(n) for n in side)
+                                        for side in g.sides),
+                                  int(g.window), bool(g.state), int(g.stride),
+                                  int(g.chunk))
                        for g in groups)
         named = sorted(i for g in groups for i in g.layers)
         if not groups or named != list(range(len(named))):
@@ -153,9 +166,21 @@ class CacheSpec:
                              f"layer 0..{len(named) - 1} once")
         if any(g.window < 0 for g in groups):
             raise ValueError("a group's window must be >= 0")
-        if any(g.state and (g.window or len(g.sides) != 1) for g in groups):
-            raise ValueError("a state group keeps one buffer a slot a layer "
-                             "and no window")
+        for g in groups:
+            arities = sorted(len(side) for side in g.sides)
+            if g.state and (g.window or arities not in ([2], [3], [2, 3])):
+                raise ValueError(
+                    "a state group keeps one buffer of a kind a slot a layer "
+                    "— a shift side (rows, width), a recurrent side (heads, "
+                    "d_k, d_v) or one of each — and no window")
+            if not g.state and (g.stride or arities != [2] * len(arities)):
+                raise ValueError("a group that keeps tokens states (heads, "
+                                 "width) sides and no snapshot stride")
+            if g.stride < 0:
+                raise ValueError("a group's snapshot stride must be >= 0")
+            if g.chunk < 0 or (g.chunk and not (g.state and 3 in arities)):
+                raise ValueError("a scan's chunk is stated beside a recurrent "
+                                 "side, and is >= 0")
         if groups[0].state:
             raise ValueError("the first group counts the sequence's "
                              "positions in blocks: it is no state group")

@@ -1121,6 +1121,30 @@ class PagedCacheContext(CacheContext):
             raise ValueError("a state group has no verify form")
         return self.cache.state_decode(self.layer_idx, z, self.active)
 
+    def recurrent_start(self, seq_len: int):
+        """A tail prefill of ``seq_len`` rows in a layer whose group keeps a
+        recurrent side: ``(state, ends)`` — the state before the tail's first
+        row (of the snapshot the slot's plan names; zeros for a cold prompt)
+        and the tail-relative row counts the model returns the state after
+        (:meth:`recurrent_finish`; 0: none).  Raw arrays."""
+        if self.mode != "prefill":
+            raise ValueError("a state group has no verify form")
+        return self.cache.recurrent_start(self.layer_idx, self.slot, seq_len)
+
+    def recurrent_finish(self, seq_len: int, last, kept) -> None:
+        """The state at the tail's real end (the slot's from now on) and at
+        :meth:`recurrent_start`'s ends (the planned snapshots)."""
+        self.cache.recurrent_finish(self.layer_idx, self.slot, seq_len, last,
+                                    kept)
+
+    def recurrent_step(self, step):
+        """A decode step of such a layer: ``step(state [slots, heads, d_k,
+        d_v], active [slots]) -> (out, state)`` rewrites the running slots'
+        state in place; returns ``out``."""
+        if self.mode != "decode":
+            raise ValueError("a state group has no verify form")
+        return self.cache.recurrent_step(self.layer_idx, step, self.active)
+
     def write_prefill(self, k, v) -> None:
         self.cache.prefill_write(self.layer_idx, self.slot, k, v,
                                  self._prefill_start())

@@ -73,7 +73,8 @@ def pool_programs(one_chip):
     once a module and a program compiled once."""
     from families import BY_KIND
     from paddle_tpu.core.autograd import no_grad
-    from paddle_tpu.models import (deepseek_v3, held_experts, keye_vl2, lfm2)
+    from paddle_tpu.models import (deepseek_v3, held_experts, keye_vl2,
+                                   kimi_linear, lfm2)
     from paddle_tpu.serving import Engine
 
     engines, compiled = {}, {}
@@ -101,7 +102,8 @@ def pool_programs(one_chip):
             assert eng.cache.begin_sequence(0, [], 0, bucket)
         try:
             with pytest.MonkeyPatch.context() as mp, no_grad():
-                for module in (deepseek_v3, held_experts, keye_vl2, lfm2):
+                for module in (deepseek_v3, held_experts, keye_vl2, kimi_linear,
+                               lfm2):
                     mp.setattr(module, "_interpret", lambda: False)
                 compiled[built, program] = compile_for(
                     one_chip, fn._fn, [paddle.to_tensor(a) for a in args])

@@ -512,6 +512,73 @@ FAMILIES = {f.name: f for f in (
             attention_layers=(2,),
             scoped=[f"{i}/conv/{part}" for i in (0, 1, 3) for part in (
                 "in_proj", "out_proj", "conv.mix", "state.write")])),
+    Family(
+        "kimi_linear", "kimi_linear", "KimiLinearForCausalLM",
+        "kimi_linear_tiny", "grouped_recurrent",
+        seed=2 ** 31 + 44, tokens=(160, 500), want=128, atol=2e-5,
+        kernel="reference", buckets=(16, 32, 64),
+        spec=lambda: CacheSpec.by_layer([
+            CacheGroup((3,), ((1, 40),), 0),
+            CacheGroup((0, 1, 2), ((3, 96), (2, 16, 16)), 0, True, 16, 64)],
+            kind="latent"),
+        sides=((1, 40),),
+        refuses={
+            "mesh": _STATE.format("KimiLinearForCausalLM")
+            + r"a serving mesh of more than one device \(the state "
+              r"and its snapshot pool are not sharded\)",
+            "speculation": _STATE.format("KimiLinearForCausalLM")
+            + r"speculation= \(the verify window has no state form\)"},
+        moe=lambda models: importlib.import_module(
+            "paddle_tpu.models.deepseek_v3").DeepseekV3MoE,
+        # Kimi-Linear-48B-A3B's widths (hidden 2304; KDA 32 heads of 128
+        # behind a 4-tap convolution; latent attention 512 + 64 stored in 640
+        # lanes under 32 heads of 128 + 64, nothing rotated; dense SwiGLU of
+        # 9,216, experts of 1,024 with 16 of 256 held and one shared; KDA,
+        # KDA, KDA, MLA: one dense and three expert layers, vocabulary cut to
+        # 8,192), 64 slots of 32,768 positions, block 16, a 2,305-block
+        # latent group (for ONE layer), and the state group's two sides: the
+        # shift side ``[3, 3, 64, 12288]`` with its snapshot pool ``[3, 3,
+        # 128, 12288]`` and, a buffer a layer, the recurrent side ``[64, 32,
+        # 128, 128]`` float32 with its snapshot pool ``[128, 32, 128, 128]``
+        # (268 MB a layer).  Nothing of the shift state's size or more moves
+        # that has a pool's, a state's or a snapshot pool's shape; all are
+        # aliased whole, but that a decode step takes no snapshot: neither
+        # snapshot pool is an operand of it.  The decode step's state kernel
+        # (``kda_decode_step``, one call a KDA layer) and the prefill's scan
+        # (``kda_chunk_prefill``) are under their layer's ``kda`` scope.
+        chip=dict(
+            config=dict(vocab_size=8192, num_hidden_layers=4,
+                        held_experts=(0, 16), max_position_embeddings=32768,
+                        dtype="bfloat16"),
+            engine=dict(num_slots=64, max_seq=32768, min_bucket=256,
+                        num_kv_blocks=2305, num_state_snapshots=128),
+            buffers=[(2305, 16, 1, 640), (3, 3, 64, 12288),
+                     (3, 3, 128, 12288)] + [(64, 32, 128, 128)] * 3
+            + [(128, 32, 128, 128)] * 3,
+            programs={"decode": None, "prefill": 256, "prefill-4096": 4096},
+            kernels={"decode": {"mla_paged_decode": (1, 1),
+                                "kda_decode_step": (3, 3),
+                                "moe_grouped_matmul": (6, 6)},
+                     "prefill": {"mla_paged_prefill": (1, 1),
+                                 "mla_flash_prefill": (1, 1),
+                                 "kda_chunk_prefill": (3, 3),
+                                 "moe_grouped_matmul": (6, 6)}},
+            moves=(3 * 3 * 64 * 12288 * 2,
+                   ("[2305,16,1,640]", "[3,3,64,12288]", "[3,3,128,12288]",
+                    "[64,32,128,128]", "[128,32,128,128]")),
+            alias=lambda cache, program: cache.nbytes() - (
+                cache.states[0].snapshots._value().nbytes
+                + sum(int(b._value().nbytes)
+                      for b in cache.states[0].recurrent_snapshots)
+                if program == "decode" else 0),
+            says={"decode": ("kv.write", "state.write", "kda.mix",
+                             "kda.step"),
+                  "prefill": ("kv.write", "state.write", "kda.mix",
+                              "kda.scan", "[128,32,128,128]")},
+            forbid={"decode": (r"\[128,32,128,128\]",
+                               r"\[3,3,128,12288\]")},
+            scoped=[f"{i}/kda/{part}" for i in (0, 1, 2) for part in (
+                "q_proj", "o_proj", "kda.mix", "state.write")])),
 )}
 
 

@@ -314,7 +314,8 @@ def test_a_deferred_admission_leaves_tables_and_refcounts(served):
     # all but a prompt's bucket of blocks held back (8 blocks a bucket of 64;
     # a cache by layer keeps one more to get, a windowed one a piece's window)
     pool = getattr(eng.cache, "pools", [eng.cache])[0].allocator
-    room = {"windowed": 11, "grouped_state": 9}.get(kind, 8)
+    room = {"windowed": 11, "grouped_state": 9,
+            "grouped_recurrent": 9}.get(kind, 8)
     held = pool.alloc(pool.free_blocks - room)
     try:
         first = eng.add_request(prompt_of(40, seed=1), max_new_tokens=8)
