@@ -8,10 +8,16 @@ Reference parity targets:
   projection).  On TPU the bottleneck is HBM, not the kernel launch: a GPT-2
   [B,S,V] logits tensor (B16 S1024 V50304) is 1.6 GB in bf16 and 3.3 GB as
   the f32 softmax temp — it caps the achievable batch and with it MFU.  This
-  op never materializes logits: it scans vocab blocks, keeping only f32
+  op never materializes logits: it walks vocab blocks, keeping only f32
   [N]-shaped running (max, sumexp, picked) statistics, and recomputes each
   block's logits in the backward (FLOPs ≈ 4/3 of the unfused head for >10×
-  less live memory).
+  less live memory).  The forward is one Pallas kernel on a TPU
+  (``ops/pallas/streamed_ce_kernel.py``: a tile of logits goes from the
+  MXU's accumulator to the statistics inside VMEM) and a ``lax.scan`` over
+  blocks elsewhere — the kernel's oracle; the backward is a scan everywhere.
+  The whole [N, V] logits reach HBM in neither; the scans write a block of
+  them at a time (134 MB at the shape above, read back twice by the
+  forward's scan: that, not the matmul, was what the kernel took away).
 - fused_feedforward / fused_bias_dropout_residual_layer_norm etc. are NOT
   ops here by design: XLA fuses those elementwise chains automatically
   (SURVEY.md §7) — the nn layers compose them and the compiler emits the
@@ -42,21 +48,32 @@ def _block_view(w, block: int):
     return w.reshape(nb, block, H), nb, pad
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flce(h2, w, labels, valid, block, compute_dtype):
-    loss, _ = _flce_fwd(h2, w, labels, valid, block, compute_dtype)
+def _forward_interpret(h2, w, compute_dtype):
+    """How the forward computes its statistics: ``None`` by the scan, else
+    the kernel's ``interpret`` — ``False`` on a TPU at shapes the kernel
+    takes (the repo's rule, ``ops/pallas/__init__.py``; a test that runs the
+    kernel on the CPU answers ``True`` here)."""
+    from .pallas import use_pallas
+    from .pallas.streamed_ce_kernel import supports
+
+    if use_pallas() and supports(*h2.shape, w.shape[0], compute_dtype):
+        return False
+    return None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _flce(h2, w, labels, valid, block, compute_dtype, interpret):
+    loss, _ = _flce_fwd(h2, w, labels, valid, block, compute_dtype, interpret)
     return loss
 
 
-def _flce_fwd(h2, w, labels, valid, block, compute_dtype):
-    """h2 [N,H] activations, w [V,H] vocab-major head weight, labels [N] int,
-    valid [N] bool → per-token f32 loss [N] (0 where invalid)."""
-    N, H = h2.shape
-    V = w.shape[0]
-    hc = h2.astype(compute_dtype)
-    wb, nb, pad = _block_view(w.astype(compute_dtype), block)
+def _scan_stats(hc, wc, lbl, block):
+    """``(lse, picked)`` of ``hc [N, H]`` against ``wc [V, H]``, a block of
+    the vocabulary a trip: the forward off a TPU, and the kernel's oracle."""
+    N = hc.shape[0]
+    V = wc.shape[0]
+    wb, nb, pad = _block_view(wc, block)
     offsets = jnp.arange(nb, dtype=jnp.int32) * block
-    lbl = labels.astype(jnp.int32)
 
     def body(carry, xs):
         m, s, picked = carry
@@ -80,12 +97,27 @@ def _flce_fwd(h2, w, labels, valid, block, compute_dtype):
     m0 = jnp.full((N,), -jnp.inf, jnp.float32)
     s0 = jnp.zeros((N,), jnp.float32)
     (m, s, picked), _ = jax.lax.scan(body, (m0, s0, m0), (wb, offsets))
-    lse = m + jnp.log(s)
+    return m + jnp.log(s), picked
+
+
+def _flce_fwd(h2, w, labels, valid, block, compute_dtype, interpret):
+    """h2 [N,H] activations, w [V,H] vocab-major head weight, labels [N] int,
+    valid [N] bool → per-token f32 loss [N] (0 where invalid)."""
+    hc = h2.astype(compute_dtype)
+    wc = w.astype(compute_dtype)
+    lbl = labels.astype(jnp.int32)
+    if interpret is None:
+        lse, picked = _scan_stats(hc, wc, lbl, block)
+    else:
+        from .pallas import streamed_ce_stats_on_mesh
+
+        lse, picked = streamed_ce_stats_on_mesh(hc, wc, lbl,
+                                                interpret=interpret)
     loss = jnp.where(valid, lse - picked, 0.0)
     return loss, (h2, w, lbl, valid, lse)
 
 
-def _flce_bwd(block, compute_dtype, res, g):
+def _flce_bwd(block, compute_dtype, interpret, res, g):
     h2, w, lbl, valid, lse = res
     N, H = h2.shape
     V = w.shape[0]
@@ -135,8 +167,10 @@ def fused_linear_cross_entropy(hidden, weight, label, loss_mask=None,
         label: [...] int token ids; ``ignore_index`` positions contribute 0
             loss and 0 gradient.
         loss_mask: optional [...] multiplicative mask.
-        block_size: vocab tile width; None reads PADDLE_TPU_FLCE_BLOCK
-            (default 2048) so the bench can sweep without code changes.
+        block_size: the scans' vocab tile width; None reads
+            PADDLE_TPU_FLCE_BLOCK (default 2048) so the bench can sweep
+            without code changes.  The TPU forward's kernel sizes its own
+            tiles from the operands' shapes.
     Returns:
         scalar mean loss over non-ignored (and mask-weighted) positions.
     """
@@ -161,7 +195,8 @@ def fused_linear_cross_entropy(hidden, weight, label, loss_mask=None,
         # wraps it (``jvp(loss.streamed_ce)``, ``transpose(jvp(...))``), so
         # every op of either pass carries the words in its op_name
         with jax.named_scope(CE_SCOPE):
-            loss = _flce(h2, w, safe, valid, int(block_size), cdt)  # [N] f32
+            loss = _flce(h2, w, safe, valid, int(block_size), cdt,
+                         _forward_interpret(h2, w, cdt))         # [N] f32
         if maybe_mask:
             mflat = maybe_mask[0].reshape(N).astype(jnp.float32)
             return jnp.sum(loss * mflat) / jnp.maximum(jnp.sum(mflat), 1.0)

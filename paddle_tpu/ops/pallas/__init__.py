@@ -28,9 +28,10 @@ ATTN_SCOPE_PALLAS = "attention.pallas_flash"
 ATTN_SCOPE_XLA = "attention.xla_sdpa"
 
 
-def per_shard(kernel, args, specs, mesh):
+def per_shard(kernel, args, specs, mesh, out=None):
     """Call ``kernel(*args)`` once per shard of ``mesh``; the result has
-    the shape and the spec of the first argument.
+    the shape and the spec of the first argument, or the specs that
+    ``out(specs as fitted)`` gives.
 
     GSPMD cannot partition a Mosaic kernel: any program over more than one
     device that contains one fails to lower with "Mosaic kernels cannot be
@@ -57,7 +58,8 @@ def per_shard(kernel, args, specs, mesh):
         fitted.append(P(*(e if _divisible((d,), P(e), mesh) else None
                           for d, e in zip(a.shape, spec))))
     return jax.shard_map(kernel, mesh=mesh, in_specs=tuple(fitted),
-                         out_specs=fitted[0], check_vma=False)(*args)
+                         out_specs=out(fitted) if out else fitted[0],
+                         check_vma=False)(*args)
 
 
 @functools.lru_cache(maxsize=1)
@@ -119,6 +121,24 @@ def flash_qkv_on_mesh(qkv, causal, interpret=False):
 
     return _flash_per_shard(flash_attention_fused_qkv, (qkv,), causal,
                             interpret)
+
+
+def streamed_ce_stats_on_mesh(h, w, labels, interpret=False):
+    """The streamed CE's forward kernel over ``h [N, H]``, ``w [V, H]`` and
+    ``labels [N]`` → ``(lse, picked)``, ``[N]`` each: one call per shard of
+    the global mesh, the rows split over its data axes, the weight whole on
+    every shard — see :func:`per_shard`."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed import mesh as _mesh_mod
+    from ...distributed.sharding_spec import BATCH_AXES
+    from .streamed_ce_kernel import streamed_ce_stats
+
+    return per_shard(
+        functools.partial(streamed_ce_stats, interpret=interpret),
+        (h, w, labels), (P(BATCH_AXES, None), P(None, None), P(BATCH_AXES)),
+        _mesh_mod.get_global_mesh(),
+        out=lambda fitted: (P(fitted[0][0]),) * 2)
 
 
 def _ring_mesh(q_len, k_len):

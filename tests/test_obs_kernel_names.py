@@ -182,3 +182,75 @@ def test_paged_kernels_are_named_and_decode_is_still_told_by_its_operands(
             if any(re.search(p, ln) for p in kc.PATTERNS)]
     assert len(told) == 2
     assert all(ln.startswith("%paged_decode_attention.") for ln in told)
+
+
+def test_the_streamed_ces_forward_is_one_kernel_under_its_scope(one_chip):
+    """The fused loss at the train cell's widths (rows 16,384, hidden 1,024,
+    vocabulary 50,304, bf16), forward and with its backward, compiled for the
+    described v5e as a TPU traces it: the forward's statistics are one
+    custom call, named, under ``loss.streamed_ce`` forward — where
+    ``ce_device_ms`` looks, by the parts of an instruction's scope — with no
+    ``while`` beside it; the backward keeps its loop; the whole fits the chip
+    many times over.  The same call over a data mesh of the host's four
+    chips sits in a ``shard_map``, a shard's rows a chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.obs import hlo_cost
+    from paddle_tpu.ops import fused
+    from paddle_tpu.ops.pallas import streamed_ce_kernel as ck
+
+    N, H, V = 16384, 1024, 50304
+    assert ck.supports(N, H, V, jnp.bfloat16)
+    assert ck.plan(N, H, V, 2) == (ck.ROW_TILE, ck.VOCAB_TILE, ck.VOCAB_SUB)
+
+    def loss(h, w, lbl):
+        with jax.named_scope(fused.CE_SCOPE):
+            return fused._flce(h, w, lbl, lbl >= 0, 2048, jnp.bfloat16,
+                               False).sum()
+
+    def step(h, w, lbl):
+        return jax.value_and_grad(loss, argnums=(0, 1))(h, w, lbl)
+
+    def shapes(rows, sharding):
+        return (jax.ShapeDtypeStruct((rows, H), jnp.bfloat16,
+                                     sharding=sharding(P("data", None))),
+                jax.ShapeDtypeStruct((V, H), jnp.bfloat16,
+                                     sharding=sharding(P())),
+                jax.ShapeDtypeStruct((rows,), jnp.int32,
+                                     sharding=sharding(P("data"))))
+
+    for fn, loops in ((loss, 0), (step, 1)):
+        compiled = jax.jit(fn).lower(*shapes(N, lambda _s: one_chip)).compile()
+        (kernel,) = custom_call_lines(compiled)
+        name = kernel.split(" = ")[0].lstrip("%")
+        assert re.sub(r"\.\d+$", "", name) == ck.FWD_NAME
+        assert not any(re.search(p, kernel)
+                       for p in load_patterns("flash_attention").PATTERNS)
+        hlo = compiled.as_text()
+        got = hlo_cost.scope_map(hlo)["instructions"]
+        assert got[name] == (fused.CE_SCOPE + "/" + ck.FWD_NAME, "fwd")
+        assert fused.CE_SCOPE in got[name][0].split("/")   # the reader's rule
+        whiles = [got[r[1]] for r in hlo_cost.instructions(hlo)
+                  if r[2] == "while"]
+        assert whiles == [(fused.CE_SCOPE, "bwd")] * loops
+        mem = compiled.memory_analysis()
+        # no block of logits: the forward alone holds nothing, the step the
+        # backward's one float32 block (134 MB) and its bf16 copy
+        assert mem.temp_size_in_bytes < (1 << 20 if fn is loss else 256 << 20)
+
+    chips = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2").devices
+    mesh = mesh_mod.hybrid_mesh(dp=4, devices=chips)
+    mesh_mod.set_global_mesh(mesh)
+    try:
+        compiled = jax.jit(step).lower(*shapes(
+            4 * N, lambda spec: NamedSharding(mesh, spec))).compile()
+    finally:
+        mesh_mod.set_global_mesh(None)
+    (kernel,) = custom_call_lines(compiled)
+    assert kernel.startswith("%" + ck.FWD_NAME)
+    assert f"bf16[{N},{H}]" in kernel and f"bf16[{V},{H}]" in kernel
